@@ -2,7 +2,7 @@
 
 PYTHON ?= python
 
-.PHONY: help test test-fast chaos lint-invariants native bench bench-serving bench-serve bench-fleet bench-train bench-attn bench-autoscale bench-lora bench-canary bench-goodput bench-reqtrace bench-elastic bench-prefill bench-fleet-elastic bench-reconcile bench-kv-tier bench-failslow bench-spec bench-index obs-smoke dryrun clean
+.PHONY: help test test-fast chaos lint-invariants native bench bench-serve bench-fleet bench-train bench-attn bench-autoscale bench-lora bench-canary bench-goodput bench-reqtrace bench-elastic bench-prefill bench-fleet-elastic bench-reconcile bench-kv-tier bench-failslow bench-spec bench-index obs-smoke dryrun clean
 
 help:            ## list targets with their one-line descriptions
 	@grep -E '^[a-z][a-zA-Z_-]*:.*##' $(MAKEFILE_LIST) | \
@@ -29,11 +29,8 @@ lint-invariants: ## mlt-lint: AST invariant checker over the package (docs/stati
 native:          ## build the C++ log collector (mlt-logd)
 	$(MAKE) -C native
 
-bench:           ## training benchmark (one JSON line)
+bench:           ## training benchmark on a TPU (one JSON line; exits non-zero without a chip)
 	$(PYTHON) bench.py
-
-bench-serving:   ## serving TTFT benchmark (one JSON line)
-	$(PYTHON) scripts/bench_serving.py
 
 bench-serve:     ## prefix-cache / chunked-prefill microbench, CPU-runnable (one JSON line)
 	JAX_PLATFORMS=cpu $(PYTHON) bench_serve.py

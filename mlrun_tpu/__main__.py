@@ -251,7 +251,12 @@ def db(port, host):
 def serve(port, host, func_url):
     """Start a serving-graph gateway (SERVING_SPEC_ENV or --function)."""
     from .serving.asgi import serve as serve_graph
+    from .utils import compile_cache
 
+    # engines compile a program per prefill bucket and decode variant at
+    # load: keep them across restarts (JAX_COMPILATION_CACHE_DIR, else
+    # <checkout>/.jax_cache)
+    compile_cache.configure_default()
     function = None
     if func_url:
         from .run import import_function

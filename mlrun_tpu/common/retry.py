@@ -19,7 +19,7 @@ from ..config import mlconf
 
 
 class FailureClass:
-    """Coarse failure taxonomy recorded on ``status.failure_class``."""
+    """Coarse failure classes recorded on ``status.failure_class``."""
 
     # retryable infra faults
     preemption = "preemption"                  # spot/preemptible eviction

@@ -98,14 +98,6 @@ def make_context_parallel_loss(config: LlamaConfig, mesh: Mesh,
     """
     data_axes = tuple(data_axes or ())
     manual = frozenset({seq_axis, *data_axes})
-    if not hasattr(jax, "shard_map"):
-        # legacy (jax.experimental) shard_map cannot lower axis_index /
-        # ring collectives while another mesh axis stays auto (the SPMD
-        # partitioner rejects the PartitionId it emits) — go full-manual
-        # over every mesh axis instead; axes the specs leave unmentioned
-        # ride replicated, which is exactly the partial-manual semantics
-        # for the batch dim here
-        manual = frozenset(mesh.axis_names)
     batch_spec = tuple(data_axes) or None
     data_spec = P(batch_spec, seq_axis)
 
@@ -131,8 +123,8 @@ def make_context_parallel_loss(config: LlamaConfig, mesh: Mesh,
         if not data_axes:
             # pin the auto (batch) axes replicated: GSPMD may otherwise
             # pick a sharding the out_specs (manual axes only) cannot
-            # express. NamedSharding (not a bare spec): legacy jax builds
-            # require a mesh context for PartitionSpec constraints.
+            # express. NamedSharding (not a bare spec): a bare
+            # PartitionSpec constraint needs a mesh context.
             nll = jax.lax.with_sharding_constraint(
                 nll, NamedSharding(mesh, P(None, None)))
         return nll

@@ -58,5 +58,6 @@ def rms_norm_pallas(x: jax.Array, scale: jax.Array, eps: float = 1e-5,
         ],
         out_specs=pl.BlockSpec((block_rows, features), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
+        name="rms_norm",
     )(x2, scale)
     return out.reshape(orig_shape)

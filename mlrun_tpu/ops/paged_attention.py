@@ -5,7 +5,7 @@ The paged engine (serving/paged.py) historically materialized a dense
 the page table) and ran plain masked attention on it — HBM traffic on the
 order of the whole cache for every generated token. This module computes
 the same attention by indexing the page pool THROUGH the page table inside
-a Pallas kernel: each grid step DMAs exactly one (page, kv-head) tile from
+a Pallas kernel: each grid step DMAs exactly one physical page from
 HBM into VMEM, so the bytes read per tick are the slot's *live* pages once
 — never a gathered copy of the full view.
 
@@ -22,34 +22,36 @@ Layout (one layer of the pool, see serving/paged.py):
 - pos:        [slots] int32 absolute position of the current token
   (valid cache length is ``pos + 1``).
 
-Grid ``(slots, kv_heads, pages_per_slot)``: for a fixed (slot, kv head)
-the kernel streams that slot's pages in order, carrying the online-softmax
-running max/denominator/accumulator for the head's GQA query group in VMEM
-scratch — the same accumulation scheme as the verified flash_v2 kernel
-(ops/attention.py), so numerics match the dense reference to float32
-round-off. The page table and positions ride scalar prefetch
-(``PrefetchScalarGridSpec``) because the k/v BlockSpec index maps need
-them to translate (slot, page-slot) -> physical page id before the DMA.
+Grid ``(slots, pages_per_slot)``: for a fixed slot the kernel streams
+that slot's pages in order — all kv heads of a page per step, which is
+the blocking the TPU lowering accepts for this pool layout — carrying
+the online-softmax running max/denominator/accumulator of every GQA
+query group in VMEM scratch — the same accumulation scheme as the
+verified flash_v2 kernel (ops/attention.py), so numerics match the dense
+reference to float32 round-off. The page table and positions ride scalar
+prefetch (``PrefetchScalarGridSpec``) because the k/v BlockSpec index
+maps need them to translate (slot, page-slot) -> physical page id before
+the DMA.
 
 Beyond decode, this module carries the other two KV-heavy moments of the
-serving path (docs/serving.md "Attention kernels"):
+serving path (docs/serving.md "Attention kernels"), both on ONE
+multi-row chunk kernel (``_paged_chunk_call``: per-row page ids and
+per-row ``base`` on scalar prefetch, grid (row, q_block, page), emitting
+a partial softmax state ``(o, lse)`` that ``merge_softmax_states``
+LSE-merges with the chunk's local causal part):
 
-- **multi-row paged prefill** (``_paged_prefill_call`` /
+- **multi-row paged prefill** (``paged_prefix_part`` /
   ``paged_prefill_attention``): on a prefix-cache hit, a chunk of query
-  tokens attends the ``base`` cached prefix tokens IN PLACE through the
-  same page-table-indexed BlockSpec design (page ids + base on scalar
-  prefetch, grid (kv_head, q_block, page)), emitting a partial softmax
-  state ``(o, lse)`` that ``merge_softmax_states`` LSE-merges with the
-  local causal flash over the suffix — the admission-time dense
-  ``gather_prefix_pages`` copy becomes the CPU/reference fallback only.
-- **batched speculative verify** (``_paged_verify_call`` /
-  ``paged_verify_attention``): the in-engine speculative-decoding verify
-  dispatch (docs/serving.md "Speculative decoding") — every decode
-  slot's (k+1)-token chunk attends its own prefix pages in place
-  through the page table (per-row page ids AND per-row ``base`` on
-  scalar prefetch), merged with the chunk's local causal part. The
-  verify chunk is the prefill kernel's q-chunk form, batched per slot;
-  ``paged_verify_reference`` is the gather+dense fallback.
+  tokens attends the ``base`` cached prefix tokens IN PLACE (one row),
+  merged with the local causal flash over the suffix — the
+  admission-time dense ``gather_prefix_pages`` copy becomes the
+  CPU/reference fallback only.
+- **batched speculative verify** (``paged_verify_attention``): the
+  in-engine speculative-decoding verify dispatch (docs/serving.md
+  "Speculative decoding") — every decode slot's (k+1)-token chunk
+  attends its own prefix pages in place (one row per slot), merged with
+  the chunk's closed-form causal part; ``paged_verify_reference`` is
+  the gather+dense fallback.
 - **int8 KV pages**: all kernels take optional per-vector f32 dequant
   scales riding the same page-table-indexed operands as the pages, so a
   ``kv_dtype="int8"`` pool (double the resident pages per HBM byte)
@@ -59,8 +61,6 @@ Dispatch mirrors ``ops.attention.attention``: ``resolve_paged_impl``
 picks the kernel on TPU, the gather+dense reference on CPU — unless
 interpret mode is forced (``MLT_ATTN_INTERPRET=1``), which runs the real
 kernel code path under the Pallas interpreter so tier-1 exercises it.
-An EXPLICIT kernel request that cannot be honored raises the typed
-:class:`KernelUnavailableError` instead of silently downgrading.
 """
 
 from __future__ import annotations
@@ -69,49 +69,27 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .attention import (
     NEG_INF,
     _fit_block,
     _flash_fwd_v2_cached_bounded,
     _on_tpu,
-    _PALLAS_OK,
     _repeat_kv,
+    interpret_default,
     interpret_forced,
 )
-
-if _PALLAS_OK:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-
-class KernelUnavailableError(ValueError):
-    """An EXPLICIT ``attention_impl="kernel"``/``"flash"`` request cannot
-    be honored (Pallas missing from the jax build). Raised at engine
-    construction — a silent downgrade would quietly serve on the
-    reference path while the operator believes the kernel is live.
-    ``auto`` may still fall back (warned once)."""
-
-
-_warned_auto_fallback = False
 
 
 def resolve_paged_impl(impl: str = "auto") -> str:
     """Resolve a serving ``attention_impl`` knob to the paged-decode path:
     ``kernel`` (Pallas, page-table indexed) or ``reference``
     (gather+dense). ``flash`` counts as an explicit kernel opt-in;
-    ``dense`` as an explicit reference opt-in. Explicit kernel requests
-    that cannot be honored raise :class:`KernelUnavailableError` —
-    ``auto`` falls back to the reference (warned once when the fallback
-    is a missing Pallas rather than the normal CPU default)."""
-    global _warned_auto_fallback
-
+    ``dense`` as an explicit reference opt-in. ``auto`` is the kernel on
+    a TPU (or under forced interpret mode), the reference elsewhere."""
     if impl in ("kernel", "flash"):
-        if not _PALLAS_OK:
-            raise KernelUnavailableError(
-                f"attention_impl='{impl}' requested but Pallas is "
-                "unavailable in this jax build — use 'auto' (falls back "
-                "to the gather+dense reference) or 'reference'")
         return "kernel"
     if impl in ("reference", "dense"):
         return "reference"
@@ -119,72 +97,80 @@ def resolve_paged_impl(impl: str = "auto") -> str:
         raise ValueError(
             f"unknown paged attention impl '{impl}' "
             "(auto | flash | kernel | reference | dense)")
-    if not _PALLAS_OK:
-        if not _warned_auto_fallback:
-            _warned_auto_fallback = True
-            from ..utils import logger
-
-            logger.warning(
-                "paged attention: Pallas unavailable — attention_impl "
-                "'auto' resolves to the gather+dense reference path")
-        return "reference"
     if _on_tpu() or interpret_forced():
         return "kernel"
     return "reference"
 
 
 # ---------------------------------------------------------------------------
-# pallas kernel
+# pallas kernels
 # ---------------------------------------------------------------------------
+#
+# Blocking (what the TPU lowering accepts — tests/test_tpu_compile.py): a
+# block's last two dims must be (8, 128)-aligned or span the array's, so
+# every kernel takes ALL kv heads of a page per grid step (``[1,
+# page_size, Hkv, D]`` — the pool keeps its layout) and loops the GQA
+# groups in the body, reading head ``h`` as ``k_ref[0, :, h, :]``.
 
-def _decode_page_update(q_ref, k, v, m_scr, l_scr, acc_scr, *,
-                        p, pos, page_size: int, scale: float):
-    """Shared online-softmax update over one (already dequantized) page
-    tile — the native and int8 decode kernels differ only in how k/v
-    reach f32."""
-    n_rep = q_ref.shape[1]
-    q = q_ref[0].astype(jnp.float32) * scale              # [n_rep, d]
-    logits = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-    k_pos = p * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (n_rep, page_size), 1)
-    logits = jnp.where(k_pos <= pos, logits, NEG_INF)
-    m_prev = m_scr[:]
-    m_cur = jnp.max(logits, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    weight = jnp.exp(logits - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_scr[:] = l_scr[:] * alpha + jnp.sum(weight, axis=-1,
-                                          keepdims=True)
-    acc_scr[:] = acc_scr[:] * alpha + jnp.dot(
-        weight, v, preferred_element_type=jnp.float32)
-    m_scr[:] = m_new
+def _attend_page(q_ref, k_ref, v_ref, scale_refs, m_scr, l_scr, acc_scr,
+                 *, p, limit, page_size: int, scale: float):
+    """Online-softmax update of every GQA query group over the resident
+    page: q_ref [1, Hkv, rows, d], k/v [1, page_size, Hkv, d];
+    positions at or past ``limit`` are masked. ``scale_refs`` (int8
+    pools) are the per-vector f32 dequant scales ([1, page_size, Hkv])
+    riding the same page-table-indexed blocks — dequantization happens
+    in-register, everything else is one code path. The scratch refs
+    ([Hkv, rows, 1|d]) carry each group's running
+    max/denominator/accumulator across the page-slot grid dim."""
+    for h in range(k_ref.shape[2]):
+        k = k_ref[0, :, h, :].astype(jnp.float32)       # [page_size, d]
+        v = v_ref[0, :, h, :].astype(jnp.float32)
+        if scale_refs:
+            ks_ref, vs_ref = scale_refs
+            k = k * ks_ref[0, :, h:h + 1]
+            v = v * vs_ref[0, :, h:h + 1]
+        q = q_ref[0, h].astype(jnp.float32) * scale     # [rows, d]
+        logits = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+        k_pos = p * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, logits.shape, 1)
+        logits = jnp.where(k_pos < limit, logits, NEG_INF)
+        m_prev = m_scr[h]
+        m_cur = jnp.max(logits, axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        weight = jnp.exp(logits - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[h] = l_scr[h] * alpha + jnp.sum(weight, axis=-1,
+                                              keepdims=True)
+        acc_scr[h] = acc_scr[h] * alpha + jnp.dot(
+            weight, v, preferred_element_type=jnp.float32)
+        m_scr[h] = m_new
+
+
+def _reset_softmax_state(m_scr, l_scr, acc_scr):
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
 
 
 def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *refs,
                          page_size: int, pages_per_slot: int,
                          scale: float, quantized: bool):
-    """Grid (slot, kv_head, page-slot); refs:
-    q [1, n_rep, d] (this kv head's GQA query group), k/v [1, page_size,
-    1, d] (the physical page the index map resolved via the page table).
-    Scratch carries the online softmax across the page-slot grid dim.
+    """Grid (slot, page-slot); refs: q [1, Hkv, n_rep, d] (the slot's
+    token, heads grouped per kv head), k/v [1, page_size, Hkv, d] (the
+    physical page the index map resolved via the page table). Scratch
+    ([Hkv, n_rep, 1|d]) carries each group's online softmax across the
+    page-slot grid dim.
 
     ``quantized`` (static) inserts two extra refs after v: the int8
-    pool's per-vector f32 dequant scales (ks/vs [1, page_size, 1]),
-    riding the SAME page-table-indexed BlockSpecs as the pages —
-    dequantization happens in-register, everything else is one code
-    path."""
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs
-    else:
-        o_ref, m_scr, l_scr, acc_scr = refs
+    pool's dequant scales (see :func:`_attend_page`)."""
+    scale_refs, (o_ref, m_scr, l_scr, acc_scr) = (
+        (refs[:2], refs[2:]) if quantized else ((), refs))
     s = pl.program_id(0)
-    p = pl.program_id(2)
+    p = pl.program_id(1)
 
     @pl.when(p == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        _reset_softmax_state(m_scr, l_scr, acc_scr)
 
     pos = pos_ref[s]
     # pages wholly past the current position contribute nothing — skip the
@@ -194,14 +180,9 @@ def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *refs,
 
     @pl.when(live)
     def _compute():
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # [page_size, d]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if quantized:
-            k = k * ks_ref[0, :, 0][:, None]
-            v = v * vs_ref[0, :, 0][:, None]
-        _decode_page_update(q_ref, k, v, m_scr, l_scr, acc_scr,
-                            p=p, pos=pos, page_size=page_size,
-                            scale=scale)
+        _attend_page(q_ref, k_ref, v_ref, scale_refs, m_scr, l_scr,
+                     acc_scr, p=p, limit=pos + 1, page_size=page_size,
+                     scale=scale)
 
     @pl.when(p == pages_per_slot - 1)
     def _finalize():
@@ -218,7 +199,7 @@ def _paged_decode_call(q, k_pages, v_pages, page_table, pos,
     ``k_scale``/``v_scale`` ([P+1, page_size, Hkv] f32) select the int8
     kernel: pages are dequantized per vector inside the kernel."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_default()
     slots, h, d = q.shape
     hkv = k_pages.shape[2]
     n_rep = h // hkv
@@ -234,211 +215,184 @@ def _paged_decode_call(q, k_pages, v_pages, page_table, pos,
         _paged_decode_kernel, page_size=page_size,
         pages_per_slot=pages_per_slot, scale=scale, quantized=quantized)
 
-    def q_map(s, h_, p, pt, ps):
-        return (s, h_, 0)
+    def q_map(s, p, pt, ps):
+        return (s, 0, 0, 0)
 
-    def kv_map(s, h_, p, pt, ps):
-        return (pt[s, p], 0, h_, 0)
+    def kv_map(s, p, pt, ps):
+        return (pt[s, p], 0, 0, 0)
 
-    def sc_map(s, h_, p, pt, ps):
-        return (pt[s, p], 0, h_)
+    def sc_map(s, p, pt, ps):
+        return (pt[s, p], 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, n_rep, d), q_map),
-        pl.BlockSpec((1, page_size, 1, d), kv_map),
-        pl.BlockSpec((1, page_size, 1, d), kv_map),
+        pl.BlockSpec((1, hkv, n_rep, d), q_map),
+        pl.BlockSpec((1, page_size, hkv, d), kv_map),
+        pl.BlockSpec((1, page_size, hkv, d), kv_map),
     ]
-    operands = [safe_table, pos, q, k_pages, v_pages]
+    # heads h*n_rep..(h+1)*n_rep are kv head h's GQA group (matches
+    # _repeat_kv order), so grouping q per kv head is a free reshape
+    operands = [safe_table, pos, q.reshape(slots, hkv, n_rep, d),
+                k_pages, v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec((1, page_size, 1), sc_map),
-                     pl.BlockSpec((1, page_size, 1), sc_map)]
+        in_specs += [pl.BlockSpec((1, page_size, hkv), sc_map),
+                     pl.BlockSpec((1, page_size, hkv), sc_map)]
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(slots, hkv, pages_per_slot),
+        grid=(slots, pages_per_slot),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, n_rep, d), q_map),
+        out_specs=pl.BlockSpec((1, hkv, n_rep, d), q_map),
         scratch_shapes=[
-            pltpu.VMEM((n_rep, 1), jnp.float32),   # running max
-            pltpu.VMEM((n_rep, 1), jnp.float32),   # running denom
-            pltpu.VMEM((n_rep, d), jnp.float32),   # accumulator
+            pltpu.VMEM((hkv, n_rep, 1), jnp.float32),   # running max
+            pltpu.VMEM((hkv, n_rep, 1), jnp.float32),   # running denom
+            pltpu.VMEM((hkv, n_rep, d), jnp.float32),   # accumulator
         ],
     )
-    # q reshaped so the head dim blocks by kv-head group: heads h*n_rep..
-    # (h+1)*n_rep are kv head h's GQA group (matches _repeat_kv order)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((slots, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((slots, hkv, n_rep, d), q.dtype),
         interpret=interpret,
+        name="paged_decode",
     )(*operands)
+    return out.reshape(slots, h, d)
 
 
 # ---------------------------------------------------------------------------
-# multi-row paged prefill: a prompt chunk over shared prefix pages in place
+# multi-row chunks over pool pages in place: prefix-hit prefill (one
+# admission's prompt chunk) and speculative verify (a chunk per slot)
 # ---------------------------------------------------------------------------
 
-def _prefill_page_update(q_ref, k, v, m_scr, l_scr, acc_scr, *,
-                         p, base, page_size: int, scale: float):
-    """Shared prefill online-softmax update over one (already
-    dequantized) prefix page tile — positions at or past ``base`` are
-    masked; no causal mask (every prefix position precedes every query
-    row)."""
-    block_rows = q_ref.shape[1]
-    q = q_ref[0].astype(jnp.float32) * scale          # [block_rows, d]
-    logits = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-    k_pos = p * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (block_rows, page_size), 1)
-    logits = jnp.where(k_pos < base, logits, NEG_INF)
-    m_prev = m_scr[:]
-    m_cur = jnp.max(logits, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    weight = jnp.exp(logits - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_scr[:] = l_scr[:] * alpha + jnp.sum(weight, axis=-1,
-                                          keepdims=True)
-    acc_scr[:] = acc_scr[:] * alpha + jnp.dot(
-        weight, v, preferred_element_type=jnp.float32)
-    m_scr[:] = m_new
-
-
-def _paged_prefill_kernel(ids_ref, base_ref, q_ref, k_ref, v_ref, *refs,
-                          page_size: int, pages_per_slot: int,
-                          scale: float, quantized: bool):
-    """Grid (kv_head, q_block, page-slot); refs:
-    q [1, block_rows, d] (this kv head's GQA query rows, rows = token x
-    n_rep), k/v [1, page_size, 1, d] — the physical page the index map
-    resolved through the slot's page ids. Every prefix position
-    (0..base-1) precedes every query row, so no causal mask is needed;
-    pages at or past ``base`` (and -1 entries, routed to the scratch
-    page) are masked out wholesale. Scratch carries the online softmax
+def _paged_chunk_kernel(ids_ref, base_ref, q_ref, k_ref, v_ref, *refs,
+                        page_size: int, pages_per_slot: int,
+                        scale: float, quantized: bool):
+    """Grid (row, q_block, page-slot); refs: q [1, Hkv, block_rows, d]
+    (row ``r``'s chunk, rows = token x n_rep grouped per kv head), k/v
+    [1, page_size, Hkv, d] — the physical page the index map resolved
+    through row ``r``'s page ids. Every prefix position (0..base[r]-1)
+    precedes every query row, so no causal mask is needed; pages at or
+    past ``base[r]`` (and -1 entries, routed to the scratch page) are
+    masked out wholesale. Scratch carries each group's online softmax
     across the page-slot grid dim; the finalize step emits (o, lse) so
-    the caller can LSE-merge with the local causal flash over the
-    suffix chunk.
+    the caller can LSE-merge with the chunk's local causal part.
 
     ``quantized`` (static) inserts two extra refs after v: the int8
-    pool's per-vector f32 dequant scales (ks/vs [1, page_size, 1]) on
-    the same page-table-indexed BlockSpecs, dequantized in-register —
-    one code path for both pool dtypes."""
-    if quantized:
-        ks_ref, vs_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
-    else:
-        o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
+    pool's dequant scales (see :func:`_attend_page`)."""
+    scale_refs, (o_ref, lse_ref, m_scr, l_scr, acc_scr) = (
+        (refs[:2], refs[2:]) if quantized else ((), refs))
+    r = pl.program_id(0)
     p = pl.program_id(2)
 
     @pl.when(p == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        _reset_softmax_state(m_scr, l_scr, acc_scr)
 
-    base = base_ref[0]
-    block_rows = q_ref.shape[1]
+    base = base_ref[r]
     live = p * page_size < base
 
     @pl.when(live)
     def _compute():
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # [page_size, d]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if quantized:
-            k = k * ks_ref[0, :, 0][:, None]
-            v = v * vs_ref[0, :, 0][:, None]
-        _prefill_page_update(q_ref, k, v, m_scr, l_scr, acc_scr,
-                             p=p, base=base, page_size=page_size,
-                             scale=scale)
+        _attend_page(q_ref, k_ref, v_ref, scale_refs, m_scr, l_scr,
+                     acc_scr, p=p, limit=base, page_size=page_size,
+                     scale=scale)
 
     @pl.when(p == pages_per_slot - 1)
     def _finalize():
         l = jnp.maximum(l_scr[:], 1e-30)
         o_ref[0] = acc_scr[:] / l
         lse_ref[0] = jnp.broadcast_to(m_scr[:] + jnp.log(l),
-                                      (block_rows, 8))
+                                      lse_ref.shape[1:])
 
 
-@functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
-def _paged_prefill_call(q, k_pages, v_pages, page_ids, base,
-                        page_size: int, k_scale=None, v_scale=None,
-                        interpret=None):
-    """q [S, H, D] (one admission's prompt chunk, batch=1) attends over
-    the ``base`` prefix tokens stored in pool pages ``page_ids``
-    ([pages_per_slot] int32, -1 past the prefix → scratch page) —
-    in place, through the page table, never gathered. Returns
-    (o [S, H, D] f32, lse [S, H] f32) — one partial softmax state per
-    query row, LSE-merged by the caller with the local causal flash over
-    the suffix (``merge_softmax_states``)."""
+@functools.partial(jax.jit,
+                   static_argnames=("page_size", "interpret", "name"))
+def _paged_chunk_call(q, k_pages, v_pages, page_table, base,
+                      page_size: int, k_scale=None, v_scale=None,
+                      interpret=None, name: str = "paged_verify"):
+    """q [R, S, H, D] (a chunk of S query tokens per row) attends each
+    row's prefix tokens 0..base[r]-1 IN PLACE through ``page_table``
+    ([R, pages_per_slot] int32, -1 past the prefix → scratch page) —
+    never gathered. Returns (o [R, S, H, D] f32, lse [R, H, S] f32)
+    partial softmax states in the flash lse layout, ready for
+    :func:`merge_softmax_states` with the chunk's local causal part.
+    ``name`` labels the kernel in a device trace: the prefix-hit prefill
+    (R = 1) and the speculative verify (R = slots) are one kernel."""
     if interpret is None:
-        interpret = not _on_tpu()
-    s, h, d = q.shape
+        interpret = interpret_default()
+    r_, s, h, d = q.shape
     hkv = k_pages.shape[2]
     n_rep = h // hkv
-    pages_per_slot = page_ids.shape[0]
+    pages_per_slot = page_table.shape[1]
     scale = d ** -0.5
     scratch_page = k_pages.shape[0] - 1
-    safe_ids = jnp.where(page_ids >= 0, page_ids,
-                         scratch_page).astype(jnp.int32)
-    base = jnp.asarray(base, jnp.int32).reshape(1)
+    safe_table = jnp.where(page_table >= 0, page_table,
+                           scratch_page).astype(jnp.int32)
+    base = base.astype(jnp.int32)
     quantized = k_scale is not None
 
     # rows grouped per kv head (head h*n_rep+r is kv head h's GQA group,
-    # matching _repeat_kv order): [S, H, D] -> [Hkv, S*n_rep, D]
+    # matching _repeat_kv order): [R, S, H, D] -> [R, Hkv, S*n_rep, D]
     rows = s * n_rep
-    qg = q.reshape(s, hkv, n_rep, d).transpose(1, 0, 2, 3).reshape(
-        hkv, rows, d)
+    qg = q.reshape(r_, s, hkv, n_rep, d).transpose(
+        0, 2, 1, 3, 4).reshape(r_, hkv, rows, d)
     block_rows = _fit_block(rows, 256)
     pad_rows = (-rows) % block_rows
     if pad_rows:
-        qg = jnp.pad(qg, ((0, 0), (0, pad_rows), (0, 0)))
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, pad_rows), (0, 0)))
     padded_rows = rows + pad_rows
 
     kernel = functools.partial(
-        _paged_prefill_kernel, page_size=page_size,
+        _paged_chunk_kernel, page_size=page_size,
         pages_per_slot=pages_per_slot, scale=scale, quantized=quantized)
 
-    def q_map(h_, qb, p, ids, b):
-        return (h_, qb, 0)
+    def q_map(r, qb, p, ids, b):
+        return (r, 0, qb, 0)
 
-    def kv_map(h_, qb, p, ids, b):
-        return (ids[p], 0, h_, 0)
+    def kv_map(r, qb, p, ids, b):
+        return (ids[r, p], 0, 0, 0)
 
-    def sc_map(h_, qb, p, ids, b):
-        return (ids[p], 0, h_)
+    def sc_map(r, qb, p, ids, b):
+        return (ids[r, p], 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, block_rows, d), q_map),
-        pl.BlockSpec((1, page_size, 1, d), kv_map),
-        pl.BlockSpec((1, page_size, 1, d), kv_map),
+        pl.BlockSpec((1, hkv, block_rows, d), q_map),
+        pl.BlockSpec((1, page_size, hkv, d), kv_map),
+        pl.BlockSpec((1, page_size, hkv, d), kv_map),
     ]
-    operands = [safe_ids, base, qg, k_pages, v_pages]
+    operands = [safe_table, base, qg, k_pages, v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec((1, page_size, 1), sc_map),
-                     pl.BlockSpec((1, page_size, 1), sc_map)]
+        in_specs += [pl.BlockSpec((1, page_size, hkv), sc_map),
+                     pl.BlockSpec((1, page_size, hkv), sc_map)]
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(hkv, padded_rows // block_rows, pages_per_slot),
+        grid=(r_, padded_rows // block_rows, pages_per_slot),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_rows, d), q_map),
-            pl.BlockSpec((1, block_rows, 8), q_map),
+            pl.BlockSpec((1, hkv, block_rows, d), q_map),
+            pl.BlockSpec((1, hkv, block_rows, 8), q_map),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_rows, 1), jnp.float32),   # running max
-            pltpu.VMEM((block_rows, 1), jnp.float32),   # running denom
-            pltpu.VMEM((block_rows, d), jnp.float32),   # accumulator
+            pltpu.VMEM((hkv, block_rows, 1), jnp.float32),  # running max
+            pltpu.VMEM((hkv, block_rows, 1), jnp.float32),  # running denom
+            pltpu.VMEM((hkv, block_rows, d), jnp.float32),  # accumulator
         ],
     )
     o, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((hkv, padded_rows, d), jnp.float32),
-            jax.ShapeDtypeStruct((hkv, padded_rows, 8), jnp.float32),
+            jax.ShapeDtypeStruct((r_, hkv, padded_rows, d), jnp.float32),
+            jax.ShapeDtypeStruct((r_, hkv, padded_rows, 8), jnp.float32),
         ],
         interpret=interpret,
+        name=name,
     )(*operands)
-    o = o[:, :rows].reshape(hkv, s, n_rep, d).transpose(1, 0, 2, 3)
-    lse = lse[:, :rows, 0].reshape(hkv, s, n_rep).transpose(1, 0, 2)
-    return o.reshape(s, h, d), lse.reshape(s, h)
+    o = o[:, :, :rows].reshape(r_, hkv, s, n_rep, d).transpose(
+        0, 2, 1, 3, 4).reshape(r_, s, h, d)
+    lse = lse[:, :, :rows, 0].reshape(r_, hkv, s, n_rep).transpose(
+        0, 1, 3, 2).reshape(r_, h, s)
+    return o, lse
 
 
 def merge_softmax_states(o_a, lse_a, o_b, lse_b):
@@ -460,13 +414,17 @@ def merge_softmax_states(o_a, lse_a, o_b, lse_b):
 def paged_prefix_part(q, k_pages, v_pages, page_ids, base, *,
                       page_size: int, k_scale=None, v_scale=None,
                       interpret=None):
-    """Batch-1 convenience over :func:`_paged_prefill_call`: q
-    [1, S, H, D] -> (o [1, S, H, D] f32, lse [1, H, S] f32) in the flash
-    lse layout, ready for :func:`merge_softmax_states`."""
-    o, lse = _paged_prefill_call(q[0], k_pages, v_pages, page_ids, base,
-                                 page_size, k_scale=k_scale,
-                                 v_scale=v_scale, interpret=interpret)
-    return o[None], lse.T[None]
+    """The prefix-hit prefill form of :func:`_paged_chunk_call` — one
+    row: q [1, S, H, D] (one admission's prompt chunk) over the ``base``
+    prefix tokens stored in pool pages ``page_ids`` ([pages_per_slot]
+    int32, -1 past the prefix) -> (o [1, S, H, D] f32, lse [1, H, S]
+    f32) in the flash lse layout, ready for
+    :func:`merge_softmax_states`."""
+    return _paged_chunk_call(
+        q, k_pages, v_pages, page_ids[None],
+        jnp.asarray(base, jnp.int32).reshape(1), page_size,
+        k_scale=k_scale, v_scale=v_scale, interpret=interpret,
+        name="paged_prefill")
 
 
 def paged_prefill_attention(q, k_cache, v_cache, q_start, k_pages,
@@ -485,150 +443,6 @@ def paged_prefill_attention(q, k_cache, v_cache, q_start, k_pages,
         q, k_pages, v_pages, page_ids, base, page_size=page_size,
         k_scale=k_scale, v_scale=v_scale, interpret=interpret)
     return merge_softmax_states(o_pre, lse_pre, o_loc, lse_loc)
-
-
-# ---------------------------------------------------------------------------
-# batched multi-row verify: a speculative chunk per slot over the page pool
-# ---------------------------------------------------------------------------
-
-def _paged_verify_kernel(ids_ref, base_ref, q_ref, k_ref, v_ref, *refs,
-                         page_size: int, pages_per_slot: int,
-                         kv_heads: int, scale: float, quantized: bool):
-    """Grid (slot x kv_head, q_block, page-slot) — the speculative-verify
-    form of :func:`_paged_prefill_kernel`: same per-page prefix update
-    (``_prefill_page_update``), but batched over every decode slot at
-    once, each row reading ITS OWN page ids and prefix bound from the
-    prefetched ``ids_ref [slots, pages_per_slot]`` / ``base_ref [slots]``
-    (the leading grid dim collapses slot and kv head so the q blocks stay
-    the prefill kernel's 2D row tiles). The chunk's own causal part is
-    NOT computed here — the caller LSE-merges it
-    (:func:`chunk_causal_part` + :func:`merge_softmax_states`), exactly
-    like the prefill hit path merges its local flash."""
-    if quantized:
-        ks_ref, vs_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
-    else:
-        o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
-    g = pl.program_id(0)
-    p = pl.program_id(2)
-
-    @pl.when(p == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    base = base_ref[g // kv_heads]
-    block_rows = q_ref.shape[1]
-    live = p * page_size < base
-
-    @pl.when(live)
-    def _compute():
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # [page_size, d]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if quantized:
-            k = k * ks_ref[0, :, 0][:, None]
-            v = v * vs_ref[0, :, 0][:, None]
-        _prefill_page_update(q_ref, k, v, m_scr, l_scr, acc_scr,
-                             p=p, base=base, page_size=page_size,
-                             scale=scale)
-
-    @pl.when(p == pages_per_slot - 1)
-    def _finalize():
-        l = jnp.maximum(l_scr[:], 1e-30)
-        o_ref[0] = acc_scr[:] / l
-        lse_ref[0] = jnp.broadcast_to(m_scr[:] + jnp.log(l),
-                                      (block_rows, 8))
-
-
-@functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
-def _paged_verify_call(q, k_pages, v_pages, page_table, base,
-                       page_size: int, k_scale=None, v_scale=None,
-                       interpret=None):
-    """q [slots, S, H, D] (every slot's speculative verify chunk) attends
-    each row's prefix tokens 0..base[r]-1 IN PLACE through the page table
-    — the batched form of :func:`_paged_prefill_call`. Returns
-    (o [slots, S, H, D] f32, lse [slots, H, S] f32) partial softmax
-    states in the flash lse layout, ready for
-    :func:`merge_softmax_states` with the chunk's local causal part."""
-    if interpret is None:
-        interpret = not _on_tpu()
-    r_, s, h, d = q.shape
-    hkv = k_pages.shape[2]
-    n_rep = h // hkv
-    pages_per_slot = page_table.shape[1]
-    scale = d ** -0.5
-    scratch_page = k_pages.shape[0] - 1
-    safe_table = jnp.where(page_table >= 0, page_table,
-                           scratch_page).astype(jnp.int32)
-    base = base.astype(jnp.int32)
-    quantized = k_scale is not None
-
-    # rows grouped per (slot, kv head): [R, S, H, D] ->
-    # [R*Hkv, S*n_rep, D] so the q tiles are exactly the prefill
-    # kernel's shape class and the leading grid dim carries both ids
-    rows = s * n_rep
-    qg = q.reshape(r_, s, hkv, n_rep, d).transpose(
-        0, 2, 1, 3, 4).reshape(r_ * hkv, rows, d)
-    block_rows = _fit_block(rows, 256)
-    pad_rows = (-rows) % block_rows
-    if pad_rows:
-        qg = jnp.pad(qg, ((0, 0), (0, pad_rows), (0, 0)))
-    padded_rows = rows + pad_rows
-
-    kernel = functools.partial(
-        _paged_verify_kernel, page_size=page_size,
-        pages_per_slot=pages_per_slot, kv_heads=hkv, scale=scale,
-        quantized=quantized)
-
-    def q_map(g, qb, p, ids, b):
-        return (g, qb, 0)
-
-    def kv_map(g, qb, p, ids, b):
-        return (ids[g // hkv, p], 0, g % hkv, 0)
-
-    def sc_map(g, qb, p, ids, b):
-        return (ids[g // hkv, p], 0, g % hkv)
-
-    in_specs = [
-        pl.BlockSpec((1, block_rows, d), q_map),
-        pl.BlockSpec((1, page_size, 1, d), kv_map),
-        pl.BlockSpec((1, page_size, 1, d), kv_map),
-    ]
-    operands = [safe_table, base, qg, k_pages, v_pages]
-    if quantized:
-        in_specs += [pl.BlockSpec((1, page_size, 1), sc_map),
-                     pl.BlockSpec((1, page_size, 1), sc_map)]
-        operands += [k_scale, v_scale]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(r_ * hkv, padded_rows // block_rows, pages_per_slot),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_rows, d), q_map),
-            pl.BlockSpec((1, block_rows, 8), q_map),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_rows, 1), jnp.float32),   # running max
-            pltpu.VMEM((block_rows, 1), jnp.float32),   # running denom
-            pltpu.VMEM((block_rows, d), jnp.float32),   # accumulator
-        ],
-    )
-    o, lse = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((r_ * hkv, padded_rows, d),
-                                 jnp.float32),
-            jax.ShapeDtypeStruct((r_ * hkv, padded_rows, 8),
-                                 jnp.float32),
-        ],
-        interpret=interpret,
-    )(*operands)
-    o = o[:, :rows].reshape(r_, hkv, s, n_rep, d).transpose(
-        0, 2, 1, 3, 4).reshape(r_, s, h, d)
-    lse = lse[:, :rows, 0].reshape(r_, hkv, s, n_rep).transpose(
-        0, 1, 3, 2).reshape(r_, h, s)
-    return o, lse
 
 
 def chunk_causal_part(q, k, v):
@@ -720,7 +534,7 @@ def paged_verify_attention(q, chunk_k, chunk_v, k_pages, v_pages,
         return paged_verify_reference(
             q, chunk_k, chunk_v, k_pages, v_pages, page_table, base,
             page_size, k_scale=k_scale, v_scale=v_scale)
-    o_pre, lse_pre = _paged_verify_call(
+    o_pre, lse_pre = _paged_chunk_call(
         q, k_pages, v_pages, page_table, base, page_size,
         k_scale=k_scale, v_scale=v_scale, interpret=interpret)
     o_loc, lse_loc = chunk_causal_part(q, chunk_k, chunk_v)

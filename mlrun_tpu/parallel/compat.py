@@ -1,12 +1,9 @@
-"""Compatibility shims for jax API drift (mirrors the AxisType shim in
-parallel/mesh.py).
+"""``shard_map`` in the repo's calling convention.
 
-``shard_map`` moved from ``jax.experimental.shard_map`` (positional mesh,
-``check_rep``, partial-manual via ``auto=``) to ``jax.shard_map``
-(keyword-only, ``check_vma``, partial-manual via ``axis_names=``). The
-repo is written against the new calling convention; this module adapts it
-onto whichever implementation the pinned jax build ships, so the
-context/pipeline-parallel paths run on both.
+The context/pipeline-parallel paths are written against ``jax.shard_map``
+(keyword-only, ``check_vma``, partial-manual via ``axis_names=``); this
+wrapper adds the ``functools.partial`` decorator form both ops/ and
+parallel/ use.
 """
 
 from __future__ import annotations
@@ -15,36 +12,15 @@ import functools
 
 import jax
 
-try:  # legacy home (jax < 0.6); removed in newer builds
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-except Exception:  # noqa: BLE001
-    _legacy_shard_map = None
-
 
 def shard_map(f=None, *, mesh, in_specs, out_specs, check_vma=True,
               axis_names=None):
-    """``jax.shard_map``-compatible wrapper.
-
-    ``axis_names`` is the set of MANUAL axes (new-API semantics); on the
-    legacy implementation it maps to ``auto = mesh.axis_names - axis_names``
-    and ``check_vma`` maps to ``check_rep``. Usable directly or as a
-    ``functools.partial`` decorator (both call styles appear in ops/ and
-    parallel/)."""
+    """``jax.shard_map`` usable directly or as a ``functools.partial``
+    decorator. ``axis_names`` is the set of MANUAL axes."""
     if f is None:
         return functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
                                  out_specs=out_specs, check_vma=check_vma,
                                  axis_names=axis_names)
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-        kwargs = {} if axis_names is None else {"axis_names": axis_names}
-        return native(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma, **kwargs)
-    if _legacy_shard_map is None:  # pragma: no cover - no impl at all
-        raise NotImplementedError(
-            "this jax build has neither jax.shard_map nor "
-            "jax.experimental.shard_map")
-    kwargs = {}
-    if axis_names is not None:
-        kwargs["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _legacy_shard_map(f, mesh, in_specs, out_specs,
-                             check_rep=check_vma, **kwargs)
+    kwargs = {} if axis_names is None else {"axis_names": axis_names}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kwargs)

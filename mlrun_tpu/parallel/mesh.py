@@ -23,7 +23,7 @@ from typing import Optional, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 DEFAULT_AXES = ("data", "fsdp", "tensor")
 
@@ -95,14 +95,8 @@ def make_mesh(shape: dict | None = None, devices=None,
     num_slices = num_slices or _detect_num_slices(devices)
     # Auto axis types: we annotate params/data in/out shardings and let
     # GSPMD propagate + insert collectives (jax 0.9 defaults to Explicit,
-    # which demands per-op sharding types instead). jax builds that
-    # predate AxisType are Auto-only — the kwarg is simply omitted.
-    try:
-        from jax.sharding import AxisType
-
-        mesh_kwargs = {"axis_types": (AxisType.Auto,) * len(names)}
-    except ImportError:
-        mesh_kwargs = {}
+    # which demands per-op sharding types instead).
+    mesh_kwargs = {"axis_types": (AxisType.Auto,) * len(names)}
     if num_slices > 1:
         from jax.experimental.mesh_utils import create_hybrid_device_mesh
 
@@ -128,12 +122,7 @@ def make_mesh(shape: dict | None = None, devices=None,
             # semantics, just without the DCN-aware device ordering
             device_array = np.asarray(devices).reshape(sizes)
         return Mesh(device_array, names, **mesh_kwargs)
-    try:
-        return jax.make_mesh(sizes, names, devices=devices, **mesh_kwargs)
-    except TypeError:
-        # older signature without devices/axis_types kwargs
-        device_array = np.asarray(devices).reshape(sizes)
-        return Mesh(device_array, names, **mesh_kwargs)
+    return jax.make_mesh(sizes, names, devices=devices, **mesh_kwargs)
 
 
 def _detect_num_slices(devices) -> int:

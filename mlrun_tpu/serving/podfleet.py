@@ -179,10 +179,10 @@ class PodReplicaClient:
         outer: Future = Future()
         with self._lock:
             self._inflight[outer] = req
-        inner.add_done_callback(lambda fut: self._relay(outer, fut))
+        inner.add_done_callback(lambda fut: self._settle(outer, fut))
         return outer
 
-    def _relay(self, outer: Future, inner: Future):
+    def _settle(self, outer: Future, inner: Future):
         with self._lock:
             self._inflight.pop(outer, None)
         if outer.done():  # already failed by preempt()

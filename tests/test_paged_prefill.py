@@ -5,8 +5,8 @@ acceptance contract on prefix-hit admissions
 (``prefill_gather_admissions`` stays 0 under ``attention_impl=
 "kernel"``), int8 end-to-end parity on the paged engine (cold,
 prefix-hit, and through a fleet ``KVHandoff``), the ~2x
-pages-at-equal-bytes capacity claim, the typed
-``KernelUnavailableError`` at engine construction, and the
+pages-at-equal-bytes capacity claim, the ``ValueError`` on an unknown
+``attention_impl`` at engine construction, and the
 ``make bench-prefill`` smoke. CPU-only (Pallas interpret mode),
 tier-1-fast.
 
@@ -26,21 +26,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import importlib
-
 from mlrun_tpu.models import init_params, tiny_llama
 from mlrun_tpu.ops import paged_attention as pattn
-
-# the ops package re-exports the `attention` FUNCTION under the
-# submodule's name, so `import mlrun_tpu.ops.attention as m` binds the
-# function — resolve the module itself for monkeypatching
-attn_mod = importlib.import_module("mlrun_tpu.ops.attention")
 from mlrun_tpu.ops.attention import _repeat_kv, attention_reference
 from mlrun_tpu.serving.llm import _quantize_kv
 from mlrun_tpu.serving.paged import (
     PagedContinuousBatchingEngine,
     init_paged_pool,
 )
+from tests.greedy import assert_greedy_equal_up_to_tie
 
 
 @pytest.fixture(scope="module")
@@ -237,7 +231,12 @@ def test_kernel_prefix_chunked_resume_parity(setup):
         stats = eng.stats
     finally:
         eng.stop()
-    assert seed == ref_seed and out == ref
+    # tolerance-parity contract (module docstring): the merged hit path
+    # agrees with the monolithic one to round-off, so greedy streams
+    # agree up to an argmax tie at bf16 resolution (this prompt's first
+    # token has one: margin 0.0151, under one bf16 step)
+    assert seed == ref_seed
+    assert_greedy_equal_up_to_tie(cfg, params, branch, out, ref)
     assert stats["prefill_gather_admissions"] == 0
     # 12-token suffix at chunk 8 = two merged chunks + the replay
     assert stats["prefill_kernel_chunks"] >= 3
@@ -355,19 +354,15 @@ def test_int8_pool_capacity_doubles_at_equal_bytes():
     assert pages_int8 >= 1.8 * pages_native
 
 
-def test_explicit_kernel_engine_raises_typed_without_pallas(
-        setup, monkeypatch):
-    """Engine construction with an explicit kernel request that cannot
-    be honored raises the typed ValueError subclass instead of the old
-    silent downgrade; auto still constructs (reference, warn-once)."""
+def test_unknown_impl_engine_raises_at_construction(setup):
+    """Engine construction with an ``attention_impl`` no resolver knows
+    raises ValueError instead of serving on some silently picked path;
+    auto on the CPU constructs on the reference paths."""
     cfg, params = setup
-    monkeypatch.setattr(attn_mod, "_PALLAS_OK", False)
-    monkeypatch.setattr(pattn, "_PALLAS_OK", False)
-    with pytest.raises(pattn.KernelUnavailableError):
+    with pytest.raises(ValueError, match="bogus"):
         PagedContinuousBatchingEngine(
             cfg, params, max_len=64, slots=2, prefill_buckets=(16,),
-            page_size=8, kv_dtype="int8", attention_impl="kernel")
-    monkeypatch.setattr(pattn, "_warned_auto_fallback", False)
+            page_size=8, kv_dtype="int8", attention_impl="bogus")
     eng = PagedContinuousBatchingEngine(
         cfg, params, max_len=64, slots=2, prefill_buckets=(16,),
         page_size=8, attention_impl="auto")

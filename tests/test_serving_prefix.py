@@ -18,6 +18,7 @@ from mlrun_tpu.serving.llm_batch import ContinuousBatchingEngine
 from mlrun_tpu.serving.paged import PagedContinuousBatchingEngine
 from mlrun_tpu.serving.prefix import PrefixCache
 from mlrun_tpu.serving.resilience import PromptTooLongError
+from tests.greedy import assert_greedy_equal_up_to_tie
 
 
 @pytest.fixture(scope="module")
@@ -203,7 +204,12 @@ def test_chunked_prefill_resumes_across_ticks_dense(setup):
         stats = eng.stats
     finally:
         eng.stop()
-    assert t1 == _greedy_reference(cfg, params, short, 30)
+    # the already-decoding short request must be untouched by the chunks
+    # interleaving with it — up to an argmax tie at bf16 resolution (this
+    # prompt has one at token 6, margin 0.0027, with or without the long
+    # request beside it)
+    assert_greedy_equal_up_to_tie(
+        cfg, params, short, t1, _greedy_reference(cfg, params, short, 30))
     assert t2 == _greedy_reference(cfg, params, long_prompt, 6)
     assert stats["prefill_chunks"] >= 8  # 1 (short) + 7 (long)
     # tick instrumentation: no scheduler iteration absorbed more than one
