@@ -247,32 +247,36 @@ def _layer_body(config: LlamaConfig, x, layer_params, cos, sin,
             out = (out + scaling.astype(x.dtype) * delta).astype(x.dtype)
         return out
 
-    # attention block
-    h = rms_norm(x, lp["attn_norm_scale"], config.norm_eps)
-    q = proj(h, lp["wq"], "wq").reshape(b, s, config.n_heads, config.head_dim)
-    k = proj(h, lp["wk"], "wk").reshape(b, s, config.n_kv_heads,
-                                        config.head_dim)
-    v = proj(h, lp["wv"], "wv").reshape(b, s, config.n_kv_heads,
-                                        config.head_dim)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
-    if attention_fn is not None:
-        attn = attention_fn(q, k, v)
-    else:
-        attn = attention(q, k, v, causal=True, impl=config.attention_impl)
     from jax.ad_checkpoint import checkpoint_name
 
-    attn = attn.reshape(b, s, config.qkv_dim)
-    # named for the "save_attn" remat policy: backward keeps the attention
-    # output and recomputes only the MLP half
-    attn = checkpoint_name(attn, "attn_out")
-    x = x + proj(attn, lp["wo"], "wo")
+    # the named scopes are metadata a profile groups operations by
+    # (embed, layer/attn, layer/mlp, head, loss): no instruction is renamed
+    with jax.named_scope("layer/attn"):
+        h = rms_norm(x, lp["attn_norm_scale"], config.norm_eps)
+        q = proj(h, lp["wq"], "wq").reshape(b, s, config.n_heads,
+                                            config.head_dim)
+        k = proj(h, lp["wk"], "wk").reshape(b, s, config.n_kv_heads,
+                                            config.head_dim)
+        v = proj(h, lp["wv"], "wv").reshape(b, s, config.n_kv_heads,
+                                            config.head_dim)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+        if attention_fn is not None:
+            attn = attention_fn(q, k, v)
+        else:
+            attn = attention(q, k, v, causal=True,
+                             impl=config.attention_impl)
+        attn = attn.reshape(b, s, config.qkv_dim)
+        # named for the "save_attn" remat policy: backward keeps the
+        # attention output and recomputes only the MLP half
+        attn = checkpoint_name(attn, "attn_out")
+        x = x + proj(attn, lp["wo"], "wo")
 
-    # mlp block (SwiGLU)
-    h = rms_norm(x, lp["mlp_norm_scale"], config.norm_eps)
-    gate = proj(h, lp["w_gate"], "w_gate")
-    up = proj(h, lp["w_up"], "w_up")
-    x = x + proj(jax.nn.silu(gate) * up, lp["w_down"], "w_down")
+    with jax.named_scope("layer/mlp"):      # SwiGLU
+        h = rms_norm(x, lp["mlp_norm_scale"], config.norm_eps)
+        gate = proj(h, lp["w_gate"], "w_gate")
+        up = proj(h, lp["w_up"], "w_up")
+        x = x + proj(jax.nn.silu(gate) * up, lp["w_down"], "w_down")
     return x
 
 
@@ -291,8 +295,9 @@ def forward(config: LlamaConfig, params: Params, tokens: jax.Array,
     head = params.get("lm_head")
     if head is None:
         head = params["embedding"].T
-    logits = jnp.einsum("bse,ev->bsv", x, head,
-                        preferred_element_type=jnp.float32)
+    with jax.named_scope("head"):
+        logits = jnp.einsum("bse,ev->bsv", x, head,
+                            preferred_element_type=jnp.float32)
     return logits
 
 
@@ -302,11 +307,12 @@ def hidden_states(config: LlamaConfig, params: Params, tokens: jax.Array,
                   act_spec=None) -> jax.Array:
     """tokens [B, S] -> final-norm hidden [B, S, E] (no lm head)."""
     b, s = tokens.shape
-    if act_spec is not None:
-        x = params["embedding"].at[tokens].get(
-            out_sharding=act_spec).astype(config.dtype)
-    else:
-        x = params["embedding"][tokens].astype(config.dtype)
+    with jax.named_scope("embed"):
+        if act_spec is not None:
+            x = params["embedding"].at[tokens].get(
+                out_sharding=act_spec).astype(config.dtype)
+        else:
+            x = params["embedding"][tokens].astype(config.dtype)
     if positions is None:
         positions = jnp.arange(s)
     cos, sin = rope_table(positions, config.head_dim, config.rope_theta)
@@ -346,8 +352,10 @@ def chunked_loss(config: LlamaConfig, params: Params, tokens: jax.Array,
     head = params.get("lm_head")
     if head is None:
         head = params["embedding"].T
-    loss, accuracy, total = chunked_ce(x, head, targets, mask=mask,
-                                       chunk=chunk)
+    # head and loss are one chunked region here: the logits never exist
+    with jax.named_scope("loss"):
+        loss, accuracy, total = chunked_ce(x, head, targets, mask=mask,
+                                           chunk=chunk)
     return loss, {"loss": loss, "accuracy": accuracy, "tokens": total}
 
 
@@ -411,27 +419,28 @@ def loss_fn(config: LlamaConfig, params: Params, tokens: jax.Array,
         return chunked_loss(config, params, tokens, targets, mask=mask,
                             lora=lora, chunk=loss_chunk, act_spec=act_spec)
     logits = forward(config, params, tokens, lora=lora, act_spec=act_spec)
-    log_probs = jax.nn.log_softmax(logits, axis=-1)
-    if act_spec is not None:
-        from jax.sharding import NamedSharding as _NS
-        from jax.sharding import PartitionSpec as _P
+    with jax.named_scope("loss"):
+        log_probs = jax.nn.log_softmax(logits, axis=-1)
+        if act_spec is not None:
+            from jax.sharding import NamedSharding as _NS
+            from jax.sharding import PartitionSpec as _P
 
-        spec = act_spec.spec if isinstance(act_spec, _NS) else act_spec
-        gather_spec = _P(*(tuple(spec)[:2] + (None,)))
-        if isinstance(act_spec, _NS):
-            gather_spec = _NS(act_spec.mesh, gather_spec)
-        nll = -jnp.take_along_axis(
-            log_probs, targets[..., None], axis=-1,
-            out_sharding=gather_spec)[..., 0]
-    else:
-        nll = -jnp.take_along_axis(
-            log_probs, targets[..., None], axis=-1)[..., 0]
-    if mask is None:
-        mask = jnp.ones_like(targets, jnp.float32)
-    mask = mask.astype(jnp.float32)
-    total = jnp.maximum(jnp.sum(mask), 1.0)
-    loss = jnp.sum(nll * mask) / total
-    accuracy = jnp.sum(
-        (jnp.argmax(logits, axis=-1) == targets) * mask) / total
+            spec = act_spec.spec if isinstance(act_spec, _NS) else act_spec
+            gather_spec = _P(*(tuple(spec)[:2] + (None,)))
+            if isinstance(act_spec, _NS):
+                gather_spec = _NS(act_spec.mesh, gather_spec)
+            nll = -jnp.take_along_axis(
+                log_probs, targets[..., None], axis=-1,
+                out_sharding=gather_spec)[..., 0]
+        else:
+            nll = -jnp.take_along_axis(
+                log_probs, targets[..., None], axis=-1)[..., 0]
+        if mask is None:
+            mask = jnp.ones_like(targets, jnp.float32)
+        mask = mask.astype(jnp.float32)
+        total = jnp.maximum(jnp.sum(mask), 1.0)
+        loss = jnp.sum(nll * mask) / total
+        accuracy = jnp.sum(
+            (jnp.argmax(logits, axis=-1) == targets) * mask) / total
     return loss, {"loss": loss, "accuracy": accuracy,
                   "tokens": total}

@@ -25,6 +25,7 @@ from ..ops.ring_attention import ring_attention
 from ..ops.rotary import rope_table
 from ..ops.ulysses import ulysses_attention
 from ..parallel.compat import shard_map
+from ..utils.profiler import named
 from .llama import LlamaConfig, Params, _layer_body
 
 
@@ -223,7 +224,7 @@ def make_cp_train_step(config: LlamaConfig, mesh: Mesh, optimizer,
     data_sh = NamedSharding(mesh, P(batch_spec, seq_axis))
     # NOTE: no donation — donating through partial-manual shard_map trips an
     # XLA CPU CHECK ("Invalid binary instruction opcode copy") in jax 0.9
-    return jax.jit(step,
+    return jax.jit(named("mlt_train_step", step),
                    in_shardings=(param_sh, lora_sh, opt_sh, data_sh,
                                  data_sh),
                    out_shardings=(param_sh, lora_sh, opt_sh, None))

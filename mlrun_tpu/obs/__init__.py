@@ -54,6 +54,12 @@ from .goodput import (  # noqa: F401
     record_badput,
 )
 from .stats import nearest_rank  # noqa: F401
+from .ticklog import (  # noqa: F401
+    TickLog,
+    TickRecord,
+    get_tick_log,
+    tick_logs,
+)
 from .slo import (  # noqa: F401
     SLO,
     SLO_EVENT_KIND,
@@ -77,6 +83,8 @@ from .tracing import (  # noqa: F401
     parse_trace_header,
     trace_id_for,
     tracer,
+    wall_at,
+    wall_now,
 )
 from .tracing import configure_from_mlconf as _configure_tracing
 from .flight import configure_from_mlconf as _configure_flight
@@ -347,7 +355,7 @@ CHAOS_FIRED = REGISTRY.counter(
 TRAIN_MFU = REGISTRY.gauge(
     "mlt_training_mfu", "Last computed model FLOPs utilization")
 TRAIN_STEP_TIME = REGISTRY.gauge(
-    "mlt_train_step_seconds", "Last step wall time per StepTimer",
+    "mlt_train_step_seconds", "Last step wall time, by timer (Trainer.fit)",
     labels=("timer",), overflow="drop")
 TRAIN_INPUT_WAIT = REGISTRY.counter(
     "mlt_train_input_wait_seconds",

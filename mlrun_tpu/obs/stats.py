@@ -1,9 +1,8 @@
 """Tiny shared statistics helpers for the observability layer.
 
-One canonical nearest-rank percentile: the engine latency rings
-(``serving/llm_batch._percentile``) and the trainer's
-``utils/profiler.StepTimer.summary`` both quote p50/p95, and two
-hand-rolled index formulas drifted apart — ``int(n * q)`` picks the
+One canonical nearest-rank percentile for everything that quotes p50/p95
+(the engine latency rings, ``serving/llm_batch._percentile``): two
+hand-rolled index formulas had drifted apart — ``int(n * q)`` picks the
 order statistic ONE RANK HIGH of the nearest-rank definition whenever
 ``q * n`` is an integer (p95 of 100 samples must be the 95th smallest,
 ``ceil(0.95 * 100) = 95`` → index 94, not index 95). Stdlib only, same

@@ -45,6 +45,22 @@ TRACE_HEADER = "x-mlt-trace"
 
 _HEX = set("0123456789abcdef")
 
+# One clock for spans: ``time.perf_counter()`` (the request ledger's and the
+# tick log's clock) carried onto the wall by one anchor read at import. A
+# span's duration is then monotone whatever the wall clock does meanwhile,
+# and a span, a ledger phase and a tick record can be laid side by side.
+WALL0 = time.time()
+PERF0 = time.perf_counter()
+
+
+def wall_at(perf: float) -> float:
+    """The wall-clock reading of a ``time.perf_counter()`` reading."""
+    return WALL0 + (perf - PERF0)
+
+
+def wall_now() -> float:
+    return wall_at(time.perf_counter())
+
 
 def _is_hex(value: str) -> bool:
     return bool(value) and set(value) <= _HEX
@@ -109,7 +125,7 @@ class Span:
     trace_id: str
     span_id: str = field(default_factory=new_span_id)
     parent_id: Optional[str] = None
-    start: float = field(default_factory=time.time)
+    start: float = field(default_factory=wall_now)
     end: Optional[float] = None
     status: str = "ok"
     attrs: dict = field(default_factory=dict)
@@ -203,7 +219,7 @@ class Tracer:
     def end_span(self, span: Span, status: str | None = None):
         if span.end is not None:
             return
-        span.end = time.time()
+        span.end = wall_now()
         if status:
             span.status = status
         stack = self._stack()
@@ -229,8 +245,9 @@ class Tracer:
              start: float | None = None, end: float | None = None,
              status: str = "ok", attrs: dict | None = None) -> Span:
         """Record an already-finished span (scheduler phases measured with
-        perf counters resolve start/end after the fact)."""
-        now = time.time()
+        perf counters resolve start/end after the fact; ``start``/``end``
+        are :func:`wall_now` readings)."""
+        now = wall_now()
         span = Span(name=name, trace_id=trace_id, parent_id=parent_id,
                     start=start if start is not None else now,
                     status=status, attrs=dict(attrs or {}))

@@ -167,110 +167,114 @@ def _forward_with_cache(config: LlamaConfig, params: Params,
     max_len = cache["k"].shape[2]
     start = cache["pos"]  # [B]
     positions = start[:, None] + jnp.arange(s)[None, :]  # [B, S]
-    x = params["embedding"][tokens].astype(config.dtype)
+    with jax.named_scope("embed"):
+        x = params["embedding"][tokens].astype(config.dtype)
     # rope per batch row (positions differ per row only after mixed prefill;
     # keep a single table using row 0 — engine keeps pos uniform per batch)
     cos, sin = rope_table(positions[0], config.head_dim, config.rope_theta)
 
     def body(x_in, layer_idx_and_params):
         layer, lp = layer_idx_and_params
-        h = rms_norm(x_in, lp["attn_norm_scale"], config.norm_eps)
+        with jax.named_scope("layer/attn"):
+            h = rms_norm(x_in, lp["attn_norm_scale"], config.norm_eps)
 
-        def proj(h_in, w, t=None):
-            out = jnp.einsum("bse,eh->bsh", h_in, w,
-                             preferred_element_type=jnp.float32)
-            if lora is not None and t is not None and t in lora:
-                out = out + _lora_delta(h_in, lora[t], layer, adapter_ids)
-            return out.astype(x_in.dtype)
+            def proj(h_in, w, t=None):
+                out = jnp.einsum("bse,eh->bsh", h_in, w,
+                                 preferred_element_type=jnp.float32)
+                if lora is not None and t is not None and t in lora:
+                    out = out + _lora_delta(h_in, lora[t], layer, adapter_ids)
+                return out.astype(x_in.dtype)
 
-        q = proj(h, lp["wq"], "wq").reshape(b, s, config.n_heads,
-                                            config.head_dim)
-        k = proj(h, lp["wk"], "wk").reshape(b, s, config.n_kv_heads,
-                                            config.head_dim)
-        v = proj(h, lp["wv"], "wv").reshape(b, s, config.n_kv_heads,
-                                            config.head_dim)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        quantized = "k_scale" in cache
-        if quantized:
-            kq, ks = _quantize_kv(k)
-            vq, vs = _quantize_kv(v)
-            k_cache = jax.lax.dynamic_update_slice(
-                cache["k"][layer], kq, (0, start[0], 0, 0))
-            v_cache = jax.lax.dynamic_update_slice(
-                cache["v"][layer], vq, (0, start[0], 0, 0))
-            k_scale = jax.lax.dynamic_update_slice(
-                cache["k_scale"][layer], ks, (0, start[0], 0))
-            v_scale = jax.lax.dynamic_update_slice(
-                cache["v_scale"][layer], vs, (0, start[0], 0))
-            k_attn = _dequantize_kv(k_cache, k_scale, config.dtype)
-            v_attn = _dequantize_kv(v_cache, v_scale, config.dtype)
-            scales = (k_scale, v_scale)
-        else:
-            # write k,v into the cache at start..start+s (uniform start)
-            k_cache = jax.lax.dynamic_update_slice(
-                cache["k"][layer], k.astype(cache["k"].dtype),
-                (0, start[0], 0, 0))
-            v_cache = jax.lax.dynamic_update_slice(
-                cache["v"][layer], v.astype(cache["v"].dtype),
-                (0, start[0], 0, 0))
-            k_attn, v_attn = k_cache, v_cache
-            scales = None
-        if prefix_kv is not None:
-            # paged prefix-hit suffix prefill: local rows (>= base) via
-            # bounded flash (s > 1) or the bounded dense form (the
-            # 1-token last-position replay), the cached prefix via the
-            # multi-row paged prefill kernel reading pool pages in
-            # place — partial softmax states LSE-merged
-            # (docs/serving.md "Attention kernels")
-            from ..ops.attention import (
-                _flash_fwd_v2_cached_bounded,
-                _repeat_kv,
-            )
-            from ..ops.paged_attention import (
-                merge_softmax_states,
-                paged_prefix_part,
-            )
-
-            n_rep = config.n_heads // config.n_kv_heads
-            base = prefix_kv["base"]
-            if attn_impl == "flash" and s > 1:
-                o_loc, lse_loc = _flash_fwd_v2_cached_bounded(
-                    q, _repeat_kv(k_attn, n_rep),
-                    _repeat_kv(v_attn, n_rep), start[0], base)
+            q = proj(h, lp["wq"], "wq").reshape(b, s, config.n_heads,
+                                                config.head_dim)
+            k = proj(h, lp["wk"], "wk").reshape(b, s, config.n_kv_heads,
+                                                config.head_dim)
+            v = proj(h, lp["wv"], "wv").reshape(b, s, config.n_kv_heads,
+                                                config.head_dim)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+            quantized = "k_scale" in cache
+            if quantized:
+                kq, ks = _quantize_kv(k)
+                vq, vs = _quantize_kv(v)
+                k_cache = jax.lax.dynamic_update_slice(
+                    cache["k"][layer], kq, (0, start[0], 0, 0))
+                v_cache = jax.lax.dynamic_update_slice(
+                    cache["v"][layer], vq, (0, start[0], 0, 0))
+                k_scale = jax.lax.dynamic_update_slice(
+                    cache["k_scale"][layer], ks, (0, start[0], 0))
+                v_scale = jax.lax.dynamic_update_slice(
+                    cache["v_scale"][layer], vs, (0, start[0], 0))
+                k_attn = _dequantize_kv(k_cache, k_scale, config.dtype)
+                v_attn = _dequantize_kv(v_cache, v_scale, config.dtype)
+                scales = (k_scale, v_scale)
             else:
-                o_loc, lse_loc = _cached_attention_lse(
-                    config, q, k_attn, v_attn, positions, base)
-            o_pre, lse_pre = paged_prefix_part(
-                q, prefix_kv["k"][layer], prefix_kv["v"][layer],
-                prefix_kv["page_ids"], base, page_size=page_size,
-                k_scale=(prefix_kv["k_scale"][layer]
-                         if "k_scale" in prefix_kv else None),
-                v_scale=(prefix_kv["v_scale"][layer]
-                         if "v_scale" in prefix_kv else None))
-            attn = merge_softmax_states(o_pre, lse_pre, o_loc,
-                                        lse_loc).astype(x_in.dtype)
-        elif attn_impl == "flash" and s > 1:
-            from ..ops.attention import _repeat_kv, flash_attention_cached
+                # write k,v into the cache at start..start+s (uniform start)
+                k_cache = jax.lax.dynamic_update_slice(
+                    cache["k"][layer], k.astype(cache["k"].dtype),
+                    (0, start[0], 0, 0))
+                v_cache = jax.lax.dynamic_update_slice(
+                    cache["v"][layer], v.astype(cache["v"].dtype),
+                    (0, start[0], 0, 0))
+                k_attn, v_attn = k_cache, v_cache
+                scales = None
+            if prefix_kv is not None:
+                # paged prefix-hit suffix prefill: local rows (>= base) via
+                # bounded flash (s > 1) or the bounded dense form (the
+                # 1-token last-position replay), the cached prefix via the
+                # multi-row paged prefill kernel reading pool pages in
+                # place — partial softmax states LSE-merged
+                # (docs/serving.md "Attention kernels")
+                from ..ops.attention import (
+                    _flash_fwd_v2_cached_bounded,
+                    _repeat_kv,
+                )
+                from ..ops.paged_attention import (
+                    merge_softmax_states,
+                    paged_prefix_part,
+                )
 
-            n_rep = config.n_heads // config.n_kv_heads
-            # positions are uniform per batch row on the prefill path
-            # (mixed-start batches never reach here — see rope note above).
-            # 1-token dispatches (last-prompt-token replay, warmup) stay
-            # dense: a block_q=1 kernel instance gains nothing and is a
-            # shape class TPU lowering never otherwise sees
-            attn = flash_attention_cached(
-                q, _repeat_kv(k_attn, n_rep), _repeat_kv(v_attn, n_rep),
-                start[0])
-        else:
-            attn = _cached_attention(config, q, k_attn, v_attn, positions,
-                                     max_len)
-        attn = attn.reshape(b, s, config.qkv_dim)
-        x_mid = x_in + proj(attn, lp["wo"], "wo")
-        h2 = rms_norm(x_mid, lp["mlp_norm_scale"], config.norm_eps)
-        gate = proj(h2, lp["w_gate"], "w_gate")
-        up = proj(h2, lp["w_up"], "w_up")
-        out = x_mid + proj(jax.nn.silu(gate) * up, lp["w_down"], "w_down")
+                n_rep = config.n_heads // config.n_kv_heads
+                base = prefix_kv["base"]
+                if attn_impl == "flash" and s > 1:
+                    o_loc, lse_loc = _flash_fwd_v2_cached_bounded(
+                        q, _repeat_kv(k_attn, n_rep),
+                        _repeat_kv(v_attn, n_rep), start[0], base)
+                else:
+                    o_loc, lse_loc = _cached_attention_lse(
+                        config, q, k_attn, v_attn, positions, base)
+                o_pre, lse_pre = paged_prefix_part(
+                    q, prefix_kv["k"][layer], prefix_kv["v"][layer],
+                    prefix_kv["page_ids"], base, page_size=page_size,
+                    k_scale=(prefix_kv["k_scale"][layer]
+                             if "k_scale" in prefix_kv else None),
+                    v_scale=(prefix_kv["v_scale"][layer]
+                             if "v_scale" in prefix_kv else None))
+                attn = merge_softmax_states(o_pre, lse_pre, o_loc,
+                                            lse_loc).astype(x_in.dtype)
+            elif attn_impl == "flash" and s > 1:
+                from ..ops.attention import _repeat_kv, flash_attention_cached
+
+                n_rep = config.n_heads // config.n_kv_heads
+                # positions are uniform per batch row on the prefill path
+                # (mixed-start batches never reach here — see rope note
+                # above).
+                # 1-token dispatches (last-prompt-token replay, warmup) stay
+                # dense: a block_q=1 kernel instance gains nothing and is a
+                # shape class TPU lowering never otherwise sees
+                attn = flash_attention_cached(
+                    q, _repeat_kv(k_attn, n_rep), _repeat_kv(v_attn, n_rep),
+                    start[0])
+            else:
+                attn = _cached_attention(config, q, k_attn, v_attn, positions,
+                                         max_len)
+            attn = attn.reshape(b, s, config.qkv_dim)
+            x_mid = x_in + proj(attn, lp["wo"], "wo")
+        with jax.named_scope("layer/mlp"):
+            h2 = rms_norm(x_mid, lp["mlp_norm_scale"], config.norm_eps)
+            gate = proj(h2, lp["w_gate"], "w_gate")
+            up = proj(h2, lp["w_up"], "w_up")
+            out = x_mid + proj(jax.nn.silu(gate) * up, lp["w_down"], "w_down")
         return out, (k_cache, v_cache, scales)
 
     # python loop over layers: compiled once per bucket; exposes per-layer
@@ -285,12 +289,13 @@ def _forward_with_cache(config: LlamaConfig, params: Params,
             new_ks.append(scales[0])
             new_vs.append(scales[1])
 
-    x = rms_norm(x, params["final_norm_scale"], config.norm_eps)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embedding"].T
-    logits = jnp.einsum("bse,ev->bsv", x if all_logits else x[:, -1:],
-                        head, preferred_element_type=jnp.float32)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm_scale"], config.norm_eps)
+        head = params.get("lm_head")
+        if head is None:
+            head = params["embedding"].T
+        logits = jnp.einsum("bse,ev->bsv", x if all_logits else x[:, -1:],
+                            head, preferred_element_type=jnp.float32)
     new_cache = {
         "k": jnp.stack(new_k),
         "v": jnp.stack(new_v),

@@ -47,16 +47,20 @@ from ..obs import (
     LLM_TTFT,
     REGISTRY,
     RequestLedger,
+    TickRecord,
     export_phases,
     flight_record,
     get_flight_recorder,
+    get_tick_log,
     get_tracer,
     register_memory_collector,
+    wall_now,
 )
 from ..obs.stats import nearest_rank
 from ..ops.norms import rms_norm
 from ..ops.rotary import apply_rope, rope_table
 from ..utils import logger
+from ..utils.profiler import annotate, named
 from ..utils.profiler import tick as profiler_tick
 from .canary import get_canary_router, split_key_for
 from .llm import _cached_attention, _forward_with_cache, init_kv_cache
@@ -93,67 +97,71 @@ def _decode_rowwise(config: LlamaConfig, params: Params, tokens: jax.Array,
     start = cache["pos"]                      # [B]
     positions = start[:, None]                # [B, 1]
     rows = jnp.arange(b)
-    x = params["embedding"][tokens].astype(config.dtype)
+    with jax.named_scope("embed"):
+        x = params["embedding"][tokens].astype(config.dtype)
     cos, sin = rope_table(positions, config.head_dim, config.rope_theta)
 
     new_k, new_v, new_ks, new_vs = [], [], [], []
     for layer in range(config.n_layers):
         lp = jax.tree_util.tree_map(lambda a: a[layer], params["layers"])
-        h = rms_norm(x, lp["attn_norm_scale"], config.norm_eps)
+        with jax.named_scope("layer/attn"):
+            h = rms_norm(x, lp["attn_norm_scale"], config.norm_eps)
 
-        def proj(h_in, w, t=None, _layer=layer):
-            out = jnp.einsum("bse,eh->bsh", h_in, w,
-                             preferred_element_type=jnp.float32)
-            if lora is not None and t is not None and t in lora:
-                out = out + _lora_delta(h_in, lora[t], _layer, adapter_ids)
-            return out.astype(x.dtype)
+            def proj(h_in, w, t=None, _layer=layer):
+                out = jnp.einsum("bse,eh->bsh", h_in, w,
+                                 preferred_element_type=jnp.float32)
+                if lora is not None and t is not None and t in lora:
+                    out = out + _lora_delta(h_in, lora[t], _layer, adapter_ids)
+                return out.astype(x.dtype)
 
-        q = proj(h, lp["wq"], "wq").reshape(b, 1, config.n_heads,
-                                            config.head_dim)
-        k = proj(h, lp["wk"], "wk").reshape(b, 1, config.n_kv_heads,
-                                            config.head_dim)
-        v = proj(h, lp["wv"], "wv").reshape(b, 1, config.n_kv_heads,
-                                            config.head_dim)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        quantized = "k_scale" in cache
-        if quantized:
-            from .llm import _dequantize_kv, _quantize_kv
+            q = proj(h, lp["wq"], "wq").reshape(b, 1, config.n_heads,
+                                                config.head_dim)
+            k = proj(h, lp["wk"], "wk").reshape(b, 1, config.n_kv_heads,
+                                                config.head_dim)
+            v = proj(h, lp["wv"], "wv").reshape(b, 1, config.n_kv_heads,
+                                                config.head_dim)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+            quantized = "k_scale" in cache
+            if quantized:
+                from .llm import _dequantize_kv, _quantize_kv
 
-            kq, ks = _quantize_kv(k[:, 0])
-            vq, vs = _quantize_kv(v[:, 0])
-            k_cache = cache["k"][layer].at[rows, start].set(kq)
-            v_cache = cache["v"][layer].at[rows, start].set(vq)
-            k_scale = cache["k_scale"][layer].at[rows, start].set(ks)
-            v_scale = cache["v_scale"][layer].at[rows, start].set(vs)
-            k_attn = _dequantize_kv(k_cache, k_scale, config.dtype)
-            v_attn = _dequantize_kv(v_cache, v_scale, config.dtype)
-            new_ks.append(k_scale)
-            new_vs.append(v_scale)
-        else:
-            # per-row scatter at each row's own position
-            k_cache = cache["k"][layer].at[rows, start].set(
-                k[:, 0].astype(cache["k"].dtype))
-            v_cache = cache["v"][layer].at[rows, start].set(
-                v[:, 0].astype(cache["v"].dtype))
-            k_attn, v_attn = k_cache, v_cache
-        attn = _cached_attention(config, q, k_attn, v_attn, positions,
-                                 cache["k"].shape[2])
-        attn = attn.reshape(b, 1, config.qkv_dim)
-        x_mid = x + proj(attn, lp["wo"], "wo")
-        h2 = rms_norm(x_mid, lp["mlp_norm_scale"], config.norm_eps)
-        gate = proj(h2, lp["w_gate"], "w_gate")
-        up = proj(h2, lp["w_up"], "w_up")
-        x = x_mid + proj(jax.nn.silu(gate) * up, lp["w_down"], "w_down")
+                kq, ks = _quantize_kv(k[:, 0])
+                vq, vs = _quantize_kv(v[:, 0])
+                k_cache = cache["k"][layer].at[rows, start].set(kq)
+                v_cache = cache["v"][layer].at[rows, start].set(vq)
+                k_scale = cache["k_scale"][layer].at[rows, start].set(ks)
+                v_scale = cache["v_scale"][layer].at[rows, start].set(vs)
+                k_attn = _dequantize_kv(k_cache, k_scale, config.dtype)
+                v_attn = _dequantize_kv(v_cache, v_scale, config.dtype)
+                new_ks.append(k_scale)
+                new_vs.append(v_scale)
+            else:
+                # per-row scatter at each row's own position
+                k_cache = cache["k"][layer].at[rows, start].set(
+                    k[:, 0].astype(cache["k"].dtype))
+                v_cache = cache["v"][layer].at[rows, start].set(
+                    v[:, 0].astype(cache["v"].dtype))
+                k_attn, v_attn = k_cache, v_cache
+            attn = _cached_attention(config, q, k_attn, v_attn, positions,
+                                     cache["k"].shape[2])
+            attn = attn.reshape(b, 1, config.qkv_dim)
+            x_mid = x + proj(attn, lp["wo"], "wo")
+        with jax.named_scope("layer/mlp"):
+            h2 = rms_norm(x_mid, lp["mlp_norm_scale"], config.norm_eps)
+            gate = proj(h2, lp["w_gate"], "w_gate")
+            up = proj(h2, lp["w_up"], "w_up")
+            x = x_mid + proj(jax.nn.silu(gate) * up, lp["w_down"], "w_down")
         new_k.append(k_cache)
         new_v.append(v_cache)
 
-    x = rms_norm(x, params["final_norm_scale"], config.norm_eps)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embedding"].T
-    logits = jnp.einsum("bse,ev->bsv", x, head,
-                        preferred_element_type=jnp.float32)[:, 0]
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm_scale"], config.norm_eps)
+        head = params.get("lm_head")
+        if head is None:
+            head = params["embedding"].T
+        logits = jnp.einsum("bse,ev->bsv", x, head,
+                            preferred_element_type=jnp.float32)[:, 0]
     if rng is None:
         next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     else:
@@ -194,70 +202,74 @@ def _verify_rowwise(config: LlamaConfig, params: Params, chunk: jax.Array,
     start = cache["pos"]                               # [B]
     positions = start[:, None] + jnp.arange(s)[None, :]  # [B, S]
     rows = jnp.arange(b)[:, None]                      # [B, 1]
-    x = params["embedding"][chunk].astype(config.dtype)
+    with jax.named_scope("embed"):
+        x = params["embedding"][chunk].astype(config.dtype)
     cos, sin = rope_table(positions, config.head_dim, config.rope_theta)
 
     new_k, new_v, new_ks, new_vs = [], [], [], []
     for layer in range(config.n_layers):
         lp = jax.tree_util.tree_map(lambda a: a[layer], params["layers"])
-        h = rms_norm(x, lp["attn_norm_scale"], config.norm_eps)
+        with jax.named_scope("layer/attn"):
+            h = rms_norm(x, lp["attn_norm_scale"], config.norm_eps)
 
-        def proj(h_in, w, t=None, _layer=layer):
-            out = jnp.einsum("bse,eh->bsh", h_in, w,
-                             preferred_element_type=jnp.float32)
-            if lora is not None and t is not None and t in lora:
-                out = out + _lora_delta(h_in, lora[t], _layer, adapter_ids)
-            return out.astype(x.dtype)
+            def proj(h_in, w, t=None, _layer=layer):
+                out = jnp.einsum("bse,eh->bsh", h_in, w,
+                                 preferred_element_type=jnp.float32)
+                if lora is not None and t is not None and t in lora:
+                    out = out + _lora_delta(h_in, lora[t], _layer, adapter_ids)
+                return out.astype(x.dtype)
 
-        q = proj(h, lp["wq"], "wq").reshape(b, s, config.n_heads,
-                                            config.head_dim)
-        k = proj(h, lp["wk"], "wk").reshape(b, s, config.n_kv_heads,
-                                            config.head_dim)
-        v = proj(h, lp["wv"], "wv").reshape(b, s, config.n_kv_heads,
-                                            config.head_dim)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        quantized = "k_scale" in cache
-        if quantized:
-            from .llm import _dequantize_kv, _quantize_kv
+            q = proj(h, lp["wq"], "wq").reshape(b, s, config.n_heads,
+                                                config.head_dim)
+            k = proj(h, lp["wk"], "wk").reshape(b, s, config.n_kv_heads,
+                                                config.head_dim)
+            v = proj(h, lp["wv"], "wv").reshape(b, s, config.n_kv_heads,
+                                                config.head_dim)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+            quantized = "k_scale" in cache
+            if quantized:
+                from .llm import _dequantize_kv, _quantize_kv
 
-            kq, ks = _quantize_kv(k)
-            vq, vs = _quantize_kv(v)
-            k_cache = cache["k"][layer].at[rows, positions].set(
-                kq, mode="drop")
-            v_cache = cache["v"][layer].at[rows, positions].set(
-                vq, mode="drop")
-            k_scale = cache["k_scale"][layer].at[rows, positions].set(
-                ks, mode="drop")
-            v_scale = cache["v_scale"][layer].at[rows, positions].set(
-                vs, mode="drop")
-            k_attn = _dequantize_kv(k_cache, k_scale, config.dtype)
-            v_attn = _dequantize_kv(v_cache, v_scale, config.dtype)
-            new_ks.append(k_scale)
-            new_vs.append(v_scale)
-        else:
-            k_cache = cache["k"][layer].at[rows, positions].set(
-                k.astype(cache["k"].dtype), mode="drop")
-            v_cache = cache["v"][layer].at[rows, positions].set(
-                v.astype(cache["v"].dtype), mode="drop")
-            k_attn, v_attn = k_cache, v_cache
-        attn = _cached_attention(config, q, k_attn, v_attn, positions,
-                                 cache["k"].shape[2])
-        attn = attn.reshape(b, s, config.qkv_dim)
-        x_mid = x + proj(attn, lp["wo"], "wo")
-        h2 = rms_norm(x_mid, lp["mlp_norm_scale"], config.norm_eps)
-        gate = proj(h2, lp["w_gate"], "w_gate")
-        up = proj(h2, lp["w_up"], "w_up")
-        x = x_mid + proj(jax.nn.silu(gate) * up, lp["w_down"], "w_down")
+                kq, ks = _quantize_kv(k)
+                vq, vs = _quantize_kv(v)
+                k_cache = cache["k"][layer].at[rows, positions].set(
+                    kq, mode="drop")
+                v_cache = cache["v"][layer].at[rows, positions].set(
+                    vq, mode="drop")
+                k_scale = cache["k_scale"][layer].at[rows, positions].set(
+                    ks, mode="drop")
+                v_scale = cache["v_scale"][layer].at[rows, positions].set(
+                    vs, mode="drop")
+                k_attn = _dequantize_kv(k_cache, k_scale, config.dtype)
+                v_attn = _dequantize_kv(v_cache, v_scale, config.dtype)
+                new_ks.append(k_scale)
+                new_vs.append(v_scale)
+            else:
+                k_cache = cache["k"][layer].at[rows, positions].set(
+                    k.astype(cache["k"].dtype), mode="drop")
+                v_cache = cache["v"][layer].at[rows, positions].set(
+                    v.astype(cache["v"].dtype), mode="drop")
+                k_attn, v_attn = k_cache, v_cache
+            attn = _cached_attention(config, q, k_attn, v_attn, positions,
+                                     cache["k"].shape[2])
+            attn = attn.reshape(b, s, config.qkv_dim)
+            x_mid = x + proj(attn, lp["wo"], "wo")
+        with jax.named_scope("layer/mlp"):
+            h2 = rms_norm(x_mid, lp["mlp_norm_scale"], config.norm_eps)
+            gate = proj(h2, lp["w_gate"], "w_gate")
+            up = proj(h2, lp["w_up"], "w_up")
+            x = x_mid + proj(jax.nn.silu(gate) * up, lp["w_down"], "w_down")
         new_k.append(k_cache)
         new_v.append(v_cache)
 
-    x = rms_norm(x, params["final_norm_scale"], config.norm_eps)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embedding"].T
-    logits = jnp.einsum("bse,ev->bsv", x, head,
-                        preferred_element_type=jnp.float32)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm_scale"], config.norm_eps)
+        head = params.get("lm_head")
+        if head is None:
+            head = params["embedding"].T
+        logits = jnp.einsum("bse,ev->bsv", x, head,
+                            preferred_element_type=jnp.float32)
     verified = jnp.argmax(logits, axis=-1).astype(jnp.int32)   # [B, S]
     new_cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v),
                  "pos": cache["pos"]}
@@ -273,8 +285,7 @@ _ENGINE_SEQUENCE = iter(range(1, 1 << 30))
 
 def _percentile(sorted_samples: list, q: float) -> float:
     """Nearest-rank percentile over an already-sorted sample list (the
-    shared ``obs.stats.nearest_rank`` helper — one definition for the
-    engine rings and the trainer's StepTimer; kept as a module name for
+    shared ``obs.stats.nearest_rank`` helper; kept as a module name for
     existing importers, e.g. serving/fleet.py)."""
     return nearest_rank(sorted_samples, q)
 
@@ -550,10 +561,13 @@ class ContinuousBatchingEngine:
         self.prefill_buckets = tuple(
             b for b in sorted(prefill_buckets) if b <= max_len) or (max_len,)
 
-        self._prefill = jax.jit(functools.partial(
-            _forward_with_cache, config, attn_impl=self.prefill_impl))
-        self._decode = jax.jit(functools.partial(_decode_rowwise, config),
-                               donate_argnums=(2,))
+        # every jitted step under a stable name (utils/profiler.named):
+        # a profile's modules then read jit_mlt_prefill, jit_mlt_decode, ...
+        self._prefill = jax.jit(named("mlt_prefill", functools.partial(
+            _forward_with_cache, config, attn_impl=self.prefill_impl)))
+        self._decode = jax.jit(
+            named("mlt_decode", functools.partial(_decode_rowwise, config)),
+            donate_argnums=(2,))
         # the sampled variant is the same jit object called with the extra
         # (rng, temperature, top_k, top_p) args — jax.jit specializes per
         # argument structure, so greedy and sampled ticks each get their
@@ -572,7 +586,8 @@ class ContinuousBatchingEngine:
             big_cache["pos"] = big_cache["pos"].at[slot].set(pos)
             return big_cache
 
-        self._insert = jax.jit(insert, donate_argnums=(0,))
+        self._insert = jax.jit(named("mlt_insert", insert),
+                               donate_argnums=(0,))
 
         self._cache = self._make_cache()
         self._slot_state = [_Slot() for _ in range(slots)]
@@ -593,6 +608,13 @@ class ContinuousBatchingEngine:
         # standalone engine) — set it BEFORE start()/first submit()
         self._obs_name = (f"{type(self).__name__}-"
                           f"{next(_ENGINE_SEQUENCE)}")
+        # one record per scheduler iteration that did work
+        # (obs/ticklog.py), kept by name so that it outlives the engine;
+        # ``_tick`` is the iteration being filled (a scratch record where
+        # a test drives the ticks itself)
+        self._tick_log = get_tick_log(self._obs_name)
+        self._tick = TickRecord()
+        self._iterations = 0
         self.replica = ""
         self._metrics_collector = None
         self._next_id = 0
@@ -663,8 +685,9 @@ class ContinuousBatchingEngine:
                             "spec_accepted": 0, "spec_rejected": 0,
                             "spec_tokens": 0, "spec_parked_ticks": 0,
                             "spec_resyncs": 0})
-        self._spec_draft_prefill = jax.jit(functools.partial(
-            _forward_with_cache, draft_config))
+        self._spec_draft_prefill = jax.jit(named(
+            "mlt_draft_prefill",
+            functools.partial(_forward_with_cache, draft_config)))
         k_max = self.spec_k
 
         def draft_steps(params, tokens, cache, lora=None, adapter_ids=None):
@@ -680,7 +703,8 @@ class ContinuousBatchingEngine:
                 body, (tokens, cache), None, length=k_max)
             return proposals.T, cache
 
-        self._spec_draft_steps = jax.jit(draft_steps, donate_argnums=(2,))
+        self._spec_draft_steps = jax.jit(named("mlt_draft", draft_steps),
+                                         donate_argnums=(2,))
         # engine-specific multi-token verify program, built lazily on the
         # first speculative tick (the paged subclass resolves its kernel
         # impl after this base ctor runs)
@@ -689,8 +713,10 @@ class ContinuousBatchingEngine:
     def _make_verify_fn(self):
         """Jitted (verified [B,S], new_cache) verify program (hook: the
         paged engine swaps in the page-pool verify)."""
-        return jax.jit(functools.partial(_verify_rowwise, self.config),
-                       donate_argnums=(2,))
+        return jax.jit(
+            named("mlt_verify",
+                  functools.partial(_verify_rowwise, self.config)),
+            donate_argnums=(2,))
 
     def _spec_verify_fn(self):
         if self._spec_verify is None:
@@ -855,8 +881,6 @@ class ContinuousBatchingEngine:
         multi-token verify dispatch, then per-row accept/rollback.
         Returns None to fall through to the plain tick (chaos park, or
         every row's gate parked this round)."""
-        from .speculative import accept_tokens
-
         # chaos: an armed llm.spec_verify fault parks THIS tick to plain
         # decode — never a client error; the stream stays exact-greedy
         # because plain ticks emit the same target argmax
@@ -891,21 +915,33 @@ class ContinuousBatchingEngine:
         if not any_spec:
             return None
 
-        last = np.zeros((self.slots, 1), np.int32)
-        for i in active:
-            last[i, 0] = self._slot_state[i].tokens[-1]
-        self._ledger_mark(active, "decode_active")
-        draft_lora_kw = self._spec_lora_kwargs(
-            self._spec_slot_draft_ids(active))
-        self._spec_dcache["pos"] = jnp.asarray(self._spec_dpos)
-        proposals, self._spec_dcache = self._spec_draft_steps(
-            self._spec_draft_params, jnp.asarray(last), self._spec_dcache,
-            **draft_lora_kw)
-        proposals_h = np.asarray(proposals)           # [slots, k_max]
-        chunk = np.zeros((self.slots, k_max + 1), np.int32)
-        chunk[:, 0] = last[:, 0]
-        chunk[:, 1:] = proposals_h
-        verified_h = self._spec_verify_dispatch(chunk, active)
+        # a round is logged no finer than draft-and-verify as one wait
+        # (t_built = t_dispatched = t_admit)
+        tick = self._tick
+        tick.kind = "spec"
+        with annotate("mlt.sched.fetch"):
+            last, tick.ctx_tokens = self._tick_inputs(active)
+            self._ledger_mark(active, "decode_active")
+            draft_lora_kw = self._spec_lora_kwargs(
+                self._spec_slot_draft_ids(active))
+            self._spec_dcache["pos"] = jnp.asarray(self._spec_dpos)
+            proposals, self._spec_dcache = self._spec_draft_steps(
+                self._spec_draft_params, jnp.asarray(last),
+                self._spec_dcache, **draft_lora_kw)
+            proposals_h = np.asarray(proposals)       # [slots, k_max]
+            chunk = np.zeros((self.slots, k_max + 1), np.int32)
+            chunk[:, 0] = last[:, 0]
+            chunk[:, 1:] = proposals_h
+            verified_h = self._spec_verify_dispatch(chunk, active)
+        tick.t_fetched = time.perf_counter()
+        with annotate("mlt.sched.commit"):
+            return self._spec_commit(active, k_effs, proposals_h,
+                                     verified_h)
+
+    def _spec_commit(self, active, k_effs, proposals_h, verified_h) -> int:
+        """Per-row accept/rollback of one verified round."""
+        from .speculative import accept_tokens
+
         self._ledger_mark(active, "decode_stall")
 
         finished = []
@@ -1720,6 +1756,9 @@ class ContinuousBatchingEngine:
         if ticks:
             out["decode_tick_p50_s"] = _percentile(ticks, 0.50)
             out["decode_tick_p95_s"] = _percentile(ticks, 0.95)
+        # over the tick log's ring: live rows a decode tick, the loop's
+        # share not blocked on the device, admission's share of the loop
+        out.update(self._tick_log.summary())
         out["attention_impl"] = self.attention_impl
         out["prefill_impl"] = self.prefill_impl
         out["queue_depth"] = self._queue_depth()
@@ -1798,6 +1837,7 @@ class ContinuousBatchingEngine:
             adm, jnp.asarray(padded), lora_kw)
         adm.offset += take
         adm.chunks += 1
+        self._tick.prefill_tokens += take
         with self._lock:
             self._stats["prefill_chunks"] += 1
             # tick instrumentation: the most prefill compute any single
@@ -1813,6 +1853,8 @@ class ContinuousBatchingEngine:
             logits, adm.small = self._prefill_dispatch(
                 adm, jnp.asarray([[prompt[-1]]], dtype=jnp.int32),
                 lora_kw)
+        # from here the scheduler waits for the prefill on the device
+        waited = time.perf_counter()
         if sampling_enabled():
             # monitoring tap: first-token top1-top2 logit gap (a cheap
             # model-confidence proxy for the drift analyzer's "logit
@@ -1823,6 +1865,7 @@ class ContinuousBatchingEngine:
                 top2 = np.partition(row, -2)[-2:]
                 adm.logit_margin = float(top2[1] - top2[0])
         adm.first_token = self._first_token(logits, adm.sampling)
+        self._tick.admit_wait_s += time.perf_counter() - waited
         return True
 
     def _prefill_dispatch(self, adm: _Admission, tokens, lora_kw):
@@ -1859,7 +1902,7 @@ class ContinuousBatchingEngine:
         slot.adapter_slot = adapter_slot
         slot.logit_margin = logit_margin
         slot.ledger = ledger
-        slot.decode_started = time.time()
+        slot.decode_started = wall_now()
         if ledger is not None:
             # the row now waits for its first decode dispatch; every
             # tick flips decode_active around the device step
@@ -1926,7 +1969,7 @@ class ContinuousBatchingEngine:
                     slot=free, request_id=request_id, prompt=prompt,
                     max_new=max_new, eos_id=eos_id, future=future,
                     submitted=submitted, sampling=sampling,
-                    expires=expires, trace=item[8], claimed=time.time(),
+                    expires=expires, trace=item[8], claimed=wall_now(),
                     adapter=adapter, adapter_slot=adapter_slot,
                     ledger=ledger)
                 self._apply_directive(adm, extra)
@@ -1988,7 +2031,8 @@ class ContinuousBatchingEngine:
                                    len(adm.prompt))
 
     def _finish_admission(self, adm: _Admission):
-        self._complete_storage(adm)
+        with annotate("mlt.sched.insert"):
+            self._complete_storage(adm)
         if adm.ledger is not None:
             adm.ledger.note("prefill_chunks", adm.chunks)
             if adm.base:
@@ -2045,7 +2089,8 @@ class ContinuousBatchingEngine:
             return False
         self._admission = adm
         if not adm.prefilled:
-            self._run_prefill(adm, limit=None)
+            with annotate("mlt.sched.prefill"):
+                self._run_prefill(adm, limit=None)
         self._finish_admission(adm)
         self._admission = None
         return True
@@ -2071,7 +2116,11 @@ class ContinuousBatchingEngine:
         # moment the request was dequeued in _prepare_admission — a
         # mid-prefill admission is being served, not waiting (the
         # unchunked path behaves the same)
-        if adm.prefilled or self._run_prefill(adm, limit=self.prefill_chunk):
+        done = adm.prefilled
+        if not done:
+            with annotate("mlt.sched.prefill"):
+                done = self._run_prefill(adm, limit=self.prefill_chunk)
+        if done:
             self._finish_admission(adm)
             self._admission = None
 
@@ -2148,41 +2197,66 @@ class ContinuousBatchingEngine:
             self._spec_stale.update(active)
         return self._plain_decode_tick(active)
 
-    def _plain_decode_tick(self, active) -> int:
+    def _tick_inputs(self, active) -> tuple:
+        """([slots, 1] last committed token of every live row, the tokens
+        those rows attend in this tick: prompt + generated so far)."""
         last = np.zeros((self.slots, 1), np.int32)
-        for i in active:
-            last[i, 0] = self._slot_state[i].tokens[-1]
-        lora_kw = self._lora_kwargs(self._slot_adapter_ids()) \
-            if self._adapters is not None else {}
-        self._ledger_mark(active, "decode_active")
-        if any(self._slot_state[i].temperature > 0 for i in active):
-            temp = np.zeros((self.slots,), np.float32)
-            top_k = np.zeros((self.slots,), np.int32)
-            top_p = np.ones((self.slots,), np.float32)
-            for i in active:
-                slot = self._slot_state[i]
-                temp[i] = slot.temperature
-                top_k[i] = slot.top_k
-                top_p[i] = slot.top_p
-            self._rng, sub = jax.random.split(self._rng)
-            next_token, self._cache = self._decode_sampled(
-                self.params, jnp.asarray(last), self._cache, sub,
-                jnp.asarray(temp), jnp.asarray(top_k), jnp.asarray(top_p),
-                **lora_kw)
-        else:
-            next_token, self._cache = self._decode(
-                self.params, jnp.asarray(last), self._cache, **lora_kw)
-        tokens_host = np.asarray(next_token)
-        self._ledger_mark(active, "decode_stall")
+        ctx_tokens = 0
         for i in active:
             slot = self._slot_state[i]
-            token = int(tokens_host[i])
-            slot.tokens.append(token)
-            slot.remaining -= 1
-            capacity = slot.prompt_len + len(slot.tokens) >= self.max_len
-            if (slot.eos_id is not None and token == slot.eos_id) or \
-                    slot.remaining <= 0 or capacity:
-                self._finish(i)
+            last[i, 0] = slot.tokens[-1]
+            ctx_tokens += slot.prompt_len + len(slot.tokens)
+        return last, ctx_tokens
+
+    def _sampling_args(self, active) -> tuple:
+        """The sampled program's extra arguments (rng, temperature, top_k,
+        top_p per slot), or () where every live row is greedy."""
+        if not any(self._slot_state[i].temperature > 0 for i in active):
+            return ()
+        temp = np.zeros((self.slots,), np.float32)
+        top_k = np.zeros((self.slots,), np.int32)
+        top_p = np.ones((self.slots,), np.float32)
+        for i in active:
+            slot = self._slot_state[i]
+            temp[i] = slot.temperature
+            top_k[i] = slot.top_k
+            top_p[i] = slot.top_p
+        self._rng, sub = jax.random.split(self._rng)
+        return (sub, jnp.asarray(temp), jnp.asarray(top_k),
+                jnp.asarray(top_p))
+
+    def _plain_decode_tick(self, active) -> int:
+        # build | dispatch | fetch | commit: the tick record's inner
+        # boundaries, and with tick and admit the iteration's siblings
+        tick = self._tick
+        with annotate("mlt.sched.build"):
+            last, tick.ctx_tokens = self._tick_inputs(active)
+            lora_kw = self._lora_kwargs(self._slot_adapter_ids()) \
+                if self._adapters is not None else {}
+            self._ledger_mark(active, "decode_active")
+            # the sampled variant is the same jit object with extra args
+            args = (jnp.asarray(last), self._cache) \
+                + self._sampling_args(active)
+        tick.t_built = time.perf_counter()
+        with annotate("mlt.sched.dispatch"):
+            next_token, self._cache = self._decode(self.params, *args,
+                                                   **lora_kw)
+        tick.t_dispatched = time.perf_counter()
+        with annotate("mlt.sched.fetch"):
+            tokens_host = np.asarray(next_token)
+        tick.t_fetched = time.perf_counter()
+        with annotate("mlt.sched.commit"):
+            self._ledger_mark(active, "decode_stall")
+            for i in active:
+                slot = self._slot_state[i]
+                token = int(tokens_host[i])
+                slot.tokens.append(token)
+                slot.remaining -= 1
+                capacity = slot.prompt_len + len(slot.tokens) \
+                    >= self.max_len
+                if (slot.eos_id is not None and token == slot.eos_id) or \
+                        slot.remaining <= 0 or capacity:
+                    self._finish(i)
         return len(active)
 
     def _consume_budget(self, expires: float | None):
@@ -2237,6 +2311,64 @@ class ContinuousBatchingEngine:
         unsafe by construction; docs/serving.md "Hierarchical KV").
         Base engine: nothing."""
 
+    def _count_attention_tick(self):
+        """Per-tick counters of the engine's attention path, taken under
+        the lock the tick's bookkeeping holds (hook: the paged engine
+        counts kernel and gather ticks)."""
+
+    def _iterate(self, started: float) -> int:
+        """One scheduler iteration: admission, then one decode tick over
+        the live rows; returns how many rows it decoded. What it did goes
+        to the tick log, and as spans into the profiler's trace: tick |
+        admit | build | dispatch | fetch | commit, siblings that partition
+        the iteration, with prefill and insert inside admit.
+
+        ``mlt.sched.tick`` opens the iteration and carries its index; it
+        does not enclose the other five. A tool that puts a device gap down
+        to the host span overlapping it most (the benchmark's
+        ``trace_reduce.attribute_gap``) would name an enclosing span for
+        every gap, since a gap runs from one part into the next (measured,
+        PERF.md PR 27)."""
+        index = self._iterations
+        self._iterations = index + 1
+        with annotate("mlt.sched.tick", n=index):
+            tick = self._tick = TickRecord(index, started)
+        with annotate("mlt.sched.admit"):
+            # fail-slow injection seam: an armed delay() narrowed to one
+            # replica stretches every scheduler iteration there — TTFT
+            # and ITL rise, nothing ever errors
+            fire(FaultPoints.fleet_degrade, replica=self.replica,
+                 engine=self._obs_name)
+            self._expire_queued()
+            self._control_tick()
+            self._admission_tick()
+        tick.admitted(time.perf_counter())
+        # per-tenant ITL: one observation per adapter active in the tick
+        # (captured BEFORE the tick — finished rows are reset inside it)
+        tick_adapters = {s.adapter for s in self._slot_state if s.active}
+        tick.rows = self._decode_tick()
+        if not tick.rows and not tick.prefill_tokens:
+            return 0                        # an idle poll writes nothing
+        tick.t1 = time.perf_counter()
+        elapsed = tick.t1 - tick.t0
+        tick_s = tick.t1 - tick.t_admit
+        with self._lock:
+            self._tick_log.append(tick)
+            if tick.rows:
+                self._itl_ring.append(elapsed)
+                # decode dispatch alone (admission prefill excluded): the
+                # per-tick attention cost the kernel work targets
+                self._tick_ring.append(tick_s)
+                self._adapter_labels_seen.update(
+                    a for a in tick_adapters if a)
+                self._count_attention_tick()
+        if tick.rows:
+            for tick_adapter in tick_adapters:
+                LLM_ITL.observe(elapsed, replica=self.replica,
+                                adapter=tick_adapter)
+            LLM_DECODE_TICK.observe(tick_s, replica=self.replica)
+        return tick.rows
+
     def _loop(self, epoch: int = 0):
         try:
             while self._running:
@@ -2248,40 +2380,9 @@ class ContinuousBatchingEngine:
                 # on-demand profiling (POST /debug/profile): claims or
                 # advances an armed capture — one global check when dark
                 profiler_tick(self._obs_name)
-                # fail-slow injection seam: an armed delay() narrowed to
-                # one replica stretches every scheduler iteration there —
-                # TTFT and ITL rise, nothing ever errors
-                fire(FaultPoints.fleet_degrade, replica=self.replica,
-                     engine=self._obs_name)
-                self._expire_queued()
-                self._control_tick()
-                self._admission_tick()
-                if not any(s.active for s in self._slot_state):
-                    if self._admission is None:
-                        time.sleep(0.002)  # idle: poll admissions at 2ms
-                    continue
-                t_tick = time.perf_counter()
-                # per-tenant ITL: one observation per adapter active in
-                # the tick (captured BEFORE the tick — finished rows are
-                # reset inside it)
-                tick_adapters = {s.adapter for s in self._slot_state
-                                 if s.active}
-                if self._decode_tick():
-                    now = time.perf_counter()
-                    elapsed = now - started
-                    tick_s = now - t_tick
-                    with self._lock:
-                        self._itl_ring.append(elapsed)
-                        # decode dispatch alone (admission prefill
-                        # excluded): the per-tick attention cost the
-                        # kernel work targets
-                        self._tick_ring.append(tick_s)
-                        self._adapter_labels_seen.update(
-                            a for a in tick_adapters if a)
-                    for tick_adapter in tick_adapters:
-                        LLM_ITL.observe(elapsed, replica=self.replica,
-                                        adapter=tick_adapter)
-                    LLM_DECODE_TICK.observe(tick_s, replica=self.replica)
+                if not self._iterate(started) \
+                        and self._admission is None:
+                    time.sleep(0.002)  # idle: poll admissions at 2ms
         except Exception as exc:  # noqa: BLE001 - a dead scheduler must
             # fail pending work loudly, not leave futures hanging forever
             logger.error("continuous batching scheduler died",

@@ -56,8 +56,9 @@ import numpy as np
 from ..chaos import FaultPoints, fire
 from ..config import mlconf
 from ..models.llama import LlamaConfig
-from ..obs import KV_TIER_BYTES, KV_TIER_EVENTS, KV_TIER_HITS
+from ..obs import KV_TIER_BYTES, KV_TIER_EVENTS, KV_TIER_HITS, wall_now
 from ..utils import logger
+from ..utils.profiler import annotate, named
 from .kv_tier import HostKVTier
 from .llm import _forward_with_cache, init_kv_cache
 from .llm_batch import ContinuousBatchingEngine, KVHandoff, _Admission
@@ -206,7 +207,8 @@ def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
     positions = pos[:, None]
     rows = jnp.arange(b)
     safe_table = jnp.maximum(page_table, 0)            # [slots, pages]
-    x = params["embedding"][tokens].astype(config.dtype)
+    with jax.named_scope("embed"):
+        x = params["embedding"][tokens].astype(config.dtype)
     cos, sin = rope_table(positions, config.head_dim, config.rope_theta)
     quantized = "k_scale" in pool
     use_kernel = attn_impl == "kernel"
@@ -222,92 +224,95 @@ def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
     k_new, v_new = [], []
     for layer in range(config.n_layers):
         lp = jax.tree_util.tree_map(lambda a: a[layer], params["layers"])
-        h = rms_norm(x, lp["attn_norm_scale"], config.norm_eps)
+        with jax.named_scope("layer/attn"):
+            h = rms_norm(x, lp["attn_norm_scale"], config.norm_eps)
 
-        def proj(h_in, w, t=None, _layer=layer):
-            out = jnp.einsum("bse,eh->bsh", h_in, w,
-                             preferred_element_type=jnp.float32)
-            if lora is not None and t is not None and t in lora:
-                out = out + _lora_delta(h_in, lora[t], _layer, adapter_ids)
-            return out.astype(x.dtype)
+            def proj(h_in, w, t=None, _layer=layer):
+                out = jnp.einsum("bse,eh->bsh", h_in, w,
+                                 preferred_element_type=jnp.float32)
+                if lora is not None and t is not None and t in lora:
+                    out = out + _lora_delta(h_in, lora[t], _layer, adapter_ids)
+                return out.astype(x.dtype)
 
-        q = proj(h, lp["wq"], "wq").reshape(b, 1, config.n_heads,
-                                            config.head_dim)
-        k = proj(h, lp["wk"], "wk").reshape(b, 1, config.n_kv_heads,
-                                            config.head_dim)
-        v = proj(h, lp["wv"], "wv").reshape(b, 1, config.n_kv_heads,
-                                            config.head_dim)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+            q = proj(h, lp["wq"], "wq").reshape(b, 1, config.n_heads,
+                                                config.head_dim)
+            k = proj(h, lp["wk"], "wk").reshape(b, 1, config.n_kv_heads,
+                                                config.head_dim)
+            v = proj(h, lp["wv"], "wv").reshape(b, 1, config.n_kv_heads,
+                                                config.head_dim)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
 
-        if use_kernel:
-            # token KV lands in the pool first (unmapped slots route to
-            # the never-read scratch page), then the kernel attends
-            # pool-side via the page table — no dense view, no gather.
-            # int8 pools quantize the token per vector on the way in and
-            # the kernel dequantizes in-register (scales ride
-            # page-table-indexed operands)
-            scales_kw = {}
-            if quantized:
-                kq_, ks_ = _quantize_kv(k[:, 0])
-                vq_, vs_ = _quantize_kv(v[:, 0])
-                pool["k"] = pool["k"].at[layer, pid_safe, offset].set(kq_)
-                pool["v"] = pool["v"].at[layer, pid_safe, offset].set(vq_)
-                pool["k_scale"] = pool["k_scale"].at[
-                    layer, pid_safe, offset].set(ks_)
-                pool["v_scale"] = pool["v_scale"].at[
-                    layer, pid_safe, offset].set(vs_)
-                scales_kw = {"k_scale": pool["k_scale"][layer],
-                             "v_scale": pool["v_scale"][layer]}
+            if use_kernel:
+                # token KV lands in the pool first (unmapped slots route to
+                # the never-read scratch page), then the kernel attends
+                # pool-side via the page table — no dense view, no gather.
+                # int8 pools quantize the token per vector on the way in and
+                # the kernel dequantizes in-register (scales ride
+                # page-table-indexed operands)
+                scales_kw = {}
+                if quantized:
+                    kq_, ks_ = _quantize_kv(k[:, 0])
+                    vq_, vs_ = _quantize_kv(v[:, 0])
+                    pool["k"] = pool["k"].at[layer, pid_safe, offset].set(kq_)
+                    pool["v"] = pool["v"].at[layer, pid_safe, offset].set(vq_)
+                    pool["k_scale"] = pool["k_scale"].at[
+                        layer, pid_safe, offset].set(ks_)
+                    pool["v_scale"] = pool["v_scale"].at[
+                        layer, pid_safe, offset].set(vs_)
+                    scales_kw = {"k_scale": pool["k_scale"][layer],
+                                 "v_scale": pool["v_scale"][layer]}
+                else:
+                    pool["k"] = pool["k"].at[layer, pid_safe, offset].set(
+                        k[:, 0].astype(pool["k"].dtype))
+                    pool["v"] = pool["v"].at[layer, pid_safe, offset].set(
+                        v[:, 0].astype(pool["v"].dtype))
+                attn = paged_attention(
+                    q[:, 0], pool["k"][layer], pool["v"][layer], page_table,
+                    pos, page_size=page_size, impl="kernel",
+                    **scales_kw)[:, None]
             else:
-                pool["k"] = pool["k"].at[layer, pid_safe, offset].set(
-                    k[:, 0].astype(pool["k"].dtype))
-                pool["v"] = pool["v"].at[layer, pid_safe, offset].set(
-                    v[:, 0].astype(pool["v"].dtype))
-            attn = paged_attention(
-                q[:, 0], pool["k"][layer], pool["v"][layer], page_table,
-                pos, page_size=page_size, impl="kernel",
-                **scales_kw)[:, None]
-        else:
-            # dense per-layer view of this slot's pages (dequantized)
-            kp = jnp.take(pool["k"][layer], safe_table, axis=0)
-            vp = jnp.take(pool["v"][layer], safe_table, axis=0)
-            s_, p_, ps_, hh, dd = kp.shape
-            kd = kp.reshape(s_, p_ * ps_, hh, dd)
-            vd = vp.reshape(s_, p_ * ps_, hh, dd)
-            if quantized:
-                ksc = jnp.take(pool["k_scale"][layer], safe_table,
-                               axis=0).reshape(s_, p_ * ps_, hh)
-                vsc = jnp.take(pool["v_scale"][layer], safe_table,
-                               axis=0).reshape(s_, p_ * ps_, hh)
-                kd = (kd.astype(jnp.float32) * ksc[..., None]).astype(
-                    config.dtype)
-                vd = (vd.astype(jnp.float32) * vsc[..., None]).astype(
-                    config.dtype)
-            else:
-                kd = kd.astype(config.dtype)
-                vd = vd.astype(config.dtype)
-            # splice the new token into the dense view at each slot's
-            # position
-            kd = kd.at[rows, pos].set(k[:, 0])
-            vd = vd.at[rows, pos].set(v[:, 0])
-            attn = _cached_attention(config, q, kd, vd, positions,
-                                     kd.shape[1])
-            k_new.append(k[:, 0])
-            v_new.append(v[:, 0])
-        attn = attn.reshape(b, 1, config.qkv_dim)
-        x_mid = x + proj(attn, lp["wo"], "wo")
-        h2 = rms_norm(x_mid, lp["mlp_norm_scale"], config.norm_eps)
-        gate = proj(h2, lp["w_gate"], "w_gate")
-        up = proj(h2, lp["w_up"], "w_up")
-        x = x_mid + proj(jax.nn.silu(gate) * up, lp["w_down"], "w_down")
+                # dense per-layer view of this slot's pages (dequantized)
+                kp = jnp.take(pool["k"][layer], safe_table, axis=0)
+                vp = jnp.take(pool["v"][layer], safe_table, axis=0)
+                s_, p_, ps_, hh, dd = kp.shape
+                kd = kp.reshape(s_, p_ * ps_, hh, dd)
+                vd = vp.reshape(s_, p_ * ps_, hh, dd)
+                if quantized:
+                    ksc = jnp.take(pool["k_scale"][layer], safe_table,
+                                   axis=0).reshape(s_, p_ * ps_, hh)
+                    vsc = jnp.take(pool["v_scale"][layer], safe_table,
+                                   axis=0).reshape(s_, p_ * ps_, hh)
+                    kd = (kd.astype(jnp.float32) * ksc[..., None]).astype(
+                        config.dtype)
+                    vd = (vd.astype(jnp.float32) * vsc[..., None]).astype(
+                        config.dtype)
+                else:
+                    kd = kd.astype(config.dtype)
+                    vd = vd.astype(config.dtype)
+                # splice the new token into the dense view at each slot's
+                # position
+                kd = kd.at[rows, pos].set(k[:, 0])
+                vd = vd.at[rows, pos].set(v[:, 0])
+                attn = _cached_attention(config, q, kd, vd, positions,
+                                         kd.shape[1])
+                k_new.append(k[:, 0])
+                v_new.append(v[:, 0])
+            attn = attn.reshape(b, 1, config.qkv_dim)
+            x_mid = x + proj(attn, lp["wo"], "wo")
+        with jax.named_scope("layer/mlp"):
+            h2 = rms_norm(x_mid, lp["mlp_norm_scale"], config.norm_eps)
+            gate = proj(h2, lp["w_gate"], "w_gate")
+            up = proj(h2, lp["w_up"], "w_up")
+            x = x_mid + proj(jax.nn.silu(gate) * up, lp["w_down"], "w_down")
 
-    x = rms_norm(x, params["final_norm_scale"], config.norm_eps)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embedding"].T
-    logits = jnp.einsum("bse,ev->bsv", x, head,
-                        preferred_element_type=jnp.float32)[:, 0]
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm_scale"], config.norm_eps)
+        head = params.get("lm_head")
+        if head is None:
+            head = params["embedding"].T
+        logits = jnp.einsum("bse,ev->bsv", x, head,
+                            preferred_element_type=jnp.float32)[:, 0]
     if rng is None:
         next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     else:
@@ -371,7 +376,8 @@ def _verify_rowwise_paged(config: LlamaConfig, page_size: int,
     b, s = chunk.shape
     pps = page_table.shape[1]
     positions = pos[:, None] + jnp.arange(s)[None, :]     # [slots, S]
-    x = params["embedding"][chunk].astype(config.dtype)
+    with jax.named_scope("embed"):
+        x = params["embedding"][chunk].astype(config.dtype)
     cos, sin = rope_table(positions, config.head_dim, config.rope_theta)
     quantized = "k_scale" in pool
     use_kernel = attn_impl == "kernel"
@@ -389,68 +395,71 @@ def _verify_rowwise_paged(config: LlamaConfig, page_size: int,
 
     for layer in range(config.n_layers):
         lp = jax.tree_util.tree_map(lambda a: a[layer], params["layers"])
-        h = rms_norm(x, lp["attn_norm_scale"], config.norm_eps)
+        with jax.named_scope("layer/attn"):
+            h = rms_norm(x, lp["attn_norm_scale"], config.norm_eps)
 
-        def proj(h_in, w, t=None, _layer=layer):
-            out = jnp.einsum("bse,eh->bsh", h_in, w,
-                             preferred_element_type=jnp.float32)
-            if lora is not None and t is not None and t in lora:
-                out = out + _lora_delta(h_in, lora[t], _layer, adapter_ids)
-            return out.astype(x.dtype)
+            def proj(h_in, w, t=None, _layer=layer):
+                out = jnp.einsum("bse,eh->bsh", h_in, w,
+                                 preferred_element_type=jnp.float32)
+                if lora is not None and t is not None and t in lora:
+                    out = out + _lora_delta(h_in, lora[t], _layer, adapter_ids)
+                return out.astype(x.dtype)
 
-        q = proj(h, lp["wq"], "wq").reshape(b, s, config.n_heads,
-                                            config.head_dim)
-        k = proj(h, lp["wk"], "wk").reshape(b, s, config.n_kv_heads,
-                                            config.head_dim)
-        v = proj(h, lp["wv"], "wv").reshape(b, s, config.n_kv_heads,
-                                            config.head_dim)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+            q = proj(h, lp["wq"], "wq").reshape(b, s, config.n_heads,
+                                                config.head_dim)
+            k = proj(h, lp["wk"], "wk").reshape(b, s, config.n_kv_heads,
+                                                config.head_dim)
+            v = proj(h, lp["wv"], "wv").reshape(b, s, config.n_kv_heads,
+                                                config.head_dim)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
 
-        scales_kw = {}
-        if quantized:
-            kq_, ks_ = _quantize_kv(k)
-            vq_, vs_ = _quantize_kv(v)
-            pool["k"] = pool["k"].at[layer, pid_safe, offset].set(kq_)
-            pool["v"] = pool["v"].at[layer, pid_safe, offset].set(vq_)
-            pool["k_scale"] = pool["k_scale"].at[
-                layer, pid_safe, offset].set(ks_)
-            pool["v_scale"] = pool["v_scale"].at[
-                layer, pid_safe, offset].set(vs_)
-            scales_kw = {"k_scale": pool["k_scale"][layer],
-                         "v_scale": pool["v_scale"][layer]}
-            if use_kernel:
-                # the kernel's local chunk part must see the SAME bits a
-                # later decode tick reads back from the int8 pool
-                chunk_k = _dequantize_kv(kq_, ks_, config.dtype)
-                chunk_v = _dequantize_kv(vq_, vs_, config.dtype)
+            scales_kw = {}
+            if quantized:
+                kq_, ks_ = _quantize_kv(k)
+                vq_, vs_ = _quantize_kv(v)
+                pool["k"] = pool["k"].at[layer, pid_safe, offset].set(kq_)
+                pool["v"] = pool["v"].at[layer, pid_safe, offset].set(vq_)
+                pool["k_scale"] = pool["k_scale"].at[
+                    layer, pid_safe, offset].set(ks_)
+                pool["v_scale"] = pool["v_scale"].at[
+                    layer, pid_safe, offset].set(vs_)
+                scales_kw = {"k_scale": pool["k_scale"][layer],
+                             "v_scale": pool["v_scale"][layer]}
+                if use_kernel:
+                    # the kernel's local chunk part must see the SAME bits a
+                    # later decode tick reads back from the int8 pool
+                    chunk_k = _dequantize_kv(kq_, ks_, config.dtype)
+                    chunk_v = _dequantize_kv(vq_, vs_, config.dtype)
+                else:
+                    # reference decode splices the RAW token KV into its
+                    # dequantized view — the verify fallback matches it
+                    chunk_k, chunk_v = k, v
             else:
-                # reference decode splices the RAW token KV into its
-                # dequantized view — the verify fallback matches it
+                pool["k"] = pool["k"].at[layer, pid_safe, offset].set(
+                    k.astype(pool["k"].dtype))
+                pool["v"] = pool["v"].at[layer, pid_safe, offset].set(
+                    v.astype(pool["v"].dtype))
                 chunk_k, chunk_v = k, v
-        else:
-            pool["k"] = pool["k"].at[layer, pid_safe, offset].set(
-                k.astype(pool["k"].dtype))
-            pool["v"] = pool["v"].at[layer, pid_safe, offset].set(
-                v.astype(pool["v"].dtype))
-            chunk_k, chunk_v = k, v
-        attn = paged_verify_attention(
-            q, chunk_k, chunk_v, pool["k"][layer], pool["v"][layer],
-            page_table, pos, page_size=page_size,
-            impl="kernel" if use_kernel else "reference", **scales_kw)
-        attn = attn.astype(x.dtype).reshape(b, s, config.qkv_dim)
-        x_mid = x + proj(attn, lp["wo"], "wo")
-        h2 = rms_norm(x_mid, lp["mlp_norm_scale"], config.norm_eps)
-        gate = proj(h2, lp["w_gate"], "w_gate")
-        up = proj(h2, lp["w_up"], "w_up")
-        x = x_mid + proj(jax.nn.silu(gate) * up, lp["w_down"], "w_down")
+            attn = paged_verify_attention(
+                q, chunk_k, chunk_v, pool["k"][layer], pool["v"][layer],
+                page_table, pos, page_size=page_size,
+                impl="kernel" if use_kernel else "reference", **scales_kw)
+            attn = attn.astype(x.dtype).reshape(b, s, config.qkv_dim)
+            x_mid = x + proj(attn, lp["wo"], "wo")
+        with jax.named_scope("layer/mlp"):
+            h2 = rms_norm(x_mid, lp["mlp_norm_scale"], config.norm_eps)
+            gate = proj(h2, lp["w_gate"], "w_gate")
+            up = proj(h2, lp["w_up"], "w_up")
+            x = x_mid + proj(jax.nn.silu(gate) * up, lp["w_down"], "w_down")
 
-    x = rms_norm(x, params["final_norm_scale"], config.norm_eps)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embedding"].T
-    logits = jnp.einsum("bse,ev->bsv", x, head,
-                        preferred_element_type=jnp.float32)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm_scale"], config.norm_eps)
+        head = params.get("lm_head")
+        if head is None:
+            head = params["embedding"].T
+        logits = jnp.einsum("bse,ev->bsv", x, head,
+                            preferred_element_type=jnp.float32)
     verified = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return verified, pool
 
@@ -571,18 +580,20 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         # the paged engine's prefill carries the pool page size so a
         # prefix-hit dispatch can attend pool pages in place
         # (prefix_kv= — see _prefill_dispatch)
-        self._prefill = jax.jit(functools.partial(
+        self._prefill = jax.jit(named("mlt_prefill", functools.partial(
             _forward_with_cache, config, attn_impl=self.prefill_impl,
-            page_size=page_size))
+            page_size=page_size)))
         self._decode_paged = jax.jit(
-            functools.partial(_decode_rowwise_paged, config, page_size,
-                              self.attn_impl),
+            named("mlt_decode", functools.partial(
+                _decode_rowwise_paged, config, page_size, self.attn_impl)),
             donate_argnums=(2,))
         self._insert_paged = jax.jit(
-            functools.partial(insert_prompt_pages, page_size=page_size),
+            named("mlt_insert", functools.partial(
+                insert_prompt_pages, page_size=page_size)),
             donate_argnums=(0,))
         self._gather_paged = jax.jit(
-            functools.partial(gather_prefix_pages, page_size=page_size),
+            named("mlt_gather", functools.partial(
+                gather_prefix_pages, page_size=page_size)),
             donate_argnums=(1,))
 
     def _make_cache(self):
@@ -661,9 +672,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
 
     def _spec_warmup_verify(self):
         # all-(-1) table routes every chunk write to the scratch page
-        # and marks zero pages live; outputs are discarded. Called
-        # directly (not via _spec_verify_dispatch) so warmup doesn't
-        # count attention ticks.
+        # and marks zero pages live; outputs are discarded
         chunk = jnp.zeros((self.slots, self.spec_k + 1), jnp.int32)
         table = jnp.full((self.slots, self.pages_per_slot), -1, jnp.int32)
         pos = jnp.zeros((self.slots,), jnp.int32)
@@ -1108,7 +1117,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                     slot=free, request_id=request_id, prompt=prompt,
                     max_new=max_new, eos_id=eos_id, future=future,
                     submitted=submitted, sampling=sampling,
-                    expires=expires, trace=item[8], claimed=time.time(),
+                    expires=expires, trace=item[8], claimed=wall_now(),
                     base=k * self.page_size, offset=k * self.page_size,
                     adapter=adapter, adapter_slot=adapter_slot,
                     ledger=ledger)
@@ -1287,8 +1296,9 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
 
     def _make_verify_fn(self):
         return jax.jit(
-            functools.partial(_verify_rowwise_paged, self.config,
-                              self.page_size, self.attn_impl),
+            named("mlt_verify", functools.partial(
+                _verify_rowwise_paged, self.config, self.page_size,
+                self.attn_impl)),
             donate_argnums=(2,))
 
     def _spec_apply_positions(self, committed: dict):
@@ -1309,66 +1319,51 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         verified, self._pool = self._spec_verify_fn()(
             self.params, jnp.asarray(chunk), self._pool, table, pos,
             **lora_kw)
-        with self._lock:
-            # a verify dispatch is one attention tick like any other: on
-            # the kernel path it never gathers a dense view
-            # (attn_gather_ticks stays 0) and the avoided HBM copy is
-            # accounted the same way as a decode tick
-            if self.attn_impl == "kernel":
-                self._stats["attn_kernel_ticks"] += 1
-                self._stats["attn_hbm_bytes_avoided"] += \
-                    self._gather_bytes_per_tick
-            else:
-                self._stats["attn_gather_ticks"] += 1
         return np.asarray(verified)
 
+    def _count_attention_tick(self):
+        # the microbench/acceptance stat, once per decoded iteration (a
+        # verify dispatch is one attention tick like any other): on the
+        # kernel path the tick never gathers a dense view
+        # (attn_gather_ticks stays 0) and the avoided HBM copy is
+        # accounted per tick
+        if self.attn_impl == "kernel":
+            self._stats["attn_kernel_ticks"] += 1
+            self._stats["attn_hbm_bytes_avoided"] += \
+                self._gather_bytes_per_tick
+        else:
+            self._stats["attn_gather_ticks"] += 1
+
     def _plain_decode_tick(self, active) -> int:
-        last = np.zeros((self.slots, 1), np.int32)
-        for i in active:
-            last[i, 0] = self._slot_state[i].tokens[-1]
-        table = jnp.asarray(self._page_table)
-        pos = jnp.asarray(self._pos)
-        lora_kw = self._lora_kwargs(self._slot_adapter_ids()) \
-            if self._adapters is not None else {}
-        self._ledger_mark(active, "decode_active")
-        if any(self._slot_state[i].temperature > 0 for i in active):
-            temp = np.zeros((self.slots,), np.float32)
-            top_k = np.zeros((self.slots,), np.int32)
-            top_p = np.ones((self.slots,), np.float32)
+        tick = self._tick
+        with annotate("mlt.sched.build"):
+            last, tick.ctx_tokens = self._tick_inputs(active)
+            table = jnp.asarray(self._page_table)
+            pos = jnp.asarray(self._pos)
+            lora_kw = self._lora_kwargs(self._slot_adapter_ids()) \
+                if self._adapters is not None else {}
+            self._ledger_mark(active, "decode_active")
+            args = (jnp.asarray(last), self._pool, table, pos) \
+                + self._sampling_args(active)
+        tick.t_built = time.perf_counter()
+        with annotate("mlt.sched.dispatch"):
+            next_token, self._pool, _ = self._decode_paged(
+                self.params, *args, **lora_kw)
+        tick.t_dispatched = time.perf_counter()
+        with annotate("mlt.sched.fetch"):
+            tokens_host = np.asarray(next_token)
+        tick.t_fetched = time.perf_counter()
+        with annotate("mlt.sched.commit"):
+            self._ledger_mark(active, "decode_stall")
             for i in active:
                 slot = self._slot_state[i]
-                temp[i] = slot.temperature
-                top_k[i] = slot.top_k
-                top_p[i] = slot.top_p
-            self._rng, sub = jax.random.split(self._rng)
-            next_token, self._pool, _ = self._decode_paged(
-                self.params, jnp.asarray(last), self._pool, table, pos,
-                sub, jnp.asarray(temp), jnp.asarray(top_k),
-                jnp.asarray(top_p), **lora_kw)
-        else:
-            next_token, self._pool, _ = self._decode_paged(
-                self.params, jnp.asarray(last), self._pool, table, pos,
-                **lora_kw)
-        tokens_host = np.asarray(next_token)
-        self._ledger_mark(active, "decode_stall")
-        with self._lock:
-            # the microbench/acceptance stat: on the kernel path the tick
-            # never gathers a dense view (attn_gather_ticks stays 0) and
-            # the avoided HBM copy is accounted per tick
-            if self.attn_impl == "kernel":
-                self._stats["attn_kernel_ticks"] += 1
-                self._stats["attn_hbm_bytes_avoided"] += \
-                    self._gather_bytes_per_tick
-            else:
-                self._stats["attn_gather_ticks"] += 1
-        for i in active:
-            slot = self._slot_state[i]
-            token = int(tokens_host[i])
-            slot.tokens.append(token)
-            slot.remaining -= 1
-            self._pos[i] += 1
-            capacity = slot.prompt_len + len(slot.tokens) >= self.max_len
-            if (slot.eos_id is not None and token == slot.eos_id) or \
-                    slot.remaining <= 0 or capacity:
-                self._finish(i)
+                token = int(tokens_host[i])
+                slot.tokens.append(token)
+                slot.remaining -= 1
+                self._pos[i] += 1
+                capacity = slot.prompt_len + len(slot.tokens) \
+                    >= self.max_len
+                if (slot.eos_id is not None and token == slot.eos_id) or \
+                        slot.remaining <= 0 or capacity:
+                    self._finish(i)
         return len(active)

@@ -30,6 +30,7 @@ from ..parallel.sharding import (
     tree_shardings,
 )
 from ..utils import logger
+from ..utils.profiler import named
 from .mfu import ThroughputTracker, chip_peak_flops, mfu
 
 
@@ -319,7 +320,7 @@ def make_train_step(model_config: LlamaConfig, train_config: TrainConfig,
                                      replicated, None)
 
     jitted = jax.jit(
-        step_fn,
+        named("mlt_train_step", step_fn),   # the module's name in a profile
         in_shardings=(state_shardings, data_sh, data_sh),
         out_shardings=(state_shardings, replicated),
         donate_argnums=(0,),
@@ -454,7 +455,7 @@ def _make_pp_step(model_config, train_config: TrainConfig, optimizer,
                                  replicated, None)
     data_sh = NamedSharding(mesh, PartitionSpec(batch_axis))
     jitted = jax.jit(
-        step_fn,
+        named("mlt_train_step", step_fn),   # the module's name in a profile
         in_shardings=(state_shardings, data_sh, data_sh),
         out_shardings=(state_shardings, replicated),
         donate_argnums=(0,),

@@ -3,11 +3,12 @@
 Reference has no distributed tracer (SURVEY.md §5.1); on TPU the equivalents
 are XLA device traces (jax.profiler → TensorBoard) plus per-step wall-time
 tracking. ``profile_run`` captures a device trace into the run's artifact
-path and registers it; ``StepTimer`` feeds per-step timing into run metrics;
-``arm_profile``/``tick`` let a live trainer or engine be profiled for the
-next N steps/seconds WITHOUT a restart (the ``POST /debug/profile``
-endpoints arm it; the hot loops tick it — docs/observability.md "Flight
-recorder & debug endpoints").
+path and registers it; ``arm_profile``/``tick`` let a live trainer or engine
+be profiled for the next N steps/seconds WITHOUT a restart (the ``POST
+/debug/profile`` endpoints arm it; the hot loops tick it —
+docs/observability.md "Flight recorder & debug endpoints"); ``annotate``
+writes the program's own spans (the scheduler's ``mlt.sched.*``) into that
+trace and ``named`` gives a jitted step the module name it is found by.
 """
 
 from __future__ import annotations
@@ -290,79 +291,57 @@ def _finalize_capture(finished: dict, context, reason: str):
         pass
 
 
-@contextlib.contextmanager
-def annotate(name: str):
-    """Named region in the device trace (TraceAnnotation). When a request
-    span is active on this thread the trace id is stamped into the region
-    name (``<name>|trace=<id16>``), so an XLA device trace in TensorBoard
-    joins the span timeline of the request that dispatched the compute
-    (docs/observability.md)."""
+# (TraceAnnotation, the tracer's current() or None): resolved on first use,
+# so that a scheduler tick pays neither an import nor a config read
+_annotation = None
+
+
+def _resolve_annotation():
     import jax
 
+    current = None
     try:
         from ..config import mlconf
         from ..obs import get_tracer
 
         if bool(mlconf.observability.xla_annotations):
-            current = get_tracer().current()
-            if current is not None:
-                name = f"{name}|trace={current.trace_id[:16]}"
+            current = get_tracer().current
     except Exception:  # noqa: BLE001 - annotation is best-effort telemetry
         pass
-    with jax.profiler.TraceAnnotation(name):
-        yield
+    return jax.profiler.TraceAnnotation, current
 
 
-class StepTimer:
-    """Rolling per-step wall-time stats for trainer/serving loops.
-    ``name`` keys the ``mlt_train_step_seconds`` gauge on /metrics."""
+def annotate(name: str, **metadata):
+    """Named region in the profiler's trace, on the device trace's clock
+    (a ``TraceAnnotation``; ``metadata`` lands in the event's stats). When
+    a request span is active on this thread the trace id is stamped into
+    the region name (``<name>|trace=<id16>``), so an XLA device trace in
+    TensorBoard joins the span timeline of the request that dispatched the
+    compute (docs/observability.md). ``mlconf.observability.
+    xla_annotations`` is read once, at the first call. With no capture
+    running the region costs under a microsecond."""
+    global _annotation
 
-    def __init__(self, window: int = 100, name: str = "step"):
-        self.window = window
-        self.name = name
-        self._times: list[float] = []
-        self._last: Optional[float] = None
+    if _annotation is None:
+        _annotation = _resolve_annotation()
+    region, current = _annotation
+    if current is not None:
+        span = current()
+        if span is not None:
+            name = f"{name}|trace={span.trace_id[:16]}"
+    return region(name, **metadata)
 
-    def start(self):
-        self._last = time.perf_counter()
 
-    def stop(self) -> float:
-        if self._last is None:
-            return 0.0
-        elapsed = time.perf_counter() - self._last
-        self._times.append(elapsed)
-        if len(self._times) > self.window:
-            del self._times[: len(self._times) - self.window]
-        self._last = None
-        try:
-            from ..obs import TRAIN_STEP_TIME
+def named(name: str, fn):
+    """``fn`` under a stable ``__name__``. ``jax.jit`` names the compiled
+    module after the function it is given (``jit_<name>``), and a
+    ``functools.partial`` has no name: its module reads ``jit__unknown`` in
+    a profile. Metadata only; nothing about the program changes."""
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
 
-            TRAIN_STEP_TIME.set(elapsed, timer=self.name)
-        except Exception:  # noqa: BLE001 - telemetry must not break a step
-            pass
-        return elapsed
-
-    @contextlib.contextmanager
-    def measure(self):
-        self.start()
-        try:
-            yield
-        finally:
-            self.stop()
-
-    def summary(self) -> dict:
-        if not self._times:
-            return {}
-        from ..obs.stats import nearest_rank
-
-        ordered = sorted(self._times)
-        n = len(ordered)
-        return {
-            "step_time_mean_s": sum(ordered) / n,
-            "step_time_p50_s": nearest_rank(ordered, 0.50),
-            "step_time_p95_s": nearest_rank(ordered, 0.95),
-            "steps_measured": n,
-        }
+    program.__name__ = program.__qualname__ = name
+    return program
 
 
 def memory_sample() -> dict:

@@ -398,14 +398,6 @@ def test_nearest_rank_fixes_one_rank_high_bias():
 
     assert _percentile(samples, 0.95) == nearest_rank(samples, 0.95)
 
-    from mlrun_tpu.utils.profiler import StepTimer
-
-    timer = StepTimer(window=200, name="t-goodput")
-    timer._times = list(samples)
-    summary = timer.summary()
-    assert summary["step_time_p95_s"] == 95.0
-    assert summary["step_time_p50_s"] == 50.0
-
 
 # -- satellite: memory exposition --------------------------------------------
 

@@ -48,18 +48,9 @@ def test_render_html():
     assert "m1" in html2
 
 
-def test_step_timer_and_memory_report():
-    import time
+def test_memory_report():
+    from mlrun_tpu.utils.profiler import memory_report
 
-    from mlrun_tpu.utils.profiler import StepTimer, memory_report
-
-    timer = StepTimer()
-    for _ in range(3):
-        with timer.measure():
-            time.sleep(0.01)
-    summary = timer.summary()
-    assert summary["steps_measured"] == 3
-    assert summary["step_time_mean_s"] >= 0.01
     report = memory_report()
     assert "host_vmrss" in report
 
