@@ -100,12 +100,22 @@ def phase_kernels(config, geo, interpret: bool, seed: int):
         return (jax.random.normal(jax.random.fold_in(key, i), shape,
                                   jnp.float32) * scale).astype(dtype)
 
-    k_pages = normal(0, (n_pages + 1, ps, hkv, d), 0.3)
-    v_pages = normal(1, (n_pages + 1, ps, hkv, d), 0.3)
-    k8, ks = _quantize_kv(k_pages)
-    v8, vs = _quantize_kv(v_pages)
-    pools = {"bf16": (k_pages, v_pages, {}),
+    # the kernels take the pool as the engine stores it and a layer
+    # index: two layers that differ, the second attended, and each
+    # reference handed that layer alone (a wrong index cannot pass)
+    layer = 1
+    k_pool = normal(0, (2, n_pages + 1, ps, hkv, d), 0.3)
+    v_pool = normal(1, (2, n_pages + 1, ps, hkv, d), 0.3)
+    k8, ks = _quantize_kv(k_pool)
+    v8, vs = _quantize_kv(v_pool)
+    pools = {"bf16": (k_pool, v_pool, {}),
              "int8": (k8, v8, {"k_scale": ks, "v_scale": vs})}
+
+    def only(arr):
+        return arr[layer:layer + 1]
+
+    alone = {label: {n: only(s) for n, s in scales.items()}
+             for label, (_, _, scales) in pools.items()}
 
     # every slot maps a distinct run of shuffled pages; positions span
     # one token, mid-page, page edges and the full context; pages past
@@ -139,10 +149,12 @@ def phase_kernels(config, geo, interpret: bool, seed: int):
         # paged decode: one token per slot
         q = normal(2, (slots, h, d), 0.5)
         for label, (kp, vp, scales) in pools.items():
-            out = pattn._paged_decode_call(q, kp, vp, table_j, pos_j, ps,
-                                           interpret=interpret, **scales)
-            ref = pattn.paged_decode_reference(q, kp, vp, table_j, pos_j,
-                                               ps, **scales)
+            out = pattn._paged_decode_call(q, kp, vp, layer, table_j,
+                                           pos_j, ps, interpret=interpret,
+                                           **scales)
+            ref = pattn.paged_decode_reference(q, only(kp), only(vp), 0,
+                                               table_j, pos_j, ps,
+                                               **alone[label])
             check(f"paged_decode/{label}", out, ref)
 
         # paged verify: k+1 rows per slot over the prefix, chunk merged
@@ -157,10 +169,11 @@ def phase_kernels(config, geo, interpret: bool, seed: int):
             -1).astype(np.int32))
         for label, (kp, vp, scales) in pools.items():
             out = pattn.paged_verify_attention(
-                qv, ck, cv, kp, vp, vtable, base_j, page_size=ps,
+                qv, ck, cv, kp, vp, layer, vtable, base_j, page_size=ps,
                 impl="kernel", interpret=interpret, **scales)
             ref = pattn.paged_verify_reference(
-                qv, ck, cv, kp, vp, vtable, base_j, ps, **scales)
+                qv, ck, cv, only(kp), only(vp), 0, vtable, base_j, ps,
+                **alone[label])
             check(f"paged_verify/{label}", out, ref)
 
         # paged prefill on a prefix hit: a suffix chunk over `cached`
@@ -178,8 +191,7 @@ def phase_kernels(config, geo, interpret: bool, seed: int):
         positions = cached + jnp.arange(chunk)[None, :]
         suffix_rows = slice(cached, cached + chunk)
         for label, (kp, vp, scales) in pools.items():
-            pool = {"k": kp[None], "v": vp[None],
-                    **{n: s[None] for n, s in scales.items()}}
+            pool = {"k": only(kp), "v": only(vp), **alone[label]}
             small = gather_prefix_pages(
                 pool, init_kv_cache(one_layer, 1, max_len,
                                     kv_dtype="int8" if scales else "native"),
@@ -201,7 +213,7 @@ def phase_kernels(config, geo, interpret: bool, seed: int):
             k_loc, k_dense = local_and_dense("k", k_suf)
             v_loc, v_dense = local_and_dense("v", v_suf)
             out = pattn.paged_prefill_attention(
-                qp, k_loc, v_loc, jnp.int32(cached), kp, vp, ids_j,
+                qp, k_loc, v_loc, jnp.int32(cached), kp, vp, layer, ids_j,
                 jnp.int32(cached), page_size=ps, interpret=interpret,
                 **scales)
             ref = _cached_attention(one_layer, qp.astype(jnp.float32),

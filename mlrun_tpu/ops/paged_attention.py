@@ -9,15 +9,16 @@ a Pallas kernel: each grid step DMAs exactly one physical page from
 HBM into VMEM, so the bytes read per tick are the slot's *live* pages once
 — never a gathered copy of the full view.
 
-Layout (one layer of the pool, see serving/paged.py):
+Layout (the pool as serving/paged.py stores it, all layers stacked):
 
 - q:          [slots, n_heads, head_dim]   — the current decode token,
   post-RoPE (its KV must already be written into the pool; the kernel
   masks ``k_pos <= pos`` so the current position participates).
-- k/v pages:  [n_pages + 1, page_size, n_kv_heads, head_dim] — the LAST
-  physical page is the scratch page; page-table entries < 0 are routed to
-  it (they are masked out by ``pos`` anyway, the routing just keeps the
-  DMA addresses in-bounds).
+- k/v pool:   [n_layers, n_pages + 1, page_size, n_kv_heads, head_dim]
+  — the LAST physical page of a layer is the scratch page; page-table
+  entries < 0 are routed to it (they are masked out by ``pos`` anyway,
+  the routing just keeps the DMA addresses in-bounds).
+- layer:      int32 scalar, traced — which layer of the pool to attend.
 - page_table: [slots, pages_per_slot] int32, -1 = unmapped.
 - pos:        [slots] int32 absolute position of the current token
   (valid cache length is ``pos + 1``).
@@ -28,10 +29,18 @@ the blocking the TPU lowering accepts for this pool layout — carrying
 the online-softmax running max/denominator/accumulator of every GQA
 query group in VMEM scratch — the same accumulation scheme as the
 verified flash_v2 kernel (ops/attention.py), so numerics match the dense
-reference to float32 round-off. The page table and positions ride scalar
-prefetch (``PrefetchScalarGridSpec``) because the k/v BlockSpec index
-maps need them to translate (slot, page-slot) -> physical page id before
-the DMA.
+reference to float32 round-off. The layer index, the page table and the
+positions ride scalar prefetch (``PrefetchScalarGridSpec``) because the
+k/v BlockSpec index maps need them to translate (slot, page-slot) ->
+(layer, physical page id) before the DMA.
+
+Why the whole pool and an index, not ``pool[layer]``: an operand of a
+``pallas_call`` is a buffer of its own, so a sliced layer is a copy XLA
+must make before every call — a whole pool layer moved to read a few
+live pages of it. Handed the pool as stored, the kernel reaches the
+layer through its index maps and no program copies anything; because
+``layer`` is a value and not a Python constant, all layers of a program
+are one kernel (tests/test_tpu_compile.py holds both).
 
 Beyond decode, this module carries the other two KV-heavy moments of the
 serving path (docs/serving.md "Attention kernels"), both on ONE
@@ -109,8 +118,9 @@ def resolve_paged_impl(impl: str = "auto") -> str:
 # Blocking (what the TPU lowering accepts — tests/test_tpu_compile.py): a
 # block's last two dims must be (8, 128)-aligned or span the array's, so
 # every kernel takes ALL kv heads of a page per grid step (``[1,
-# page_size, Hkv, D]`` — the pool keeps its layout) and loops the GQA
-# groups in the body, reading head ``h`` as ``k_ref[0, :, h, :]``.
+# page_size, Hkv, D]`` of the 5-D pool, the layer dim squeezed — the pool
+# keeps its layout) and loops the GQA groups in the body, reading head
+# ``h`` as ``k_ref[0, :, h, :]``.
 
 def _attend_page(q_ref, k_ref, v_ref, scale_refs, m_scr, l_scr, acc_scr,
                  *, p, limit, page_size: int, scale: float):
@@ -152,12 +162,27 @@ def _reset_softmax_state(m_scr, l_scr, acc_scr):
     acc_scr[:] = jnp.zeros_like(acc_scr)
 
 
-def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *refs,
-                         page_size: int, pages_per_slot: int,
+def _safe_table(page_table, k_pool):
+    """Page ids with -1 (unmapped) routed to the pool's scratch page —
+    masked out by position anyway, this keeps the DMA in bounds."""
+    scratch_page = k_pool.shape[1] - 1
+    return jnp.where(page_table >= 0, page_table,
+                     scratch_page).astype(jnp.int32)
+
+
+def _layer_prefetch(layer):
+    """The layer index as a scalar-prefetch operand (int32[1]): a traced
+    value, so every layer of a program runs one kernel."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def _paged_decode_kernel(layer_ref, pt_ref, pos_ref, q_ref, k_ref, v_ref,
+                         *refs, page_size: int, pages_per_slot: int,
                          scale: float, quantized: bool):
     """Grid (slot, page-slot); refs: q [1, Hkv, n_rep, d] (the slot's
     token, heads grouped per kv head), k/v [1, page_size, Hkv, d] (the
-    physical page the index map resolved via the page table). Scratch
+    physical page the index map resolved via ``layer_ref`` and the page
+    table; only the index maps read ``layer_ref``). Scratch
     ([Hkv, n_rep, 1|d]) carries each group's online softmax across the
     page-slot grid dim.
 
@@ -191,23 +216,22 @@ def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *refs,
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
-def _paged_decode_call(q, k_pages, v_pages, page_table, pos,
+def _paged_decode_call(q, k_pool, v_pool, layer, page_table, pos,
                        page_size: int, k_scale=None, v_scale=None,
                        interpret=None):
-    """q [slots, H, D] x pool pages [P+1, page_size, Hkv, D] -> [slots,
-    H, D]. ``page_table`` may contain -1 (routed to the scratch page).
-    ``k_scale``/``v_scale`` ([P+1, page_size, Hkv] f32) select the int8
-    kernel: pages are dequantized per vector inside the kernel."""
+    """q [slots, H, D] x layer ``layer`` (traced int32 scalar) of the
+    pool [L, P+1, page_size, Hkv, D] -> [slots, H, D]. ``page_table``
+    may contain -1 (routed to the scratch page). ``k_scale``/``v_scale``
+    ([L, P+1, page_size, Hkv] f32) select the int8 kernel: pages are
+    dequantized per vector inside the kernel."""
     if interpret is None:
         interpret = interpret_default()
     slots, h, d = q.shape
-    hkv = k_pages.shape[2]
+    hkv = k_pool.shape[3]
     n_rep = h // hkv
     pages_per_slot = page_table.shape[1]
     scale = d ** -0.5
-    scratch_page = k_pages.shape[0] - 1
-    safe_table = jnp.where(page_table >= 0, page_table,
-                           scratch_page).astype(jnp.int32)
+    safe_table = _safe_table(page_table, k_pool)
     pos = pos.astype(jnp.int32)
     quantized = k_scale is not None
 
@@ -215,30 +239,30 @@ def _paged_decode_call(q, k_pages, v_pages, page_table, pos,
         _paged_decode_kernel, page_size=page_size,
         pages_per_slot=pages_per_slot, scale=scale, quantized=quantized)
 
-    def q_map(s, p, pt, ps):
+    def q_map(s, p, ly, pt, ps):
         return (s, 0, 0, 0)
 
-    def kv_map(s, p, pt, ps):
-        return (pt[s, p], 0, 0, 0)
+    def kv_map(s, p, ly, pt, ps):
+        return (ly[0], pt[s, p], 0, 0, 0)
 
-    def sc_map(s, p, pt, ps):
-        return (pt[s, p], 0, 0)
+    def sc_map(s, p, ly, pt, ps):
+        return (ly[0], pt[s, p], 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, hkv, n_rep, d), q_map),
-        pl.BlockSpec((1, page_size, hkv, d), kv_map),
-        pl.BlockSpec((1, page_size, hkv, d), kv_map),
+        pl.BlockSpec((None, 1, page_size, hkv, d), kv_map),
+        pl.BlockSpec((None, 1, page_size, hkv, d), kv_map),
     ]
     # heads h*n_rep..(h+1)*n_rep are kv head h's GQA group (matches
     # _repeat_kv order), so grouping q per kv head is a free reshape
-    operands = [safe_table, pos, q.reshape(slots, hkv, n_rep, d),
-                k_pages, v_pages]
+    operands = [_layer_prefetch(layer), safe_table, pos,
+                q.reshape(slots, hkv, n_rep, d), k_pool, v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, page_size, hkv), sc_map),
-                     pl.BlockSpec((1, page_size, hkv), sc_map)]
+        in_specs += [pl.BlockSpec((None, 1, page_size, hkv), sc_map),
+                     pl.BlockSpec((None, 1, page_size, hkv), sc_map)]
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(slots, pages_per_slot),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, hkv, n_rep, d), q_map),
@@ -263,18 +287,19 @@ def _paged_decode_call(q, k_pages, v_pages, page_table, pos,
 # admission's prompt chunk) and speculative verify (a chunk per slot)
 # ---------------------------------------------------------------------------
 
-def _paged_chunk_kernel(ids_ref, base_ref, q_ref, k_ref, v_ref, *refs,
-                        page_size: int, pages_per_slot: int,
+def _paged_chunk_kernel(layer_ref, ids_ref, base_ref, q_ref, k_ref, v_ref,
+                        *refs, page_size: int, pages_per_slot: int,
                         scale: float, quantized: bool):
     """Grid (row, q_block, page-slot); refs: q [1, Hkv, block_rows, d]
     (row ``r``'s chunk, rows = token x n_rep grouped per kv head), k/v
     [1, page_size, Hkv, d] — the physical page the index map resolved
-    through row ``r``'s page ids. Every prefix position (0..base[r]-1)
-    precedes every query row, so no causal mask is needed; pages at or
-    past ``base[r]`` (and -1 entries, routed to the scratch page) are
-    masked out wholesale. Scratch carries each group's online softmax
-    across the page-slot grid dim; the finalize step emits (o, lse) so
-    the caller can LSE-merge with the chunk's local causal part.
+    through ``layer_ref`` and row ``r``'s page ids. Every prefix
+    position (0..base[r]-1) precedes every query row, so no causal mask
+    is needed; pages at or past ``base[r]`` (and -1 entries, routed to
+    the scratch page) are masked out wholesale. Scratch carries each
+    group's online softmax across the page-slot grid dim; the finalize
+    step emits (o, lse) so the caller can LSE-merge with the chunk's
+    local causal part.
 
     ``quantized`` (static) inserts two extra refs after v: the int8
     pool's dequant scales (see :func:`_attend_page`)."""
@@ -306,27 +331,26 @@ def _paged_chunk_kernel(ids_ref, base_ref, q_ref, k_ref, v_ref, *refs,
 
 @functools.partial(jax.jit,
                    static_argnames=("page_size", "interpret", "name"))
-def _paged_chunk_call(q, k_pages, v_pages, page_table, base,
+def _paged_chunk_call(q, k_pool, v_pool, layer, page_table, base,
                       page_size: int, k_scale=None, v_scale=None,
                       interpret=None, name: str = "paged_verify"):
     """q [R, S, H, D] (a chunk of S query tokens per row) attends each
-    row's prefix tokens 0..base[r]-1 IN PLACE through ``page_table``
-    ([R, pages_per_slot] int32, -1 past the prefix → scratch page) —
-    never gathered. Returns (o [R, S, H, D] f32, lse [R, H, S] f32)
-    partial softmax states in the flash lse layout, ready for
-    :func:`merge_softmax_states` with the chunk's local causal part.
+    row's prefix tokens 0..base[r]-1 IN PLACE in layer ``layer`` (traced
+    int32 scalar) of the pool [L, P+1, page_size, Hkv, D] through
+    ``page_table`` ([R, pages_per_slot] int32, -1 past the prefix →
+    scratch page) — never gathered. Returns (o [R, S, H, D] f32, lse
+    [R, H, S] f32) partial softmax states in the flash lse layout, ready
+    for :func:`merge_softmax_states` with the chunk's local causal part.
     ``name`` labels the kernel in a device trace: the prefix-hit prefill
     (R = 1) and the speculative verify (R = slots) are one kernel."""
     if interpret is None:
         interpret = interpret_default()
     r_, s, h, d = q.shape
-    hkv = k_pages.shape[2]
+    hkv = k_pool.shape[3]
     n_rep = h // hkv
     pages_per_slot = page_table.shape[1]
     scale = d ** -0.5
-    scratch_page = k_pages.shape[0] - 1
-    safe_table = jnp.where(page_table >= 0, page_table,
-                           scratch_page).astype(jnp.int32)
+    safe_table = _safe_table(page_table, k_pool)
     base = base.astype(jnp.int32)
     quantized = k_scale is not None
 
@@ -345,27 +369,28 @@ def _paged_chunk_call(q, k_pages, v_pages, page_table, base,
         _paged_chunk_kernel, page_size=page_size,
         pages_per_slot=pages_per_slot, scale=scale, quantized=quantized)
 
-    def q_map(r, qb, p, ids, b):
+    def q_map(r, qb, p, ly, ids, b):
         return (r, 0, qb, 0)
 
-    def kv_map(r, qb, p, ids, b):
-        return (ids[r, p], 0, 0, 0)
+    def kv_map(r, qb, p, ly, ids, b):
+        return (ly[0], ids[r, p], 0, 0, 0)
 
-    def sc_map(r, qb, p, ids, b):
-        return (ids[r, p], 0, 0)
+    def sc_map(r, qb, p, ly, ids, b):
+        return (ly[0], ids[r, p], 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, hkv, block_rows, d), q_map),
-        pl.BlockSpec((1, page_size, hkv, d), kv_map),
-        pl.BlockSpec((1, page_size, hkv, d), kv_map),
+        pl.BlockSpec((None, 1, page_size, hkv, d), kv_map),
+        pl.BlockSpec((None, 1, page_size, hkv, d), kv_map),
     ]
-    operands = [safe_table, base, qg, k_pages, v_pages]
+    operands = [_layer_prefetch(layer), safe_table, base, qg, k_pool,
+                v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, page_size, hkv), sc_map),
-                     pl.BlockSpec((1, page_size, hkv), sc_map)]
+        in_specs += [pl.BlockSpec((None, 1, page_size, hkv), sc_map),
+                     pl.BlockSpec((None, 1, page_size, hkv), sc_map)]
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(r_, padded_rows // block_rows, pages_per_slot),
         in_specs=in_specs,
         out_specs=[
@@ -411,36 +436,38 @@ def merge_softmax_states(o_a, lse_a, o_b, lse_b):
             + o_b.astype(jnp.float32) * wb) / (wa + wb)
 
 
-def paged_prefix_part(q, k_pages, v_pages, page_ids, base, *,
+def paged_prefix_part(q, k_pool, v_pool, layer, page_ids, base, *,
                       page_size: int, k_scale=None, v_scale=None,
                       interpret=None):
     """The prefix-hit prefill form of :func:`_paged_chunk_call` — one
     row: q [1, S, H, D] (one admission's prompt chunk) over the ``base``
-    prefix tokens stored in pool pages ``page_ids`` ([pages_per_slot]
-    int32, -1 past the prefix) -> (o [1, S, H, D] f32, lse [1, H, S]
-    f32) in the flash lse layout, ready for
+    prefix tokens stored in pages ``page_ids`` ([pages_per_slot] int32,
+    -1 past the prefix) of pool layer ``layer`` -> (o [1, S, H, D] f32,
+    lse [1, H, S] f32) in the flash lse layout, ready for
     :func:`merge_softmax_states`."""
     return _paged_chunk_call(
-        q, k_pages, v_pages, page_ids[None],
+        q, k_pool, v_pool, layer, page_ids[None],
         jnp.asarray(base, jnp.int32).reshape(1), page_size,
         k_scale=k_scale, v_scale=v_scale, interpret=interpret,
         name="paged_prefill")
 
 
-def paged_prefill_attention(q, k_cache, v_cache, q_start, k_pages,
-                            v_pages, page_ids, base, *, page_size: int,
-                            k_scale=None, v_scale=None, interpret=None):
+def paged_prefill_attention(q, k_cache, v_cache, q_start, k_pool,
+                            v_pool, layer, page_ids, base, *,
+                            page_size: int, k_scale=None, v_scale=None,
+                            interpret=None):
     """Merged suffix-prefill attention on a prefix-cache hit: q
     [1, S, H, D] rows at absolute positions ``q_start + i``; local cache
     k_cache/v_cache [1, M, H, D] (kv repeated to q heads, rows valid
-    from ``base``); prefix tokens 0..base-1 live in pool pages and are
-    attended IN PLACE through ``page_ids``. Returns the merged [1, S, H,
-    D] f32 output — the hit-path analog of flash_attention_cached over a
-    densely gathered cache, without the gather."""
+    from ``base``); prefix tokens 0..base-1 live in pages of pool layer
+    ``layer`` and are attended IN PLACE through ``page_ids``. Returns
+    the merged [1, S, H, D] f32 output — the hit-path analog of
+    flash_attention_cached over a densely gathered cache, without the
+    gather."""
     o_loc, lse_loc = _flash_fwd_v2_cached_bounded(
         q, k_cache, v_cache, q_start, base, interpret=interpret)
     o_pre, lse_pre = paged_prefix_part(
-        q, k_pages, v_pages, page_ids, base, page_size=page_size,
+        q, k_pool, v_pool, layer, page_ids, base, page_size=page_size,
         k_scale=k_scale, v_scale=v_scale, interpret=interpret)
     return merge_softmax_states(o_pre, lse_pre, o_loc, lse_loc)
 
@@ -470,7 +497,7 @@ def chunk_causal_part(q, k, v):
     return o, m + jnp.log(l)
 
 
-def paged_verify_reference(q, chunk_k, chunk_v, k_pages, v_pages,
+def paged_verify_reference(q, chunk_k, chunk_v, k_pool, v_pool, layer,
                            page_table, base, page_size: int,
                            k_scale=None, v_scale=None):
     """Dense-view verify reference: gather every slot's pages into
@@ -481,20 +508,10 @@ def paged_verify_reference(q, chunk_k, chunk_v, k_pages, v_pages,
     accepted length are computed-and-discarded garbage, exactly like the
     kernel path."""
     r_, s, h, d = q.shape
-    hkv = k_pages.shape[2]
-    n_rep = h // hkv
-    safe = jnp.maximum(page_table, 0)
-    kd = jnp.take(k_pages, safe, axis=0)     # [slots, pps, ps, hkv, d]
-    vd = jnp.take(v_pages, safe, axis=0)
-    s_, p_, ps_, hh, dd = kd.shape
-    m = p_ * ps_
-    kd = kd.reshape(s_, m, hh, dd).astype(jnp.float32)
-    vd = vd.reshape(s_, m, hh, dd).astype(jnp.float32)
-    if k_scale is not None:
-        ksc = jnp.take(k_scale, safe, axis=0).reshape(s_, m, hh)
-        vsc = jnp.take(v_scale, safe, axis=0).reshape(s_, m, hh)
-        kd = kd * ksc[..., None]
-        vd = vd * vsc[..., None]
+    n_rep = h // k_pool.shape[3]
+    kd = _gather_dense(k_pool, k_scale, layer, page_table)
+    vd = _gather_dense(v_pool, v_scale, layer, page_table)
+    m = kd.shape[1]
     positions = base[:, None] + jnp.arange(s)[None, :]   # [B, S]
     rows = jnp.arange(r_)[:, None]
     # mode="drop": a chunk lane past the view tail (row at the very end
@@ -516,7 +533,7 @@ def paged_verify_reference(q, chunk_k, chunk_v, k_pages, v_pages,
     return jnp.einsum("bhqk,bkhd->bqhd", weights, vd)
 
 
-def paged_verify_attention(q, chunk_k, chunk_v, k_pages, v_pages,
+def paged_verify_attention(q, chunk_k, chunk_v, k_pool, v_pool, layer,
                            page_table, base, *, page_size: int,
                            impl: str = "auto", k_scale=None,
                            v_scale=None, interpret=None):
@@ -524,18 +541,18 @@ def paged_verify_attention(q, chunk_k, chunk_v, k_pages, v_pages,
     [slots, S, H, D] are each row's draft positions ``base[r]..base[r] +
     S - 1`` (S = k + 1: the committed last token plus k draft tokens);
     their KV (``chunk_k``/``chunk_v`` [slots, S, Hkv, D]) has already
-    been written into the pool. The kernel path attends the prefix pages
-    in place — the verify chunk is literally the prefill kernel's
-    q-chunk form, batched per slot — and LSE-merges the chunk's local
-    causal part; no dense gather, int8 pools included. Returns the
-    merged [slots, S, H, D] f32 output."""
+    been written into layer ``layer`` of the pool. The kernel path
+    attends the prefix pages in place — the verify chunk is literally
+    the prefill kernel's q-chunk form, batched per slot — and LSE-merges
+    the chunk's local causal part; no dense gather, int8 pools included.
+    Returns the merged [slots, S, H, D] f32 output."""
     impl = resolve_paged_impl(impl)
     if impl == "reference":
         return paged_verify_reference(
-            q, chunk_k, chunk_v, k_pages, v_pages, page_table, base,
-            page_size, k_scale=k_scale, v_scale=v_scale)
+            q, chunk_k, chunk_v, k_pool, v_pool, layer, page_table,
+            base, page_size, k_scale=k_scale, v_scale=v_scale)
     o_pre, lse_pre = _paged_chunk_call(
-        q, k_pages, v_pages, page_table, base, page_size,
+        q, k_pool, v_pool, layer, page_table, base, page_size,
         k_scale=k_scale, v_scale=v_scale, interpret=interpret)
     o_loc, lse_loc = chunk_causal_part(q, chunk_k, chunk_v)
     return merge_softmax_states(o_pre, lse_pre, o_loc, lse_loc)
@@ -545,48 +562,53 @@ def paged_verify_attention(q, chunk_k, chunk_v, k_pages, v_pages,
 # gather+dense reference (the pre-kernel engine math)
 # ---------------------------------------------------------------------------
 
-def paged_decode_reference(q, k_pages, v_pages, page_table, pos,
-                           page_size: int, k_scale=None, v_scale=None):
-    """Dense-view reference: gather every slot's pages into
-    [slots, max_len] (the materialization the kernel exists to avoid) and
-    run masked attention. Used for parity tests and as the CPU path.
-    int8 pools pass per-vector ``k_scale``/``v_scale`` ([P+1, page_size,
-    Hkv] f32) and dequantize after the gather."""
-    slots, h, d = q.shape
-    hkv = k_pages.shape[2]
-    n_rep = h // hkv
+def _gather_dense(pool, pool_scale, layer, page_table):
+    """One layer of the pool gathered through ``page_table`` into the
+    dense f32 view [slots, max_len, Hkv, D] (the materialization the
+    kernels exist to avoid); -1 entries read page 0 and are masked by
+    position downstream. int8 pools dequantize by ``pool_scale`` ([L,
+    P+1, page_size, Hkv] f32) after the gather."""
     safe = jnp.maximum(page_table, 0)
-    kd = jnp.take(k_pages, safe, axis=0)     # [slots, pps, ps, hkv, d]
-    vd = jnp.take(v_pages, safe, axis=0)
-    s_, p_, ps_, hh, dd = kd.shape
-    kd = kd.reshape(s_, p_ * ps_, hh, dd).astype(jnp.float32)
-    vd = vd.reshape(s_, p_ * ps_, hh, dd).astype(jnp.float32)
-    if k_scale is not None:
-        ksc = jnp.take(k_scale, safe, axis=0).reshape(s_, p_ * ps_, hh)
-        vsc = jnp.take(v_scale, safe, axis=0).reshape(s_, p_ * ps_, hh)
-        kd = kd * ksc[..., None]
-        vd = vd * vsc[..., None]
+    dense = jnp.take(pool[layer], safe, axis=0)  # [slots, pps, ps, hkv, d]
+    s_, p_, ps_, hh, dd = dense.shape
+    dense = dense.reshape(s_, p_ * ps_, hh, dd).astype(jnp.float32)
+    if pool_scale is not None:
+        sc = jnp.take(pool_scale[layer], safe, axis=0)
+        dense = dense * sc.reshape(s_, p_ * ps_, hh, 1)
+    return dense
+
+
+def paged_decode_reference(q, k_pool, v_pool, layer, page_table, pos,
+                           page_size: int, k_scale=None, v_scale=None):
+    """Dense-view reference: gather every slot's pages of pool layer
+    ``layer`` into [slots, max_len] and run masked attention. Used for
+    parity tests and as the CPU path."""
+    slots, h, d = q.shape
+    n_rep = h // k_pool.shape[3]
+    kd = _gather_dense(k_pool, k_scale, layer, page_table)
+    vd = _gather_dense(v_pool, v_scale, layer, page_table)
     kd = _repeat_kv(kd, n_rep)
     vd = _repeat_kv(vd, n_rep)
     scale = d ** -0.5
     logits = jnp.einsum("bhd,bkhd->bhk", q.astype(jnp.float32), kd,
                         preferred_element_type=jnp.float32) * scale
-    k_pos = jnp.arange(p_ * ps_)[None, None, :]
+    k_pos = jnp.arange(kd.shape[1])[None, None, :]
     logits = jnp.where(k_pos <= pos[:, None, None], logits, NEG_INF)
     weights = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhk,bkhd->bhd", weights, vd).astype(q.dtype)
 
 
-def paged_attention(q, k_pages, v_pages, page_table, pos, *,
+def paged_attention(q, k_pool, v_pool, layer, page_table, pos, *,
                     page_size: int, impl: str = "auto",
                     k_scale=None, v_scale=None, interpret=None):
-    """Dispatching paged-decode attention (see module docstring).
-    ``k_scale``/``v_scale`` select the int8 path in both impls."""
+    """Dispatching paged-decode attention over layer ``layer`` of the
+    pool (see module docstring). ``k_scale``/``v_scale`` select the int8
+    path in both impls."""
     impl = resolve_paged_impl(impl)
     if impl == "reference":
-        return paged_decode_reference(q, k_pages, v_pages, page_table,
-                                      pos, page_size, k_scale=k_scale,
-                                      v_scale=v_scale)
-    return _paged_decode_call(q, k_pages, v_pages, page_table, pos,
+        return paged_decode_reference(q, k_pool, v_pool, layer,
+                                      page_table, pos, page_size,
+                                      k_scale=k_scale, v_scale=v_scale)
+    return _paged_decode_call(q, k_pool, v_pool, layer, page_table, pos,
                               page_size, k_scale=k_scale,
                               v_scale=v_scale, interpret=interpret)

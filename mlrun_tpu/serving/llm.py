@@ -155,7 +155,7 @@ def _forward_with_cache(config: LlamaConfig, params: Params,
     engines' prefill hot path (docs/serving.md "Attention kernels").
 
     ``prefix_kv`` is the paged engine's prefix-hit form (batch=1): a
-    dict of the pool's per-layer pages — ``{"k": [L, P+1, ps, Hkv, D],
+    dict of the pool as stored, all layers — ``{"k": [L, P+1, ps, Hkv, D],
     "v": ..., "page_ids": [pages_per_slot] int32, "base": int32
     scalar[, "k_scale"/"v_scale": [L, P+1, ps, Hkv] f32 on int8
     pools]}``. Cache rows below ``base`` are zeros — the cached prefix
@@ -244,12 +244,10 @@ def _forward_with_cache(config: LlamaConfig, params: Params,
                     o_loc, lse_loc = _cached_attention_lse(
                         config, q, k_attn, v_attn, positions, base)
                 o_pre, lse_pre = paged_prefix_part(
-                    q, prefix_kv["k"][layer], prefix_kv["v"][layer],
+                    q, prefix_kv["k"], prefix_kv["v"], layer,
                     prefix_kv["page_ids"], base, page_size=page_size,
-                    k_scale=(prefix_kv["k_scale"][layer]
-                             if "k_scale" in prefix_kv else None),
-                    v_scale=(prefix_kv["v_scale"][layer]
-                             if "v_scale" in prefix_kv else None))
+                    k_scale=prefix_kv.get("k_scale"),
+                    v_scale=prefix_kv.get("v_scale"))
                 attn = merge_softmax_states(o_pre, lse_pre, o_loc,
                                             lse_loc).astype(x_in.dtype)
             elif attn_impl == "flash" and s > 1:

@@ -260,15 +260,17 @@ def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
                         layer, pid_safe, offset].set(ks_)
                     pool["v_scale"] = pool["v_scale"].at[
                         layer, pid_safe, offset].set(vs_)
-                    scales_kw = {"k_scale": pool["k_scale"][layer],
-                                 "v_scale": pool["v_scale"][layer]}
+                    scales_kw = {"k_scale": pool["k_scale"],
+                                 "v_scale": pool["v_scale"]}
                 else:
                     pool["k"] = pool["k"].at[layer, pid_safe, offset].set(
                         k[:, 0].astype(pool["k"].dtype))
                     pool["v"] = pool["v"].at[layer, pid_safe, offset].set(
                         v[:, 0].astype(pool["v"].dtype))
+                # the kernel takes the pool as stored and the layer's
+                # index: a sliced layer would be a copy per call
                 attn = paged_attention(
-                    q[:, 0], pool["k"][layer], pool["v"][layer], page_table,
+                    q[:, 0], pool["k"], pool["v"], layer, page_table,
                     pos, page_size=page_size, impl="kernel",
                     **scales_kw)[:, None]
             else:
@@ -424,8 +426,8 @@ def _verify_rowwise_paged(config: LlamaConfig, page_size: int,
                     layer, pid_safe, offset].set(ks_)
                 pool["v_scale"] = pool["v_scale"].at[
                     layer, pid_safe, offset].set(vs_)
-                scales_kw = {"k_scale": pool["k_scale"][layer],
-                             "v_scale": pool["v_scale"][layer]}
+                scales_kw = {"k_scale": pool["k_scale"],
+                             "v_scale": pool["v_scale"]}
                 if use_kernel:
                     # the kernel's local chunk part must see the SAME bits a
                     # later decode tick reads back from the int8 pool
@@ -442,7 +444,7 @@ def _verify_rowwise_paged(config: LlamaConfig, page_size: int,
                     v.astype(pool["v"].dtype))
                 chunk_k, chunk_v = k, v
             attn = paged_verify_attention(
-                q, chunk_k, chunk_v, pool["k"][layer], pool["v"][layer],
+                q, chunk_k, chunk_v, pool["k"], pool["v"], layer,
                 page_table, pos, page_size=page_size,
                 impl="kernel" if use_kernel else "reference", **scales_kw)
             attn = attn.astype(x.dtype).reshape(b, s, config.qkv_dim)

@@ -134,10 +134,12 @@ def run():
     n_pages = slots * pages_per_slot
     key = jax.random.PRNGKey(7)
     kq, kk, kv_, kt = jax.random.split(key, 4)
+    # the kernels take the pool as the engine stores it ([L, P+1, ...])
+    # and a layer index: one layer here
     k_pages = jax.random.normal(
-        kk, (n_pages + 1, page_size, hkv, d), jnp.float32) * 0.3
+        kk, (1, n_pages + 1, page_size, hkv, d), jnp.float32) * 0.3
     v_pages = jax.random.normal(
-        kv_, (n_pages + 1, page_size, hkv, d), jnp.float32) * 0.3
+        kv_, (1, n_pages + 1, page_size, hkv, d), jnp.float32) * 0.3
     q = jax.random.normal(kq, (slots, hkv * n_rep, d), jnp.float32) * 0.5
     table = np.arange(n_pages, dtype=np.int32).reshape(slots, pages_per_slot)
     # slots mid-generation at assorted depths (partial last pages)
@@ -145,16 +147,16 @@ def run():
 
     dense = jax.jit(functools.partial(paged_decode_reference,
                                       page_size=page_size))
-    out_ref = dense(q, k_pages, v_pages, jnp.asarray(table),
+    out_ref = dense(q, k_pages, v_pages, 0, jnp.asarray(table),
                     jnp.asarray(pos))
     out_ref.block_until_ready()
-    gather_ms = timeit(dense, q, k_pages, v_pages, jnp.asarray(table),
+    gather_ms = timeit(dense, q, k_pages, v_pages, 0, jnp.asarray(table),
                        jnp.asarray(pos)) * 1e3
 
     start = time.perf_counter()
-    out_kernel = _paged_decode_call(q, k_pages, v_pages, jnp.asarray(table),
-                                    jnp.asarray(pos), page_size,
-                                    interpret=True)
+    out_kernel = _paged_decode_call(q, k_pages, v_pages, 0,
+                                    jnp.asarray(table), jnp.asarray(pos),
+                                    page_size, interpret=True)
     out_kernel.block_until_ready()
     kernel_interp_s = time.perf_counter() - start
 
@@ -193,11 +195,11 @@ def run():
     v8, vs = _quantize_kv(v_pages)
     dense8 = jax.jit(functools.partial(paged_decode_reference,
                                        page_size=page_size))
-    out_ref8 = dense8(q, k8, v8, jnp.asarray(table), jnp.asarray(pos),
+    out_ref8 = dense8(q, k8, v8, 0, jnp.asarray(table), jnp.asarray(pos),
                       k_scale=ks, v_scale=vs)
     out_ref8.block_until_ready()
     start = time.perf_counter()
-    out_k8 = _paged_decode_call(q, k8, v8, jnp.asarray(table),
+    out_k8 = _paged_decode_call(q, k8, v8, 0, jnp.asarray(table),
                                 jnp.asarray(pos), page_size,
                                 k_scale=ks, v_scale=vs, interpret=True)
     out_k8.block_until_ready()
@@ -247,16 +249,16 @@ def run():
 
     start = time.perf_counter()
     out_pf = paged_prefill_attention(
-        qp, k_loc, v_loc, jnp.int32(base), k_pages, v_pages,
+        qp, k_loc, v_loc, jnp.int32(base), k_pages, v_pages, 0,
         jnp.asarray(ids), jnp.int32(base), page_size=page_size,
         interpret=True)
     out_pf.block_until_ready()
     prefill_interp_s = time.perf_counter() - start
 
     # reference: dense concat of the gathered prefix + the suffix rows
-    k_pre = _repeat_kv(k_pages[:base_pages].reshape(
+    k_pre = _repeat_kv(k_pages[0, :base_pages].reshape(
         1, base, hkv, d), n_rep)
-    v_pre = _repeat_kv(v_pages[:base_pages].reshape(
+    v_pre = _repeat_kv(v_pages[0, :base_pages].reshape(
         1, base, hkv, d), n_rep)
     k_full = jnp.concatenate([k_pre, k_loc[:, base:base + s_chunk]], 1)
     v_full = jnp.concatenate([v_pre, v_loc[:, base:base + s_chunk]], 1)

@@ -21,6 +21,7 @@ from mlrun_tpu.ops.attention import (
     flash_attention_cached,
     resolve_prefill_impl,
 )
+from mlrun_tpu.serving.llm import _quantize_kv
 from mlrun_tpu.serving.paged import PagedContinuousBatchingEngine
 
 
@@ -45,13 +46,26 @@ def _greedy_reference(cfg, params, prompt, n):
 
 
 # -- op level -----------------------------------------------------------------
-def _random_pool(key, n_pages, page_size, hkv, d):
+def _random_pool(key, n_pages, page_size, hkv, d, layers=1):
+    """A pool [layers, n_pages + 1, ...] whose layers all differ."""
     kk, kv = jax.random.split(key)
-    k_pages = jax.random.normal(
-        kk, (n_pages + 1, page_size, hkv, d), jnp.float32) * 0.3
-    v_pages = jax.random.normal(
-        kv, (n_pages + 1, page_size, hkv, d), jnp.float32) * 0.3
-    return k_pages, v_pages
+    shape = (layers, n_pages + 1, page_size, hkv, d)
+    k_pool = jax.random.normal(kk, shape, jnp.float32) * 0.3
+    v_pool = jax.random.normal(kv, shape, jnp.float32) * 0.3
+    return k_pool, v_pool
+
+
+def _decode_case(key):
+    """q, page table (unmapped -1 entries in the mix) and mid-page
+    positions over a 10-page pool of page_size 8."""
+    slots, pps, h, d = 3, 4, 4, 32
+    q = jax.random.normal(jax.random.fold_in(key, 1),
+                          (slots, h, d), jnp.float32) * 0.5
+    table = np.full((slots, pps), -1, np.int32)
+    table[0, :2] = [3, 7]
+    table[1, :4] = [0, 1, 2, 8]
+    table[2, :1] = [9]
+    return q, jnp.asarray(table), jnp.asarray([11, 31, 0], jnp.int32)
 
 
 def test_paged_kernel_matches_gather_dense():
@@ -59,21 +73,41 @@ def test_paged_kernel_matches_gather_dense():
     the dense gathered view, with unmapped (-1) entries and mid-page
     positions in the mix."""
     key = jax.random.PRNGKey(0)
-    slots, pps, ps, hkv, d, h = 3, 4, 8, 2, 32, 4
-    k_pages, v_pages = _random_pool(key, 10, ps, hkv, d)
-    q = jax.random.normal(jax.random.fold_in(key, 1),
-                          (slots, h, d), jnp.float32) * 0.5
-    table = np.full((slots, pps), -1, np.int32)
-    table[0, :2] = [3, 7]
-    table[1, :4] = [0, 1, 2, 8]
-    table[2, :1] = [9]
-    pos = jnp.asarray([11, 31, 0], jnp.int32)
-    out_k = pattn._paged_decode_call(q, k_pages, v_pages,
-                                     jnp.asarray(table), pos, ps,
+    k_pool, v_pool = _random_pool(key, 10, 8, 2, 32)
+    q, table, pos = _decode_case(key)
+    out_k = pattn._paged_decode_call(q, k_pool, v_pool, 0, table, pos, 8,
                                      interpret=True)
-    out_r = pattn.paged_decode_reference(q, k_pages, v_pages,
-                                         jnp.asarray(table), pos, ps)
+    out_r = pattn.paged_decode_reference(q, k_pool, v_pool, 0, table,
+                                         pos, 8)
     assert float(jnp.max(jnp.abs(out_k - out_r))) < 2e-6
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_paged_kernel_reads_its_layer(kv_dtype, layer):
+    """The kernel reaches layer ``layer`` of a three-layer pool through
+    its index maps: parity with that layer's plain slice gathered dense,
+    and far from every other layer's (a wrong layer index must fail)."""
+    key = jax.random.PRNGKey(7)
+    k_pool, v_pool = _random_pool(key, 10, 8, 2, 32, layers=3)
+    q, table, pos = _decode_case(key)
+    scales = {}
+    if kv_dtype == "int8":
+        k_pool, scales["k_scale"] = _quantize_kv(k_pool)
+        v_pool, scales["v_scale"] = _quantize_kv(v_pool)
+    else:
+        k_pool = k_pool.astype(jnp.bfloat16)
+        v_pool = v_pool.astype(jnp.bfloat16)
+    out = pattn._paged_decode_call(q, k_pool, v_pool, jnp.int32(layer),
+                                   table, pos, 8, interpret=True,
+                                   **scales)
+    for other in range(3):
+        one = {name: arr[other:other + 1] for name, arr in scales.items()}
+        ref = pattn.paged_decode_reference(
+            q, k_pool[other:other + 1], v_pool[other:other + 1], 0, table,
+            pos, 8, **one)
+        gap = float(jnp.max(jnp.abs(out - ref)))
+        assert gap < 2e-6 if other == layer else gap > 0.05
 
 
 def test_paged_kernel_interpret_smoke_seq2048():
@@ -81,15 +115,15 @@ def test_paged_kernel_interpret_smoke_seq2048():
     2048), GQA group of 2 — whole-grid interpret run stays correct."""
     key = jax.random.PRNGKey(42)
     slots, ps, pps, hkv, d = 2, 128, 16, 1, 64
-    k_pages, v_pages = _random_pool(key, slots * pps, ps, hkv, d)
+    k_pool, v_pool = _random_pool(key, slots * pps, ps, hkv, d)
     q = jax.random.normal(jax.random.fold_in(key, 1),
                           (slots, 2, d), jnp.float32) * 0.5
     table = np.arange(slots * pps, dtype=np.int32).reshape(slots, pps)
     pos = jnp.asarray([2047, 900], jnp.int32)
-    out_k = pattn._paged_decode_call(q, k_pages, v_pages,
+    out_k = pattn._paged_decode_call(q, k_pool, v_pool, 0,
                                      jnp.asarray(table), pos, ps,
                                      interpret=True)
-    out_r = pattn.paged_decode_reference(q, k_pages, v_pages,
+    out_r = pattn.paged_decode_reference(q, k_pool, v_pool, 0,
                                          jnp.asarray(table), pos, ps)
     assert out_k.shape == (slots, 2, d)
     assert float(jnp.max(jnp.abs(out_k - out_r))) < 2e-6

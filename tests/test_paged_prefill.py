@@ -58,13 +58,56 @@ PROMPT = [1, 7, 3, 9, 2, 4, 6, 8, 5, 3, 1, 2]  # one full block at ps=8
 
 
 # -- op level -----------------------------------------------------------------
-def _prefix_setup(key, n_pages, ps, hkv, d, scale=0.3):
+def _prefix_setup(key, n_pages, ps, hkv, d, scale=0.3, layers=1):
+    """A pool [layers, n_pages + 1, ...] whose layers all differ."""
     kk, kv = jax.random.split(key)
-    k_pages = jax.random.normal(
-        kk, (n_pages + 1, ps, hkv, d), jnp.float32) * scale
-    v_pages = jax.random.normal(
-        kv, (n_pages + 1, ps, hkv, d), jnp.float32) * scale
-    return k_pages, v_pages
+    shape = (layers, n_pages + 1, ps, hkv, d)
+    k_pool = jax.random.normal(kk, shape, jnp.float32) * scale
+    v_pool = jax.random.normal(kv, shape, jnp.float32) * scale
+    return k_pool, v_pool
+
+
+PS, PPS, HKV, H, D = 8, 4, 2, 4, 32
+BASE = 2 * PS
+
+
+def _prefill_case(key, s, ids):
+    """q [1, S, H, D] at positions BASE.., a local cache whose rows
+    BASE..BASE+S-1 alone are live, and the page ids of the prefix."""
+    q = jax.random.normal(jax.random.fold_in(key, 1),
+                          (1, s, H, D), jnp.float32) * 0.5
+    m = 32
+    kc, vc = jax.random.split(jax.random.fold_in(key, 2))
+    k_loc = jax.random.normal(kc, (1, m, HKV, D), jnp.float32) * 0.3
+    v_loc = jax.random.normal(vc, (1, m, HKV, D), jnp.float32) * 0.3
+    live = (jnp.arange(m) >= BASE) & (jnp.arange(m) < BASE + s)
+    k_loc = k_loc * live[None, :, None, None]
+    v_loc = v_loc * live[None, :, None, None]
+    page_ids = np.full((PPS,), -1, np.int32)
+    page_ids[:2] = ids
+    return q, k_loc, v_loc, jnp.asarray(page_ids)
+
+
+def _merged_prefill(q, k_loc, v_loc, k_pool, v_pool, layer, page_ids,
+                    **scales):
+    n_rep = H // HKV
+    return pattn.paged_prefill_attention(
+        q, _repeat_kv(k_loc, n_rep), _repeat_kv(v_loc, n_rep),
+        jnp.int32(BASE), k_pool, v_pool, layer, page_ids,
+        jnp.int32(BASE), page_size=PS, interpret=True, **scales)
+
+
+def _dense_prefill(q, k_loc, v_loc, k_pages, v_pages, ids):
+    """Plain causal attention over the densely concatenated [prefix;
+    suffix] KV; ``k_pages``/``v_pages`` are ONE layer's pages, f32."""
+    s = q.shape[1]
+    k_pre = jnp.concatenate([k_pages[i] for i in ids], axis=0)[None]
+    v_pre = jnp.concatenate([v_pages[i] for i in ids], axis=0)[None]
+    k_full = jnp.concatenate([k_pre, k_loc[:, BASE:BASE + s]], axis=1)
+    v_full = jnp.concatenate([v_pre, v_loc[:, BASE:BASE + s]], axis=1)
+    return attention_reference(q, k_full, v_full, causal=True,
+                               positions_q=BASE + jnp.arange(s),
+                               positions_k=jnp.arange(BASE + s))
 
 
 def test_paged_prefill_kernel_matches_dense_reference():
@@ -73,34 +116,10 @@ def test_paged_prefill_kernel_matches_dense_reference():
     densely concatenated [prefix; suffix] KV — the f32 round-off bound
     of the tolerance-parity contract."""
     key = jax.random.PRNGKey(0)
-    S, H, hkv, d, ps, pps = 6, 4, 2, 32, 8, 4
-    n_rep = H // hkv
-    base = 2 * ps
-    k_pages, v_pages = _prefix_setup(key, 10, ps, hkv, d)
-    ids = np.full((pps,), -1, np.int32)
-    ids[:2] = [3, 7]
-    q = jax.random.normal(jax.random.fold_in(key, 1),
-                          (1, S, H, d), jnp.float32) * 0.5
-    M = 32
-    kc, vc = jax.random.split(jax.random.fold_in(key, 2))
-    k_loc = jax.random.normal(kc, (1, M, hkv, d), jnp.float32) * 0.3
-    v_loc = jax.random.normal(vc, (1, M, hkv, d), jnp.float32) * 0.3
-    live = (jnp.arange(M) >= base) & (jnp.arange(M) < base + S)
-    k_loc = k_loc * live[None, :, None, None]
-    v_loc = v_loc * live[None, :, None, None]
-
-    out = pattn.paged_prefill_attention(
-        q, _repeat_kv(k_loc, n_rep), _repeat_kv(v_loc, n_rep),
-        jnp.int32(base), k_pages, v_pages, jnp.asarray(ids),
-        jnp.int32(base), page_size=ps, interpret=True)
-
-    k_pre = jnp.concatenate([k_pages[3], k_pages[7]], axis=0)[None]
-    v_pre = jnp.concatenate([v_pages[3], v_pages[7]], axis=0)[None]
-    k_full = jnp.concatenate([k_pre, k_loc[:, base:base + S]], axis=1)
-    v_full = jnp.concatenate([v_pre, v_loc[:, base:base + S]], axis=1)
-    ref = attention_reference(q, k_full, v_full, causal=True,
-                              positions_q=base + jnp.arange(S),
-                              positions_k=jnp.arange(base + S))
+    k_pool, v_pool = _prefix_setup(key, 10, PS, HKV, D)
+    q, k_loc, v_loc, page_ids = _prefill_case(key, 6, [3, 7])
+    out = _merged_prefill(q, k_loc, v_loc, k_pool, v_pool, 0, page_ids)
+    ref = _dense_prefill(q, k_loc, v_loc, k_pool[0], v_pool[0], [3, 7])
     assert float(jnp.max(jnp.abs(out - ref))) < 2e-6
 
 
@@ -111,9 +130,9 @@ def test_int8_decode_kernel_matches_dequant_reference():
     quantization bound applies between pools, not between impls."""
     key = jax.random.PRNGKey(0)
     slots, pps, ps, hkv, d, h = 3, 4, 8, 2, 32, 4
-    k_pages, v_pages = _prefix_setup(key, 10, ps, hkv, d)
-    k8, ks = _quantize_kv(k_pages)
-    v8, vs = _quantize_kv(v_pages)
+    k_pool, v_pool = _prefix_setup(key, 10, ps, hkv, d)
+    k8, ks = _quantize_kv(k_pool)
+    v8, vs = _quantize_kv(v_pool)
     q = jax.random.normal(jax.random.fold_in(key, 1),
                           (slots, h, d), jnp.float32) * 0.5
     table = np.full((slots, pps), -1, np.int32)
@@ -121,16 +140,16 @@ def test_int8_decode_kernel_matches_dequant_reference():
     table[1, :4] = [0, 1, 2, 8]
     table[2, :1] = [9]
     pos = jnp.asarray([11, 31, 0], jnp.int32)
-    out_k = pattn._paged_decode_call(q, k8, v8, jnp.asarray(table), pos,
-                                     ps, k_scale=ks, v_scale=vs,
+    out_k = pattn._paged_decode_call(q, k8, v8, 0, jnp.asarray(table),
+                                     pos, ps, k_scale=ks, v_scale=vs,
                                      interpret=True)
-    out_r = pattn.paged_decode_reference(q, k8, v8, jnp.asarray(table),
+    out_r = pattn.paged_decode_reference(q, k8, v8, 0, jnp.asarray(table),
                                          pos, ps, k_scale=ks, v_scale=vs)
     assert float(jnp.max(jnp.abs(out_k - out_r))) < 2e-6
     # and the quantization bound itself vs the native pool: per-element
     # error <= |x|_max/254, attention output within 2e-2 on this data
     out_native = pattn.paged_decode_reference(
-        q, k_pages, v_pages, jnp.asarray(table), pos, ps)
+        q, k_pool, v_pool, 0, jnp.asarray(table), pos, ps)
     assert float(jnp.max(jnp.abs(out_k - out_native))) < 2e-2
 
 
@@ -138,39 +157,44 @@ def test_int8_prefill_kernel_matches_dequant_reference():
     """The paged prefill kernel over int8 pages + scales matches the
     dense dequantized reference to f32 round-off."""
     key = jax.random.PRNGKey(4)
-    S, H, hkv, d, ps, pps = 5, 4, 2, 32, 8, 4
-    n_rep = H // hkv
-    base = 2 * ps
-    k_pages, v_pages = _prefix_setup(key, 10, ps, hkv, d)
-    k8, ks = _quantize_kv(k_pages)
-    v8, vs = _quantize_kv(v_pages)
-    ids = np.full((pps,), -1, np.int32)
-    ids[:2] = [1, 6]
-    q = jax.random.normal(jax.random.fold_in(key, 1),
-                          (1, S, H, d), jnp.float32) * 0.5
-    M = 32
-    kc, vc = jax.random.split(jax.random.fold_in(key, 2))
-    k_loc = jax.random.normal(kc, (1, M, hkv, d), jnp.float32) * 0.3
-    v_loc = jax.random.normal(vc, (1, M, hkv, d), jnp.float32) * 0.3
-    live = (jnp.arange(M) >= base) & (jnp.arange(M) < base + S)
-    k_loc = k_loc * live[None, :, None, None]
-    v_loc = v_loc * live[None, :, None, None]
-
-    out = pattn.paged_prefill_attention(
-        q, _repeat_kv(k_loc, n_rep), _repeat_kv(v_loc, n_rep),
-        jnp.int32(base), k8, v8, jnp.asarray(ids), jnp.int32(base),
-        page_size=ps, k_scale=ks, v_scale=vs, interpret=True)
-
+    k_pool, v_pool = _prefix_setup(key, 10, PS, HKV, D)
+    k8, ks = _quantize_kv(k_pool)
+    v8, vs = _quantize_kv(v_pool)
+    q, k_loc, v_loc, page_ids = _prefill_case(key, 5, [1, 6])
+    out = _merged_prefill(q, k_loc, v_loc, k8, v8, 0, page_ids,
+                          k_scale=ks, v_scale=vs)
     kd = k8.astype(jnp.float32) * ks[..., None]
     vd = v8.astype(jnp.float32) * vs[..., None]
-    k_pre = jnp.concatenate([kd[1], kd[6]], axis=0)[None]
-    v_pre = jnp.concatenate([vd[1], vd[6]], axis=0)[None]
-    k_full = jnp.concatenate([k_pre, k_loc[:, base:base + S]], axis=1)
-    v_full = jnp.concatenate([v_pre, v_loc[:, base:base + S]], axis=1)
-    ref = attention_reference(q, k_full, v_full, causal=True,
-                              positions_q=base + jnp.arange(S),
-                              positions_k=jnp.arange(base + S))
+    ref = _dense_prefill(q, k_loc, v_loc, kd[0], vd[0], [1, 6])
     assert float(jnp.max(jnp.abs(out - ref))) < 2e-6
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_paged_prefill_kernel_reads_its_layer(kv_dtype, layer):
+    """The prefill kernel reaches layer ``layer`` of a three-layer pool
+    through its index maps: parity with that layer's pages concatenated
+    dense, and far from every other layer's (a wrong layer index must
+    fail)."""
+    key = jax.random.PRNGKey(11)
+    k_pool, v_pool = _prefix_setup(key, 10, PS, HKV, D, layers=3)
+    q, k_loc, v_loc, page_ids = _prefill_case(key, 5, [2, 9])
+    scales = {}
+    if kv_dtype == "int8":
+        k_pool, scales["k_scale"] = _quantize_kv(k_pool)
+        v_pool, scales["v_scale"] = _quantize_kv(v_pool)
+        kd = k_pool.astype(jnp.float32) * scales["k_scale"][..., None]
+        vd = v_pool.astype(jnp.float32) * scales["v_scale"][..., None]
+    else:
+        k_pool = k_pool.astype(jnp.bfloat16)
+        v_pool = v_pool.astype(jnp.bfloat16)
+        kd, vd = k_pool.astype(jnp.float32), v_pool.astype(jnp.float32)
+    out = _merged_prefill(q, k_loc, v_loc, k_pool, v_pool,
+                          jnp.int32(layer), page_ids, **scales)
+    for other in range(3):
+        ref = _dense_prefill(q, k_loc, v_loc, kd[other], vd[other], [2, 9])
+        gap = float(jnp.max(jnp.abs(out - ref)))
+        assert gap < 2e-6 if other == layer else gap > 0.02
 
 
 # -- engine level -------------------------------------------------------------

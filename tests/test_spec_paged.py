@@ -69,18 +69,16 @@ def _engine(cfg, params, *, spec=None, **kw):
 
 
 # -- op level -----------------------------------------------------------------
-def test_verify_chunk_kernel_vs_reference_parity():
-    """The batched verify chunk attending the page pool in place
-    (kernel) matches the dense-gather reference bit-for-bit up to f32
-    accumulation order — native and int8 pools, including a base=0 row
-    (cold chunk, nothing behind it) and a row deep into its pages."""
+def _verify_case(layers):
+    """A pool of ``layers`` differing layers, a 5-token chunk per slot,
+    and rows with base 0 (cold chunk, nothing behind it), mid-page and
+    deep into their pages."""
     ps, slots, hkv, h, d, s = 8, 3, 2, 4, 32, 5
     n_pages = 8
     kk, kv, kq, kc1, kc2 = jax.random.split(jax.random.PRNGKey(0), 5)
-    k_pages = jax.random.normal(
-        kk, (n_pages + 1, ps, hkv, d), jnp.float32) * 0.3
-    v_pages = jax.random.normal(
-        kv, (n_pages + 1, ps, hkv, d), jnp.float32) * 0.3
+    shape = (layers, n_pages + 1, ps, hkv, d)
+    k_pool = jax.random.normal(kk, shape, jnp.float32) * 0.3
+    v_pool = jax.random.normal(kv, shape, jnp.float32) * 0.3
     q = jax.random.normal(kq, (slots, s, h, d), jnp.float32)
     chunk_k = jax.random.normal(kc1, (slots, s, hkv, d), jnp.float32) * 0.3
     chunk_v = jax.random.normal(kc2, (slots, s, hkv, d), jnp.float32) * 0.3
@@ -88,20 +86,58 @@ def test_verify_chunk_kernel_vs_reference_parity():
     table = jnp.asarray([[0, 1, -1, -1],
                          [-1, -1, -1, -1],
                          [2, 3, 4, 5]], jnp.int32)
+    return ps, k_pool, v_pool, q, chunk_k, chunk_v, table, base
+
+
+def test_verify_chunk_kernel_vs_reference_parity():
+    """The batched verify chunk attending the page pool in place
+    (kernel) matches the dense-gather reference bit-for-bit up to f32
+    accumulation order — native and int8 pools, including a base=0 row
+    (cold chunk, nothing behind it) and a row deep into its pages."""
+    ps, k_pool, v_pool, q, chunk_k, chunk_v, table, base = _verify_case(1)
 
     def both(kp, vp, **scales):
         ref = pattn.paged_verify_attention(
-            q, chunk_k, chunk_v, kp, vp, table, base, page_size=ps,
+            q, chunk_k, chunk_v, kp, vp, 0, table, base, page_size=ps,
             impl="reference", **scales)
         ker = pattn.paged_verify_attention(
-            q, chunk_k, chunk_v, kp, vp, table, base, page_size=ps,
+            q, chunk_k, chunk_v, kp, vp, 0, table, base, page_size=ps,
             impl="kernel", interpret=True, **scales)
         return float(jnp.max(jnp.abs(ker - ref)))
 
-    assert both(k_pages, v_pages) < 2e-5
-    k8, ks = _quantize_kv(k_pages)
-    v8, vs = _quantize_kv(v_pages)
+    assert both(k_pool, v_pool) < 2e-5
+    k8, ks = _quantize_kv(k_pool)
+    v8, vs = _quantize_kv(v_pool)
     assert both(k8, v8, k_scale=ks, v_scale=vs) < 2e-5
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_verify_kernel_reads_its_layer(kv_dtype, layer):
+    """The verify kernel reaches layer ``layer`` of a three-layer pool
+    through its index maps: parity with the reference over that layer's
+    plain slice, and far from every other layer's (a wrong layer index
+    must fail)."""
+    ps, k_pool, v_pool, q, chunk_k, chunk_v, table, base = _verify_case(3)
+    scales = {}
+    if kv_dtype == "int8":
+        k_pool, scales["k_scale"] = _quantize_kv(k_pool)
+        v_pool, scales["v_scale"] = _quantize_kv(v_pool)
+    else:
+        k_pool = k_pool.astype(jnp.bfloat16)
+        v_pool = v_pool.astype(jnp.bfloat16)
+    out = pattn.paged_verify_attention(
+        q, chunk_k, chunk_v, k_pool, v_pool, jnp.int32(layer), table,
+        base, page_size=ps, impl="kernel", interpret=True, **scales)
+    for other in range(3):
+        one = {name: arr[other:other + 1] for name, arr in scales.items()}
+        ref = pattn.paged_verify_reference(
+            q, chunk_k, chunk_v, k_pool[other:other + 1],
+            v_pool[other:other + 1], 0, table, base, ps, **one)
+        # rows 0 and 2 have a prefix in the pool; row 1 (base 0) attends
+        # its own chunk only, whatever the layer
+        gap = float(jnp.max(jnp.abs(out - ref)[jnp.asarray([0, 2])]))
+        assert gap < 2e-5 if other == layer else gap > 0.02
 
 
 # -- engine level -------------------------------------------------------------

@@ -6,6 +6,13 @@ rehearsal). Interpret mode accepts block shapes, VMEM footprints and
 slices the TPU lowering refuses; these cases are what stands between a
 passing tier-1 and a first decode tick that raises on the chip.
 
+The kernels take the page pool as it is stored (``[L, P+1, page_size,
+Hkv, D]``) and a traced layer index. ``test_pool_programs_copy_no_layer``
+compiles the serving programs around them and reads the optimised HLO:
+a kernel handed ``pool[layer]`` makes XLA copy a whole pool layer before
+every call (an operand of a kernel is a buffer of its own), which no
+result or interpret-mode test can see.
+
 A compile that passes is not a chip run: nothing here executes, and
 nothing here says anything about results or times (``chip_smoke.py``
 phase ``kernels`` checks results on the chip).
@@ -15,15 +22,19 @@ nowhere else — only the xdist worker that is handed this file loads the
 TPU library, and it compiles in its own process.
 """
 
+import functools
 import importlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from mlrun_tpu.models.llama import LlamaConfig, init_params
 from mlrun_tpu.ops import paged_attention as pattn
+from mlrun_tpu.serving import llm, paged
 
 # ``mlrun_tpu.ops.attention`` the attribute is the dispatcher function
 attn = importlib.import_module("mlrun_tpu.ops.attention")
@@ -35,6 +46,8 @@ N_HEADS, N_KV_HEADS = 32, 8
 HEAD_DIMS = {"llama3-1b": 64, "llama3-8b": 128}
 PREFILL_CHUNK = 512      # a prefill bucket; 1 is the last-token replay
 VERIFY_ROWS = 5          # speculative k + 1
+POOL_LAYERS = 3          # a wrong layer index or a per-layer copy shows
+KV_DTYPES = {"bf16": "native", "int8": "int8"}   # the engine's names
 
 
 @pytest.fixture(scope="module")
@@ -63,13 +76,15 @@ def on_chip():
 
 
 def _pool(on_chip, head_dim, kv_dtype):
-    """(pages, scales-kwargs) shapes of one pool layer."""
-    dims = (N_PAGES + 1, PAGE_SIZE, N_KV_HEADS, head_dim)
+    """(pool, layer, scales-kwargs) shapes: the pool as the engine stores
+    it and the traced layer index."""
+    dims = (POOL_LAYERS, N_PAGES + 1, PAGE_SIZE, N_KV_HEADS, head_dim)
+    layer = on_chip((), jnp.int32)
     if kv_dtype == "int8":
         scale = on_chip(dims[:-1], jnp.float32)
-        return on_chip(dims, jnp.int8), {"k_scale": scale,
-                                         "v_scale": scale}
-    return on_chip(dims, jnp.bfloat16), {}
+        return on_chip(dims, jnp.int8), layer, {"k_scale": scale,
+                                                "v_scale": scale}
+    return on_chip(dims, jnp.bfloat16), layer, {}
 
 
 def _compile(fn, *args, **kwargs):
@@ -81,16 +96,17 @@ def _compile(fn, *args, **kwargs):
 @pytest.mark.parametrize("model", list(HEAD_DIMS))
 def test_paged_decode_compiles(on_chip, model, kv_dtype):
     d = HEAD_DIMS[model]
-    pages, scales = _pool(on_chip, d, kv_dtype)
+    pool, layer, scales = _pool(on_chip, d, kv_dtype)
     q = on_chip((SLOTS, N_HEADS, d), jnp.bfloat16)
     table = on_chip((SLOTS, PAGES_PER_SLOT), jnp.int32)
     pos = on_chip((SLOTS,), jnp.int32)
 
-    def decode(q, k, v, table, pos, **scales):
-        return pattn._paged_decode_call(q, k, v, table, pos, PAGE_SIZE,
-                                        interpret=False, **scales)
+    def decode(q, k, v, layer, table, pos, **scales):
+        return pattn._paged_decode_call(q, k, v, layer, table, pos,
+                                        PAGE_SIZE, interpret=False,
+                                        **scales)
 
-    _compile(decode, q, pages, pages, table, pos, **scales)
+    _compile(decode, q, pool, pool, layer, table, pos, **scales)
 
 
 @pytest.mark.parametrize("chunk", [PREFILL_CHUNK, 1])
@@ -98,36 +114,36 @@ def test_paged_decode_compiles(on_chip, model, kv_dtype):
 @pytest.mark.parametrize("model", list(HEAD_DIMS))
 def test_paged_prefill_compiles(on_chip, model, kv_dtype, chunk):
     d = HEAD_DIMS[model]
-    pages, scales = _pool(on_chip, d, kv_dtype)
+    pool, layer, scales = _pool(on_chip, d, kv_dtype)
     q = on_chip((1, chunk, N_HEADS, d), jnp.bfloat16)
     ids = on_chip((PAGES_PER_SLOT,), jnp.int32)
     base = on_chip((), jnp.int32)
 
-    def prefill(q, k, v, ids, base, **scales):
-        return pattn.paged_prefix_part(q, k, v, ids, base,
+    def prefill(q, k, v, layer, ids, base, **scales):
+        return pattn.paged_prefix_part(q, k, v, layer, ids, base,
                                        page_size=PAGE_SIZE,
                                        interpret=False, **scales)
 
-    _compile(prefill, q, pages, pages, ids, base, **scales)
+    _compile(prefill, q, pool, pool, layer, ids, base, **scales)
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
 @pytest.mark.parametrize("model", list(HEAD_DIMS))
 def test_paged_verify_compiles(on_chip, model, kv_dtype):
     d = HEAD_DIMS[model]
-    pages, scales = _pool(on_chip, d, kv_dtype)
+    pool, layer, scales = _pool(on_chip, d, kv_dtype)
     q = on_chip((SLOTS, VERIFY_ROWS, N_HEADS, d), jnp.bfloat16)
     chunk_kv = on_chip((SLOTS, VERIFY_ROWS, N_KV_HEADS, d), jnp.bfloat16)
     table = on_chip((SLOTS, PAGES_PER_SLOT), jnp.int32)
     base = on_chip((SLOTS,), jnp.int32)
 
-    def verify(q, ck, cv, k, v, table, base, **scales):
+    def verify(q, ck, cv, k, v, layer, table, base, **scales):
         return pattn.paged_verify_attention(
-            q, ck, cv, k, v, table, base, page_size=PAGE_SIZE,
+            q, ck, cv, k, v, layer, table, base, page_size=PAGE_SIZE,
             impl="kernel", interpret=False, **scales)
 
-    _compile(verify, q, chunk_kv, chunk_kv, pages, pages, table, base,
-             **scales)
+    _compile(verify, q, chunk_kv, chunk_kv, pool, pool, layer, table,
+             base, **scales)
 
 
 @pytest.mark.parametrize("form", ["self", "cached", "bounded"])
@@ -161,3 +177,95 @@ def test_rms_norm_pallas_compiles(on_chip, hidden):
     x = on_chip((8, 2048, hidden), jnp.bfloat16)
     scale = on_chip((hidden,), jnp.float32)
     _compile(lambda x, scale: rms_norm_pallas(x, scale), x, scale)
+
+
+# -- the serving programs around the kernels ---------------------------------
+
+def _pool_layer_results(hlo: str, head_dim: int):
+    """Opcodes of the instructions whose result is one layer of the page
+    pool — its pages ``[P+1, page_size, Hkv, D]`` or its int8 scales
+    ``[P+1, page_size, Hkv]``, with or without a leading 1."""
+    dims = f"{N_PAGES + 1},{PAGE_SIZE},{N_KV_HEADS}"
+    shape = rf"\w+\[(?:1,)?{dims}(?:,{head_dim})?\]"
+    result = re.compile(rf"^\s*(?:ROOT )?\S+ = {shape}\S* ([\w-]+)\(")
+    return {m.group(1) for line in hlo.splitlines()
+            if (m := result.match(line))}
+
+
+@pytest.fixture
+def program_shapes(on_chip, monkeypatch):
+    """``program_shapes(head_dim, kv_dtype)``: a narrow model at the
+    real head shapes, its page pool, and ``place`` for further operands.
+    The programs' kernel calls ask ``interpret_default()``, which sees
+    this sandbox's CPU: answered here as the chip would."""
+    monkeypatch.setattr(pattn, "interpret_default", lambda: False)
+    monkeypatch.setattr(attn, "interpret_default", lambda: False)
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda a: on_chip(a.shape, a.dtype), tree)
+
+    def shapes(head_dim, kv_dtype):
+        config = LlamaConfig(
+            vocab_size=1024, n_layers=POOL_LAYERS, embed_dim=512,
+            n_heads=N_HEADS, n_kv_heads=N_KV_HEADS, head_dim=head_dim,
+            mlp_dim=1024)
+        params = place(jax.eval_shape(
+            lambda: init_params(config, jax.random.PRNGKey(0))))
+        pool = place(jax.eval_shape(lambda: paged.init_paged_pool(
+            config, N_PAGES + 1, PAGE_SIZE, KV_DTYPES[kv_dtype])))
+        return config, params, pool, place
+
+    return shapes
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("head_dim", [128, 64])
+@pytest.mark.parametrize("program", ["decode", "verify", "prefill_hit"])
+def test_pool_programs_copy_no_layer(program_shapes, on_chip, program,
+                                     head_dim, kv_dtype):
+    """The decode, speculative-verify and prefix-hit prefill programs
+    hand their kernels the pool as stored: in the optimised HLO nothing
+    but a ``bitcast`` yields an array the shape of a pool layer, and at
+    head_dim 128 on a bf16 pool the program's temporaries stay under
+    one layer (a copy per kernel call needs two: K and V).
+
+    Known and left alone (PERF.md section 7): at head_dim 64 the pool,
+    and on int8 pools the scales, arrive in a page_size-minor device
+    layout and are copied whole into row-major and back around the
+    program; the scales' copy is made of layer-sized ``slice-done``
+    pieces that a ``ConcatBitcast`` joins."""
+    config, params, pool, place = program_shapes(head_dim, kv_dtype)
+    table = on_chip((SLOTS, PAGES_PER_SLOT), jnp.int32)
+    pos = on_chip((SLOTS,), jnp.int32)
+    kwargs, donate = {}, (2,)      # the engine donates the pool
+    if program == "decode":
+        fn = functools.partial(paged._decode_rowwise_paged, config,
+                               PAGE_SIZE, "kernel")
+        args = (params, on_chip((SLOTS, 1), jnp.int32), pool, table, pos)
+    elif program == "verify":
+        fn = functools.partial(paged._verify_rowwise_paged, config,
+                               PAGE_SIZE, "kernel")
+        args = (params, on_chip((SLOTS, VERIFY_ROWS), jnp.int32), pool,
+                table, pos)
+    else:
+        fn = functools.partial(llm._forward_with_cache, config,
+                               attn_impl="flash", page_size=PAGE_SIZE)
+        cache = place(jax.eval_shape(lambda: llm.init_kv_cache(
+            config, 1, PAGES_PER_SLOT * PAGE_SIZE,
+            kv_dtype=KV_DTYPES[kv_dtype])))
+        args = (params, on_chip((1, PREFILL_CHUNK), jnp.int32), cache)
+        kwargs = {"prefix_kv": dict(
+            pool, page_ids=on_chip((PAGES_PER_SLOT,), jnp.int32),
+            base=on_chip((), jnp.int32))}
+        donate = ()                # a hit only reads the pool
+    compiled = jax.jit(fn, donate_argnums=donate).lower(
+        *args, **kwargs).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") >= POOL_LAYERS
+    allowed = {"bitcast", "slice-done"} if kv_dtype == "int8" \
+        else {"bitcast"}
+    assert _pool_layer_results(hlo, head_dim) <= allowed
+    if head_dim == 128 and kv_dtype == "bf16":
+        layer_bytes = (N_PAGES + 1) * PAGE_SIZE * N_KV_HEADS * head_dim * 2
+        assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
