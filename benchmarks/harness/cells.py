@@ -4,12 +4,15 @@ does not know.
 A cell (``workloads/<cell>.json``) names a configuration
 (``configs/<config>.json``) and a traffic mix (``traffic/<traffic>.json``);
 every ``layer_metrics/*.json`` whose ``workloads`` lists the cell belongs to
-it. A later PR adds a cell, a configuration, a mix or a metric as a new file
-and edits none that is here.
+it. A configuration names its model family, a cell and its mix their kind:
+``harness/family_<family>.py`` and ``harness/<kind>.py``, modules found by
+name as the data files are. A later PR adds a cell, a configuration, a mix,
+a metric, a family or a kind as a new file and edits none that is here.
 """
 
 from __future__ import annotations
 
+import importlib
 import json
 import os
 import re
@@ -24,18 +27,16 @@ CELL_KEYS = {"name", "kind", "config", "traffic", "chips", "geometry",
              "rehearsal"}
 CELL_REQUIRED = {"name", "kind", "config", "traffic", "chips", "check", "why",
                  "who"}
+# what every family's configuration carries; the model's own keys are its
+# family module's ``CONFIG_REQUIRED`` and ``CONFIG_KEYS``
 CONFIG_REQUIRED = {"name", "source", "reduced", "assumed", "deployment",
-                   "published", "hidden_size", "intermediate_size",
-                   "num_attention_heads", "num_key_value_heads", "head_dim",
-                   "num_hidden_layers", "vocab_size", "rope_theta",
-                   "rms_norm_eps", "tie_word_embeddings", "torch_dtype"}
-CONFIG_KEYS = CONFIG_REQUIRED | {
-    "model_type", "max_position_embeddings", "hidden_act", "sliding_window",
-    "weights", "precision"}
+                   "published", "torch_dtype"}
+CONFIG_KEYS = CONFIG_REQUIRED | {"family", "model_type", "weights",
+                                 "precision"}
+DEFAULT_FAMILY = "llama"
 METRIC_KEYS = {"name", "layer", "unit", "better", "source", "moves",
                "workloads", "reader", "module", "args", "what"}
 METRIC_REQUIRED = METRIC_KEYS - {"module", "args"}
-KINDS = ("serve", "train")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 
 
@@ -63,6 +64,36 @@ def _load(directory: str, name: str, base: str | None = None) -> dict:
     return data
 
 
+def harness_module(what: str, module: str):
+    """``harness/<module>.py``, found by the name a data file gives."""
+    name = f"benchmarks.harness.{module}"
+    try:
+        return importlib.import_module(name)
+    except ModuleNotFoundError as exc:
+        if not name.startswith(exc.name or "\0"):
+            raise           # the module is there; what it imports is not
+        raise CellError(
+            f"{what}: no file benchmarks/harness/{module}.py") from None
+
+
+def family_of(config: dict, what: str | None = None):
+    """The module of the configuration's model family: its config keys, its
+    map onto the program, its reference and costs, and what a serve cell
+    compares."""
+    family = check_name(config.get("family", DEFAULT_FAMILY))
+    what = what or f"configuration {config.get('name')!r}"
+    return harness_module(f"{what}: family {family!r}", f"family_{family}")
+
+
+def kind_module(what: str, kind):
+    """The module of a kind of cell; it has ``run``."""
+    module = harness_module(f"{what}: kind {check_name(kind)!r}", kind)
+    if not callable(getattr(module, "run", None)):
+        raise CellError(f"{what}: benchmarks/harness/{kind}.py has no run(), "
+                        f"so {kind!r} is no kind of cell")
+    return module
+
+
 def _check_keys(what: str, data: dict, allowed: set, required: set):
     unknown = sorted(set(data) - allowed)
     if unknown:
@@ -74,7 +105,10 @@ def _check_keys(what: str, data: dict, allowed: set, required: set):
 
 def load_config(name: str, base: str | None = None) -> dict:
     data = _load("configs", name, base)
-    _check_keys(f"configs/{name}.json", data, CONFIG_KEYS, CONFIG_REQUIRED)
+    family = family_of(data, f"configs/{name}.json")
+    _check_keys(f"configs/{name}.json", data,
+                CONFIG_KEYS | set(family.CONFIG_KEYS),
+                CONFIG_REQUIRED | set(family.CONFIG_REQUIRED))
     if data["name"] != name:
         raise CellError(f"configs/{name}.json names itself {data['name']!r}")
     for key in data["reduced"]:
@@ -88,8 +122,7 @@ def load_config(name: str, base: str | None = None) -> dict:
 
 def load_traffic(name: str, base: str | None = None) -> dict:
     data = _load("traffic", name, base)
-    if data.get("kind") not in KINDS:
-        raise CellError(f"traffic/{name}.json: kind must be one of {KINDS}")
+    kind_module(f"traffic/{name}.json", data.get("kind"))
     return data
 
 
@@ -97,12 +130,13 @@ def load_cell(name: str, base: str | None = None) -> dict:
     """The cell with its configuration under ``config_data`` and its mix
     under ``traffic_data``."""
     cell = _load("workloads", name, base)
-    _check_keys(f"workloads/{name}.json", cell, CELL_KEYS, CELL_REQUIRED)
+    kind = kind_module(f"workloads/{name}.json", cell.get("kind"))
+    _check_keys(f"workloads/{name}.json", cell,
+                CELL_KEYS | set(getattr(kind, "CELL_KEYS", ())),
+                CELL_REQUIRED)
     if cell["name"] != name:
         raise CellError(f"workloads/{name}.json names itself "
                         f"{cell['name']!r}")
-    if cell["kind"] not in KINDS:
-        raise CellError(f"workloads/{name}.json: kind must be one of {KINDS}")
     if cell["chips"] not in (1, 4):
         raise CellError(f"workloads/{name}.json: chips is 1 or 4")
     cell = dict(cell)
@@ -148,26 +182,7 @@ def rehearsed(cell: dict) -> dict:
     out = dict(cell)
     out["config_data"] = {**cell["config_data"], **over.get("config", {})}
     out["traffic_data"] = {**cell["traffic_data"], **over.get("traffic", {})}
-    out["geometry"] = {**cell.get("geometry", {}),
-                       **over.get("geometry", {})}
-    out["check"] = {**cell["check"], **over.get("check", {})}
+    for key in {"geometry", "check"} | set(over) - {"config", "traffic"}:
+        out[key] = {**cell.get(key, {}), **over.get(key, {})}
     return out
 
-
-def llama_fields(config: dict) -> dict:
-    """The published keys under the names ``models/llama.LlamaConfig``
-    takes (dtype stays the dataclass's default, bfloat16)."""
-    if config["torch_dtype"] != "bfloat16":
-        raise CellError("only bfloat16 configurations run here")
-    return {
-        "vocab_size": int(config["vocab_size"]),
-        "n_layers": int(config["num_hidden_layers"]),
-        "embed_dim": int(config["hidden_size"]),
-        "n_heads": int(config["num_attention_heads"]),
-        "n_kv_heads": int(config["num_key_value_heads"]),
-        "head_dim": int(config["head_dim"]),
-        "mlp_dim": int(config["intermediate_size"]),
-        "rope_theta": float(config["rope_theta"]),
-        "norm_eps": float(config["rms_norm_eps"]),
-        "tie_embeddings": bool(config["tie_word_embeddings"]),
-    }

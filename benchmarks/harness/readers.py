@@ -8,8 +8,9 @@ A reader takes the run's context and returns its number, or ``None`` where
 it finds nothing to read: the harness then leaves the metric out of the
 line. It never returns 0 for a share of a roofline or of a peak.
 
-The context: ``cell``, ``fields``, ``chips``, ``peak`` (the chip's row of peaks.json;
-None in a rehearsal),
+The context: ``cell``, ``fields``, ``costs`` (the cost module of the
+configuration's family; ``costs.py`` where the context names none),
+``chips``, ``peak`` (the chip's row of peaks.json; None in a rehearsal),
 ``window_s``, ``trace`` (the reduced profile of the traced part, or None),
 ``traced`` (that part's start and stop on the host's clock, where known),
 and for a serve cell ``finished`` (the window's requests, each with
@@ -25,6 +26,11 @@ from statistics import median
 
 from . import costs, trace_reduce
 from .common import percentile
+
+
+def _costs(ctx):
+    """The cost functions of the cell's family."""
+    return ctx.get("costs", costs)
 
 
 def engine_stat(ctx, key: str, scale: float = 1.0):
@@ -79,7 +85,7 @@ def window_mfu(ctx, cost: str):
         finished = ctx.get("finished") or []
         if not finished or ctx["window_s"] <= 0:
             return None
-        flops = sum(costs.serve_request_flops(
+        flops = sum(_costs(ctx).serve_request_flops(
             ctx["fields"], len(r["prompt"]), len(r["tokens"]))
             for r in finished)
         return 100.0 * flops / ctx["window_s"] / peak
@@ -87,7 +93,7 @@ def window_mfu(ctx, cost: str):
         rate = ctx.get("tokens_per_s")
         if not rate:
             return None
-        return 100.0 * rate * costs.lora_train_flops_per_token(
+        return 100.0 * rate * _costs(ctx).lora_train_flops_per_token(
             ctx["fields"], ctx["seq_len"]) / peak
     raise ValueError(f"unknown cost {cost!r}")
 
@@ -143,15 +149,15 @@ def kernel_roofline(ctx, pattern: str, cost: str, kernels: list | None = None):
         rows = _decoding_rows(finished, ctx.get("traced"), ctx["window_s"])
         context = sum(len(r["prompt"]) + len(r["tokens"]) / 2.0
                       for r in finished) / len(finished)
-        call = costs.paged_decode_call(fields, rows, context)
+        call = _costs(ctx).paged_decode_call(fields, rows, context)
         least = count * costs.roofline_seconds(call, peak)[0]
     elif cost == "flash":
         least, seconds = 0.0, 0.0
         for kernel in kernels:
             spent, calls = trace_reduce.matching(trace, kernel["pattern"])
-            call = costs.flash_call(fields, ctx["batch_size"],
-                                    ctx["seq_len"], kernel["products"],
-                                    kernel["tensors"])
+            call = _costs(ctx).flash_call(
+                fields, ctx["batch_size"], ctx["seq_len"],
+                kernel["products"], kernel["tensors"])
             least += calls * costs.roofline_seconds(call, peak)[0]
             seconds += spent
         if seconds <= 0:

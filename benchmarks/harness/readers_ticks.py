@@ -93,8 +93,9 @@ def kernel_roofline_ticks(ctx, pattern: str, tolerance: float = 0.2):
     expected = len(decoding) * fields["n_layers"]
     if abs(count - expected) > tolerance * expected:
         return None
+    call = ctx.get("costs", costs).paged_decode_call
     least = fields["n_layers"] * sum(
-        costs.roofline_seconds(costs.paged_decode_call(
+        costs.roofline_seconds(call(
             fields, r["rows"], r["ctx_tokens"] / r["rows"]), peak)[0]
         for r in decoding)
     return 100.0 * least * (count / expected) / seconds

@@ -6,7 +6,12 @@ Set-up builds the graph once (weights, warm-up, one request per prefill
 bucket). The window opens as the first client starts, and closes once
 every request sent in ``--seconds`` has been answered: it counts all of
 them, over all of that time. Then the engine is stopped and freed, and the
-plain reference judges a seeded sample of what was served.
+plain reference of the configuration's family judges a seeded sample of
+what was served.
+
+Beside the common keys a serve cell may carry ``server`` (handed to
+``add_model`` as it is) and ``request`` (merged into every request's
+body); the whole answer stays in the request's record under ``body``.
 """
 
 from __future__ import annotations
@@ -18,18 +23,20 @@ import time
 
 import numpy as np
 
-from . import cells, reference
+from . import cells
 from .common import percentile
 from .traffic import RequestStream
 
 INFER_PATH = "/v2/models/llm/infer"
 MODEL_CLASS = "mlrun_tpu.serving.llm.LLMModelServer"
+CELL_KEYS = {"server", "request"}
 
 
 class ServeCell:
     def __init__(self, cell: dict):
         self.cell = cell
-        self.fields = cells.llama_fields(cell["config_data"])
+        self.family = cells.family_of(cell["config_data"])
+        self.fields = self.family.fields(cell["config_data"])
         self.geometry = cell["geometry"]
         self.traffic = cell["traffic_data"]
         self.server = self.engine = self.route = None
@@ -38,26 +45,29 @@ class ServeCell:
     def build(self):
         import mlrun_tpu
         from mlrun_tpu.frameworks.jax.auto_trainer import MODEL_PRESETS
-        from mlrun_tpu.models.llama import LlamaConfig
 
-        fields = dict(self.fields)
         preset = self.cell["config"]
-        MODEL_PRESETS[preset] = lambda **over: LlamaConfig(
-            **{**fields, **over})
+        MODEL_PRESETS[preset] = self.family.preset(self.fields)
         geo = self.geometry
         if int(self.traffic["output_tokens"]) != int(geo["max_new_tokens"]):
             raise cells.CellError(
                 "the server fixes max_new_tokens for all requests: the "
                 "mix's output_tokens has to equal the geometry's")
-        fn = mlrun_tpu.new_function(f"bench-{self.cell['name']}",
-                                    kind="serving")
-        fn.set_topology("router")
-        self.route = fn.add_model(
-            "llm", class_name=MODEL_CLASS, model_preset=preset,
+        model = dict(
+            class_name=MODEL_CLASS, model_preset=preset,
             continuous_batching=True, paged=True,
             page_size=geo["page_size"], slots=geo["slots"],
             max_len=geo["max_len"], n_pages=geo["n_pages"], warmup=True,
             max_new_tokens=geo["max_new_tokens"])
+        server = self.cell.get("server", {})
+        if set(server) & set(model):
+            raise cells.CellError(
+                f"server: {sorted(set(server) & set(model))} are set by the "
+                f"harness or the cell's geometry")
+        fn = mlrun_tpu.new_function(f"bench-{self.cell['name']}",
+                                    kind="serving")
+        fn.set_topology("router")
+        self.route = fn.add_model("llm", **model, **server)
         self.server = fn.to_mock_server()   # weights, warm-up, engine start
         self.engine = self.route.object.engine
 
@@ -67,11 +77,12 @@ class ServeCell:
         sent = time.perf_counter()
         with jax.profiler.TraceAnnotation("bench.request"):
             body = self.server.test(
-                INFER_PATH, body={"inputs": [prompt], "timing": True})
+                INFER_PATH, body={**self.cell.get("request", {}),
+                                  "inputs": [prompt], "timing": True})
         done = time.perf_counter()
         return {"sent": sent, "done": done, "prompt": prompt,
                 "tokens": list(body["outputs"][0]),
-                "timing": (body.get("timing") or [None])[0]}
+                "timing": (body.get("timing") or [None])[0], "body": body}
 
     def warm_buckets(self):
         """One request per prefill bucket through the graph, so that what
@@ -224,59 +235,13 @@ def sample_finished(finished: list, seed: int, count: int) -> list:
     return [longest] + [rest[i] for i in sorted(picks)]
 
 
-def pad_length(cell: dict) -> int:
-    """The one length the reference pads every sampled request to."""
-    return int(cell["traffic_data"]["prompt_tokens"]["max"]) \
-        + int(cell["geometry"]["max_new_tokens"])
-
-
-def served_gap(fields: dict, weights: dict, sample: list, pad_to: int,
-               quant=None) -> dict:
-    """The widest gap by which a served token's logit lies below the
-    reference's best, over the sample. With ``quant`` set it is the
-    control's reading instead: the gap of the token that the lower
-    precision puts first, at the same positions."""
-    widest, where, tokens_seen = 0.0, None, 0
-    for record in sample:
-        served = record["tokens"]
-        if any(not 0 <= t < fields["vocab_size"] for t in served):
-            return {"value": float("inf"), "tokens": tokens_seen,
-                    "where": f"request {record['index']}: id out of range"}
-        exact = np.asarray(reference.served_logits(
-            fields, weights, record["prompt"], served, pad_to))
-        if quant is None:
-            chosen = served
-        else:
-            low = np.asarray(reference.served_logits(
-                fields, weights, record["prompt"], served, pad_to,
-                quant=quant))
-            chosen = low.argmax(axis=-1)
-        gaps = reference.gap_below_best(exact, chosen)
-        tokens_seen += len(served)
-        if float(gaps.max()) >= widest:
-            widest = float(gaps.max())
-            where = f"request {record['index']} token {int(gaps.argmax())}"
-    return {"value": widest, "tokens": tokens_seen, "where": where}
-
-
 def check(cell: dict, fields: dict, finished: list, seed: int) -> dict:
-    """What is compared, each beside its limit. Weights are the server's
-    recipe from key 0, made anew here."""
+    """What is compared, each beside its limit: the configuration's family
+    decides it, over a sample of the finished requests drawn here."""
     sample = sample_finished(finished, seed,
                              int(cell["check"]["sample_requests"]))
-    if sample:
-        reading = served_gap(fields,
-                             reference.make_weights(fields, 0, eager=True),
-                             sample, pad_length(cell))
-    else:
-        reading = {"value": float("inf"), "tokens": 0,
-                   "where": "no request finished in the window"}
-    limit = float(cell["check"]["limits"]["served_logit_gap_max"])
-    return {"served_logit_gap_max": {
-        "value": reading["value"], "limit": limit,
-        "ok": bool(reading["value"] <= limit),
-        "served_tokens": reading["tokens"], "requests": len(sample),
-        "where": reading["where"]}}
+    return cells.family_of(cell["config_data"]).serve_check(
+        cell, fields, sample)
 
 
 # -- one run -------------------------------------------------------------------
@@ -302,7 +267,7 @@ def run(cell: dict, layer_metrics: list, args, device: dict,
         trace_seconds=float(cell.get("trace_seconds", 3.0)))
     compiled = compiles.since_mark()
     peak_bytes = common.memory_peak_bytes(cell["chips"])
-    fields = serving.fields
+    family, fields = serving.family, serving.fields
     serving.close()
 
     out_tokens = int(cell["geometry"]["max_new_tokens"])
@@ -321,8 +286,8 @@ def run(cell: dict, layer_metrics: list, args, device: dict,
     if args.trace:
         metrics, breakdown = common.traced_metrics(
             tracer, layer_metrics,
-            {"cell": cell, "fields": fields, "chips": cell["chips"],
-             "window_s": window_s, "finished": good,
+            {"cell": cell, "fields": fields, "costs": family.costs,
+             "chips": cell["chips"], "window_s": window_s, "finished": good,
              "traced": result["traced"],
              "engine_stats": result["engine_stats"]},
             device, bool(args.rehearse))
@@ -340,3 +305,29 @@ def run(cell: dict, layer_metrics: list, args, device: dict,
         breakdown=breakdown,
         notes={"window_s": window_s, "finished": len(good),
                "first_failure": (result["failed"] or [{}])[0].get("error")})
+
+
+# -- the readings the limits are set from (readings.py) -----------------------
+def readings(cell: dict, seeds: list, controls: int, seconds: float):
+    """One engine, a window per seed; then, the engine freed, the family's
+    readings over each window's sample."""
+    serving = ServeCell(cell)
+    serving.build()
+    serving.warm_buckets()
+    family, fields = serving.family, serving.fields
+    windows = []
+    for seed in seeds:
+        result = serving.window(seed, seconds)
+        _metrics, good, malformed = end_to_end(
+            result, int(cell["geometry"]["max_new_tokens"]))
+        windows.append({"seed": seed, "finished": len(good),
+                        "failed": len(result["failed"]) + len(malformed)
+                        + result["hung"],
+                        "sample": sample_finished(
+                            good, seed,
+                            int(cell["check"]["sample_requests"]))})
+    serving.close()
+    read = family.serve_readings(cell, fields,
+                                 [w.pop("sample") for w in windows], controls)
+    for window, entry in zip(windows, read):
+        yield {**window, **entry}

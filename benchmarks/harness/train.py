@@ -18,7 +18,7 @@ import time
 
 import numpy as np
 
-from . import cells, reference
+from . import cells
 from .traffic import train_batches
 
 B1 = 0.9        # the trainer's TrainConfig defaults, stated here because the
@@ -199,9 +199,10 @@ def compare(program: dict, ref: dict) -> dict:
                                          f"{change_leaf} of {len(moved)}")}
 
 
-def reference_readings(fields: dict, traffic: dict, seed: int,
+def reference_readings(reference, fields: dict, traffic: dict, seed: int,
                        quant=None, drop_half_batch=False) -> dict:
-    """The reference through the checked steps, from the seed alone."""
+    """The family's ``reference`` through the checked steps, from the seed
+    alone."""
     import jax
 
     steps = int(traffic["checked_steps"])
@@ -233,7 +234,9 @@ def program_readings(clock) -> dict:
 
 def check(cell: dict, fields: dict, program: dict, seed: int) -> dict:
     limits = cell["check"]["limits"]
-    ref = reference_readings(fields, cell["traffic_data"], seed)
+    ref = reference_readings(
+        cells.family_of(cell["config_data"]).reference, fields,
+        cell["traffic_data"], seed)
     compared = {}
     for name, (value, where) in compare(program, ref).items():
         limit = float(limits[name])
@@ -253,7 +256,8 @@ def drive(cell: dict, seed: int, seconds: float, compiles, tracer,
     from .common import stamp
 
     traffic = cell["traffic_data"]
-    fields = cells.llama_fields(cell["config_data"])
+    family = cells.family_of(cell["config_data"])
+    fields = family.fields(cell["config_data"])
     clock = make_clock(seconds, int(traffic["checked_steps"]), compiles,
                        tracer, int(cell.get("trace_steps", 3)),
                        process_start)
@@ -262,7 +266,7 @@ def drive(cell: dict, seed: int, seconds: float, compiles, tracer,
         from mlrun_tpu.frameworks.jax import train
 
         stamp(process_start, "handler entered")
-        return train(context, model=dict(fields),
+        return train(context, model=family.train_model(fields),
                      lora_rank=int(traffic["lora_rank"]),
                      lora_alpha=float(traffic["lora_alpha"]),
                      seq_len=int(traffic["seq_len"]),
@@ -286,7 +290,8 @@ def run(cell: dict, layer_metrics: list, args, device: dict,
     from . import common
 
     traffic = cell["traffic_data"]
-    fields = cells.llama_fields(cell["config_data"])
+    family = cells.family_of(cell["config_data"])
+    fields = family.fields(cell["config_data"])
     compiles = common.CompileCounter()
     tracer = common.Tracer(cell["name"]) if args.trace else None
     clock = drive(cell, args.seed, args.seconds, compiles, tracer,
@@ -314,8 +319,9 @@ def run(cell: dict, layer_metrics: list, args, device: dict,
     if args.trace:
         metrics, breakdown = common.traced_metrics(
             tracer, layer_metrics,
-            {"cell": cell, "fields": fields, "chips": cell["chips"],
-             "window_s": window_s, "tokens_per_s": rate,
+            {"cell": cell, "fields": fields, "costs": family.costs,
+             "chips": cell["chips"], "window_s": window_s,
+             "tokens_per_s": rate,
              "batch_size": int(traffic["batch_size"]),
              "seq_len": int(traffic["seq_len"])},
             device, bool(args.rehearse))
@@ -338,3 +344,44 @@ def run(cell: dict, layer_metrics: list, args, device: dict,
         breakdown=breakdown,
         notes={"window_s": window_s, "steps": clock.steps,
                "error": clock.error})
+
+
+# -- the readings the limits are set from (readings.py) -----------------------
+def readings(cell: dict, seeds: list, controls: int, seconds: float):
+    """The program and the reference over each seed, and over the first
+    ``controls`` the control and the planted fault in the reference's
+    place."""
+    from . import common
+
+    family = cells.family_of(cell["config_data"])
+    fields = family.fields(cell["config_data"])
+    compiles = common.CompileCounter()
+    for i, seed in enumerate(seeds):
+        clock = drive(cell, seed, seconds, compiles, None,
+                      time.perf_counter())
+        if clock.error:
+            yield {"seed": seed, "error": clock.error}
+            continue
+        program = program_readings(clock)
+        del clock
+        gc.collect()
+        started = time.perf_counter()
+        ref = reference_readings(family.reference, fields,
+                                 cell["traffic_data"], seed)
+        took = time.perf_counter() - started
+        entry = {"seed": seed, "reference_s": took,
+                 "program_losses": program["losses"],
+                 "reference_losses": ref["losses"]}
+        for name, (value, where) in compare(program, ref).items():
+            entry[name] = value
+            entry[name + ".where"] = where
+        if i < controls:
+            for label, kw in (("control_int8", {"quant": "int8"}),
+                              ("half_batch", {"drop_half_batch": True})):
+                other = reference_readings(
+                    family.reference, fields, cell["traffic_data"], seed,
+                    **kw)
+                for name, (value, _w) in compare(other, ref).items():
+                    entry[f"{label}.{name}"] = value
+        yield entry
+        gc.collect()

@@ -21,7 +21,6 @@ import time
 PROCESS_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
 
@@ -60,9 +59,8 @@ def main(argv=None) -> int:
                  f"seconds={args.seconds} trace={args.trace} device={device} "
                  f"compile_cache={cache_dir}")
 
-    kind = importlib.import_module(f"benchmarks.harness.{cell['kind']}")
-    line = kind.run(cell, layer_metrics, args, device,
-                             PROCESS_START)
+    kind = cells.kind_module(f"workloads/{args.workload}.json", cell["kind"])
+    line = kind.run(cell, layer_metrics, args, device, PROCESS_START)
     if args.rehearse:
         print(line, file=sys.stderr)
         print("benchmarks/run.py: rehearsal finished; this is not a chip "

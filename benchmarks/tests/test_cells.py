@@ -4,7 +4,7 @@ import shutil
 
 import pytest
 
-from benchmarks.harness import cells
+from benchmarks.harness import cells, family_llama
 
 
 def test_benchmark_json_lists_what_the_loader_finds():
@@ -84,6 +84,6 @@ def test_rehearsal_changes_sizes_not_the_cell():
     tiny = cells.rehearsed(cell)
     assert tiny["config_data"]["hidden_size"] < 4096
     assert cell["config_data"]["hidden_size"] == 4096
-    fields = cells.llama_fields(cell["config_data"])
+    fields = family_llama.llama_fields(cell["config_data"])
     assert fields["n_layers"] == 16 and fields["embed_dim"] == 4096
     assert fields["n_kv_heads"] == 8 and fields["rope_theta"] == 1e6

@@ -10,7 +10,7 @@ import json
 import numpy as np
 import pytest
 
-from benchmarks.harness import cells, reference, serve, train
+from benchmarks.harness import cells, family_llama, reference, serve, train
 
 DEVICE = {"platform": "cpu", "kind": "cpu", "count": 1}
 
@@ -53,9 +53,10 @@ def test_serve_sound_run_is_correct_and_the_control_reads_wider(served):
     assert compared["served_logit_gap_max"]["served_tokens"] > 0
     weights = reference.make_weights(fields, 0, eager=True)
     sample = serve.sample_finished(good, 11, len(good))
-    pad_to = serve.pad_length(cell)
-    program = serve.served_gap(fields, weights, sample, pad_to)
-    control = serve.served_gap(fields, weights, sample, pad_to, quant="int8")
+    pad_to = family_llama.pad_length(cell)
+    program = family_llama.served_gap(fields, weights, sample, pad_to)
+    control = family_llama.served_gap(fields, weights, sample, pad_to,
+                                      quant="int8")
     assert control["value"] > program["value"]
 
 
@@ -196,12 +197,12 @@ def test_train_half_the_batch_left_out_is_not_correct(monkeypatch):
 
 def test_train_control_and_planted_fault_read_wider_than_the_program():
     cell = cells.rehearsed(cells.load_cell("nemo-train-lora"))
-    fields = cells.llama_fields(cell["config_data"])
-    ref = train.reference_readings(fields, cell["traffic_data"], 9)
-    control = train.reference_readings(fields, cell["traffic_data"], 9,
-                                       quant="int8")
-    half = train.reference_readings(fields, cell["traffic_data"], 9,
-                                    drop_half_batch=True)
+    fields = family_llama.llama_fields(cell["config_data"])
+    ref = train.reference_readings(reference, fields, cell["traffic_data"], 9)
+    control = train.reference_readings(
+        reference, fields, cell["traffic_data"], 9, quant="int8")
+    half = train.reference_readings(
+        reference, fields, cell["traffic_data"], 9, drop_half_batch=True)
     same = train.compare(ref, ref)
     assert all(value == 0 for value, _w in same.values())
     low = train.compare(control, ref)

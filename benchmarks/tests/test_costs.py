@@ -2,10 +2,10 @@
 
 import pytest
 
-from benchmarks.harness import cells, costs
+from benchmarks.harness import cells, costs, family_llama
 
-M7B = cells.llama_fields(cells.load_config("mistral-7b-v0.3"))
-NEMO = cells.llama_fields(cells.load_config("mistral-nemo-12b"))
+M7B = family_llama.llama_fields(cells.load_config("mistral-7b-v0.3"))
+NEMO = family_llama.llama_fields(cells.load_config("mistral-nemo-12b"))
 
 
 def test_parameter_counts():
