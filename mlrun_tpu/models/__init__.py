@@ -1,7 +1,6 @@
 from .llama import (  # noqa: F401
     LlamaConfig,
     forward,
-    init_params,
     init_permutation_params,
     llama3_1b,
     llama3_8b,
@@ -29,10 +28,22 @@ from .bert import (  # noqa: F401
 )
 from .moe import (  # noqa: F401
     MoEConfig,
+    SdarConfig,
     make_moe_rules,
     mixtral_8x7b_like,
     tiny_moe,
+    tiny_sdar,
 )
+from . import llama as _llama, moe as _moe
+
+
+def init_params(config, key):
+    """The weights that the config's own module defines, by its recipe:
+    ``models/moe.py`` for a config with experts, ``models/llama.py`` for
+    the dense decoder."""
+    module = _moe if isinstance(config, MoEConfig) else _llama
+    return module.init_params(config, key)
+
 from . import vit  # noqa: F401  (vit.classify/encode stay namespaced —
 # bert exports the same verb names at package level)
 from .vit import ViTConfig, tiny_vit, vit_b16, vit_l16  # noqa: F401

@@ -50,6 +50,15 @@ class LlamaConfig:
     # FLOPs); "dots" saves every matmul output (max memory, min recompute)
     remat_policy: str = "nothing"
     attention_impl: str = "auto"
+    # q and k normalised per head (one learned scale of head_dim each)
+    # before the rotation; off for the Llama and Mistral families
+    qk_norm: bool = False
+    # attention mask by blocks of positions aligned to multiples of this
+    # length: position i sees j iff j // block_length <= i // block_length.
+    # 1 is the causal mask; > 1 is a model that generates by diffusion over
+    # blocks (models/moe.py SdarConfig, docs/serving.md "Block-diffusion
+    # decoding"), which only the paged engine serves
+    block_length: int = 1
 
     @property
     def qkv_dim(self) -> int:
@@ -278,6 +287,51 @@ def _layer_body(config: LlamaConfig, x, layer_params, cos, sin,
         up = proj(h, lp["w_up"], "w_up")
         x = x + proj(jax.nn.silu(gate) * up, lp["w_down"], "w_down")
     return x
+
+
+def qk_normed(config: LlamaConfig, q, k, lp):
+    """q and k as the rotation takes them: under ``config.qk_norm`` each
+    head's vector is RMS-normalised with the layer's learned scale
+    (``q_norm_scale`` / ``k_norm_scale`` [head_dim]) first. The one place
+    the serving programs (serving/llm.py ``_forward_with_cache``,
+    serving/paged.py ``_decode_rowwise_paged`` and ``_verify_rowwise_paged``)
+    learn about q/k norms."""
+    if not config.qk_norm:
+        return q, k
+    return (rms_norm(q, lp["q_norm_scale"], config.norm_eps),
+            rms_norm(k, lp["k_norm_scale"], config.norm_eps))
+
+
+def layer_slice(layers: Params, layer: int) -> Params:
+    """Layer ``layer``'s parameters out of the stacked tree, for the
+    serving programs' Python loop over layers. Stacks of experts
+    (``experts_*``) stay whole: their grouped products reach a layer's
+    experts through the group sizes (models/moe.py ``_grouped``), where a
+    sliced stack would be copied before every product."""
+    return {name: (leaf if name.startswith("experts_") else leaf[layer])
+            for name, leaf in layers.items()}
+
+
+def layer_mlp(config: LlamaConfig, h2, lp, proj, live=None, layer=None):
+    """The layer's MLP over the normed input ``h2`` [B, S, E], as the
+    serving programs call it: the dense SwiGLU through the caller's
+    ``proj`` (which adds a tenant's LoRA delta), or, where the layer's
+    parameters carry experts, the dropless expert layer of models/moe.py
+    over the experts the config holds. ``live`` [B, S] bool leaves dead
+    rows out of the expert routing; ``layer`` is the layer's index where
+    ``lp`` came from :func:`layer_slice` (its experts are still stacked).
+    Returns ``(out, load)``: ``load`` is
+    the pairs each held expert got (int32 [experts held]), ``None`` for
+    the dense MLP."""
+    if "experts_gate" in lp:
+        from .moe import moe_mlp
+
+        return moe_mlp(config, h2, lp,
+                       held=getattr(config, "experts_held", None), live=live,
+                       layer=layer)
+    gate = proj(h2, lp["w_gate"], "w_gate")
+    up = proj(h2, lp["w_up"], "w_up")
+    return proj(jax.nn.silu(gate) * up, lp["w_down"], "w_down"), None
 
 
 def forward(config: LlamaConfig, params: Params, tokens: jax.Array,

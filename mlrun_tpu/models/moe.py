@@ -1,11 +1,23 @@
-"""Mixture-of-Experts llama variant — GShard-style capacity dispatch + EP.
+"""Mixture-of-Experts llama variant: two expert layers over one weight
+layout (experts stacked ``[L, E, ...]``).
 
-Completes the parallelism inventory (SURVEY.md §2.4 reserved the expert
-axis): the dense SwiGLU MLP is replaced by top-k routed experts whose
-weights are stacked ``[L, E, ...]`` and sharded over an ``expert`` mesh axis
-(parallel/sharding rules below). Dispatch/combine are the TPU-idiomatic
-one-hot einsums (static capacity; no dynamic shapes), so XLA lays the token
-shuffle onto all-to-alls across the expert axis.
+**The trainer's** (``_moe_mlp``, with ``forward`` / ``loss_fn`` here): a
+GShard-style capacity dispatch. Completes the parallelism inventory
+(SURVEY.md §2.4 reserved the expert axis): the experts are sharded over an
+``expert`` mesh axis (parallel/sharding rules below); dispatch/combine are
+the TPU-idiomatic one-hot einsums (static capacity; no dynamic shapes), so
+XLA lays the token shuffle onto all-to-alls across the expert axis. It
+drops the tokens past an expert's capacity and carries the aux loss.
+
+**The server's** (``moe_mlp``): dropless. It is told which experts it holds,
+routes over the router's full width, sorts the token-expert pairs by
+expert, runs one grouped matrix product per projection over the experts
+held and combines with the gates; what absent experts would add is left
+out. The serving programs reach it through ``models/llama.layer_mlp``
+(docs/serving.md "Block-diffusion decoding"). ``SdarConfig`` is the family
+it serves: q/k norms, every layer experts, generation by diffusion over
+blocks. The two layers meet in a later PR (moving the trainer needs the
+exchange across the ``expert`` axis, ROADMAP S6).
 """
 
 from __future__ import annotations
@@ -48,12 +60,20 @@ class MoEConfig(LlamaConfig):
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
 
+    @property
+    def expert_width(self) -> int:
+        """Hidden width of one expert (``mlp_dim`` unless the family keeps
+        a dense width apart from it)."""
+        return self.mlp_dim
+
     def param_count(self) -> int:
         embed = self.vocab_size * self.embed_dim
         attn = (self.embed_dim * self.qkv_dim
                 + 2 * self.embed_dim * self.kv_dim
                 + self.qkv_dim * self.embed_dim)
-        moe = (self.n_experts * 3 * self.embed_dim * self.mlp_dim
+        if self.qk_norm:
+            attn += 2 * self.head_dim
+        moe = (self.n_experts * 3 * self.embed_dim * self.expert_width
                + self.embed_dim * self.n_experts)
         per_layer = attn + moe + 2 * self.embed_dim
         head = 0 if self.tie_embeddings else self.vocab_size * self.embed_dim
@@ -66,12 +86,45 @@ class MoEConfig(LlamaConfig):
         attn = (self.embed_dim * self.qkv_dim
                 + 2 * self.embed_dim * self.kv_dim
                 + self.qkv_dim * self.embed_dim)
-        active_moe = (self.top_k * 3 * self.embed_dim * self.mlp_dim
+        active_moe = (self.top_k * 3 * self.embed_dim * self.expert_width
                       + self.embed_dim * self.n_experts)
         matmul = (self.n_layers * (attn + active_moe)
                   + self.vocab_size * self.embed_dim)
         attn_flops = 2 * self.n_layers * seq_len * self.qkv_dim
         return 6.0 * matmul + 6.0 * attn_flops
+
+
+@dataclasses.dataclass(frozen=True)
+class SdarConfig(MoEConfig):
+    """The SDAR family (``model_type: sdar_moe``): q/k norms, every layer a
+    mixture of ``n_experts`` experts of width ``expert_dim`` with ``top_k``
+    a token and no shared expert, generation by diffusion over blocks of
+    ``block_length`` positions (``LlamaConfig.block_length``; masked
+    positions embed ``mask_token_id``). Served only, by the paged engine
+    through ``moe_mlp``; ``mlp_dim`` is the family's unused dense width."""
+
+    qk_norm: bool = True
+    block_length: int = 4
+    mask_token_id: int = 151669
+    expert_dim: int = 768
+    # gates of the chosen experts renormalised to sum to 1
+    norm_topk: bool = True
+    # the contiguous range (lo, hi) of experts whose weights are held
+    # here; None: all of them
+    experts_held: Optional[tuple] = None
+    remat: bool = False
+
+    @property
+    def expert_width(self) -> int:
+        return self.expert_dim
+
+
+def tiny_sdar(**overrides) -> SdarConfig:
+    return dataclasses.replace(SdarConfig(
+        vocab_size=512, n_layers=2, embed_dim=64, n_heads=4, n_kv_heads=2,
+        head_dim=16, mlp_dim=128, n_experts=8, top_k=2, expert_dim=32,
+        block_length=4, mask_token_id=511, rope_theta=1e6, norm_eps=1e-6,
+        tie_embeddings=False), **overrides)
 
 
 def tiny_moe(**overrides) -> MoEConfig:
@@ -88,16 +141,26 @@ def mixtral_8x7b_like(**overrides) -> MoEConfig:
         rope_theta=1e6), **overrides)
 
 
+@functools.partial(jax.jit, static_argnames=("fan_in", "shape", "dtype"))
+def _normal_leaf(k, fan_in: int, shape: tuple, dtype):
+    # under jit: the float32 draw of a stack of experts never exists whole
+    return (jax.random.normal(k, shape, jnp.float32)
+            * fan_in ** -0.5).astype(dtype)
+
+
 def init_params(config: MoEConfig, key: jax.Array) -> Params:
+    """The recipe of models/llama.py (normal x fan_in^-0.5, norm scales 1)
+    over ten keys split from ``key``; with ``experts_held`` set the experts'
+    leaves are the held slice of the whole draw, so an expert's weights do
+    not depend on which share holds it."""
     keys = jax.random.split(key, 10)
     dtype = config.dtype
     e, h, kv, m = (config.embed_dim, config.qkv_dim, config.kv_dim,
-                   config.mlp_dim)
+                   config.expert_width)
     L, E = config.n_layers, config.n_experts
 
     def norm_init(fan_in, shape, k):
-        return (jax.random.normal(k, shape, jnp.float32)
-                * fan_in ** -0.5).astype(dtype)
+        return _normal_leaf(k, fan_in, tuple(shape), jnp.dtype(dtype))
 
     params: Params = {
         "embedding": norm_init(e, (config.vocab_size, e), keys[0]),
@@ -115,14 +178,125 @@ def init_params(config: MoEConfig, key: jax.Array) -> Params:
         },
         "final_norm_scale": jnp.ones((e,), dtype),
     }
+    if config.qk_norm:
+        params["layers"]["q_norm_scale"] = jnp.ones((L, config.head_dim),
+                                                    dtype)
+        params["layers"]["k_norm_scale"] = jnp.ones((L, config.head_dim),
+                                                    dtype)
+    held = getattr(config, "experts_held", None)
+    if held is not None:
+        for name in ("experts_gate", "experts_up", "experts_down"):
+            params["layers"][name] = \
+                params["layers"][name][:, held[0]:held[1]]
     if not config.tie_embeddings:
         params["lm_head"] = norm_init(
             e, (e, config.vocab_size), keys[9])
     return params
 
 
+def _grouped(lhs, rhs, group_sizes, layer=None):
+    """``lhs[rows of group g] @ rhs[g]`` for every group, rows sorted by
+    group: [P, K] x [G, K, N] -> [P, N] float32, by the library's megablox
+    ``gmm`` kernel (its operations read ``gmm`` in a device trace). Rows
+    past the groups' sum come out undefined; the caller weights them with 0.
+
+    With ``layer`` given, ``rhs`` is the stack of all layers' groups [L, G,
+    K, N] and the product runs over layer ``layer``'s: the kernel is handed
+    the stack as stored, viewed as L x G groups of which only the layer's
+    have rows (it visits no empty group). A sliced layer would be an
+    operand of its own, a copy of every expert's weights before each
+    product (what a pool layer was to the paged kernels, PERF.md PR 28).
+
+    Kept after one timing on the chip (PERF.md, PR 30): the three products
+    of a layer at this family's widths take 1.73 ms at 1,024 pairs over 128
+    experts and 2.29 ms at 8,192 with these tiles, ``jax.lax.ragged_dot``
+    4.83 and 5.44 ms."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    from ..ops.attention import interpret_default
+
+    if layer is not None:
+        n_layers, groups = rhs.shape[:2]
+        group_sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((n_layers * groups,), jnp.int32), group_sizes,
+            (layer * groups,))
+        rhs = rhs.reshape(n_layers * groups, *rhs.shape[2:])
+    rows, k = lhs.shape
+    n = rhs.shape[-1]
+    tile_rows = min(128, -(-rows // 8) * 8)
+    pad = (-rows) % tile_rows
+    if pad:
+        lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+    out = gmm(lhs, rhs, group_sizes, jnp.float32,
+              (tile_rows, min(k, 2048), min(n, 2048)),
+              interpret=interpret_default())
+    return out[:rows]
+
+
+def moe_mlp(config: MoEConfig, x, lp, held=None, live=None, layer=None):
+    """The served, dropless expert layer: x [B, S, M] -> (y [B, S, M],
+    load int32 [experts held]).
+
+    Routes every token over the router's full width (softmax in float32,
+    top-k, gates renormalised under ``norm_topk``), keeps the
+    token-expert pairs whose expert lies in ``held`` (a contiguous range
+    ``(lo, hi)``; ``None``: all) and whose token is ``live`` ([B, S] bool;
+    ``None``: all), sorts them by expert, runs one grouped product per
+    projection over the held experts' weights (``lp["experts_*"]``
+    [hi - lo, ...]) and adds each pair's output, times its gate, to its
+    token. No token is dropped; what an absent expert would add is left
+    out and nothing stands in for it. ``load`` is the pairs each held
+    expert got. With ``layer`` given, ``lp["experts_*"]`` are the stacks
+    of every layer's held experts ([L, hi - lo, ...], as the parameters
+    store them) and the products run over that layer's (:func:`_grouped`). The routing runs under the named scope
+    ``layer/moe/route``, the products under ``layer/moe/experts``."""
+    b, s, m = x.shape
+    E, k = config.n_experts, config.top_k
+    lo, hi = (0, E) if held is None else held
+    n_held = hi - lo
+    given = lp["experts_gate"].shape[0 if layer is None else 1]
+    if given != n_held:
+        raise ValueError(
+            f"moe_mlp holds experts [{lo}, {hi}) but was given {given} "
+            f"experts' weights")
+    t = b * s
+    xt = x.reshape(t, m)
+    with jax.named_scope("layer/moe/route"):
+        logits = jnp.einsum("tm,me->te", xt.astype(jnp.float32),
+                            lp["router"].astype(jnp.float32))
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, chosen = jax.lax.top_k(probs, k)              # [T, k]
+        if getattr(config, "norm_topk", True):
+            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        expert = chosen.reshape(t * k)
+        kept = (expert >= lo) & (expert < hi)
+        if live is not None:
+            kept = kept & jnp.repeat(live.reshape(t), k)
+        # pairs that are not kept sort behind the last group
+        group = jnp.where(kept, expert - lo, n_held)
+        order = jnp.argsort(group)                           # stable
+        load = jnp.zeros((n_held + 1,), jnp.int32).at[group].add(
+            1)[:n_held]
+        rows = xt[order // k]                                # [T * k, M]
+    with jax.named_scope("layer/moe/experts"):
+        gate_h = _grouped(rows, lp["experts_gate"], load, layer)
+        up_h = _grouped(rows, lp["experts_up"], load, layer)
+        hidden = (jax.nn.silu(gate_h) * up_h).astype(x.dtype)
+        out = _grouped(hidden, lp["experts_down"], load, layer)  # [T*k, M]
+    with jax.named_scope("layer/moe/route"):
+        weight = jnp.where(kept, gates.reshape(t * k), 0.0)
+        pairs = jnp.where(kept[:, None], out[jnp.argsort(order)], 0.0)
+        y = jnp.sum((pairs * weight[:, None]).reshape(t, k, m), axis=1)
+    return y.astype(x.dtype).reshape(b, s, m), load
+
+
 def _moe_mlp(config: MoEConfig, x, lp):
-    """GShard top-k dispatch: x [B, S, M] -> [B, S, M] + aux loss scalar."""
+    """GShard top-k dispatch: x [B, S, M] -> [B, S, M] + aux loss scalar.
+
+    The trainer's layer: static capacity, tokens past it dropped, the aux
+    loss, the ``expert`` mesh axis. The server runs :func:`moe_mlp`
+    (dropless, told which experts it holds); the two meet in a later PR
+    (ROADMAP S6)."""
     b, s, m = x.shape
     E, k = config.n_experts, config.top_k
     capacity = max(1, int(config.capacity_factor * s * k / E))

@@ -28,7 +28,8 @@ KEPT_LOGS = 16
 
 FIELDS = ("n", "t0", "t_admit", "t_built", "t_dispatched", "t_fetched",
           "t1", "admit_wait_s", "rows", "ctx_tokens", "prefill_tokens",
-          "kind")
+          "kind", "positions", "tokens_out", "commit_rows", "expert_pairs",
+          "experts_touched", "expert_load_max")
 
 
 class TickRecord:
@@ -42,7 +43,21 @@ class TickRecord:
     first-token fetch). ``rows`` live rows of the decode tick (0 where the
     iteration only admitted), ``ctx_tokens`` the tokens those rows attend
     (prompt + generated so far, summed), ``prefill_tokens`` prompt tokens
-    prefilled in this iteration, ``kind`` ``plain`` or ``spec``.
+    prefilled in this iteration, ``kind`` ``plain``, ``spec`` or
+    ``denoise``. ``tokens_out`` is what the tick yielded over all rows: one
+    a row (plain), the tokens a speculative round emitted, the positions a
+    denoising pass unmasked (none in a row whose pass committed its block).
+
+    A ``denoise`` tick (a pass of a block-diffusion model,
+    docs/serving.md "Block-diffusion decoding") also fills ``positions``
+    (row-positions in the pass: live rows x block length), ``commit_rows``
+    (rows whose pass was the commit of a finished block) and, from the
+    pass's own fetch, the expert layers' counters over the live rows:
+    ``expert_pairs`` (token-expert pairs routed, summed over layers),
+    ``experts_touched`` (experts that got at least one pair, summed over
+    layers) and ``expert_load_max`` (the most pairs one expert got in one
+    layer). There ``ctx_tokens`` is the positions the live rows attend:
+    each row's committed prefix and its block.
 
     A boundary that an iteration never reaches stays at the one before it,
     so every interval is defined and non-negative.
@@ -54,6 +69,8 @@ class TickRecord:
         self.n = n
         self.admit_wait_s = 0.0
         self.rows = self.ctx_tokens = self.prefill_tokens = 0
+        self.positions = self.tokens_out = self.commit_rows = 0
+        self.expert_pairs = self.experts_touched = self.expert_load_max = 0
         self.kind = "plain"
         self.t0 = t0
         self.admitted(t0)

@@ -151,9 +151,20 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     lse_ref[0] = jnp.broadcast_to(m + jnp.log(l), (block_q, 8))
 
 
+def causal_bound(q_pos, block_length: int = 1):
+    """The last position that ``q_pos`` sees under the block mask: the end
+    of its block of ``block_length`` positions, blocks aligned to multiples
+    of the length from 0 (``k_pos <= (q_pos // B) * B + B - 1``). At 1 it is
+    ``q_pos`` itself, the causal mask, and nothing is computed."""
+    if block_length == 1:
+        return q_pos
+    return (q_pos // block_length) * block_length + (block_length - 1)
+
+
 def _flash_v2_body(q_off, k_lo, q_ref, k_ref, v_ref, o_ref, lse_ref,
                    m_scr, l_scr, acc_scr, *,
-                   num_kb: int, kv_len: int, scale: float, causal: bool):
+                   num_kb: int, kv_len: int, scale: float, causal: bool,
+                   block_length: int = 1):
     """Grid-pipelined flash forward body: grid (bh, q_blocks, k_blocks).
 
     Unlike the v1 kernel (full KV resident in VMEM), each program sees one
@@ -172,6 +183,9 @@ def _flash_v2_body(q_off, k_lo, q_ref, k_ref, v_ref, o_ref, lse_ref,
     cache rows < k_lo belong to shared prefix pages attended separately
     by the paged prefill kernel and LSE-merged afterwards —
     ops/paged_attention.py).
+
+    ``block_length`` (static) widens the causal bound of a q position to
+    the end of its block (:func:`causal_bound`); 1 is the causal mask.
     """
     qi = pl.program_id(1)
     kb = pl.program_id(2)
@@ -190,7 +204,8 @@ def _flash_v2_body(q_off, k_lo, q_ref, k_ref, v_ref, o_ref, lse_ref,
     # causal: whole tile masked out when every k is beyond every q
     # (python bool when q_off is the static 0, a traced predicate when it
     # is the dynamic cached-prefill offset — pl.when takes both)
-    live = (not causal) or (k_start <= q_off + q_start + block_q - 1)
+    live = (not causal) or (k_start <= causal_bound(
+        q_off + q_start + block_q - 1, block_length))
     if bounded:
         # tiles wholly below the lower bound contribute nothing
         live = live & (k_start + block_k - 1 >= k_lo)
@@ -206,7 +221,8 @@ def _flash_v2_body(q_off, k_lo, q_ref, k_ref, v_ref, o_ref, lse_ref,
         if causal:
             q_pos = q_off + q_start + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+            s = jnp.where(causal_bound(q_pos, block_length) >= k_pos, s,
+                          NEG_INF)
         if bounded:
             s = jnp.where(k_pos >= k_lo, s, NEG_INF)
         s = jnp.where(k_pos < kv_len, s, NEG_INF)
@@ -256,7 +272,7 @@ def _flash_fwd_kernel_v2_bounded(q_off_ref, k_lo_ref, q_ref, k_ref, v_ref,
 
 
 def _flash_v2_call(q, k, v, causal, block_q, block_k, interpret, q_offset,
-                   k_lo=None):
+                   k_lo=None, block_length: int = 1):
     """Shared v2 plumbing (block fit, padding, fold batch*heads, grid,
     scratch) for the self-attention and cached-prefill forms — one body,
     so the two can never diverge (the cold-vs-hit parity contract rides
@@ -286,7 +302,8 @@ def _flash_v2_call(q, k, v, causal, block_q, block_k, interpret, q_offset,
     vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
     num_kb = sk // block_k
     grid = (b * h, sq // block_q, num_kb)
-    static = dict(num_kb=num_kb, kv_len=orig_sk, scale=scale, causal=causal)
+    static = dict(num_kb=num_kb, kv_len=orig_sk, scale=scale, causal=causal,
+                  block_length=block_length)
     if q_offset is None:
         kernel = functools.partial(_flash_fwd_kernel_v2, **static)
         off_specs, off_args = [], ()
@@ -348,9 +365,9 @@ def _flash_fwd_v2(q, k, v, causal=True, block_q=512, block_k=512,
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k",
-                                             "interpret"))
+                                             "interpret", "block_length"))
 def _flash_fwd_v2_cached(q, k, v, q_offset, block_q=512, block_k=512,
-                         interpret=None):
+                         interpret=None, block_length: int = 1):
     """Causal grid-pipelined flash where q rows sit at absolute positions
     ``q_offset + i`` against kv rows indexed from 0 — the serving prefill
     form (q is a prompt chunk, k/v the full KV cache with the chunk
@@ -362,13 +379,14 @@ def _flash_fwd_v2_cached(q, k, v, q_offset, block_q=512, block_k=512,
     prefix state instead and carries a tolerance contract
     (docs/serving.md "Attention kernels")."""
     return _flash_v2_call(q, k, v, True, block_q, block_k, interpret,
-                          q_offset)
+                          q_offset, block_length=block_length)
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k",
-                                             "interpret"))
+                                             "interpret", "block_length"))
 def _flash_fwd_v2_cached_bounded(q, k, v, q_offset, k_lo, block_q=512,
-                                 block_k=512, interpret=None):
+                                 block_k=512, interpret=None,
+                                 block_length: int = 1):
     """Causal cached flash with a kv lower bound: rows < ``k_lo`` are
     masked out (the serving engines' suffix-prefill form on a paged
     prefix-cache hit — those positions live in shared pool pages, not
@@ -376,16 +394,18 @@ def _flash_fwd_v2_cached_bounded(q, k, v, q_offset, k_lo, block_q=512,
     Returns (o, lse) so the caller can LSE-merge the two partial
     softmax states (ops/paged_attention.merge_softmax_states)."""
     return _flash_v2_call(q, k, v, True, block_q, block_k, interpret,
-                          q_offset, k_lo=k_lo)
+                          q_offset, k_lo=k_lo, block_length=block_length)
 
 
-def flash_attention_cached(q, k, v, q_start) -> jax.Array:
+def flash_attention_cached(q, k, v, q_start,
+                           block_length: int = 1) -> jax.Array:
     """Forward-only flash over a KV cache: q [B, S, H, D] rows at
     positions ``q_start + i``; k/v [B, M, H, D] the cache (kv already
     repeated to q heads, current rows written at q_start..q_start+S).
-    Rows past ``q_start + S - 1`` are excluded by the causal mask, so the
-    cache tail needs no explicit length."""
-    o, _ = _flash_fwd_v2_cached(q, k, v, q_start)
+    Rows past the last q row's bound (its own position; under
+    ``block_length`` > 1 the end of its block, :func:`causal_bound`) are
+    excluded by the mask, so the cache tail needs no explicit length."""
+    o, _ = _flash_fwd_v2_cached(q, k, v, q_start, block_length=block_length)
     return o
 
 

@@ -85,6 +85,7 @@ from .attention import (
     NEG_INF,
     _fit_block,
     _flash_fwd_v2_cached_bounded,
+    causal_bound,
     _on_tpu,
     _repeat_kv,
     interpret_default,
@@ -472,14 +473,18 @@ def paged_prefill_attention(q, k_cache, v_cache, q_start, k_pool,
     return merge_softmax_states(o_pre, lse_pre, o_loc, lse_loc)
 
 
-def chunk_causal_part(q, k, v):
-    """Closed-form causal partial softmax of a verify chunk over ITSELF:
-    q [B, S, H, D], k/v [B, S, Hkv, D] (the chunk's own just-computed
-    KV — for int8 pools the caller passes the quantize->dequantize
-    round-trip so the chunk attends exactly what the pool stores).
-    S is tiny (k draft tokens + 1), so a dense S x S pass beats a flash
-    instance. Returns (o [B, S, H, D] f32, lse [B, H, S] f32) for
-    :func:`merge_softmax_states` with the paged prefix part."""
+def chunk_causal_part(q, k, v, block_length: int = 1):
+    """Closed-form partial softmax of a chunk over ITSELF under the block
+    mask: q [B, S, H, D], k/v [B, S, Hkv, D] (the chunk's own
+    just-computed KV — for int8 pools the caller passes the
+    quantize->dequantize round-trip so the chunk attends exactly what
+    the pool stores). Lane ``i`` sees lane ``j`` iff ``j <=
+    causal_bound(i, block_length)``: causal at 1 (the speculative verify
+    chunk), full for a chunk that is one block (a denoising pass; the
+    chunk starts on a block boundary, so lanes stand for positions).
+    S is tiny (k draft tokens + 1, or a block), so a dense S x S pass
+    beats a flash instance. Returns (o [B, S, H, D] f32, lse [B, H, S]
+    f32) for :func:`merge_softmax_states` with the paged prefix part."""
     b, s, h, d = q.shape
     n_rep = h // k.shape[2]
     k = _repeat_kv(k.astype(jnp.float32), n_rep)
@@ -488,7 +493,7 @@ def chunk_causal_part(q, k, v):
     logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k,
                         preferred_element_type=jnp.float32) * scale
     i = jnp.arange(s)
-    causal = i[None, :] <= i[:, None]                  # [q, kv]
+    causal = i[None, :] <= causal_bound(i, block_length)[:, None]  # [q, kv]
     logits = jnp.where(causal[None, None], logits, NEG_INF)
     m = jnp.max(logits, axis=-1)                       # [B, H, S]
     w = jnp.exp(logits - m[..., None])
@@ -499,11 +504,13 @@ def chunk_causal_part(q, k, v):
 
 def paged_verify_reference(q, chunk_k, chunk_v, k_pool, v_pool, layer,
                            page_table, base, page_size: int,
-                           k_scale=None, v_scale=None):
+                           k_scale=None, v_scale=None,
+                           block_length: int = 1):
     """Dense-view verify reference: gather every slot's pages into
     [slots, max_len] (the materialization the verify kernel avoids),
     splice the chunk KV at positions ``base[r] + i``, and run one masked
-    softmax with the per-position causal bound ``k_pos <= base[r] + i``.
+    softmax with the per-position bound ``k_pos <= causal_bound(base[r] +
+    i)`` (the position itself, or its block's end under ``block_length``).
     Chunk lanes past the view tail drop (see below); lanes past a row's
     accepted length are computed-and-discarded garbage, exactly like the
     kernel path."""
@@ -527,8 +534,8 @@ def paged_verify_reference(q, chunk_k, chunk_v, k_pool, v_pool, layer,
     logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), kd,
                         preferred_element_type=jnp.float32) * scale
     k_pos = jnp.arange(m)[None, None, :]
-    mask = k_pos <= positions[:, :, None]               # [B, S, M]
-    logits = jnp.where(mask[:, None], logits, NEG_INF)
+    mask = k_pos <= causal_bound(positions, block_length)[:, :, None]
+    logits = jnp.where(mask[:, None], logits, NEG_INF)          # [B, S, M]
     weights = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", weights, vd)
 
@@ -536,7 +543,8 @@ def paged_verify_reference(q, chunk_k, chunk_v, k_pool, v_pool, layer,
 def paged_verify_attention(q, chunk_k, chunk_v, k_pool, v_pool, layer,
                            page_table, base, *, page_size: int,
                            impl: str = "auto", k_scale=None,
-                           v_scale=None, interpret=None):
+                           v_scale=None, interpret=None,
+                           block_length: int = 1):
     """Speculative multi-token verify attention over the page pool: q
     [slots, S, H, D] are each row's draft positions ``base[r]..base[r] +
     S - 1`` (S = k + 1: the committed last token plus k draft tokens);
@@ -545,16 +553,21 @@ def paged_verify_attention(q, chunk_k, chunk_v, k_pool, v_pool, layer,
     attends the prefix pages in place — the verify chunk is literally
     the prefill kernel's q-chunk form, batched per slot — and LSE-merges
     the chunk's local causal part; no dense gather, int8 pools included.
+    Under ``block_length`` > 1 the chunk is one block of a block-diffusion
+    model starting at ``base[r]`` (a multiple of the length): the prefix
+    part is unchanged (it reads positions ``< base``) and the local part
+    is full (:func:`chunk_causal_part`).
     Returns the merged [slots, S, H, D] f32 output."""
     impl = resolve_paged_impl(impl)
     if impl == "reference":
         return paged_verify_reference(
             q, chunk_k, chunk_v, k_pool, v_pool, layer, page_table,
-            base, page_size, k_scale=k_scale, v_scale=v_scale)
+            base, page_size, k_scale=k_scale, v_scale=v_scale,
+            block_length=block_length)
     o_pre, lse_pre = _paged_chunk_call(
         q, k_pool, v_pool, layer, page_table, base, page_size,
         k_scale=k_scale, v_scale=v_scale, interpret=interpret)
-    o_loc, lse_loc = chunk_causal_part(q, chunk_k, chunk_v)
+    o_loc, lse_loc = chunk_causal_part(q, chunk_k, chunk_v, block_length)
     return merge_softmax_states(o_pre, lse_pre, o_loc, lse_loc)
 
 

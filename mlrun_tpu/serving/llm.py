@@ -18,7 +18,14 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from ..models.llama import LlamaConfig, Params
+from ..models.llama import (
+    LlamaConfig,
+    Params,
+    layer_mlp,
+    layer_slice,
+    qk_normed,
+)
+from ..ops.attention import causal_bound
 from ..ops.norms import rms_norm
 from ..ops.rotary import apply_rope, rope_table
 from ..utils import logger
@@ -66,7 +73,8 @@ def _dequantize_kv(q: jax.Array, scale: jax.Array, dtype) -> jax.Array:
 
 
 def _cached_attention(config, q, k_cache, v_cache, q_positions, cache_len):
-    """q: [B, S, H, D]; caches: [B, M, HKV, D]. Causal over positions."""
+    """q: [B, S, H, D]; caches: [B, M, HKV, D]. Causal over positions (by
+    blocks of ``config.block_length``: ops.attention.causal_bound)."""
     n_rep = config.n_heads // config.n_kv_heads
     b, m = k_cache.shape[0], k_cache.shape[1]
     if n_rep > 1:
@@ -76,7 +84,8 @@ def _cached_attention(config, q, k_cache, v_cache, q_positions, cache_len):
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_cache,
                         preferred_element_type=jnp.float32) * scale
     k_pos = jnp.arange(m)[None, :]  # [1, M]
-    mask = (k_pos[None] <= q_positions[:, :, None])  # [B, S, M]
+    bound = causal_bound(q_positions, config.block_length)
+    mask = (k_pos[None] <= bound[:, :, None])  # [B, S, M]
     logits = jnp.where(mask[:, None], logits, -2.0**30)
     weights = jax.nn.softmax(logits, axis=-1).astype(v_cache.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v_cache)
@@ -102,7 +111,8 @@ def _cached_attention_lse(config, q, k_cache, v_cache, q_positions, k_lo):
                         k_cache.astype(jnp.float32),
                         preferred_element_type=jnp.float32) * scale
     k_pos = jnp.arange(m)[None, :]  # [1, M]
-    mask = (k_pos[None] <= q_positions[:, :, None]) \
+    bound = causal_bound(q_positions, config.block_length)
+    mask = (k_pos[None] <= bound[:, :, None]) \
         & (k_pos[None] >= k_lo)     # [B, S, M]
     logits = jnp.where(mask[:, None], logits, -2.0**30)
     m_max = jnp.max(logits, axis=-1)                      # [B, H, S]
@@ -191,6 +201,7 @@ def _forward_with_cache(config: LlamaConfig, params: Params,
                                                 config.head_dim)
             v = proj(h, lp["wv"], "wv").reshape(b, s, config.n_kv_heads,
                                                 config.head_dim)
+            q, k = qk_normed(config, q, k, lp)
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
             quantized = "k_scale" in cache
@@ -239,7 +250,8 @@ def _forward_with_cache(config: LlamaConfig, params: Params,
                 if attn_impl == "flash" and s > 1:
                     o_loc, lse_loc = _flash_fwd_v2_cached_bounded(
                         q, _repeat_kv(k_attn, n_rep),
-                        _repeat_kv(v_attn, n_rep), start[0], base)
+                        _repeat_kv(v_attn, n_rep), start[0], base,
+                        block_length=config.block_length)
                 else:
                     o_loc, lse_loc = _cached_attention_lse(
                         config, q, k_attn, v_attn, positions, base)
@@ -262,7 +274,7 @@ def _forward_with_cache(config: LlamaConfig, params: Params,
                 # shape class TPU lowering never otherwise sees
                 attn = flash_attention_cached(
                     q, _repeat_kv(k_attn, n_rep), _repeat_kv(v_attn, n_rep),
-                    start[0])
+                    start[0], block_length=config.block_length)
             else:
                 attn = _cached_attention(config, q, k_attn, v_attn, positions,
                                          max_len)
@@ -270,16 +282,14 @@ def _forward_with_cache(config: LlamaConfig, params: Params,
             x_mid = x_in + proj(attn, lp["wo"], "wo")
         with jax.named_scope("layer/mlp"):
             h2 = rms_norm(x_mid, lp["mlp_norm_scale"], config.norm_eps)
-            gate = proj(h2, lp["w_gate"], "w_gate")
-            up = proj(h2, lp["w_up"], "w_up")
-            out = x_mid + proj(jax.nn.silu(gate) * up, lp["w_down"], "w_down")
+            out = x_mid + layer_mlp(config, h2, lp, proj, layer=layer)[0]
         return out, (k_cache, v_cache, scales)
 
     # python loop over layers: compiled once per bucket; exposes per-layer
     # cache updates without scan-carry gymnastics
     new_k, new_v, new_ks, new_vs = [], [], [], []
     for layer in range(config.n_layers):
-        lp = jax.tree_util.tree_map(lambda a: a[layer], params["layers"])
+        lp = layer_slice(params["layers"], layer)
         x, (k_cache, v_cache, scales) = body(x, (layer, lp))
         new_k.append(k_cache)
         new_v.append(v_cache)
@@ -318,6 +328,13 @@ class LLMEngine:
         from ..config import mlconf
         from ..ops.attention import resolve_prefill_impl
 
+        if getattr(config, "block_length", 1) > 1:
+            from .llm_batch import BlockDecodingError
+
+            raise BlockDecodingError(
+                f"LLMEngine decodes one token a step; a model with "
+                f"block_length {config.block_length} needs the paged "
+                f"engine (continuous_batching=True, paged=True)")
         self.config = config
         self.params = params
         self.max_len = max_len
@@ -746,7 +763,9 @@ class LLMModelServer:
                          adapter_rate: float | None = None,
                          adapter_burst: float | None = None,
                          request_ledger: bool | None = None,
-                         speculative: dict | bool | None = None, **kw):
+                         speculative: dict | bool | None = None,
+                         denoising_steps: int | None = None,
+                         remasking: str = "low_confidence_static", **kw):
                 super().__init__(*a, **kw)
                 self.model_preset = model_preset
                 self.tokenizer_id = tokenizer
@@ -798,6 +817,13 @@ class LLMModelServer:
                 # preset} enables a resident draft model; None = the
                 # mlconf.serving.llm.speculative defaults decide
                 self.speculative = speculative
+                # block-diffusion decoding (docs/serving.md
+                # "Block-diffusion decoding"), for a model with
+                # block_length > 1: denoising passes a block (None: the
+                # block length) and the unmasking rule; the paged engine
+                # checks both against the model
+                self.denoising_steps = denoising_steps
+                self.remasking = remasking
                 self._tokenizer = None
                 self.engine = None
                 # predict→postprocess handover for the opt-in "timing"
@@ -881,7 +907,9 @@ class LLMModelServer:
                                 adapter_rate=self.adapter_rate,
                                 adapter_burst=self.adapter_burst,
                                 request_ledger=self.request_ledger,
-                                speculative=spec_conf)
+                                speculative=spec_conf,
+                                denoising_steps=self.denoising_steps,
+                                remasking=self.remasking)
                         from .llm_batch import ContinuousBatchingEngine
 
                         return ContinuousBatchingEngine(
@@ -954,6 +982,11 @@ class LLMModelServer:
                 # request.
                 self._timing_out.value = None
                 want_timing = bool(request.get("timing"))
+                # {"return_unmask_pass": true}: for a block-diffusion
+                # model, the pass within its block that unmasked each
+                # returned position and the confidence it had in that
+                # pass, one list each per output
+                want_passes = bool(request.get("return_unmask_pass"))
                 id_lists = []
                 for item in inputs:
                     if isinstance(item, str):
@@ -991,9 +1024,14 @@ class LLMModelServer:
                                 "prefill_chunks"):
                         if key in engine_stats:
                             self.set_metric(key, engine_stats[key])
+                    extras = {}
                     if want_timing:
-                        self._timing_out.value = [s.get("timing")
-                                                  for _, s in results]
+                        extras["timing"] = [s.get("timing")
+                                            for _, s in results]
+                    if want_passes:
+                        for name in ("unmask_pass", "unmask_confidence"):
+                            extras[name] = [s.get(name) for _, s in results]
+                    self._timing_out.value = extras or None
                     out_tokens = [tokens for tokens, _ in results]
                 else:
                     out_tokens = []
@@ -1019,10 +1057,11 @@ class LLMModelServer:
                 # next to "outputs" (one entry per input, aligned):
                 # phase-attributed wall + trace id, straight from the
                 # engine's request ledger
-                timings = getattr(self._timing_out, "value", None)
+                extras = getattr(self._timing_out, "value", None) or {}
                 self._timing_out.value = None
-                if timings and any(t is not None for t in timings):
-                    response["timing"] = timings
+                for name, values in extras.items():
+                    if any(v is not None for v in values):
+                        response[name] = values
                 return response
 
         return _Server(*args, **kwargs)

@@ -283,6 +283,15 @@ def _verify_rowwise(config: LlamaConfig, params: Params, chunk: jax.Array,
 _ENGINE_SEQUENCE = iter(range(1, 1 << 30))
 
 
+class BlockDecodingError(ValueError):
+    """A model that generates by diffusion over blocks
+    (``config.block_length`` > 1, docs/serving.md "Block-diffusion
+    decoding") was asked for something its decoding cannot do here: a dense
+    engine, speculation, a page or prefill chunk its blocks do not divide,
+    more denoising steps than a block has positions, sampling inside a
+    block, an unmasking rule that is not implemented, a KV handoff."""
+
+
 def _percentile(sorted_samples: list, q: float) -> float:
     """Nearest-rank percentile over an already-sorted sample list (the
     shared ``obs.stats.nearest_rank`` helper; kept as a module name for
@@ -427,6 +436,27 @@ class _Slot:
     # per-request phase ledger, handed over from the admission; the
     # decode loop flips it decode_active/decode_stall around every tick
     ledger: Optional[RequestLedger] = None
+    # what the request asked for: ``_finish`` cuts the answer to it (a
+    # block model denoises its last block whole and may overrun)
+    max_new: int = 0
+    # block-diffusion decoding (serving/paged.py ``_denoise_tick``): the
+    # block being filled in starts at absolute position ``block_base``;
+    # ``block_ids`` its ids so far, ``block_masked`` which positions are
+    # still masked (host state, never inferred from an id), ``block_m0``
+    # the masked count when it was opened, ``passes_in_block`` the
+    # denoising passes it has had, ``block_pass`` the pass that unmasked
+    # each position (-1: given by the prompt) and ``block_confidence`` the
+    # confidence it had then. ``unmask_pass`` and ``unmask_confidence`` are
+    # those two for every committed generated position, in position order
+    block_base: int = 0
+    block_ids: list = field(default_factory=list)
+    block_masked: list = field(default_factory=list)
+    block_m0: int = 0
+    passes_in_block: int = 0
+    block_pass: list = field(default_factory=list)
+    block_confidence: list = field(default_factory=list)
+    unmask_pass: list = field(default_factory=list)
+    unmask_confidence: list = field(default_factory=list)
 
     @property
     def active(self) -> bool:
@@ -440,6 +470,9 @@ class ContinuousBatchingEngine:
     (tokens, stats). All device dispatch happens on the single scheduler
     thread, so the engine serializes TPU access by construction.
     """
+
+    # whether the engine has a tick for ``config.block_length`` > 1
+    _serves_blocks = False
 
     def __init__(self, config: LlamaConfig, params: Params,
                  max_len: int = 2048, slots: int = 4,
@@ -463,6 +496,14 @@ class ContinuousBatchingEngine:
         self.max_len = max_len
         self.slots = slots
         self.kv_dtype = kv_dtype
+        # a model that generates by diffusion over blocks: only the paged
+        # engine has the tick that serves it
+        self.block_length = int(getattr(config, "block_length", 1))
+        if self.block_length > 1 and not self._serves_blocks:
+            raise BlockDecodingError(
+                f"{type(self).__name__} decodes one token a step; a model "
+                f"with block_length {self.block_length} needs the paged "
+                f"engine (paged=True)")
         # -- overload protection (docs/serving_resilience.md) --------------
         # max_queue_size: bounded admission queue, reject-newest shedding
         # (0 = unbounded, the pre-resilience behavior)
@@ -486,6 +527,11 @@ class ContinuousBatchingEngine:
         if prefill_chunk < 0:
             raise ValueError("prefill_chunk must be >= 0")
         self.prefill_chunk = min(int(prefill_chunk), max_len)
+        if self.prefill_chunk % self.block_length:
+            raise BlockDecodingError(
+                f"prefill_chunk {self.prefill_chunk} is not a multiple of "
+                f"block_length {self.block_length}: a chunk would end "
+                f"inside a block that sees all of itself")
         if latency_window is None:
             latency_window = int(llm_defaults.latency_window)
         if latency_window <= 0:
@@ -631,6 +677,12 @@ class ContinuousBatchingEngine:
                        "handoffs_out": 0, "handoff_bytes_out": 0,
                        "handoffs_in": 0, "handoff_bytes_in": 0,
                        "adapter_rate_limited": 0}
+        if self.block_length > 1:
+            # row-passes that denoised and that committed a block, and the
+            # most pairs one expert got in one layer of one pass
+            self._stats.update({"denoise_passes": 0, "commit_passes": 0,
+                                "expert_load_max": 0,
+                                "unmasked_positions": 0})
         # -- in-engine speculative decoding (docs/serving.md
         # "Speculative decoding"): draft model resident alongside the
         # target, per-row adaptive k, one multi-token verify dispatch per
@@ -658,6 +710,11 @@ class ContinuousBatchingEngine:
                                            or 16))
         self.spec_enabled = bool(enabled and draft_config is not None
                                  and draft_params is not None)
+        if self.spec_enabled and self.block_length > 1:
+            raise BlockDecodingError(
+                "speculative decoding proposes one token after another; a "
+                f"model with block_length {self.block_length} fills a "
+                "block in any order: turn one of the two off")
         if not self.spec_enabled:
             return
         if draft_config.vocab_size != self.config.vocab_size:
@@ -947,6 +1004,7 @@ class ContinuousBatchingEngine:
         finished = []
         committed = {}
         rounds = proposed_total = accepted_total = tokens_total = 0
+        emitted_total = 0
         for i in active:
             slot = self._slot_state[i]
             k_eff = int(k_effs[i])
@@ -962,6 +1020,7 @@ class ContinuousBatchingEngine:
             emitted = emitted[:max(0, slot.remaining)]
             slot.tokens.extend(int(t) for t in emitted)
             slot.remaining -= len(emitted)
+            emitted_total += len(emitted)
             if k_eff > 0:
                 tokens_total += len(emitted)
             pos_i = slot.prompt_len + len(slot.tokens) - 1
@@ -978,6 +1037,7 @@ class ContinuousBatchingEngine:
             self._stats["spec_accepted"] += accepted_total
             self._stats["spec_rejected"] += proposed_total - accepted_total
             self._stats["spec_tokens"] += tokens_total
+        self._tick.tokens_out = emitted_total
         for i in finished:
             self._finish(i)
         return len(active)
@@ -1390,6 +1450,11 @@ class ContinuousBatchingEngine:
             future.set_exception(EngineStoppedError(
                 f"engine is stopped, not accepting requests{cause}"))
             return future
+        if self.block_length > 1 and (temperature > 0 or _extra is not None):
+            future.set_exception(BlockDecodingError(
+                "a block model is served greedily and whole: no sampling "
+                "with a temperature inside a block, no KV handoff"))
+            return future
         prompt_len = len(prompt_tokens)
         if prompt_len + max_new_tokens > self.max_len:
             # 400-class rejection up front — past the largest bucket the
@@ -1764,6 +1829,10 @@ class ContinuousBatchingEngine:
         out["queue_depth"] = self._queue_depth()
         out["pressure_level"] = self.pressure_level()
         out["speculative_enabled"] = self.speculative_enabled
+        if "denoise_passes" in out:
+            passes = out["denoise_passes"] + out["commit_passes"]
+            out["tokens_per_row_pass"] = (
+                out.pop("unmasked_positions") / passes if passes else 0.0)
         if "spec_rounds" in out:
             out["acceptance_rate"] = (
                 out["spec_accepted"] / out["spec_proposed"]
@@ -1778,6 +1847,12 @@ class ContinuousBatchingEngine:
         return out
 
     # -- scheduler ----------------------------------------------------------
+    def _prompt_lead(self, prompt_len: int) -> int:
+        """The prompt positions that the prefill covers: all of them, or
+        for a block model its whole leading blocks (the trailing ``P mod
+        B`` tokens open the first block already unmasked)."""
+        return prompt_len - prompt_len % self.block_length
+
     def _bucket_for(self, length: int) -> int:
         for bucket in self.prefill_buckets:
             if length <= bucket:
@@ -1815,9 +1890,12 @@ class ContinuousBatchingEngine:
         fire(FaultPoints.llm_prefill, request_id=adm.request_id,
              slot=adm.slot, offset=adm.offset, chunks=adm.chunks)
         prompt = adm.prompt
-        total = len(prompt)
+        # a block model takes no token from the prefill either
+        total = self._prompt_lead(len(prompt))
         start = adm.offset
         remaining = total - start
+        if remaining <= 0:
+            return True                     # nothing left of it to prefill
         cap = self.max_len - start
         if limit is None:
             # prefer a warmed bucket shape that still fits the cache tail
@@ -1846,6 +1924,8 @@ class ContinuousBatchingEngine:
                 self._stats["prefill_tokens_tick_max"] = take
         if adm.offset < total:
             return False
+        if self.block_length > 1:
+            return True
         if take != pad_len:
             # padding advanced pos past the prompt; replay the last real
             # token for its logits (same trick as LLMEngine.generate)
@@ -1887,8 +1967,13 @@ class ContinuousBatchingEngine:
         temperature, top_k, top_p = sampling
         slot = self._slot_state[free]
         slot.request_id = request_id
-        slot.tokens = [first_token]
-        slot.remaining = max_new - 1
+        slot.max_new = max_new
+        if self.block_length > 1:
+            # no token yet: the first block's passes make the first ones
+            slot.tokens, slot.remaining = [], max_new
+        else:
+            slot.tokens = [first_token]
+            slot.remaining = max_new - 1
         slot.eos_id = eos_id
         slot.future = future
         slot.started = submitted
@@ -1914,6 +1999,8 @@ class ContinuousBatchingEngine:
         LLM_TTFT.observe(slot.ttft,
                          exemplar=(trace[0] if trace else None),
                          replica=self.replica, adapter=adapter)
+        if self.block_length > 1:
+            return
         if (eos_id is not None and first_token == eos_id) or \
                 slot.remaining <= 0:
             self._finish(free)
@@ -2072,6 +2159,10 @@ class ContinuousBatchingEngine:
                             adapter_slot=adm.adapter_slot,
                             logit_margin=adm.logit_margin,
                             ledger=adm.ledger)
+        if self.block_length > 1:
+            lead = self._prompt_lead(len(adm.prompt))
+            self._open_block(self._slot_state[adm.slot], lead,
+                             adm.prompt[lead:])
 
     def _abort_admission(self, adm: _Admission):
         """Release admission-held storage (expiry mid-prefill, stop). The
@@ -2135,12 +2226,22 @@ class ContinuousBatchingEngine:
 
     def _finish(self, index: int):
         slot = self._slot_state[index]
+        # a step may yield more than one token a row (a block, a
+        # speculative round): the answer is what was asked for, no more
+        if 0 < slot.max_new < len(slot.tokens):
+            del slot.tokens[slot.max_new:]
         stats = {
             "ttft_s": slot.ttft,
             "generated": len(slot.tokens),
             "prompt_len": slot.prompt_len,
             "total_s": time.perf_counter() - slot.started,
         }
+        if self.block_length > 1:
+            # the pass, within its block, that unmasked each token returned,
+            # and the confidence softmax(logits)[token] it had in that pass
+            stats["unmask_pass"] = slot.unmask_pass[:len(slot.tokens)]
+            stats["unmask_confidence"] = \
+                slot.unmask_confidence[:len(slot.tokens)]
         timing = None
         if slot.ledger is not None:
             timing = slot.ledger.close()
@@ -2187,6 +2288,8 @@ class ContinuousBatchingEngine:
         active = [i for i, s in enumerate(self._slot_state) if s.active]
         if not active:
             return 0
+        if self.block_length > 1:
+            return self._denoise_tick(active)
         if self._spec_tick_viable(active):
             done = self._spec_decode_tick(active)
             if done is not None:
@@ -2257,6 +2360,7 @@ class ContinuousBatchingEngine:
                 if (slot.eos_id is not None and token == slot.eos_id) or \
                         slot.remaining <= 0 or capacity:
                     self._finish(i)
+        tick.tokens_out = len(active)
         return len(active)
 
     def _consume_budget(self, expires: float | None):

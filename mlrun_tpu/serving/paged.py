@@ -55,13 +55,18 @@ import numpy as np
 
 from ..chaos import FaultPoints, fire
 from ..config import mlconf
-from ..models.llama import LlamaConfig
+from ..models.llama import LlamaConfig, layer_mlp, layer_slice, qk_normed
 from ..obs import KV_TIER_BYTES, KV_TIER_EVENTS, KV_TIER_HITS, wall_now
 from ..utils import logger
 from ..utils.profiler import annotate, named
 from .kv_tier import HostKVTier
 from .llm import _forward_with_cache, init_kv_cache
-from .llm_batch import ContinuousBatchingEngine, KVHandoff, _Admission
+from .llm_batch import (
+    BlockDecodingError,
+    ContinuousBatchingEngine,
+    KVHandoff,
+    _Admission,
+)
 from .prefix import PrefixCache, block_chain_key
 
 
@@ -207,6 +212,7 @@ def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
     positions = pos[:, None]
     rows = jnp.arange(b)
     safe_table = jnp.maximum(page_table, 0)            # [slots, pages]
+    live = page_table[:, :1] >= 0       # [slots, 1]: the row holds a request
     with jax.named_scope("embed"):
         x = params["embedding"][tokens].astype(config.dtype)
     cos, sin = rope_table(positions, config.head_dim, config.rope_theta)
@@ -223,7 +229,7 @@ def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
 
     k_new, v_new = [], []
     for layer in range(config.n_layers):
-        lp = jax.tree_util.tree_map(lambda a: a[layer], params["layers"])
+        lp = layer_slice(params["layers"], layer)
         with jax.named_scope("layer/attn"):
             h = rms_norm(x, lp["attn_norm_scale"], config.norm_eps)
 
@@ -240,6 +246,7 @@ def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
                                                 config.head_dim)
             v = proj(h, lp["wv"], "wv").reshape(b, 1, config.n_kv_heads,
                                                 config.head_dim)
+            q, k = qk_normed(config, q, k, lp)
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
 
@@ -304,9 +311,8 @@ def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
             x_mid = x + proj(attn, lp["wo"], "wo")
         with jax.named_scope("layer/mlp"):
             h2 = rms_norm(x_mid, lp["mlp_norm_scale"], config.norm_eps)
-            gate = proj(h2, lp["w_gate"], "w_gate")
-            up = proj(h2, lp["w_up"], "w_up")
-            x = x_mid + proj(jax.nn.silu(gate) * up, lp["w_down"], "w_down")
+            x = x_mid + layer_mlp(config, h2, lp, proj, live=live,
+                                  layer=layer)[0]
 
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm_scale"], config.norm_eps)
@@ -342,12 +348,32 @@ def _verify_rowwise_paged(config: LlamaConfig, page_size: int,
                           attn_impl: str, params, chunk: jax.Array,
                           pool: dict, page_table: jax.Array,
                           pos: jax.Array, lora=None,
-                          adapter_ids: jax.Array = None):
-    """Batched multi-token speculative verify against the page pool
-    (docs/serving.md "Speculative decoding"). ``chunk``: [slots, S] =
-    each slot's committed last token plus its k draft proposals at
-    absolute positions ``pos[r]..pos[r]+S-1``. ONE forward computes the
-    target argmax at all S positions per slot.
+                          adapter_ids: jax.Array = None,
+                          masked: jax.Array = None):
+    """Batched multi-token forward of one chunk a slot against the page
+    pool: the speculative verify (docs/serving.md "Speculative decoding")
+    and, with ``masked`` given, the pass of a block-diffusion model
+    (docs/serving.md "Block-diffusion decoding"). ``chunk``: [slots, S]
+    ids at absolute positions ``pos[r]..pos[r]+S-1``; for the verify, each
+    slot's committed last token plus its k draft proposals. ONE forward
+    computes the target argmax at all S positions per slot.
+
+    A **denoising or commit pass** (``masked`` [slots, S] bool, S =
+    ``config.block_length``, ``pos[r]`` the block's start): masked lanes
+    embed ``config.mask_token_id`` whatever id the chunk holds there, the
+    chunk attends its prefix pages and, under the block mask, all of
+    itself. It returns ``(packed, new_pool)``: ``packed`` one int32 vector
+    of, in order, the argmax ``x0`` of every lane [slots * S], the bits of
+    its float32 confidence ``softmax(logits)[x0]`` [slots * S], and three
+    counters of the expert layers over the live rows (pairs routed and
+    experts that got a pair, both summed over layers, and the most pairs
+    one expert got in one layer; zeros for a dense MLP): one fetch brings
+    all of it. A pass writes the chunk's keys and values like the verify
+    does: a denoising pass's are overwritten by the block's commit pass
+    before anything reads them (the prefix part reads positions below
+    ``pos[r]`` only), the rule the speculative path relies on too. Rows in
+    a commit pass (no lane masked) and rows in a denoising pass share the
+    dispatch; which is which is the host's.
 
     ``attn_impl="kernel"``: per layer, the chunk's KV scatters into the
     pool through the page table FIRST (int8 pools quantize per vector on
@@ -378,6 +404,10 @@ def _verify_rowwise_paged(config: LlamaConfig, page_size: int,
     b, s = chunk.shape
     pps = page_table.shape[1]
     positions = pos[:, None] + jnp.arange(s)[None, :]     # [slots, S]
+    live = jnp.broadcast_to(page_table[:, :1] >= 0, (b, s))
+    if masked is not None:
+        chunk = jnp.where(masked, config.mask_token_id, chunk)
+    loads = []
     with jax.named_scope("embed"):
         x = params["embedding"][chunk].astype(config.dtype)
     cos, sin = rope_table(positions, config.head_dim, config.rope_theta)
@@ -396,7 +426,7 @@ def _verify_rowwise_paged(config: LlamaConfig, page_size: int,
     pool = dict(pool)
 
     for layer in range(config.n_layers):
-        lp = jax.tree_util.tree_map(lambda a: a[layer], params["layers"])
+        lp = layer_slice(params["layers"], layer)
         with jax.named_scope("layer/attn"):
             h = rms_norm(x, lp["attn_norm_scale"], config.norm_eps)
 
@@ -413,6 +443,7 @@ def _verify_rowwise_paged(config: LlamaConfig, page_size: int,
                                                 config.head_dim)
             v = proj(h, lp["wv"], "wv").reshape(b, s, config.n_kv_heads,
                                                 config.head_dim)
+            q, k = qk_normed(config, q, k, lp)
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
 
@@ -446,14 +477,17 @@ def _verify_rowwise_paged(config: LlamaConfig, page_size: int,
             attn = paged_verify_attention(
                 q, chunk_k, chunk_v, pool["k"], pool["v"], layer,
                 page_table, pos, page_size=page_size,
-                impl="kernel" if use_kernel else "reference", **scales_kw)
+                impl="kernel" if use_kernel else "reference",
+                block_length=config.block_length, **scales_kw)
             attn = attn.astype(x.dtype).reshape(b, s, config.qkv_dim)
             x_mid = x + proj(attn, lp["wo"], "wo")
         with jax.named_scope("layer/mlp"):
             h2 = rms_norm(x_mid, lp["mlp_norm_scale"], config.norm_eps)
-            gate = proj(h2, lp["w_gate"], "w_gate")
-            up = proj(h2, lp["w_up"], "w_up")
-            x = x_mid + proj(jax.nn.silu(gate) * up, lp["w_down"], "w_down")
+            out, load = layer_mlp(config, h2, lp, proj, live=live,
+                                  layer=layer)
+            x = x_mid + out
+            if load is not None:
+                loads.append(load)
 
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm_scale"], config.norm_eps)
@@ -463,7 +497,23 @@ def _verify_rowwise_paged(config: LlamaConfig, page_size: int,
         logits = jnp.einsum("bse,ev->bsv", x, head,
                             preferred_element_type=jnp.float32)
     verified = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return verified, pool
+    if masked is None:
+        return verified, pool
+    with jax.named_scope("head"):
+        # softmax(logits)[x0] = exp(max - logsumexp)
+        confidence = jnp.exp(jnp.max(logits, axis=-1)
+                             - jax.nn.logsumexp(logits, axis=-1))
+        counters = jnp.zeros((3,), jnp.int32)
+        if loads:
+            stacked = jnp.stack(loads)                   # [L, experts held]
+            counters = jnp.stack([jnp.sum(stacked), jnp.sum(stacked > 0),
+                                  jnp.max(stacked)]).astype(jnp.int32)
+        packed = jnp.concatenate([
+            verified.reshape(-1),
+            jax.lax.bitcast_convert_type(confidence.astype(jnp.float32),
+                                         jnp.int32).reshape(-1),
+            counters])
+    return packed, pool
 
 
 class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
@@ -475,7 +525,15 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
     size it SMALLER to oversubscribe memory when typical prompt+generation
     lengths are below max_len. Pages for prompt+max_new are reserved at
     admission and requests wait (in order) until enough pages are free.
+
+    A model with ``config.block_length`` > 1 generates by diffusion over
+    blocks (docs/serving.md "Block-diffusion decoding"): its ticks are
+    ``_denoise_tick``, ``denoising_steps`` passes a block (default: the
+    block length) under the ``remasking`` rule, then a commit pass.
     """
+
+    _serves_blocks = True
+    REMASKING = ("low_confidence_static",)
 
     def __init__(self, config: LlamaConfig, params, max_len: int = 2048,
                  slots: int = 4, prefill_buckets: tuple = (128, 512, 1024),
@@ -491,13 +549,32 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                  adapter_rate: float | None = None,
                  adapter_burst: float | None = None,
                  request_ledger: bool | None = None,
-                 kv_tier=None, speculative: dict | None = None):
+                 kv_tier=None, speculative: dict | None = None,
+                 denoising_steps: int | None = None,
+                 remasking: str = "low_confidence_static"):
         from ..ops.paged_attention import resolve_paged_impl
 
         if max_len % page_size:
             raise ValueError(
                 f"max_len {max_len} must be a multiple of page_size "
                 f"{page_size} (a partial last page would misalign KV rows)")
+        block = int(getattr(config, "block_length", 1))
+        if page_size % block:
+            raise BlockDecodingError(
+                f"page_size {page_size} is not a multiple of block_length "
+                f"{block}: a cached prefix page would depend on the page "
+                f"after it")
+        self.denoising_steps = block if denoising_steps is None \
+            else int(denoising_steps)
+        if not 1 <= self.denoising_steps <= block:
+            raise BlockDecodingError(
+                f"denoising_steps {self.denoising_steps} must lie in "
+                f"[1, block_length {block}]")
+        if remasking not in self.REMASKING:
+            raise BlockDecodingError(
+                f"remasking rule {remasking!r} is not supported (have "
+                f"{list(self.REMASKING)})")
+        self.remasking = remasking
         # set before super().__init__ — _make_cache runs during it
         self.page_size = page_size
         self.pages_per_slot = max_len // page_size
@@ -589,6 +666,11 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             named("mlt_decode", functools.partial(
                 _decode_rowwise_paged, config, page_size, self.attn_impl)),
             donate_argnums=(2,))
+        # a block model's pass: the verify program with a mask bitmap
+        self._denoise_paged = jax.jit(
+            named("mlt_denoise", functools.partial(
+                _verify_rowwise_paged, config, page_size, self.attn_impl)),
+            donate_argnums=(2,))
         self._insert_paged = jax.jit(
             named("mlt_insert", functools.partial(
                 insert_prompt_pages, page_size=page_size)),
@@ -657,6 +739,19 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         step = jnp.zeros((self.slots, 1), jnp.int32)
         table = jnp.asarray(self._page_table)
         pos = jnp.asarray(self._pos)
+        if self.block_length > 1:
+            # the one program a block model decodes with (all-(-1) table:
+            # every write lands on the scratch page)
+            shape = (self.slots, self.block_length)
+            packed, self._pool = self._denoise_paged(
+                self.params, jnp.zeros(shape, jnp.int32), self._pool, table,
+                pos, masked=jnp.ones(shape, bool), **decode_kw)
+            jax.block_until_ready(packed)
+            logger.info("paged engine warm", slots=self.slots,
+                        pages=self.n_pages, page_size=self.page_size,
+                        block_length=self.block_length,
+                        warmup_s=round(time.perf_counter() - started, 2))
+            return
         tok, self._pool, _ = self._decode_paged(
             self.params, step, self._pool, table, pos, **decode_kw)
         jax.block_until_ready(tok)
@@ -1017,6 +1112,9 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             adapter = item[10] if len(item) > 10 else ""
             ledger = item[11] if len(item) > 11 else None
             prompt_len = len(prompt)
+            # a block model denoises its last block whole and may overrun
+            # by up to B - 1 positions: a page holds whole blocks
+            # (page_size % B == 0), so these pages cover that too
             needed = -(-(prompt_len + max_new) // self.page_size)
             if needed > self.n_pages:
                 # would never fit — fail fast instead of blocking the
@@ -1233,7 +1331,9 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         self._slot_pages[adm.slot] = pages
         self._slot_prefix_nodes[adm.slot] = held
         self._page_table[adm.slot] = adm.page_ids
-        self._pos[adm.slot] = len(adm.prompt)
+        # where the next step writes: the prompt's end, or for a block
+        # model the start of the block that the prompt's tail opens
+        self._pos[adm.slot] = self._prompt_lead(len(adm.prompt))
 
     def _abort_admission(self, adm: _Admission):
         self._free_pages.extend(adm.pages)
@@ -1368,4 +1468,122 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 if (slot.eos_id is not None and token == slot.eos_id) or \
                         slot.remaining <= 0 or capacity:
                     self._finish(i)
+        tick.tokens_out = len(active)
         return len(active)
+
+    # -- block-diffusion decoding (docs/serving.md) -------------------------
+
+    def _denoise_tick(self, active) -> int:
+        """One pass over every live row's current block: rows whose block
+        still has masked positions are denoised (the pass unmasks this
+        pass's share of them, the most confident first), rows whose block
+        is full are committed (the pass stores the block's keys and values
+        for good, yields no token, and the next block opens). One dispatch
+        for both; which row is which is decided here."""
+        tick = self._tick
+        tick.kind = "denoise"
+        size = self.block_length
+        with annotate("mlt.sched.build"):
+            chunk = np.zeros((self.slots, size), np.int32)
+            masked = np.zeros((self.slots, size), bool)
+            for i in active:
+                slot = self._slot_state[i]
+                chunk[i] = slot.block_ids
+                masked[i] = slot.block_masked
+                tick.ctx_tokens += slot.block_base + size
+            tick.positions = len(active) * size
+            table = jnp.asarray(self._page_table)
+            pos = jnp.asarray(self._pos)
+            lora_kw = self._lora_kwargs(self._slot_adapter_ids()) \
+                if self._adapters is not None else {}
+            self._ledger_mark(active, "decode_active")
+            args = (jnp.asarray(chunk), self._pool, table, pos)
+            masked_dev = jnp.asarray(masked)
+        tick.t_built = time.perf_counter()
+        with annotate("mlt.sched.dispatch"):
+            packed, self._pool = self._denoise_paged(
+                self.params, *args, masked=masked_dev, **lora_kw)
+        tick.t_dispatched = time.perf_counter()
+        with annotate("mlt.sched.fetch"):
+            host = np.asarray(packed)
+        tick.t_fetched = time.perf_counter()
+        with annotate("mlt.sched.commit"):
+            lanes = self.slots * size
+            x0 = host[:lanes].reshape(self.slots, size)
+            confidence = host[lanes:2 * lanes].view(np.float32).reshape(
+                self.slots, size)
+            tick.expert_pairs, tick.experts_touched, \
+                tick.expert_load_max = (int(v) for v in host[2 * lanes:])
+            self._ledger_mark(active, "decode_stall")
+            for i in active:
+                if masked[i].any():
+                    tick.tokens_out += self._unmask(
+                        self._slot_state[i], x0[i], confidence[i])
+                else:
+                    tick.commit_rows += 1
+                    self._commit_block(i)
+            with self._lock:
+                self._stats["commit_passes"] += tick.commit_rows
+                self._stats["denoise_passes"] += \
+                    len(active) - tick.commit_rows
+                self._stats["unmasked_positions"] += tick.tokens_out
+                self._stats["expert_load_max"] = max(
+                    self._stats["expert_load_max"], tick.expert_load_max)
+        return len(active)
+
+    def _open_block(self, slot, base: int, known=()):
+        """Open the block at ``base``: ``known`` ids (the prompt's tail, in
+        the first block) stand unmasked, every other position masked."""
+        size = self.block_length
+        slot.block_base = base
+        slot.block_ids = list(known) + [0] * (size - len(known))
+        slot.block_masked = [False] * len(known) \
+            + [True] * (size - len(known))
+        slot.block_pass = [-1] * len(known) + [0] * (size - len(known))
+        slot.block_confidence = [1.0] * size
+        slot.block_m0 = size - len(known)
+        slot.passes_in_block = 0
+
+    def _unmask(self, slot, x0, confidence) -> int:
+        """Pass ``passes_in_block`` of the slot's block under
+        ``low_confidence_static``: the block's ``m0`` masked positions are
+        split evenly over the steps (the first ``m0 mod S`` passes take one
+        more), and each pass sets the still-masked positions of highest
+        confidence to their argmax. Returns how many it unmasked."""
+        steps, m0, s = self.denoising_steps, slot.block_m0, \
+            slot.passes_in_block
+        count = m0 // steps + (1 if s < m0 % steps else 0)
+        still = [j for j, m in enumerate(slot.block_masked) if m]
+        # the most confident first; equal confidences by position
+        still.sort(key=lambda j: (-float(confidence[j]), j))
+        for j in still[:count]:
+            slot.block_ids[j] = int(x0[j])
+            slot.block_masked[j] = False
+            slot.block_pass[j] = s
+            slot.block_confidence[j] = float(confidence[j])
+        slot.passes_in_block = s + 1
+        return min(count, len(still))
+
+    def _commit_block(self, index: int):
+        """The slot's block is full and its commit pass has stored it: its
+        generated positions extend the answer in position order, and the
+        next block opens, unless the answer is complete (``max_new``
+        tokens, an end-of-sequence id, or the cache's end)."""
+        slot = self._slot_state[index]
+        size = self.block_length
+        first = max(0, slot.prompt_len - slot.block_base)
+        new = slot.block_ids[first:]
+        slot.unmask_pass.extend(slot.block_pass[first:])
+        slot.unmask_confidence.extend(slot.block_confidence[first:])
+        if slot.eos_id is not None and slot.eos_id in new:
+            new = new[:new.index(slot.eos_id) + 1]
+        slot.tokens.extend(new)
+        slot.remaining -= len(new)
+        base = slot.block_base + size
+        self._pos[index] = base
+        ended = slot.eos_id is not None and bool(new) \
+            and new[-1] == slot.eos_id
+        if ended or slot.remaining <= 0 or base + size > self.max_len:
+            self._finish(index)
+        else:
+            self._open_block(slot, base)

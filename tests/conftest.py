@@ -46,6 +46,7 @@ _COMPILE_CACHED_MODULES = {
     "test_serving_resilience", "test_llm_continuous", "test_llm_paged",
     "test_llm_engine", "test_paged_attention", "test_paged_prefill",
     "test_speculative", "test_spec_paged", "test_kv_tier",
+    "test_sdar_paged",
     "test_replica_health",
     "test_observability", "test_obs_control_plane",
     "test_continuous_tuning", "test_request_forensics",

@@ -98,3 +98,160 @@ def test_loss_metric_surface_chunk_parity(cfg):
     assert "accuracy" in plain and "accuracy" in chunked
     assert abs(float(plain["accuracy"]) - float(chunked["accuracy"])) < 1e-5
     assert abs(float(plain["ce_loss"]) - float(chunked["ce_loss"])) < 1e-4
+
+
+# -- the served, dropless expert layer (models/moe.py moe_mlp) ----------------
+import dataclasses  # noqa: E402
+
+from . import sdar_reference as ref  # noqa: E402
+
+from mlrun_tpu.models.moe import _moe_mlp, moe_mlp, tiny_sdar  # noqa: E402
+
+
+def _sdar_layer(seed=0, **overrides):
+    cfg = tiny_sdar(dtype=jnp.float32, **overrides)
+    params = init_params(cfg, jax.random.PRNGKey(seed))
+    lp = jax.tree_util.tree_map(lambda a: a[0], params["layers"])
+    return cfg, lp
+
+
+def _skewed(cfg, lp, tokens=24, seed=1):
+    """Inputs and a router under which expert 0 takes a pair of every
+    token and expert 3 none."""
+    x = jax.random.normal(jax.random.PRNGKey(seed),
+                          (2, tokens // 2, cfg.embed_dim), jnp.float32)
+    u = jnp.ones((cfg.embed_dim,)) / cfg.embed_dim ** 0.5
+    x = x + 4.0 * u
+    router = lp["router"].at[:, 0].set(3.0 * u).at[:, 3].set(-3.0 * u)
+    return x, dict(lp, router=router)
+
+
+@pytest.mark.parametrize("routing", ["even", "skewed"])
+@pytest.mark.parametrize("top_k", [1, 2, 4])
+def test_dropless_layer_matches_reference(routing, top_k):
+    """Every token's experts applied one by one (the reference) against
+    the sort and grouped product, also where one expert takes a pair of
+    every token and one none."""
+    cfg, lp = _sdar_layer(top_k=top_k)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 12, cfg.embed_dim),
+                          jnp.float32)
+    if routing == "skewed":
+        x, lp = _skewed(cfg, lp)
+    y, load = jax.jit(lambda x, lp: moe_mlp(cfg, x, lp))(x, lp)
+    want = ref.experts_mlp(ref.fields_of(cfg), x.reshape(-1, cfg.embed_dim),
+                           lp)
+    np.testing.assert_allclose(np.asarray(y).reshape(want.shape),
+                               np.asarray(want), atol=2e-5)
+    assert int(load.sum()) == 24 * top_k          # no token is dropped
+    if routing == "skewed":
+        assert int(load[0]) == 24 and int(load[3]) == 0
+
+
+@pytest.mark.parametrize("routing", ["even", "skewed"])
+def test_dropless_layer_matches_capacity_layer_when_nothing_drops(routing):
+    """The trainer's capacity dispatch with room for every pair computes
+    what the dropless layer computes."""
+    cfg, lp = _sdar_layer()
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 12, cfg.embed_dim),
+                          jnp.float32)
+    if routing == "skewed":
+        x, lp = _skewed(cfg, lp)
+    roomy = dataclasses.replace(cfg, capacity_factor=float(cfg.n_experts),
+                                mlp_dim=cfg.expert_dim)
+    want, _aux = _moe_mlp(roomy, x, lp)
+    got, _load = moe_mlp(cfg, x, lp)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+@pytest.mark.parametrize("routing", ["even", "skewed"])
+@pytest.mark.parametrize("shares", [2, 4, 8])
+def test_expert_shares_add_up_to_the_whole_layer(shares, routing):
+    """The share test: with ``held`` set to each share of the experts in
+    turn, the partial results add up to the whole layer's, in the program
+    and in the reference alike, and the loads to the whole load."""
+    cfg, lp = _sdar_layer()
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 12, cfg.embed_dim),
+                          jnp.float32)
+    if routing == "skewed":
+        x, lp = _skewed(cfg, lp)
+    whole, load = moe_mlp(cfg, x, lp)
+    fields = ref.fields_of(cfg)
+    flat = x.reshape(-1, cfg.embed_dim)
+    width = cfg.n_experts // shares
+    total, total_ref, loads = 0.0, 0.0, []
+    for lo in range(0, cfg.n_experts, width):
+        held = (lo, lo + width)
+        part = {name: (value[lo:lo + width]
+                       if name.startswith("experts_") else value)
+                for name, value in lp.items()}
+        y, part_load = moe_mlp(cfg, x, part, held=held)
+        total = total + y
+        loads.append(part_load)
+        total_ref = total_ref + ref.experts_mlp(fields, flat, part,
+                                                held=held)
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
+                               atol=2e-5)
+    np.testing.assert_allclose(np.asarray(total_ref).reshape(whole.shape),
+                               np.asarray(whole), atol=2e-5)
+    assert np.array_equal(np.concatenate(loads), np.asarray(load))
+
+
+def test_dropless_layer_leaves_dead_rows_out():
+    cfg, lp = _sdar_layer()
+    x = jax.random.normal(jax.random.PRNGKey(4), (3, 4, cfg.embed_dim),
+                          jnp.float32)
+    live = jnp.asarray([[True] * 4, [False] * 4, [True] * 4])
+    y, load = moe_mlp(cfg, x, lp, live=live)
+    whole, _ = moe_mlp(cfg, x, lp)
+    assert int(load.sum()) == 8 * cfg.top_k
+    np.testing.assert_allclose(np.asarray(y[0]), np.asarray(whole[0]),
+                               atol=2e-5)
+    assert not np.asarray(y[1]).any()
+
+
+def test_layer_of_a_stack_of_experts():
+    """The serving programs hand the layer the stacks of every layer's
+    experts and the layer's index: the same result as from its slice."""
+    cfg = tiny_sdar(dtype=jnp.float32)
+    layers = init_params(cfg, jax.random.PRNGKey(0))["layers"]
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, 6, cfg.embed_dim),
+                          jnp.float32)
+    for layer in range(cfg.n_layers):
+        lp = jax.tree_util.tree_map(lambda a: a[layer], layers)
+        want, want_load = moe_mlp(cfg, x, lp)
+        stacked = {name: (value if name.startswith("experts_")
+                          else value[layer]) for name, value in layers.items()}
+        got, load = jax.jit(lambda x, lp: moe_mlp(cfg, x, lp, layer=layer))(
+            x, stacked)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5)
+        assert np.array_equal(np.asarray(load), np.asarray(want_load))
+
+
+def test_held_experts_need_their_weights():
+    cfg, lp = _sdar_layer()
+    x = jnp.zeros((1, 4, cfg.embed_dim), jnp.float32)
+    with pytest.raises(ValueError, match="holds experts"):
+        moe_mlp(cfg, x, lp, held=(0, 2))
+
+
+def test_init_params_is_the_configs_own():
+    """``models.init_params`` makes what the config's module defines: the
+    experts and q/k norm scales of an SDAR config, the held share's slice
+    of the same draw, and the dense leaves of a Llama config."""
+    from mlrun_tpu.models import init_params as any_init, tiny_llama
+
+    cfg = tiny_sdar()
+    whole = any_init(cfg, jax.random.PRNGKey(0))["layers"]
+    assert whole["experts_gate"].shape == (2, 8, 64, 32)
+    assert whole["q_norm_scale"].shape == (2, 16)
+    share = any_init(dataclasses.replace(cfg, experts_held=(2, 4)),
+                     jax.random.PRNGKey(0))["layers"]
+    assert np.array_equal(np.asarray(share["experts_down"], np.float32),
+                          np.asarray(whole["experts_down"][:, 2:4],
+                                     np.float32))
+    dense = any_init(tiny_llama(), jax.random.PRNGKey(0))["layers"]
+    assert "w_gate" in dense and "experts_gate" not in dense
+    actual = sum(x.size for x in jax.tree_util.tree_leaves(
+        any_init(cfg, jax.random.PRNGKey(0))))
+    assert actual == cfg.param_count()

@@ -269,3 +269,73 @@ def test_pool_programs_copy_no_layer(program_shapes, on_chip, program,
     if head_dim == 128 and kv_dtype == "bf16":
         layer_bytes = (N_PAGES + 1) * PAGE_SIZE * N_KV_HEADS * head_dim * 2
         assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+
+
+# -- the block-diffusion family: experts, q/k norms, the block mask ----------
+# the benchmark cell sdar-serve-chat's shapes (benchmarks/workloads/), the
+# model cut to two layers (a copy of a layer's experts shows from two on): 32 slots x 16 pages/slot of 128, a block of 4
+SDAR_SLOTS, SDAR_BLOCK = 32, 4
+
+
+@pytest.fixture
+def sdar_shapes(on_chip, monkeypatch):
+    from mlrun_tpu.models.moe import SdarConfig
+    from mlrun_tpu.models.moe import init_params as init_moe
+
+    monkeypatch.setattr(pattn, "interpret_default", lambda: False)
+    monkeypatch.setattr(attn, "interpret_default", lambda: False)
+    config = SdarConfig(
+        vocab_size=151936, n_layers=2, embed_dim=2048, n_heads=32,
+        n_kv_heads=4, head_dim=128, mlp_dim=6144, n_experts=128, top_k=8,
+        expert_dim=768, block_length=SDAR_BLOCK, rope_theta=1e6,
+        norm_eps=1e-6)
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda a: on_chip(a.shape, a.dtype), tree)
+
+    params = place(jax.eval_shape(
+        lambda: init_moe(config, jax.random.PRNGKey(0))))
+    return config, params, place
+
+
+def test_denoise_program_compiles(sdar_shapes, on_chip):
+    """``jit_mlt_denoise``: the verify program with a mask bitmap, a block
+    of 4 a slot, q/k norms, the prefix kernel and the grouped expert
+    products (three a layer, on the device under a name a trace shows)."""
+    config, params, place = sdar_shapes
+    pool = place(jax.eval_shape(lambda: paged.init_paged_pool(
+        config, N_PAGES + 1, PAGE_SIZE)))
+    fn = functools.partial(paged._verify_rowwise_paged, config, PAGE_SIZE,
+                           "kernel")
+    lanes = (SDAR_SLOTS, SDAR_BLOCK)
+    compiled = jax.jit(fn, donate_argnums=(2,)).lower(
+        params, on_chip(lanes, jnp.int32), pool,
+        on_chip((SDAR_SLOTS, PAGES_PER_SLOT), jnp.int32),
+        on_chip((SDAR_SLOTS,), jnp.int32),
+        masked=on_chip(lanes, jnp.bool_)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") >= 4      # prefix kernel + products
+    assert len(re.findall(r"%gmm[\w.-]* = ", hlo)) == 6
+    assert re.search(r"paged_verify", hlo)
+    # the experts' stacks reach the products as stored: no copy of them
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    # packed: x0 and confidence of every lane, three counters
+    packed = 2 * SDAR_SLOTS * SDAR_BLOCK + 3
+    assert f"s32[{packed}]" in hlo
+
+
+@pytest.mark.parametrize("bucket", [128, 1024])
+def test_block_masked_prefill_compiles(sdar_shapes, on_chip, bucket):
+    """The prefill program of the same model: ``flash_v2`` under the block
+    mask (block_length 4), experts over a bucket's positions."""
+    config, params, place = sdar_shapes
+    fn = functools.partial(llm._forward_with_cache, config,
+                           attn_impl="flash", page_size=PAGE_SIZE)
+    cache = place(jax.eval_shape(lambda: llm.init_kv_cache(
+        config, 1, PAGES_PER_SLOT * PAGE_SIZE)))
+    compiled = jax.jit(fn).lower(
+        params, on_chip((1, bucket), jnp.int32), cache).compile()
+    hlo = compiled.as_text()
+    assert "flash_v2" in hlo and "tpu_custom_call" in hlo
+    assert len(re.findall(r"%gmm[\w.-]* = ", hlo)) == 6
