@@ -17,6 +17,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..models.llama import (
     LlamaConfig,
@@ -99,8 +100,9 @@ def _cached_attention_lse(config, q, k_cache, v_cache, q_positions, k_lo):
     softmax states are then LSE-merged
     (ops/paged_attention.merge_softmax_states). o is [B, S, H, D] f32,
     lse [B, H, S] f32 (the flash kernels' lse layout). This is the
-    s == 1 replay form of the hit path — a 1-row flash instance gains
-    nothing and is a shape class TPU lowering never otherwise sees."""
+    s == 1 form of the hit path (a one-token chunk) — a 1-row flash
+    instance gains nothing and is a shape class TPU lowering never
+    otherwise sees."""
     n_rep = config.n_heads // config.n_kv_heads
     m = k_cache.shape[1]
     if n_rep > 1:
@@ -148,11 +150,19 @@ def _forward_with_cache(config: LlamaConfig, params: Params,
                         prefix_kv: Optional[dict] = None,
                         all_logits: bool = False,
                         attn_impl: str = "dense",
-                        page_size: int = 0):
-    """Run tokens starting at cache['pos']; returns (logits_last, new_cache).
+                        page_size: int = 0,
+                        logits_at: Optional[jax.Array] = None):
+    """Run tokens starting at cache['pos']; returns (logits, new_cache):
+    the logits of ONE dispatched position, [B, vocab] — the last one, or
+    the one ``logits_at`` names (a traced int32 index into the ``S``
+    dispatched tokens: a bucket-padded prompt's last REAL position,
+    ``take - 1``, so one compiled program a bucket serves every prompt
+    length in it and the first token comes from the dispatch that
+    prefilled it; causal masking keeps the padding to its right from
+    touching it).
     ``all_logits=True`` returns [B, S, vocab] logits for every input
-    position instead of just the last (speculative verification needs the
-    target's distribution after each proposed token — serving/speculative.py).
+    position instead (speculative verification needs the target's
+    distribution after each proposed token — serving/speculative.py).
 
     ``lora``/``adapter_ids`` enable batched multi-tenant LoRA
     (docs/serving.md "Multi-tenant LoRA"): ``lora`` is the stacked
@@ -231,8 +241,8 @@ def _forward_with_cache(config: LlamaConfig, params: Params,
                 scales = None
             if prefix_kv is not None:
                 # paged prefix-hit suffix prefill: local rows (>= base) via
-                # bounded flash (s > 1) or the bounded dense form (the
-                # 1-token last-position replay), the cached prefix via the
+                # bounded flash (s > 1) or the bounded dense form (a
+                # one-token chunk), the cached prefix via the
                 # multi-row paged prefill kernel reading pool pages in
                 # place — partial softmax states LSE-merged
                 # (docs/serving.md "Attention kernels")
@@ -269,7 +279,7 @@ def _forward_with_cache(config: LlamaConfig, params: Params,
                 # positions are uniform per batch row on the prefill path
                 # (mixed-start batches never reach here — see rope note
                 # above).
-                # 1-token dispatches (last-prompt-token replay, warmup) stay
+                # 1-token dispatches (decode steps, a one-token chunk) stay
                 # dense: a block_q=1 kernel instance gains nothing and is a
                 # shape class TPU lowering never otherwise sees
                 attn = flash_attention_cached(
@@ -298,12 +308,17 @@ def _forward_with_cache(config: LlamaConfig, params: Params,
             new_vs.append(scales[1])
 
     with jax.named_scope("head"):
+        if not all_logits:
+            # one row through the final norm and the head: the position the
+            # caller names, else the last dispatched one
+            x = x[:, -1:] if logits_at is None else \
+                jax.lax.dynamic_slice_in_dim(x, logits_at, 1, axis=1)
         x = rms_norm(x, params["final_norm_scale"], config.norm_eps)
         head = params.get("lm_head")
         if head is None:
             head = params["embedding"].T
-        logits = jnp.einsum("bse,ev->bsv", x if all_logits else x[:, -1:],
-                            head, preferred_element_type=jnp.float32)
+        logits = jnp.einsum("bse,ev->bsv", x, head,
+                            preferred_element_type=jnp.float32)
     new_cache = {
         "k": jnp.stack(new_k),
         "v": jnp.stack(new_v),
@@ -399,8 +414,6 @@ class LLMEngine:
         rows on the base slot 0."""
         if self._adapters is None:
             return {}
-        import numpy as np
-
         if slots is None:
             ids = np.zeros((self.batch,), np.int32)
         else:
@@ -417,7 +430,9 @@ class LLMEngine:
             cache = init_kv_cache(self.config, self.batch, self.max_len,
                               kv_dtype=self.kv_dtype)
             tokens = jnp.zeros((self.batch, bucket), jnp.int32)
-            logits, cache = self._prefill(self.params, tokens, cache, **kw)
+            logits, cache = self._prefill(self.params, tokens, cache,
+                                          logits_at=np.int32(bucket - 1),
+                                          **kw)
             step_tok = jnp.zeros((self.batch, 1), jnp.int32)
             logits, cache = self._decode(self.params, step_tok, cache, **kw)
             step_tok = jnp.zeros((self.batch, 1), jnp.int32)
@@ -442,8 +457,6 @@ class LLMEngine:
         registry adapter applied to every row (404s typed when
         unknown); a tenant id with canary-loop state resolves to its
         effective versioned id first (serving/canary.py)."""
-        import numpy as np
-
         prompt = np.asarray(prompt_tokens, dtype=np.int32).reshape(1, -1)
         prompt_len = prompt.shape[1]
         if prompt_len + max_new_tokens > self.max_len:
@@ -481,8 +494,7 @@ class LLMEngine:
                 slot = self._adapters.ensure_loaded(adapter)
                 kw = self._lora_kwargs(slot)
             out_tokens, ttft, t1 = self._generate_inner(
-                prompt, prompt_len, bucket, padded, max_new_tokens,
-                eos_id, t0, kw)
+                prompt_len, padded, max_new_tokens, eos_id, t0, kw)
         finally:
             if self._adapters is not None:
                 self._adapters.unpin(adapter)
@@ -524,23 +536,22 @@ class LLMEngine:
         if self._adapters is not None:
             self._adapters.retire(name, keep_source=keep_source)
 
-    def _generate_inner(self, prompt, prompt_len, bucket, padded,
-                        max_new_tokens, eos_id, t0, kw):
-        import numpy as np
+    def _prefill_prompt(self, padded, prompt_len, cache, kw):
+        """One dispatch of the bucket-padded prompt rows: the logits of
+        the last REAL position (the program returns the position it is
+        told), the cache rewound from the bucket's end to the prompt's —
+        decoding overwrites the padding's rows from there."""
+        logits, cache = self._prefill(
+            self.params, jnp.asarray(padded), cache,
+            logits_at=np.int32(prompt_len - 1), **kw)
+        cache["pos"] = jnp.full((self.batch,), prompt_len, jnp.int32)
+        return logits, cache
 
+    def _generate_inner(self, prompt_len, padded, max_new_tokens, eos_id,
+                        t0, kw):
         cache = init_kv_cache(self.config, self.batch, self.max_len,
                               kv_dtype=self.kv_dtype)
-        logits, cache = self._prefill(self.params, jnp.asarray(padded),
-                                      cache, **kw)
-        # bucket padding advanced pos past prompt; rewind to prompt_len
-        cache["pos"] = jnp.full((self.batch,), prompt_len, jnp.int32)
-        # logits at the last *real* prompt position were computed only if
-        # prompt_len == bucket; otherwise take them from a 1-token replay of
-        # the last prompt token (cheap decode step)
-        if prompt_len != bucket:
-            cache["pos"] = jnp.full((self.batch,), prompt_len - 1, jnp.int32)
-            last = jnp.asarray(prompt[:, -1:].repeat(self.batch, 0))
-            logits, cache = self._decode(self.params, last, cache, **kw)
+        logits, cache = self._prefill_prompt(padded, prompt_len, cache, kw)
         next_token = self._sample(logits)
         jax.block_until_ready(next_token)
         ttft = time.perf_counter() - t0
@@ -593,8 +604,6 @@ class LLMEngine:
 
         Engine must be built with batch >= len(prompts).
         """
-        import numpy as np
-
         n = len(prompts)
         if n == 0:
             return [], {"ttft_s": 0.0, "decode_tokens_per_sec": 0.0,
@@ -655,8 +664,7 @@ class LLMEngine:
                     slots[i] = self._adapters.ensure_loaded(row_adapter)
                 kw = self._lora_kwargs(slots)
             out, ttft, t1, generated = self._generate_batch_inner(
-                n, prompt_len, bucket, padded, max_new_tokens, eos_id,
-                t0, kw)
+                n, prompt_len, padded, max_new_tokens, eos_id, t0, kw)
         finally:
             if self._adapters is not None:
                 for row_adapter in pinned:
@@ -670,20 +678,11 @@ class LLMEngine:
         }
         return out, stats
 
-    def _generate_batch_inner(self, n, prompt_len, bucket, padded,
-                              max_new_tokens, eos_id, t0, kw):
-        import numpy as np
-
+    def _generate_batch_inner(self, n, prompt_len, padded, max_new_tokens,
+                              eos_id, t0, kw):
         cache = init_kv_cache(self.config, self.batch, self.max_len,
                               kv_dtype=self.kv_dtype)
-        logits, cache = self._prefill(self.params, jnp.asarray(padded),
-                                      cache, **kw)
-        if prompt_len != bucket:
-            cache["pos"] = jnp.full((self.batch,), prompt_len - 1, jnp.int32)
-            last = jnp.asarray(padded[:, prompt_len - 1:prompt_len])
-            logits, cache = self._decode(self.params, last, cache, **kw)
-        else:
-            cache["pos"] = jnp.full((self.batch,), prompt_len, jnp.int32)
+        logits, cache = self._prefill_prompt(padded, prompt_len, cache, kw)
         next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         out = [[int(t)] for t in np.asarray(next_token)[:n]]
         ttft = time.perf_counter() - t0

@@ -673,7 +673,8 @@ class ContinuousBatchingEngine:
         self._stats = {"requests": 0, "completed": 0, "ttft_sum": 0.0,
                        "tokens_out": 0, "shed": 0, "expired": 0,
                        "degraded": 0, "rejected_too_long": 0,
-                       "prefill_chunks": 0, "prefill_tokens_tick_max": 0,
+                       "prefill_chunks": 0, "prefill_dispatches": 0,
+                       "prefill_tokens_tick_max": 0,
                        "handoffs_out": 0, "handoff_bytes_out": 0,
                        "handoffs_in": 0, "handoff_bytes_in": 0,
                        "adapter_rate_limited": 0}
@@ -1139,7 +1140,8 @@ class ContinuousBatchingEngine:
     # adapter activity in federated sums)
     _COUNTER_STATS = ("requests", "completed", "tokens_out", "shed",
                       "expired", "degraded", "rejected_too_long",
-                      "prefill_chunks", "prefix_queries", "prefix_hits",
+                      "prefill_chunks", "prefill_dispatches",
+                      "prefix_queries", "prefix_hits",
                       "prefix_evictions", "prefix_cached_tokens",
                       "handoffs_out", "handoff_bytes_out", "handoffs_in",
                       "handoff_bytes_in")
@@ -1335,11 +1337,10 @@ class ContinuousBatchingEngine:
             small = init_kv_cache(self.config, 1, self.max_len,
                                   kv_dtype=self.kv_dtype)
             tokens = jnp.zeros((1, bucket), jnp.int32)
+            # the index of the position whose logits come back is an input:
+            # this one program serves every prompt length in the bucket
             _, small = self._prefill(self.params, tokens, small,
-                                     **prefill_kw)
-            # the last-token replay used for non-bucket prompt lengths
-            _, small = self._prefill(self.params,
-                                     jnp.zeros((1, 1), jnp.int32), small,
+                                     logits_at=np.int32(bucket - 1),
                                      **prefill_kw)
             self._cache = self._insert(self._cache, small, 0, bucket)
         if self.prefill_chunk and self.prefill_chunk not in \
@@ -1349,7 +1350,8 @@ class ContinuousBatchingEngine:
                                   kv_dtype=self.kv_dtype)
             self._prefill(self.params,
                           jnp.zeros((1, self.prefill_chunk), jnp.int32),
-                          small, **prefill_kw)
+                          small, logits_at=np.int32(self.prefill_chunk - 1),
+                          **prefill_kw)
         step = jnp.zeros((self.slots, 1), jnp.int32)
         tok, self._cache = self._decode(self.params, step, self._cache,
                                         **decode_kw)
@@ -1911,8 +1913,10 @@ class ContinuousBatchingEngine:
         padded[0, :take] = prompt[start:start + take]
         adm.small["pos"] = jnp.full((1,), start, jnp.int32)
         lora_kw = self._lora_kwargs(adm.adapter_slot)
+        # the logits that come back are those of the chunk's last REAL
+        # position: a padded prompt's first token needs no second dispatch
         logits, adm.small = self._prefill_dispatch(
-            adm, jnp.asarray(padded), lora_kw)
+            adm, jnp.asarray(padded), np.int32(take - 1), lora_kw)
         adm.offset += take
         adm.chunks += 1
         self._tick.prefill_tokens += take
@@ -1926,13 +1930,6 @@ class ContinuousBatchingEngine:
             return False
         if self.block_length > 1:
             return True
-        if take != pad_len:
-            # padding advanced pos past the prompt; replay the last real
-            # token for its logits (same trick as LLMEngine.generate)
-            adm.small["pos"] = jnp.full((1,), total - 1, jnp.int32)
-            logits, adm.small = self._prefill_dispatch(
-                adm, jnp.asarray([[prompt[-1]]], dtype=jnp.int32),
-                lora_kw)
         # from here the scheduler waits for the prefill on the device
         waited = time.perf_counter()
         if sampling_enabled():
@@ -1948,12 +1945,19 @@ class ContinuousBatchingEngine:
         self._tick.admit_wait_s += time.perf_counter() - waited
         return True
 
-    def _prefill_dispatch(self, adm: _Admission, tokens, lora_kw):
-        """One prefill device dispatch for an admission (chunk or the
-        last-token replay). Hook: the paged engine routes prefix-hit
-        admissions through the merged paged-prefill kernel so the cached
-        prefix is attended in place instead of gathered."""
-        return self._prefill(self.params, tokens, adm.small, **lora_kw)
+    def _prefill_dispatch(self, adm: _Admission, tokens, logits_at,
+                          lora_kw, prefix_kv=None):
+        """One prefill device dispatch for an admission: a chunk of the
+        prompt, returning the logits of its position ``logits_at``. Hook:
+        the paged engine routes prefix-hit admissions through the merged
+        paged-prefill kernel (``prefix_kv``) so the cached prefix is
+        attended in place instead of gathered."""
+        with self._lock:
+            self._stats["prefill_dispatches"] += 1
+        # an absent keyword, not a None: one compiled program a shape
+        hit_kw = {} if prefix_kv is None else {"prefix_kv": prefix_kv}
+        return self._prefill(self.params, tokens, adm.small,
+                             logits_at=logits_at, **hit_kw, **lora_kw)
 
     def _activate_slot(self, free: int, request_id: int, first_token: int,
                        max_new: int, eos_id, future, submitted: float,

@@ -693,10 +693,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                                   kv_dtype=self.kv_dtype)
             _, small = self._prefill(
                 self.params, jnp.zeros((1, bucket), jnp.int32), small,
-                **prefill_kw)
-            _, small = self._prefill(
-                self.params, jnp.zeros((1, 1), jnp.int32), small,
-                **prefill_kw)
+                logits_at=np.int32(bucket - 1), **prefill_kw)
             self._pool = self._insert_paged(self._pool, small, ids)
         if self.prefill_chunk and self.prefill_chunk not in \
                 self.prefill_buckets:
@@ -704,13 +701,14 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                                   kv_dtype=self.kv_dtype)
             self._prefill(self.params,
                           jnp.zeros((1, self.prefill_chunk), jnp.int32),
-                          small, **prefill_kw)
+                          small, logits_at=np.int32(self.prefill_chunk - 1),
+                          **prefill_kw)
         if self._prefix is not None:
             if self.paged_prefill_impl == "kernel":
                 # compile the merged prefix-hit prefill programs (every
-                # bucket/chunk shape + the 1-token replay) — the first
-                # cache hit must not pay the compile. All-(-1) ids route
-                # to the never-read scratch page; outputs are discarded
+                # bucket/chunk shape) — the first cache hit must not pay
+                # the compile. All-(-1) ids route to the never-read
+                # scratch page; outputs are discarded
                 ids = jnp.full((self.pages_per_slot,), -1, jnp.int32)
                 prefix_kv = {"k": self._pool["k"], "v": self._pool["v"],
                              "page_ids": ids,
@@ -718,7 +716,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 if "k_scale" in self._pool:
                     prefix_kv["k_scale"] = self._pool["k_scale"]
                     prefix_kv["v_scale"] = self._pool["v_scale"]
-                shapes = set(self.prefill_buckets) | {1}
+                shapes = set(self.prefill_buckets)
                 if self.prefill_chunk:
                     shapes.add(self.prefill_chunk)
                 for shape in sorted(shapes):
@@ -727,6 +725,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                     self._prefill(self.params,
                                   jnp.zeros((1, shape), jnp.int32),
                                   small, prefix_kv=prefix_kv,
+                                  logits_at=np.int32(shape - 1),
                                   **prefill_kw)
             else:
                 # compile the prefix-page gather (first cache hit must
@@ -1263,14 +1262,16 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                     future.set_exception(exc)
                 raise
 
-    def _prefill_dispatch(self, adm: _Admission, tokens, lora_kw):
+    def _prefill_dispatch(self, adm: _Admission, tokens, logits_at,
+                          lora_kw):
         """Prefix-hit admissions on the kernel path attend the cached
         prefix pages in place: the pool + page ids ride the dispatch as
-        ``prefix_kv`` and every chunk (and the last-token replay)
-        LSE-merges the paged-prefill kernel's partial state with the
-        local attention over the suffix rows."""
+        ``prefix_kv`` and every chunk LSE-merges the paged-prefill
+        kernel's partial state with the local attention over the suffix
+        rows."""
         if not adm.kernel_prefix:
-            return super()._prefill_dispatch(adm, tokens, lora_kw)
+            return super()._prefill_dispatch(adm, tokens, logits_at,
+                                             lora_kw)
         prefix_kv = {"k": self._pool["k"], "v": self._pool["v"],
                      "page_ids": jnp.asarray(adm.prefix_ids),
                      "base": jnp.int32(adm.base)}
@@ -1279,8 +1280,8 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             prefix_kv["v_scale"] = self._pool["v_scale"]
         with self._lock:
             self._stats["prefill_kernel_chunks"] += 1
-        return self._prefill(self.params, tokens, adm.small,
-                             prefix_kv=prefix_kv, **lora_kw)
+        return super()._prefill_dispatch(adm, tokens, logits_at, lora_kw,
+                                         prefix_kv=prefix_kv)
 
     def _handoff_kv(self, adm: _Admission, rows: int) -> dict:
         kv = super()._handoff_kv(adm, rows)

@@ -39,3 +39,29 @@ def assert_greedy_equal_up_to_tie(cfg, params, prompt, got, want,
         f"greedy streams diverge at step {split} ({got[split]} vs "
         f"{want[split]}) with a logit margin of {abs(a - b):.4f} — "
         f"{abs(a - b) / step:.1f} bf16 steps, not a tie", got, want)
+
+
+def greedy_reference(cfg, params, prompt, n):
+    """``n`` greedy tokens after ``prompt`` by the plain full forward,
+    recomputed from scratch at every step (no cache, no padding)."""
+    from mlrun_tpu.models.llama import forward
+
+    seq, out = list(prompt), []
+    for _ in range(n):
+        logits = forward(cfg, params, jnp.asarray([seq], jnp.int32))
+        out.append(int(jnp.argmax(logits[0, -1])))
+        seq.append(out[-1])
+    return out
+
+
+def record_prefills(engine):
+    """Wrap ``engine._prefill``: the returned list grows by ``(token
+    shape, prefix_kv given)`` with every dispatch of the prefill program."""
+    dispatched, program = [], engine._prefill
+
+    def recording(params, tokens, *args, **kwargs):
+        dispatched.append((tuple(tokens.shape), "prefix_kv" in kwargs))
+        return program(params, tokens, *args, **kwargs)
+
+    engine._prefill = recording
+    return dispatched

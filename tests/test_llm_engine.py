@@ -43,6 +43,45 @@ def test_generate_matches_full_forward(engine):
     assert gen == expected, (gen, expected)
 
 
+@pytest.mark.parametrize("batched", [False, True],
+                         ids=["generate", "generate_batch"])
+@pytest.mark.parametrize("length", [31, 32, 33],
+                         ids=["below", "at", "one-above"])
+def test_first_token_from_the_padded_prefill(engine, length, batched):
+    """A prompt below, at and one above the bucket of 32: the first
+    token is read from the prefill dispatch at the prompt's last real
+    position — one prefill, no one-token dispatch through ``_decode`` —
+    and the stream is the full forward's."""
+    from tests.greedy import (
+        assert_greedy_equal_up_to_tie,
+        greedy_reference,
+        record_prefills,
+    )
+
+    cfg = engine.config
+    eng = LLMEngine(cfg, engine.params, max_len=128,
+                    prefill_buckets=(32, 64), batch=2 if batched else 1)
+    eng.decode_chunk = 4
+    prefills, decodes, decode = record_prefills(eng), [], eng._decode
+
+    def counted_decode(*args, **kwargs):
+        decodes.append(1)
+        return decode(*args, **kwargs)
+
+    eng._decode = counted_decode
+    prompt = [(7 * i + 2) % 101 for i in range(length)]
+    if batched:
+        (got, _), _ = eng.generate_batch([prompt, prompt], max_new_tokens=5)
+    else:
+        got, _ = eng.generate(prompt, max_new_tokens=5)
+    assert_greedy_equal_up_to_tie(
+        cfg, engine.params, prompt, got,
+        greedy_reference(cfg, engine.params, prompt, 5))
+    rows = 2 if batched else 1
+    assert prefills == [((rows, 32 if length <= 32 else 64), False)]
+    assert not decodes
+
+
 def test_eos_stops_generation(engine):
     full, _ = engine.generate([1, 2, 3], max_new_tokens=16)
     eos = full[1]  # pretend the 2nd generated token is eos

@@ -7,7 +7,13 @@ import numpy as np
 import pytest
 
 from mlrun_tpu.models import init_params, tiny_llama
+from mlrun_tpu.serving.llm_batch import ContinuousBatchingEngine
 from mlrun_tpu.serving.paged import PagedContinuousBatchingEngine
+from tests.greedy import (
+    assert_greedy_equal_up_to_tie,
+    greedy_reference as _greedy_reference,
+    record_prefills,
+)
 
 
 @pytest.fixture(scope="module")
@@ -15,21 +21,6 @@ def setup():
     cfg = tiny_llama(attention_impl="reference")
     params = init_params(cfg, jax.random.PRNGKey(0))
     return cfg, params
-
-
-def _greedy_reference(cfg, params, prompt, n):
-    import jax.numpy as jnp
-
-    from mlrun_tpu.models.llama import forward
-
-    seq = list(prompt)
-    out = []
-    for _ in range(n):
-        logits = forward(cfg, params, jnp.asarray([seq], jnp.int32))
-        nxt = int(jnp.argmax(logits[0, -1]))
-        out.append(nxt)
-        seq.append(nxt)
-    return out
 
 
 def test_paged_greedy_exact(setup):
@@ -45,6 +36,57 @@ def test_paged_greedy_exact(setup):
         eng.stop()
     assert tokens == _greedy_reference(cfg, params, prompt, 6)
     assert stats["ttft_s"] > 0
+
+
+@pytest.mark.parametrize("engine", ["paged", "dense"])
+@pytest.mark.parametrize("chunk", [0, 4], ids=["inline", "chunked"])
+@pytest.mark.parametrize("length", [7, 8, 9],
+                         ids=["below", "at", "one-above"])
+def test_admission_is_one_prefill_dispatch(setup, length, chunk, engine):
+    """A prompt below, at and one above a bucket (8 of (8, 16)), inline
+    and in chunks of 4: the first token comes from the dispatch that
+    completed the prompt, read at its last real position — no one-token
+    dispatch follows a padded one, ``prefill_dispatches`` equals
+    ``prefill_chunks``, and the greedy stream is the full forward's."""
+    cfg, params = setup
+    kind = PagedContinuousBatchingEngine if engine == "paged" \
+        else ContinuousBatchingEngine
+    kw = {"page_size": 8, "prefix_cache": False} if engine == "paged" \
+        else {}
+    eng = kind(cfg, params, max_len=64, slots=2, prefill_buckets=(8, 16),
+               prefill_chunk=chunk, **kw)
+    eng.warmup()
+    shapes = record_prefills(eng)
+    eng.start()
+    try:
+        prompt = [(3 * i + 1) % 97 for i in range(length)]
+        tokens, _ = eng.generate(prompt, max_new_tokens=5)
+        stats = eng.stats
+    finally:
+        eng.stop()
+    assert_greedy_equal_up_to_tie(
+        cfg, params, prompt, tokens,
+        _greedy_reference(cfg, params, prompt, 5))
+    want = [(1, chunk)] * -(-length // chunk) if chunk \
+        else [(1, 8 if length <= 8 else 16)]
+    # padded or not: no (1, 1)
+    assert [shape for shape, _ in shapes] == want
+    assert stats["prefill_dispatches"] == stats["prefill_chunks"] \
+        == len(want)
+
+
+def test_warmup_compiles_no_one_token_prefill(setup):
+    """Warm-up compiles one prefill program a bucket (and a chunk), the
+    prefix-hit forms too, and none for a single token."""
+    cfg, params = setup
+    eng = PagedContinuousBatchingEngine(
+        cfg, params, max_len=64, slots=2, prefill_buckets=(8, 16),
+        page_size=8, prefill_chunk=4, attention_impl="kernel")
+    shapes = record_prefills(eng)
+    eng.warmup()
+    # each shape in its cold and its prefix-hit form
+    assert sorted(shapes) == [((1, width), hit) for width in (4, 8, 16)
+                              for hit in (False, True)]
 
 
 def test_paged_concurrent_churn_reuses_pages(setup):

@@ -323,8 +323,8 @@ def test_prefix_shared_pages_readonly_under_evict_chaos(setup):
 
 def test_llm_engine_flash_prefill_matches_reference(setup):
     """The non-batching LLMEngine with flash prefill generates the same
-    greedy tokens as the dense path (bucket padding + last-token replay
-    included)."""
+    greedy tokens as the dense path (bucket padding included: the first
+    token is read at the prompt's last real position)."""
     from mlrun_tpu.serving.llm import LLMEngine
 
     cfg, params = setup

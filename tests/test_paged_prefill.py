@@ -34,7 +34,11 @@ from mlrun_tpu.serving.paged import (
     PagedContinuousBatchingEngine,
     init_paged_pool,
 )
-from tests.greedy import assert_greedy_equal_up_to_tie
+from tests.greedy import (
+    assert_greedy_equal_up_to_tie,
+    greedy_reference,
+    record_prefills,
+)
 
 
 @pytest.fixture(scope="module")
@@ -262,8 +266,50 @@ def test_kernel_prefix_chunked_resume_parity(setup):
     assert seed == ref_seed
     assert_greedy_equal_up_to_tie(cfg, params, branch, out, ref)
     assert stats["prefill_gather_admissions"] == 0
-    # 12-token suffix at chunk 8 = two merged chunks + the replay
-    assert stats["prefill_kernel_chunks"] >= 3
+    # 12-token suffix at chunk 8 = two merged chunks; the first token is
+    # read from the second at its last real position (no third dispatch)
+    assert stats["prefill_kernel_chunks"] == 2
+    # the seed's 16 tokens are two chunks more
+    assert stats["prefill_dispatches"] == stats["prefill_chunks"] == 4
+
+
+@pytest.mark.parametrize("impl", ["kernel", "reference"])
+@pytest.mark.parametrize("chunk", [0, 4], ids=["inline", "chunked"])
+@pytest.mark.parametrize("suffix", [7, 8, 9],
+                         ids=["below", "at", "one-above"])
+def test_prefix_hit_admission_is_one_dispatch(setup, suffix, chunk, impl):
+    """Through a prefix hit (16 cached tokens, then a suffix below, at
+    and one above the bucket of 8; inline and in chunks of 4; the merged
+    kernel path and the gather path): the suffix's first token comes from
+    the dispatch that completed it, read at its last real position. No
+    one-token dispatch, ``prefill_dispatches == prefill_chunks``, and
+    the greedy stream is the full forward's up to a bf16 tie."""
+    cfg, params = setup
+    shared = list(range(1, 17))                  # 2 full blocks at ps=8
+    branch = shared + [(5 * i + 40) % 97 for i in range(suffix)]
+    eng = _engine(cfg, params, prefill_buckets=(8, 16, 32),
+                  attention_impl=impl, prefill_chunk=chunk)
+    shapes = record_prefills(eng)
+    try:
+        eng.generate(shared, max_new_tokens=2)
+        cold = len(shapes)
+        out, _ = eng.generate(branch, max_new_tokens=5)
+        stats = eng.stats
+    finally:
+        eng.stop()
+    assert_greedy_equal_up_to_tie(cfg, params, branch, out,
+                                  greedy_reference(cfg, params, branch, 5))
+    assert stats["prefix_hits"] == 1
+    hit = shapes[cold:]
+    width = chunk or (8 if suffix <= 8 else 16)
+    count = -(-suffix // chunk) if chunk else 1
+    assert hit == [((1, width), impl == "kernel")] * count
+    assert stats["prefill_dispatches"] == stats["prefill_chunks"] \
+        == len(shapes)
+    assert stats["prefill_kernel_chunks"] == \
+        (count if impl == "kernel" else 0)
+    assert stats["prefill_gather_admissions"] == \
+        (0 if impl == "kernel" else 1)
 
 
 def test_int8_engine_kernel_parity_cold_and_hit(setup):

@@ -135,16 +135,21 @@ def _run_one(engine, prompt=PROMPT, max_new=4, fake_clock=False):
                          ids=["dense", "paged"])
 def test_engine_ledger_closure_and_greedy_parity(make):
     """Dense AND paged engines: Σ phases == wall exactly under a fake
-    ledger clock on the REAL engine, within ±0.1s of the externally
-    measured wall on the real clock, and greedy tokens bit-identical
-    with the ledger on vs off."""
+    ledger clock on the REAL engine, inside the externally measured
+    wall on the real clock, and greedy tokens bit-identical with the
+    ledger on vs off."""
     on = make(request_ledger=True)
     try:
         tokens_cold, timing, wall = _run_one(on)
         assert timing is not None and timing["attribution_closed"]
         assert timing["wall_s"] == pytest.approx(
             sum(timing["phases"].values()), abs=1e-9)
-        assert abs(timing["wall_s"] - wall) < 0.1
+        # the real clock is a sanity bound, not the identity (the fake
+        # clock below holds that exactly): the ledger opens after the
+        # caller's clock and closes before it, and what lies between the
+        # two (a thread's wake-up on a loaded machine) is not seconds
+        assert 0 < timing["wall_s"] <= wall
+        assert wall - timing["wall_s"] < 5.0
         assert {"prefill", "decode_active"} <= set(timing["phases"])
         # zero-tolerance closure under a fake clock driving the same
         # real scheduler path (integer phase durations)

@@ -44,7 +44,7 @@ attn = importlib.import_module("mlrun_tpu.ops.attention")
 SLOTS, PAGES_PER_SLOT, N_PAGES, PAGE_SIZE = 16, 16, 512, 128
 N_HEADS, N_KV_HEADS = 32, 8
 HEAD_DIMS = {"llama3-1b": 64, "llama3-8b": 128}
-PREFILL_CHUNK = 512      # a prefill bucket; 1 is the last-token replay
+PREFILL_CHUNK = 512      # a prefill bucket; 1 is a one-token chunk
 VERIFY_ROWS = 5          # speculative k + 1
 POOL_LAYERS = 3          # a wrong layer index or a per-layer copy shows
 KV_DTYPES = {"bf16": "native", "int8": "int8"}   # the engine's names
@@ -113,6 +113,10 @@ def test_paged_decode_compiles(on_chip, model, kv_dtype):
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
 @pytest.mark.parametrize("model", list(HEAD_DIMS))
 def test_paged_prefill_compiles(on_chip, model, kv_dtype, chunk):
+    """The prefix kernel under a bucket's query rows and under one: no
+    engine dispatches a one-token prefill after a padded one any more,
+    but the kernel takes a one-row query whoever hands it one (a
+    ``prefill_chunk`` of 1)."""
     d = HEAD_DIMS[model]
     pool, layer, scales = _pool(on_chip, d, kv_dtype)
     q = on_chip((1, chunk, N_HEADS, d), jnp.bfloat16)
@@ -255,9 +259,12 @@ def test_pool_programs_copy_no_layer(program_shapes, on_chip, program,
             config, 1, PAGES_PER_SLOT * PAGE_SIZE,
             kv_dtype=KV_DTYPES[kv_dtype])))
         args = (params, on_chip((1, PREFILL_CHUNK), jnp.int32), cache)
+        # as the engine dispatches it: the position whose logits come
+        # back (a padded prompt's last real one) is a traced index
         kwargs = {"prefix_kv": dict(
             pool, page_ids=on_chip((PAGES_PER_SLOT,), jnp.int32),
-            base=on_chip((), jnp.int32))}
+            base=on_chip((), jnp.int32)),
+            "logits_at": on_chip((), jnp.int32)}
         donate = ()                # a hit only reads the pool
     compiled = jax.jit(fn, donate_argnums=donate).lower(
         *args, **kwargs).compile()
@@ -335,7 +342,8 @@ def test_block_masked_prefill_compiles(sdar_shapes, on_chip, bucket):
     cache = place(jax.eval_shape(lambda: llm.init_kv_cache(
         config, 1, PAGES_PER_SLOT * PAGE_SIZE)))
     compiled = jax.jit(fn).lower(
-        params, on_chip((1, bucket), jnp.int32), cache).compile()
+        params, on_chip((1, bucket), jnp.int32), cache,
+        logits_at=on_chip((), jnp.int32)).compile()
     hlo = compiled.as_text()
     assert "flash_v2" in hlo and "tpu_custom_call" in hlo
     assert len(re.findall(r"%gmm[\w.-]* = ", hlo)) == 6
