@@ -106,7 +106,7 @@ bench-elastic:   ## elastic vs full-resubmit A-B under the same injected slice k
 		&& tail -n 1 BENCH_r13.tmp > BENCH_r13.json \
 		&& rm BENCH_r13.tmp && cat BENCH_r13.json
 
-bench-attn:      ## attention kernels vs reference (flash v1/v2 + paged decode), CPU interpret mode; rewrites BENCH_ATTN_CPU.json
+bench-attn:      ## attention kernels vs reference (flash v2 + paged decode), CPU interpret mode; rewrites BENCH_ATTN_CPU.json
 	JAX_PLATFORMS=cpu $(PYTHON) scripts/bench_attention_cpu.py
 
 obs-smoke:       ## graph + fleet + adapter + training smoke: scrape /metrics, federate, SLO status, adapter cardinality, span artifact, goodput families + flight artifact on a forced preemption (docs/observability.md)

@@ -237,27 +237,43 @@ def _remat_policy(name: str):
         f"unknown remat_policy '{name}' (nothing | save_attn | dots)")
 
 
-def _layer_body(config: LlamaConfig, x, layer_params, cos, sin,
-                lora: Optional[dict] = None, attention_fn=None):
-    """One decoder layer. x: [B, S, E]. ``attention_fn`` overrides the
-    attention dispatcher (context-parallel paths pass ring/ulysses)."""
-    b, s, e = x.shape
-    lp = layer_params
-
-    def proj(h_in, w, lora_key):
+def trainer_proj(lora: Optional[dict], dtype):
+    """The trainer's projection ``proj(h_in, w, key)``: f32 product cast to
+    ``dtype``, then the layer's LoRA delta (``lora[key]``, where present)
+    added in ``dtype``. The serving form (serving/llm.py ``_serving_proj``)
+    adds its delta in f32 before the cast: the two round in a different
+    order, so they stay two."""
+    def proj(h_in, w, key=None):
         out = jnp.einsum("bse,eh->bsh", h_in, w,
-                         preferred_element_type=jnp.float32).astype(x.dtype)
-        if lora is not None and lora_key in lora:
-            a, bb, scaling = (lora[lora_key]["lora_a"],
-                              lora[lora_key]["lora_b"],
-                              lora[lora_key]["scaling"])
-            delta = jnp.einsum("bse,er->bsr", h_in, a.astype(x.dtype))
-            delta = jnp.einsum("bsr,rh->bsh", delta, bb.astype(x.dtype))
-            out = (out + scaling.astype(x.dtype) * delta).astype(x.dtype)
+                         preferred_element_type=jnp.float32).astype(dtype)
+        if lora is not None and key in lora:
+            a, bb, scaling = (lora[key]["lora_a"], lora[key]["lora_b"],
+                              lora[key]["scaling"])
+            delta = jnp.einsum("bse,er->bsr", h_in, a.astype(dtype))
+            delta = jnp.einsum("bsr,rh->bsh", delta, bb.astype(dtype))
+            out = (out + scaling.astype(dtype) * delta).astype(dtype)
         return out
 
-    from jax.ad_checkpoint import checkpoint_name
+    return proj
 
+
+def decoder_block(config: LlamaConfig, lp, x, cos, sin, *, proj, attend,
+                  mlp=None, live=None, layer=None):
+    """The Llama-family decoder layer, written once. x: [B, S, E]; ``lp``
+    the layer's parameters. Norm, q/k/v through ``proj(h_in, w, key)``
+    (:func:`trainer_proj` or serving/llm.py ``_serving_proj``), q/k norm,
+    rope, ``attend(q, k, v) -> [B, S, Hq, D]`` (or with the heads merged
+    already, [B, S, Hq * D]), ``wo``, residual, norm,
+    MLP (:func:`layer_mlp` with ``live`` and ``layer``, unless ``mlp`` is
+    given: ``mlp(h2) -> (out, extra)``), residual.
+
+    ``attend`` is all a caller says about its cache: the closure writes K
+    and V where that caller keeps them and reads the attention back
+    (none, dense rows, pages), keeping what it wrote for its own return.
+
+    Returns ``(x, extra)``: ``extra`` is what the MLP returned beside its
+    output (expert load, aux loss, ``None``)."""
+    b, s, _ = x.shape
     # the named scopes are metadata a profile groups operations by
     # (embed, layer/attn, layer/mlp, head, loss): no instruction is renamed
     with jax.named_scope("layer/attn"):
@@ -268,34 +284,46 @@ def _layer_body(config: LlamaConfig, x, layer_params, cos, sin,
                                             config.head_dim)
         v = proj(h, lp["wv"], "wv").reshape(b, s, config.n_kv_heads,
                                             config.head_dim)
+        q, k = qk_normed(config, q, k, lp)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
+        attn = attend(q, k, v).reshape(b, s, config.qkv_dim)
+        x = x + proj(attn, lp["wo"], "wo")
+    with jax.named_scope("layer/mlp"):
+        h2 = rms_norm(x, lp["mlp_norm_scale"], config.norm_eps)
+        out, extra = mlp(h2) if mlp is not None else layer_mlp(
+            config, h2, lp, proj, live=live, layer=layer)
+        x = x + out
+    return x, extra
+
+
+def _layer_body(config: LlamaConfig, x, layer_params, cos, sin,
+                lora: Optional[dict] = None, attention_fn=None):
+    """One decoder layer of the trainer. x: [B, S, E]. ``attention_fn``
+    overrides the attention dispatcher (context-parallel paths pass
+    ring/ulysses)."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    def attend(q, k, v):
         if attention_fn is not None:
             attn = attention_fn(q, k, v)
         else:
             attn = attention(q, k, v, causal=True,
                              impl=config.attention_impl)
-        attn = attn.reshape(b, s, config.qkv_dim)
         # named for the "save_attn" remat policy: backward keeps the
-        # attention output and recomputes only the MLP half
-        attn = checkpoint_name(attn, "attn_out")
-        x = x + proj(attn, lp["wo"], "wo")
+        # attention output and recomputes only the MLP half (named as the
+        # block's wo takes it, heads merged: what is saved is what is read)
+        return checkpoint_name(
+            attn.reshape(*attn.shape[:2], config.qkv_dim), "attn_out")
 
-    with jax.named_scope("layer/mlp"):      # SwiGLU
-        h = rms_norm(x, lp["mlp_norm_scale"], config.norm_eps)
-        gate = proj(h, lp["w_gate"], "w_gate")
-        up = proj(h, lp["w_up"], "w_up")
-        x = x + proj(jax.nn.silu(gate) * up, lp["w_down"], "w_down")
-    return x
+    return decoder_block(config, layer_params, x, cos, sin,
+                         proj=trainer_proj(lora, x.dtype), attend=attend)[0]
 
 
 def qk_normed(config: LlamaConfig, q, k, lp):
     """q and k as the rotation takes them: under ``config.qk_norm`` each
     head's vector is RMS-normalised with the layer's learned scale
-    (``q_norm_scale`` / ``k_norm_scale`` [head_dim]) first. The one place
-    the serving programs (serving/llm.py ``_forward_with_cache``,
-    serving/paged.py ``_decode_rowwise_paged`` and ``_verify_rowwise_paged``)
-    learn about q/k norms."""
+    (``q_norm_scale`` / ``k_norm_scale`` [head_dim]) first."""
     if not config.qk_norm:
         return q, k
     return (rms_norm(q, lp["q_norm_scale"], config.norm_eps),
@@ -313,8 +341,8 @@ def layer_slice(layers: Params, layer: int) -> Params:
 
 
 def layer_mlp(config: LlamaConfig, h2, lp, proj, live=None, layer=None):
-    """The layer's MLP over the normed input ``h2`` [B, S, E], as the
-    serving programs call it: the dense SwiGLU through the caller's
+    """The layer's MLP over the normed input ``h2`` [B, S, E], as
+    :func:`decoder_block` calls it: the dense SwiGLU through the caller's
     ``proj`` (which adds a tenant's LoRA delta), or, where the layer's
     parameters carry experts, the dropless expert layer of models/moe.py
     over the experts the config holds. ``live`` [B, S] bool leaves dead
@@ -334,6 +362,35 @@ def layer_mlp(config: LlamaConfig, h2, lp, proj, live=None, layer=None):
     return proj(jax.nn.silu(gate) * up, lp["w_down"], "w_down"), None
 
 
+def embed(config: LlamaConfig, params: Params, tokens: jax.Array,
+          act_spec=None):
+    """tokens [B, S] -> their embeddings [B, S, E] in the model's dtype.
+    ``act_spec``: the activations' sharding, where the table is sharded
+    (:func:`forward`)."""
+    with jax.named_scope("embed"):
+        if act_spec is not None:
+            x = params["embedding"].at[tokens].get(out_sharding=act_spec)
+        else:
+            x = params["embedding"][tokens]
+        return x.astype(config.dtype)
+
+
+def lm_head(params: Params) -> jax.Array:
+    """The head's matrix [E, vocab]: the tree's own (untied) or the
+    embedding's transpose (tied)."""
+    head = params.get("lm_head")
+    return params["embedding"].T if head is None else head
+
+
+def head_logits(config: LlamaConfig, params: Params, x: jax.Array):
+    """x [B, S, E], a serving program's last layer's output -> logits
+    [B, S, vocab] f32: final norm, :func:`lm_head`, f32 product."""
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm_scale"], config.norm_eps)
+        return jnp.einsum("bse,ev->bsv", x, lm_head(params),
+                          preferred_element_type=jnp.float32)
+
+
 def forward(config: LlamaConfig, params: Params, tokens: jax.Array,
             positions: jax.Array | None = None,
             lora: Optional[Params] = None,
@@ -346,11 +403,8 @@ def forward(config: LlamaConfig, params: Params, tokens: jax.Array,
     """
     x = hidden_states(config, params, tokens, positions=positions,
                       lora=lora, act_spec=act_spec)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embedding"].T
     with jax.named_scope("head"):
-        logits = jnp.einsum("bse,ev->bsv", x, head,
+        logits = jnp.einsum("bse,ev->bsv", x, lm_head(params),
                             preferred_element_type=jnp.float32)
     return logits
 
@@ -361,12 +415,7 @@ def hidden_states(config: LlamaConfig, params: Params, tokens: jax.Array,
                   act_spec=None) -> jax.Array:
     """tokens [B, S] -> final-norm hidden [B, S, E] (no lm head)."""
     b, s = tokens.shape
-    with jax.named_scope("embed"):
-        if act_spec is not None:
-            x = params["embedding"].at[tokens].get(
-                out_sharding=act_spec).astype(config.dtype)
-        else:
-            x = params["embedding"][tokens].astype(config.dtype)
+    x = embed(config, params, tokens, act_spec)
     if positions is None:
         positions = jnp.arange(s)
     cos, sin = rope_table(positions, config.head_dim, config.rope_theta)
@@ -403,13 +452,10 @@ def chunked_loss(config: LlamaConfig, params: Params, tokens: jax.Array,
     batch 8 and batch 32 at vocab 128k on a 16GB chip.
     """
     x = hidden_states(config, params, tokens, lora=lora, act_spec=act_spec)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embedding"].T
     # head and loss are one chunked region here: the logits never exist
     with jax.named_scope("loss"):
-        loss, accuracy, total = chunked_ce(x, head, targets, mask=mask,
-                                           chunk=chunk)
+        loss, accuracy, total = chunked_ce(x, lm_head(params), targets,
+                                           mask=mask, chunk=chunk)
     return loss, {"loss": loss, "accuracy": accuracy, "tokens": total}
 
 

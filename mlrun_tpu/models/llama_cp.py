@@ -26,7 +26,7 @@ from ..ops.rotary import rope_table
 from ..ops.ulysses import ulysses_attention
 from ..parallel.compat import shard_map
 from ..utils.profiler import named
-from .llama import LlamaConfig, Params, _layer_body
+from .llama import LlamaConfig, Params, _layer_body, embed, lm_head
 
 
 def _cp_hidden(config: LlamaConfig, params: Params, tokens: jax.Array,
@@ -52,7 +52,7 @@ def _cp_hidden(config: LlamaConfig, params: Params, tokens: jax.Array,
     else:
         raise ValueError(f"unknown cp attention impl '{attn_impl}'")
 
-    x = params["embedding"][tokens].astype(config.dtype)
+    x = embed(config, params, tokens)
 
     body = functools.partial(_layer_body, config)
     if config.remat:
@@ -111,10 +111,7 @@ def make_context_parallel_loss(config: LlamaConfig, mesh: Mesh,
     def nll_shards(params, tokens, targets, lora):
         x = _cp_hidden(config, params, tokens, seq_axis, attn_impl,
                        lora=lora)
-        head = params.get("lm_head")
-        if head is None:
-            head = params["embedding"].T
-        logits = jnp.einsum("bse,ev->bsv", x, head,
+        logits = jnp.einsum("bse,ev->bsv", x, lm_head(params),
                             preferred_element_type=jnp.float32)
         log_probs = jax.nn.log_softmax(logits, axis=-1)
         # per-token nll [B_local, s_local]; the global [B, S] array

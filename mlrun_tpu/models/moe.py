@@ -31,8 +31,8 @@ import jax.numpy as jnp
 
 from ..ops.attention import attention
 from ..ops.norms import rms_norm
-from ..ops.rotary import apply_rope, rope_table
-from .llama import LlamaConfig
+from ..ops.rotary import rope_table
+from .llama import LlamaConfig, decoder_block, embed, lm_head, trainer_proj
 
 Params = dict
 
@@ -355,32 +355,20 @@ def _moe_mlp(config: MoEConfig, x, lp):
 
 
 def _layer_body(config: MoEConfig, x, lp, cos, sin):
-    b, s, e = x.shape
-
-    def proj(h_in, w):
-        return jnp.einsum("bse,eh->bsh", h_in, w,
-                          preferred_element_type=jnp.float32).astype(x.dtype)
-
-    h = rms_norm(x, lp["attn_norm_scale"], config.norm_eps)
-    q = proj(h, lp["wq"]).reshape(b, s, config.n_heads, config.head_dim)
-    key = proj(h, lp["wk"]).reshape(b, s, config.n_kv_heads, config.head_dim)
-    value = proj(h, lp["wv"]).reshape(b, s, config.n_kv_heads,
-                                      config.head_dim)
-    q = apply_rope(q, cos, sin)
-    key = apply_rope(key, cos, sin)
-    attn = attention(q, key, value, causal=True, impl=config.attention_impl)
-    x = x + proj(attn.reshape(b, s, config.qkv_dim), lp["wo"])
-
-    h2 = rms_norm(x, lp["mlp_norm_scale"], config.norm_eps)
-    moe_out, aux = _moe_mlp(config, h2, lp)
-    return x + moe_out, aux
+    """One decoder layer of the trainer: the block of models/llama.py over
+    the capacity-dispatch expert layer. Returns (x, aux loss)."""
+    return decoder_block(
+        config, lp, x, cos, sin, proj=trainer_proj(None, x.dtype),
+        attend=lambda q, k, v: attention(q, k, v, causal=True,
+                                         impl=config.attention_impl),
+        mlp=lambda h2: _moe_mlp(config, h2, lp))
 
 
 def hidden_states(config: MoEConfig, params: Params, tokens: jax.Array
                   ) -> tuple[jax.Array, jax.Array]:
     """tokens [B, S] -> (final hidden [B, S, E], aux_loss scalar)."""
     b, s = tokens.shape
-    x = params["embedding"][tokens].astype(config.dtype)
+    x = embed(config, params, tokens)
     cos, sin = rope_table(jnp.arange(s), config.head_dim, config.rope_theta)
 
     body = functools.partial(_layer_body, config)
@@ -397,16 +385,11 @@ def hidden_states(config: MoEConfig, params: Params, tokens: jax.Array
     return x, jnp.mean(aux_losses)
 
 
-def _head(params: Params) -> jax.Array:
-    head = params.get("lm_head")
-    return params["embedding"].T if head is None else head
-
-
 def forward(config: MoEConfig, params: Params, tokens: jax.Array
             ) -> tuple[jax.Array, jax.Array]:
     """tokens [B, S] -> (logits [B, S, V] f32, aux_loss scalar)."""
     x, aux = hidden_states(config, params, tokens)
-    logits = jnp.einsum("bse,ev->bsv", x, _head(params),
+    logits = jnp.einsum("bse,ev->bsv", x, lm_head(params),
                         preferred_element_type=jnp.float32)
     return logits, aux
 
@@ -420,7 +403,7 @@ def loss_fn(config: MoEConfig, params: Params, tokens, targets,
         from .llama import chunked_ce
 
         x, aux_loss = hidden_states(config, params, tokens)
-        ce, accuracy, _ = chunked_ce(x, _head(params), targets, mask=mask,
+        ce, accuracy, _ = chunked_ce(x, lm_head(params), targets, mask=mask,
                                      chunk=loss_chunk)
         loss = ce + config.router_aux_weight * aux_loss
         return loss, {"loss": loss, "ce_loss": ce, "aux_loss": aux_loss,

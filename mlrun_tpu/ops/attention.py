@@ -100,56 +100,6 @@ def _fit_block(n: int, preferred: int) -> int:
             return c
     return n
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                      block_k: int, seq_k: int, kv_len: int, scale: float,
-                      causal: bool):
-    # grid: (batch*heads, q_blocks); refs (leading block dim of 1 retained):
-    #   q: [1, block_q, d], k/v: [1, seq_k, d] (full kv in VMEM per program)
-    block_q = q_ref.shape[1]
-    d = q_ref.shape[2]
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale
-
-    m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
-
-    num_kb = seq_k // block_k
-
-    def body(kb, carry):
-        m_prev, l_prev, acc = carry
-        k_blk = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
-        k_pos = kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        if kv_len != seq_k:  # mask padded kv tail
-            s = jnp.where(k_pos < kv_len, s, NEG_INF)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + jnp.dot(p, v_blk,
-                                    preferred_element_type=jnp.float32)
-        return m_new, l_new, acc
-
-    if causal:
-        # only blocks with k_start <= q_end contribute
-        last_kb = jnp.minimum(((qi + 1) * block_q - 1) // block_k + 1, num_kb)
-    else:
-        last_kb = num_kb
-    m, l, acc = jax.lax.fori_loop(0, last_kb, body, (m0, l0, acc0))
-    l = jnp.maximum(l, 1e-30)
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
-    # lse laid out [block_q, 8] (last dim = full array dim) to satisfy the
-    # TPU (8, 128)-tiling rule on output block shapes
-    lse_ref[0] = jnp.broadcast_to(m + jnp.log(l), (block_q, 8))
-
 
 def causal_bound(q_pos, block_length: int = 1):
     """The last position that ``q_pos`` sees under the block mask: the end
@@ -167,8 +117,8 @@ def _flash_v2_body(q_off, k_lo, q_ref, k_ref, v_ref, o_ref, lse_ref,
                    block_length: int = 1):
     """Grid-pipelined flash forward body: grid (bh, q_blocks, k_blocks).
 
-    Unlike the v1 kernel (full KV resident in VMEM), each program sees one
-    (q_block, k_block) tile — pallas double-buffers the HBM→VMEM streams
+    Each program sees one (q_block, k_block) tile, not the full KV
+    resident in VMEM — pallas double-buffers the HBM→VMEM streams
     across the innermost grid dim, so sequence length is bounded by HBM,
     not VMEM. Running max/denominator/accumulator live in scratch that
     persists across the k grid steps of a fixed (bh, qi).
@@ -407,70 +357,6 @@ def flash_attention_cached(q, k, v, q_start,
     excluded by the mask, so the cache tail needs no explicit length."""
     o, _ = _flash_fwd_v2_cached(q, k, v, q_start, block_length=block_length)
     return o
-
-
-@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
-                                             "interpret"))
-def _flash_fwd(q, k, v, causal=True, block_q=256, block_k=256,
-               interpret=None):
-    """q,k,v: [B, S, H, D] (kv already repeated to H heads). Returns (o, lse)."""
-    if interpret is None:
-        interpret = interpret_default()
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
-    # pad seq dims to block multiples; padded k rows are masked out by
-    # position (causal) or an explicit kv-length bound in the kernel
-    orig_sq, orig_sk = sq, sk
-    pad_q = (-sq) % block_q
-    pad_k = (-sk) % block_k
-    if pad_q:
-        q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
-        sq += pad_q
-    if pad_k:
-        k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
-        sk += pad_k
-    scale = d ** -0.5
-    # layout: fold batch*heads, move seq to row dim
-    qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    grid = (b * h, pl.cdiv(sq, block_q))
-    kernel = functools.partial(
-        _flash_fwd_kernel, block_k=block_k, seq_k=sk, kv_len=orig_sk,
-        scale=scale, causal=causal)
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i: (bh, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, sk, d), lambda bh, i: (bh, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, sk, d), lambda bh, i: (bh, 0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i: (bh, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, 8), lambda bh, i: (bh, i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, sq, 8), jnp.float32),
-        ],
-        interpret=interpret,
-        name="flash_v1",
-    )(qt, kt, vt)
-    o = o.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
-    lse = lse[:, :, 0].reshape(b, h, sq)
-    if pad_q:
-        o = o[:, :orig_sq]
-        lse = lse[:, :, :orig_sq]
-    return o, lse
 
 
 def _blockwise_bwd(q, k, v, o, lse, g, causal: bool, block: int = 512):

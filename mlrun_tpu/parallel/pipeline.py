@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..models.llama import LlamaConfig, _layer_body
+from ..models.llama import LlamaConfig, _layer_body, embed, lm_head
 from ..ops.norms import rms_norm
 from ..ops.rotary import rope_table
 from .compat import shard_map
@@ -113,7 +113,7 @@ def make_pipeline_forward(config: LlamaConfig, mesh: Mesh,
                 f"microbatches) must divide over the '{batch_axis}' mesh "
                 f"axis ({mesh.shape[batch_axis]}); grow the batch or "
                 "shrink the data axis")
-        x = params["embedding"][tokens].astype(config.dtype)
+        x = embed(config, params, tokens)
         cos, sin = rope_table(jnp.arange(s), config.head_dim,
                               config.rope_theta)
         x_micro = x.reshape(num_microbatches, mb, s, -1)
@@ -121,10 +121,7 @@ def make_pipeline_forward(config: LlamaConfig, mesh: Mesh,
         hidden = hidden.reshape(b, s, -1)
         hidden = rms_norm(hidden, params["final_norm_scale"],
                           config.norm_eps)
-        head = params.get("lm_head")
-        if head is None:
-            head = params["embedding"].T
-        return jnp.einsum("bse,ev->bsv", hidden, head,
+        return jnp.einsum("bse,ev->bsv", hidden, lm_head(params),
                           preferred_element_type=jnp.float32)
 
     return forward

@@ -22,13 +22,13 @@ import numpy as np
 from ..models.llama import (
     LlamaConfig,
     Params,
-    layer_mlp,
+    decoder_block,
+    embed,
+    head_logits,
     layer_slice,
-    qk_normed,
 )
 from ..ops.attention import causal_bound
-from ..ops.norms import rms_norm
-from ..ops.rotary import apply_rope, rope_table
+from ..ops.rotary import rope_table
 from ..utils import logger
 
 
@@ -143,6 +143,75 @@ def _lora_delta(h_in, lora_target, layer, adapter_ids):
     return delta * scaling[:, None, None]
 
 
+def _serving_proj(lora, adapter_ids, layer: int, dtype):
+    """The serving programs' projection ``proj(h_in, w, key)`` for layer
+    ``layer``: f32 product, plus each row's LoRA delta out of the adapter
+    bank (``lora[key]``, where present) in f32, then the cast to ``dtype``
+    (models/llama.py ``trainer_proj`` casts first: they stay two)."""
+    def proj(h_in, w, key=None):
+        out = jnp.einsum("bse,eh->bsh", h_in, w,
+                         preferred_element_type=jnp.float32)
+        if lora is not None and key is not None and key in lora:
+            out = out + _lora_delta(h_in, lora[key], layer, adapter_ids)
+        return out.astype(dtype)
+
+    return proj
+
+
+def _serving_layers(config: LlamaConfig, params: Params, x, cos, sin,
+                    attend, lora=None, adapter_ids=None, live=None):
+    """The decoder's layers as every serving program runs them: a Python
+    loop (compiled once per program; exposes per-layer cache updates
+    without scan-carry gymnastics) of models/llama.py ``decoder_block``
+    over ``layer_slice`` and :func:`_serving_proj`. ``attend(layer, q, k,
+    v) -> [B, S, Hq, D]`` is the caller's cache: it writes K and V and
+    reads the attention. Returns ``(x, loads)``: ``loads`` the expert
+    layers' loads, empty for dense MLPs."""
+    loads = []
+    for layer in range(config.n_layers):
+        x, load = decoder_block(
+            config, layer_slice(params["layers"], layer), x, cos, sin,
+            proj=_serving_proj(lora, adapter_ids, layer, x.dtype),
+            attend=functools.partial(attend, layer), live=live, layer=layer)
+        if load is not None:
+            loads.append(load)
+    return x, loads
+
+
+def _stacked_cache(new: dict, pos) -> dict:
+    """The new cache out of the per-layer lists a closure filled."""
+    return {**{name: jnp.stack(rows) for name, rows in new.items()},
+            "pos": pos}
+
+
+def _kv_rows(store: dict, k, v) -> dict:
+    """K and V as ``store`` (a dense cache or a page pool) keeps them:
+    cast to its dtype, or on an int8 store quantised per vector with
+    their scales beside them."""
+    if "k_scale" in store:
+        kq, ks = _quantize_kv(k)
+        vq, vs = _quantize_kv(v)
+        return {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+    return {"k": k.astype(store["k"].dtype), "v": v.astype(store["v"].dtype)}
+
+
+def _dense_kv_write(config, cache: dict, new: dict, layer: int, k, v, write):
+    """Layer ``layer``'s K and V into a dense cache, as every dense
+    ``attend`` closure does it: each buffer of the layer rewritten by
+    ``write(buffer, rows)`` (where the rows go is the caller's) and
+    appended to ``new[name]`` (a list a buffer, which the caller stacks:
+    :func:`_stacked_cache`). Returns the layer's (k, v) as attention reads
+    them."""
+    for name, row in _kv_rows(cache, k, v).items():
+        new[name].append(write(cache[name][layer], row))
+    if "k_scale" in cache:
+        return (_dequantize_kv(new["k"][-1], new["k_scale"][-1],
+                               config.dtype),
+                _dequantize_kv(new["v"][-1], new["v_scale"][-1],
+                               config.dtype))
+    return new["k"][-1], new["v"][-1]
+
+
 def _forward_with_cache(config: LlamaConfig, params: Params,
                         tokens: jax.Array, cache: dict,
                         lora: Optional[Params] = None,
@@ -187,147 +256,79 @@ def _forward_with_cache(config: LlamaConfig, params: Params,
     max_len = cache["k"].shape[2]
     start = cache["pos"]  # [B]
     positions = start[:, None] + jnp.arange(s)[None, :]  # [B, S]
-    with jax.named_scope("embed"):
-        x = params["embedding"][tokens].astype(config.dtype)
+    x = embed(config, params, tokens)
     # rope per batch row (positions differ per row only after mixed prefill;
     # keep a single table using row 0 — engine keeps pos uniform per batch)
     cos, sin = rope_table(positions[0], config.head_dim, config.rope_theta)
+    new = {name: [] for name in cache if name != "pos"}
 
-    def body(x_in, layer_idx_and_params):
-        layer, lp = layer_idx_and_params
-        with jax.named_scope("layer/attn"):
-            h = rms_norm(x_in, lp["attn_norm_scale"], config.norm_eps)
+    def write(buffer, rows):
+        # k,v into the cache at start..start+s (uniform start)
+        return jax.lax.dynamic_update_slice(
+            buffer, rows, (0, start[0]) + (0,) * (rows.ndim - 2))
 
-            def proj(h_in, w, t=None):
-                out = jnp.einsum("bse,eh->bsh", h_in, w,
-                                 preferred_element_type=jnp.float32)
-                if lora is not None and t is not None and t in lora:
-                    out = out + _lora_delta(h_in, lora[t], layer, adapter_ids)
-                return out.astype(x_in.dtype)
+    def attend(layer, q, k, v):
+        k_attn, v_attn = _dense_kv_write(config, cache, new, layer, k, v,
+                                         write)
+        n_rep = config.n_heads // config.n_kv_heads
+        if prefix_kv is not None:
+            # paged prefix-hit suffix prefill: local rows (>= base) via
+            # bounded flash (s > 1) or the bounded dense form (a
+            # one-token chunk), the cached prefix via the
+            # multi-row paged prefill kernel reading pool pages in
+            # place — partial softmax states LSE-merged
+            # (docs/serving.md "Attention kernels")
+            from ..ops.attention import (
+                _flash_fwd_v2_cached_bounded,
+                _repeat_kv,
+            )
+            from ..ops.paged_attention import (
+                merge_softmax_states,
+                paged_prefix_part,
+            )
 
-            q = proj(h, lp["wq"], "wq").reshape(b, s, config.n_heads,
-                                                config.head_dim)
-            k = proj(h, lp["wk"], "wk").reshape(b, s, config.n_kv_heads,
-                                                config.head_dim)
-            v = proj(h, lp["wv"], "wv").reshape(b, s, config.n_kv_heads,
-                                                config.head_dim)
-            q, k = qk_normed(config, q, k, lp)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-            quantized = "k_scale" in cache
-            if quantized:
-                kq, ks = _quantize_kv(k)
-                vq, vs = _quantize_kv(v)
-                k_cache = jax.lax.dynamic_update_slice(
-                    cache["k"][layer], kq, (0, start[0], 0, 0))
-                v_cache = jax.lax.dynamic_update_slice(
-                    cache["v"][layer], vq, (0, start[0], 0, 0))
-                k_scale = jax.lax.dynamic_update_slice(
-                    cache["k_scale"][layer], ks, (0, start[0], 0))
-                v_scale = jax.lax.dynamic_update_slice(
-                    cache["v_scale"][layer], vs, (0, start[0], 0))
-                k_attn = _dequantize_kv(k_cache, k_scale, config.dtype)
-                v_attn = _dequantize_kv(v_cache, v_scale, config.dtype)
-                scales = (k_scale, v_scale)
+            base = prefix_kv["base"]
+            if attn_impl == "flash" and s > 1:
+                o_loc, lse_loc = _flash_fwd_v2_cached_bounded(
+                    q, _repeat_kv(k_attn, n_rep),
+                    _repeat_kv(v_attn, n_rep), start[0], base,
+                    block_length=config.block_length)
             else:
-                # write k,v into the cache at start..start+s (uniform start)
-                k_cache = jax.lax.dynamic_update_slice(
-                    cache["k"][layer], k.astype(cache["k"].dtype),
-                    (0, start[0], 0, 0))
-                v_cache = jax.lax.dynamic_update_slice(
-                    cache["v"][layer], v.astype(cache["v"].dtype),
-                    (0, start[0], 0, 0))
-                k_attn, v_attn = k_cache, v_cache
-                scales = None
-            if prefix_kv is not None:
-                # paged prefix-hit suffix prefill: local rows (>= base) via
-                # bounded flash (s > 1) or the bounded dense form (a
-                # one-token chunk), the cached prefix via the
-                # multi-row paged prefill kernel reading pool pages in
-                # place — partial softmax states LSE-merged
-                # (docs/serving.md "Attention kernels")
-                from ..ops.attention import (
-                    _flash_fwd_v2_cached_bounded,
-                    _repeat_kv,
-                )
-                from ..ops.paged_attention import (
-                    merge_softmax_states,
-                    paged_prefix_part,
-                )
+                o_loc, lse_loc = _cached_attention_lse(
+                    config, q, k_attn, v_attn, positions, base)
+            o_pre, lse_pre = paged_prefix_part(
+                q, prefix_kv["k"], prefix_kv["v"], layer,
+                prefix_kv["page_ids"], base, page_size=page_size,
+                k_scale=prefix_kv.get("k_scale"),
+                v_scale=prefix_kv.get("v_scale"))
+            return merge_softmax_states(o_pre, lse_pre, o_loc,
+                                        lse_loc).astype(q.dtype)
+        if attn_impl == "flash" and s > 1:
+            from ..ops.attention import _repeat_kv, flash_attention_cached
 
-                n_rep = config.n_heads // config.n_kv_heads
-                base = prefix_kv["base"]
-                if attn_impl == "flash" and s > 1:
-                    o_loc, lse_loc = _flash_fwd_v2_cached_bounded(
-                        q, _repeat_kv(k_attn, n_rep),
-                        _repeat_kv(v_attn, n_rep), start[0], base,
-                        block_length=config.block_length)
-                else:
-                    o_loc, lse_loc = _cached_attention_lse(
-                        config, q, k_attn, v_attn, positions, base)
-                o_pre, lse_pre = paged_prefix_part(
-                    q, prefix_kv["k"], prefix_kv["v"], layer,
-                    prefix_kv["page_ids"], base, page_size=page_size,
-                    k_scale=prefix_kv.get("k_scale"),
-                    v_scale=prefix_kv.get("v_scale"))
-                attn = merge_softmax_states(o_pre, lse_pre, o_loc,
-                                            lse_loc).astype(x_in.dtype)
-            elif attn_impl == "flash" and s > 1:
-                from ..ops.attention import _repeat_kv, flash_attention_cached
+            # positions are uniform per batch row on the prefill path
+            # (mixed-start batches never reach here — see rope note
+            # above).
+            # 1-token dispatches (decode steps, a one-token chunk) stay
+            # dense: a block_q=1 kernel instance gains nothing and is a
+            # shape class TPU lowering never otherwise sees
+            return flash_attention_cached(
+                q, _repeat_kv(k_attn, n_rep), _repeat_kv(v_attn, n_rep),
+                start[0], block_length=config.block_length)
+        return _cached_attention(config, q, k_attn, v_attn, positions,
+                                 max_len)
 
-                n_rep = config.n_heads // config.n_kv_heads
-                # positions are uniform per batch row on the prefill path
-                # (mixed-start batches never reach here — see rope note
-                # above).
-                # 1-token dispatches (decode steps, a one-token chunk) stay
-                # dense: a block_q=1 kernel instance gains nothing and is a
-                # shape class TPU lowering never otherwise sees
-                attn = flash_attention_cached(
-                    q, _repeat_kv(k_attn, n_rep), _repeat_kv(v_attn, n_rep),
-                    start[0], block_length=config.block_length)
-            else:
-                attn = _cached_attention(config, q, k_attn, v_attn, positions,
-                                         max_len)
-            attn = attn.reshape(b, s, config.qkv_dim)
-            x_mid = x_in + proj(attn, lp["wo"], "wo")
-        with jax.named_scope("layer/mlp"):
-            h2 = rms_norm(x_mid, lp["mlp_norm_scale"], config.norm_eps)
-            out = x_mid + layer_mlp(config, h2, lp, proj, layer=layer)[0]
-        return out, (k_cache, v_cache, scales)
-
-    # python loop over layers: compiled once per bucket; exposes per-layer
-    # cache updates without scan-carry gymnastics
-    new_k, new_v, new_ks, new_vs = [], [], [], []
-    for layer in range(config.n_layers):
-        lp = layer_slice(params["layers"], layer)
-        x, (k_cache, v_cache, scales) = body(x, (layer, lp))
-        new_k.append(k_cache)
-        new_v.append(v_cache)
-        if scales is not None:
-            new_ks.append(scales[0])
-            new_vs.append(scales[1])
-
-    with jax.named_scope("head"):
-        if not all_logits:
-            # one row through the final norm and the head: the position the
-            # caller names, else the last dispatched one
+    x, _ = _serving_layers(config, params, x, cos, sin, attend, lora,
+                           adapter_ids)
+    if not all_logits:
+        # one row through the final norm and the head: the position the
+        # caller names, else the last dispatched one
+        with jax.named_scope("head"):
             x = x[:, -1:] if logits_at is None else \
                 jax.lax.dynamic_slice_in_dim(x, logits_at, 1, axis=1)
-        x = rms_norm(x, params["final_norm_scale"], config.norm_eps)
-        head = params.get("lm_head")
-        if head is None:
-            head = params["embedding"].T
-        logits = jnp.einsum("bse,ev->bsv", x, head,
-                            preferred_element_type=jnp.float32)
-    new_cache = {
-        "k": jnp.stack(new_k),
-        "v": jnp.stack(new_v),
-        "pos": cache["pos"] + s,
-    }
-    if new_ks:
-        new_cache["k_scale"] = jnp.stack(new_ks)
-        new_cache["v_scale"] = jnp.stack(new_vs)
-    return (logits if all_logits else logits[:, 0]), new_cache
+    logits = head_logits(config, params, x)
+    return (logits if all_logits else logits[:, 0]), \
+        _stacked_cache(new, cache["pos"] + s)
 
 
 class LLMEngine:

@@ -33,7 +33,7 @@ import numpy as np
 
 from ..chaos import FaultPoints, fire
 from ..config import mlconf
-from ..models.llama import LlamaConfig, Params
+from ..models.llama import LlamaConfig, Params, embed, head_logits
 from ..obs import (
     ADAPTER_LIVE,
     ADAPTER_LOADS,
@@ -57,13 +57,19 @@ from ..obs import (
     wall_now,
 )
 from ..obs.stats import nearest_rank
-from ..ops.norms import rms_norm
-from ..ops.rotary import apply_rope, rope_table
+from ..ops.rotary import rope_table
 from ..utils import logger
 from ..utils.profiler import annotate, named
 from ..utils.profiler import tick as profiler_tick
 from .canary import get_canary_router, split_key_for
-from .llm import _cached_attention, _forward_with_cache, init_kv_cache
+from .llm import (
+    _cached_attention,
+    _dense_kv_write,
+    _forward_with_cache,
+    _serving_layers,
+    _stacked_cache,
+    init_kv_cache,
+)
 from .samples import emit_sample, sampling_enabled
 from .resilience import (  # noqa: F401 - EngineStoppedError re-exported
     DeadlineExceeded,
@@ -91,89 +97,31 @@ def _decode_rowwise(config: LlamaConfig, params: Params, tokens: jax.Array,
     (A, B) factors from the stacked adapter bank by its [B] slot index
     (0 = base model / inactive rows), so a mixed-tenant batch decodes in
     one compiled program."""
-    from .llm import _lora_delta
-
-    b = tokens.shape[0]
     start = cache["pos"]                      # [B]
     positions = start[:, None]                # [B, 1]
-    rows = jnp.arange(b)
-    with jax.named_scope("embed"):
-        x = params["embedding"][tokens].astype(config.dtype)
+    rows = jnp.arange(tokens.shape[0])
+    x = embed(config, params, tokens)
     cos, sin = rope_table(positions, config.head_dim, config.rope_theta)
+    new = {name: [] for name in cache if name != "pos"}
 
-    new_k, new_v, new_ks, new_vs = [], [], [], []
-    for layer in range(config.n_layers):
-        lp = jax.tree_util.tree_map(lambda a: a[layer], params["layers"])
-        with jax.named_scope("layer/attn"):
-            h = rms_norm(x, lp["attn_norm_scale"], config.norm_eps)
+    def attend(layer, q, k, v):
+        # per-row scatter at each row's own position
+        k_attn, v_attn = _dense_kv_write(
+            config, cache, new, layer, k[:, 0], v[:, 0],
+            lambda buffer, token: buffer.at[rows, start].set(token))
+        return _cached_attention(config, q, k_attn, v_attn, positions,
+                                 cache["k"].shape[2])
 
-            def proj(h_in, w, t=None, _layer=layer):
-                out = jnp.einsum("bse,eh->bsh", h_in, w,
-                                 preferred_element_type=jnp.float32)
-                if lora is not None and t is not None and t in lora:
-                    out = out + _lora_delta(h_in, lora[t], _layer, adapter_ids)
-                return out.astype(x.dtype)
-
-            q = proj(h, lp["wq"], "wq").reshape(b, 1, config.n_heads,
-                                                config.head_dim)
-            k = proj(h, lp["wk"], "wk").reshape(b, 1, config.n_kv_heads,
-                                                config.head_dim)
-            v = proj(h, lp["wv"], "wv").reshape(b, 1, config.n_kv_heads,
-                                                config.head_dim)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-            quantized = "k_scale" in cache
-            if quantized:
-                from .llm import _dequantize_kv, _quantize_kv
-
-                kq, ks = _quantize_kv(k[:, 0])
-                vq, vs = _quantize_kv(v[:, 0])
-                k_cache = cache["k"][layer].at[rows, start].set(kq)
-                v_cache = cache["v"][layer].at[rows, start].set(vq)
-                k_scale = cache["k_scale"][layer].at[rows, start].set(ks)
-                v_scale = cache["v_scale"][layer].at[rows, start].set(vs)
-                k_attn = _dequantize_kv(k_cache, k_scale, config.dtype)
-                v_attn = _dequantize_kv(v_cache, v_scale, config.dtype)
-                new_ks.append(k_scale)
-                new_vs.append(v_scale)
-            else:
-                # per-row scatter at each row's own position
-                k_cache = cache["k"][layer].at[rows, start].set(
-                    k[:, 0].astype(cache["k"].dtype))
-                v_cache = cache["v"][layer].at[rows, start].set(
-                    v[:, 0].astype(cache["v"].dtype))
-                k_attn, v_attn = k_cache, v_cache
-            attn = _cached_attention(config, q, k_attn, v_attn, positions,
-                                     cache["k"].shape[2])
-            attn = attn.reshape(b, 1, config.qkv_dim)
-            x_mid = x + proj(attn, lp["wo"], "wo")
-        with jax.named_scope("layer/mlp"):
-            h2 = rms_norm(x_mid, lp["mlp_norm_scale"], config.norm_eps)
-            gate = proj(h2, lp["w_gate"], "w_gate")
-            up = proj(h2, lp["w_up"], "w_up")
-            x = x_mid + proj(jax.nn.silu(gate) * up, lp["w_down"], "w_down")
-        new_k.append(k_cache)
-        new_v.append(v_cache)
-
-    with jax.named_scope("head"):
-        x = rms_norm(x, params["final_norm_scale"], config.norm_eps)
-        head = params.get("lm_head")
-        if head is None:
-            head = params["embedding"].T
-        logits = jnp.einsum("bse,ev->bsv", x, head,
-                            preferred_element_type=jnp.float32)[:, 0]
+    x, _ = _serving_layers(config, params, x, cos, sin, attend, lora,
+                           adapter_ids)
+    logits = head_logits(config, params, x)[:, 0]
     if rng is None:
         next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     else:
         from .sampling import sample_logits
 
         next_token = sample_logits(logits, rng, temperature, top_k, top_p)
-    new_cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v),
-                 "pos": cache["pos"] + 1}
-    if new_ks:
-        new_cache["k_scale"] = jnp.stack(new_ks)
-        new_cache["v_scale"] = jnp.stack(new_vs)
-    return next_token, new_cache
+    return next_token, _stacked_cache(new, cache["pos"] + 1)
 
 
 def _verify_rowwise(config: LlamaConfig, params: Params, chunk: jax.Array,
@@ -196,87 +144,27 @@ def _verify_rowwise(config: LlamaConfig, params: Params, chunk: jax.Array,
     (same stale-entry argument); writes past ``max_len`` drop
     (``mode="drop"``) rather than clamp, so a row at the cache tail
     never has a garbage lane collide with its real last entry."""
-    from .llm import _lora_delta
-
     b, s = chunk.shape
     start = cache["pos"]                               # [B]
     positions = start[:, None] + jnp.arange(s)[None, :]  # [B, S]
     rows = jnp.arange(b)[:, None]                      # [B, 1]
-    with jax.named_scope("embed"):
-        x = params["embedding"][chunk].astype(config.dtype)
+    x = embed(config, params, chunk)
     cos, sin = rope_table(positions, config.head_dim, config.rope_theta)
+    new = {name: [] for name in cache if name != "pos"}
 
-    new_k, new_v, new_ks, new_vs = [], [], [], []
-    for layer in range(config.n_layers):
-        lp = jax.tree_util.tree_map(lambda a: a[layer], params["layers"])
-        with jax.named_scope("layer/attn"):
-            h = rms_norm(x, lp["attn_norm_scale"], config.norm_eps)
+    def attend(layer, q, k, v):
+        k_attn, v_attn = _dense_kv_write(
+            config, cache, new, layer, k, v,
+            lambda buffer, lanes: buffer.at[rows, positions].set(
+                lanes, mode="drop"))
+        return _cached_attention(config, q, k_attn, v_attn, positions,
+                                 cache["k"].shape[2])
 
-            def proj(h_in, w, t=None, _layer=layer):
-                out = jnp.einsum("bse,eh->bsh", h_in, w,
-                                 preferred_element_type=jnp.float32)
-                if lora is not None and t is not None and t in lora:
-                    out = out + _lora_delta(h_in, lora[t], _layer, adapter_ids)
-                return out.astype(x.dtype)
-
-            q = proj(h, lp["wq"], "wq").reshape(b, s, config.n_heads,
-                                                config.head_dim)
-            k = proj(h, lp["wk"], "wk").reshape(b, s, config.n_kv_heads,
-                                                config.head_dim)
-            v = proj(h, lp["wv"], "wv").reshape(b, s, config.n_kv_heads,
-                                                config.head_dim)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-            quantized = "k_scale" in cache
-            if quantized:
-                from .llm import _dequantize_kv, _quantize_kv
-
-                kq, ks = _quantize_kv(k)
-                vq, vs = _quantize_kv(v)
-                k_cache = cache["k"][layer].at[rows, positions].set(
-                    kq, mode="drop")
-                v_cache = cache["v"][layer].at[rows, positions].set(
-                    vq, mode="drop")
-                k_scale = cache["k_scale"][layer].at[rows, positions].set(
-                    ks, mode="drop")
-                v_scale = cache["v_scale"][layer].at[rows, positions].set(
-                    vs, mode="drop")
-                k_attn = _dequantize_kv(k_cache, k_scale, config.dtype)
-                v_attn = _dequantize_kv(v_cache, v_scale, config.dtype)
-                new_ks.append(k_scale)
-                new_vs.append(v_scale)
-            else:
-                k_cache = cache["k"][layer].at[rows, positions].set(
-                    k.astype(cache["k"].dtype), mode="drop")
-                v_cache = cache["v"][layer].at[rows, positions].set(
-                    v.astype(cache["v"].dtype), mode="drop")
-                k_attn, v_attn = k_cache, v_cache
-            attn = _cached_attention(config, q, k_attn, v_attn, positions,
-                                     cache["k"].shape[2])
-            attn = attn.reshape(b, s, config.qkv_dim)
-            x_mid = x + proj(attn, lp["wo"], "wo")
-        with jax.named_scope("layer/mlp"):
-            h2 = rms_norm(x_mid, lp["mlp_norm_scale"], config.norm_eps)
-            gate = proj(h2, lp["w_gate"], "w_gate")
-            up = proj(h2, lp["w_up"], "w_up")
-            x = x_mid + proj(jax.nn.silu(gate) * up, lp["w_down"], "w_down")
-        new_k.append(k_cache)
-        new_v.append(v_cache)
-
-    with jax.named_scope("head"):
-        x = rms_norm(x, params["final_norm_scale"], config.norm_eps)
-        head = params.get("lm_head")
-        if head is None:
-            head = params["embedding"].T
-        logits = jnp.einsum("bse,ev->bsv", x, head,
-                            preferred_element_type=jnp.float32)
+    x, _ = _serving_layers(config, params, x, cos, sin, attend, lora,
+                           adapter_ids)
+    logits = head_logits(config, params, x)
     verified = jnp.argmax(logits, axis=-1).astype(jnp.int32)   # [B, S]
-    new_cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v),
-                 "pos": cache["pos"]}
-    if new_ks:
-        new_cache["k_scale"] = jnp.stack(new_ks)
-        new_cache["v_scale"] = jnp.stack(new_vs)
-    return verified, new_cache
+    return verified, _stacked_cache(new, cache["pos"])
 
 
 # distinct `engine` label per instance on the shared gauges/counters
@@ -673,7 +561,7 @@ class ContinuousBatchingEngine:
         self._stats = {"requests": 0, "completed": 0, "ttft_sum": 0.0,
                        "tokens_out": 0, "shed": 0, "expired": 0,
                        "degraded": 0, "rejected_too_long": 0,
-                       "prefill_chunks": 0, "prefill_dispatches": 0,
+                       "prefill_chunks": 0,
                        "prefill_tokens_tick_max": 0,
                        "handoffs_out": 0, "handoff_bytes_out": 0,
                        "handoffs_in": 0, "handoff_bytes_in": 0,
@@ -1140,7 +1028,7 @@ class ContinuousBatchingEngine:
     # adapter activity in federated sums)
     _COUNTER_STATS = ("requests", "completed", "tokens_out", "shed",
                       "expired", "degraded", "rejected_too_long",
-                      "prefill_chunks", "prefill_dispatches",
+                      "prefill_chunks",
                       "prefix_queries", "prefix_hits",
                       "prefix_evictions", "prefix_cached_tokens",
                       "handoffs_out", "handoff_bytes_out", "handoffs_in",
@@ -1952,8 +1840,6 @@ class ContinuousBatchingEngine:
         the paged engine routes prefix-hit admissions through the merged
         paged-prefill kernel (``prefix_kv``) so the cached prefix is
         attended in place instead of gathered."""
-        with self._lock:
-            self._stats["prefill_dispatches"] += 1
         # an absent keyword, not a None: one compiled program a shape
         hit_kw = {} if prefix_kv is None else {"prefix_kv": prefix_kv}
         return self._prefill(self.params, tokens, adm.small,

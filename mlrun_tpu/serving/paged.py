@@ -55,12 +55,17 @@ import numpy as np
 
 from ..chaos import FaultPoints, fire
 from ..config import mlconf
-from ..models.llama import LlamaConfig, layer_mlp, layer_slice, qk_normed
+from ..models.llama import LlamaConfig, embed, head_logits
 from ..obs import KV_TIER_BYTES, KV_TIER_EVENTS, KV_TIER_HITS, wall_now
 from ..utils import logger
 from ..utils.profiler import annotate, named
 from .kv_tier import HostKVTier
-from .llm import _forward_with_cache, init_kv_cache
+from .llm import (
+    _forward_with_cache,
+    _kv_rows,
+    _serving_layers,
+    init_kv_cache,
+)
 from .llm_batch import (
     BlockDecodingError,
     ContinuousBatchingEngine,
@@ -169,6 +174,23 @@ def _write_token_all_layers(pool: dict, k_tok, v_tok, page_table, pos,
     return out
 
 
+def _pool_write(pool: dict, layer: int, pid_safe, offset, k, v) -> dict:
+    """Layer ``layer``'s K and V into ``pool`` (a dict the caller owns,
+    updated in place) at pages ``pid_safe`` and offsets ``offset``, before
+    the layer's kernel reads them: quantised per vector on an int8 pool.
+    Returns the rows as written ({"k", "v"[, "k_scale", "v_scale"]})."""
+    rows = _kv_rows(pool, k, v)
+    for name, row in rows.items():
+        pool[name] = pool[name].at[layer, pid_safe, offset].set(row)
+    return rows
+
+
+def _pool_scales(pool: dict) -> dict:
+    """The int8 pool's scales as the paged kernels' keyword arguments."""
+    return {name: pool[name] for name in ("k_scale", "v_scale")
+            if name in pool}
+
+
 def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
                           attn_impl: str, params,
                           tokens: jax.Array, pool: dict,
@@ -202,19 +224,16 @@ def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
     tokens [slots, 1]; pos [slots] absolute positions.
     Returns (next_token, new_pool, new_pos).
     """
-    from ..ops.norms import rms_norm
     from ..ops.paged_attention import paged_attention
-    from ..ops.rotary import apply_rope, rope_table
-    from .llm import _cached_attention, _lora_delta, _quantize_kv
+    from ..ops.rotary import rope_table
+    from .llm import _cached_attention, _quantize_kv
     from .sampling import sample_logits
 
-    b = tokens.shape[0]
     positions = pos[:, None]
-    rows = jnp.arange(b)
+    rows = jnp.arange(tokens.shape[0])
     safe_table = jnp.maximum(page_table, 0)            # [slots, pages]
     live = page_table[:, :1] >= 0       # [slots, 1]: the row holds a request
-    with jax.named_scope("embed"):
-        x = params["embedding"][tokens].astype(config.dtype)
+    x = embed(config, params, tokens)
     cos, sin = rope_table(positions, config.head_dim, config.rope_theta)
     quantized = "k_scale" in pool
     use_kernel = attn_impl == "kernel"
@@ -226,101 +245,55 @@ def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
                                   axis=1)[:, 0]
         pid_safe = jnp.where(pid >= 0, pid, scratch)
         pool = dict(pool)
-
     k_new, v_new = [], []
-    for layer in range(config.n_layers):
-        lp = layer_slice(params["layers"], layer)
-        with jax.named_scope("layer/attn"):
-            h = rms_norm(x, lp["attn_norm_scale"], config.norm_eps)
 
-            def proj(h_in, w, t=None, _layer=layer):
-                out = jnp.einsum("bse,eh->bsh", h_in, w,
-                                 preferred_element_type=jnp.float32)
-                if lora is not None and t is not None and t in lora:
-                    out = out + _lora_delta(h_in, lora[t], _layer, adapter_ids)
-                return out.astype(x.dtype)
+    def attend_kernel(layer, q, k, v):
+        # token KV lands in the pool first (unmapped slots route to
+        # the never-read scratch page), then the kernel attends
+        # pool-side via the page table — no dense view, no gather.
+        # int8 pools quantize the token per vector on the way in and
+        # the kernel dequantizes in-register (scales ride
+        # page-table-indexed operands)
+        _pool_write(pool, layer, pid_safe, offset, k[:, 0], v[:, 0])
+        # the kernel takes the pool as stored and the layer's
+        # index: a sliced layer would be a copy per call
+        return paged_attention(
+            q[:, 0], pool["k"], pool["v"], layer, page_table,
+            pos, page_size=page_size, impl="kernel",
+            **_pool_scales(pool))[:, None]
 
-            q = proj(h, lp["wq"], "wq").reshape(b, 1, config.n_heads,
-                                                config.head_dim)
-            k = proj(h, lp["wk"], "wk").reshape(b, 1, config.n_kv_heads,
-                                                config.head_dim)
-            v = proj(h, lp["wv"], "wv").reshape(b, 1, config.n_kv_heads,
-                                                config.head_dim)
-            q, k = qk_normed(config, q, k, lp)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
+    def attend_reference(layer, q, k, v):
+        # dense per-layer view of this slot's pages (dequantized)
+        kp = jnp.take(pool["k"][layer], safe_table, axis=0)
+        vp = jnp.take(pool["v"][layer], safe_table, axis=0)
+        s_, p_, ps_, hh, dd = kp.shape
+        kd = kp.reshape(s_, p_ * ps_, hh, dd)
+        vd = vp.reshape(s_, p_ * ps_, hh, dd)
+        if quantized:
+            ksc = jnp.take(pool["k_scale"][layer], safe_table,
+                           axis=0).reshape(s_, p_ * ps_, hh)
+            vsc = jnp.take(pool["v_scale"][layer], safe_table,
+                           axis=0).reshape(s_, p_ * ps_, hh)
+            kd = (kd.astype(jnp.float32) * ksc[..., None]).astype(
+                config.dtype)
+            vd = (vd.astype(jnp.float32) * vsc[..., None]).astype(
+                config.dtype)
+        else:
+            kd = kd.astype(config.dtype)
+            vd = vd.astype(config.dtype)
+        # splice the new token into the dense view at each slot's
+        # position
+        kd = kd.at[rows, pos].set(k[:, 0])
+        vd = vd.at[rows, pos].set(v[:, 0])
+        k_new.append(k[:, 0])
+        v_new.append(v[:, 0])
+        return _cached_attention(config, q, kd, vd, positions, kd.shape[1])
 
-            if use_kernel:
-                # token KV lands in the pool first (unmapped slots route to
-                # the never-read scratch page), then the kernel attends
-                # pool-side via the page table — no dense view, no gather.
-                # int8 pools quantize the token per vector on the way in and
-                # the kernel dequantizes in-register (scales ride
-                # page-table-indexed operands)
-                scales_kw = {}
-                if quantized:
-                    kq_, ks_ = _quantize_kv(k[:, 0])
-                    vq_, vs_ = _quantize_kv(v[:, 0])
-                    pool["k"] = pool["k"].at[layer, pid_safe, offset].set(kq_)
-                    pool["v"] = pool["v"].at[layer, pid_safe, offset].set(vq_)
-                    pool["k_scale"] = pool["k_scale"].at[
-                        layer, pid_safe, offset].set(ks_)
-                    pool["v_scale"] = pool["v_scale"].at[
-                        layer, pid_safe, offset].set(vs_)
-                    scales_kw = {"k_scale": pool["k_scale"],
-                                 "v_scale": pool["v_scale"]}
-                else:
-                    pool["k"] = pool["k"].at[layer, pid_safe, offset].set(
-                        k[:, 0].astype(pool["k"].dtype))
-                    pool["v"] = pool["v"].at[layer, pid_safe, offset].set(
-                        v[:, 0].astype(pool["v"].dtype))
-                # the kernel takes the pool as stored and the layer's
-                # index: a sliced layer would be a copy per call
-                attn = paged_attention(
-                    q[:, 0], pool["k"], pool["v"], layer, page_table,
-                    pos, page_size=page_size, impl="kernel",
-                    **scales_kw)[:, None]
-            else:
-                # dense per-layer view of this slot's pages (dequantized)
-                kp = jnp.take(pool["k"][layer], safe_table, axis=0)
-                vp = jnp.take(pool["v"][layer], safe_table, axis=0)
-                s_, p_, ps_, hh, dd = kp.shape
-                kd = kp.reshape(s_, p_ * ps_, hh, dd)
-                vd = vp.reshape(s_, p_ * ps_, hh, dd)
-                if quantized:
-                    ksc = jnp.take(pool["k_scale"][layer], safe_table,
-                                   axis=0).reshape(s_, p_ * ps_, hh)
-                    vsc = jnp.take(pool["v_scale"][layer], safe_table,
-                                   axis=0).reshape(s_, p_ * ps_, hh)
-                    kd = (kd.astype(jnp.float32) * ksc[..., None]).astype(
-                        config.dtype)
-                    vd = (vd.astype(jnp.float32) * vsc[..., None]).astype(
-                        config.dtype)
-                else:
-                    kd = kd.astype(config.dtype)
-                    vd = vd.astype(config.dtype)
-                # splice the new token into the dense view at each slot's
-                # position
-                kd = kd.at[rows, pos].set(k[:, 0])
-                vd = vd.at[rows, pos].set(v[:, 0])
-                attn = _cached_attention(config, q, kd, vd, positions,
-                                         kd.shape[1])
-                k_new.append(k[:, 0])
-                v_new.append(v[:, 0])
-            attn = attn.reshape(b, 1, config.qkv_dim)
-            x_mid = x + proj(attn, lp["wo"], "wo")
-        with jax.named_scope("layer/mlp"):
-            h2 = rms_norm(x_mid, lp["mlp_norm_scale"], config.norm_eps)
-            x = x_mid + layer_mlp(config, h2, lp, proj, live=live,
-                                  layer=layer)[0]
-
-    with jax.named_scope("head"):
-        x = rms_norm(x, params["final_norm_scale"], config.norm_eps)
-        head = params.get("lm_head")
-        if head is None:
-            head = params["embedding"].T
-        logits = jnp.einsum("bse,ev->bsv", x, head,
-                            preferred_element_type=jnp.float32)[:, 0]
+    x, _ = _serving_layers(
+        config, params, x, cos, sin,
+        attend_kernel if use_kernel else attend_reference, lora,
+        adapter_ids, live=live)
+    logits = head_logits(config, params, x)[:, 0]
     if rng is None:
         next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     else:
@@ -396,10 +369,9 @@ def _verify_rowwise_paged(config: LlamaConfig, page_size: int,
 
     Returns (verified [slots, S] int32, new_pool).
     """
-    from ..ops.norms import rms_norm
     from ..ops.paged_attention import paged_verify_attention
-    from ..ops.rotary import apply_rope, rope_table
-    from .llm import _dequantize_kv, _lora_delta, _quantize_kv
+    from ..ops.rotary import rope_table
+    from .llm import _dequantize_kv
 
     b, s = chunk.shape
     pps = page_table.shape[1]
@@ -407,11 +379,8 @@ def _verify_rowwise_paged(config: LlamaConfig, page_size: int,
     live = jnp.broadcast_to(page_table[:, :1] >= 0, (b, s))
     if masked is not None:
         chunk = jnp.where(masked, config.mask_token_id, chunk)
-    loads = []
-    with jax.named_scope("embed"):
-        x = params["embedding"][chunk].astype(config.dtype)
+    x = embed(config, params, chunk)
     cos, sin = rope_table(positions, config.head_dim, config.rope_theta)
-    quantized = "k_scale" in pool
     use_kernel = attn_impl == "kernel"
     scratch = pool["k"].shape[1] - 1
     page_idx = positions // page_size
@@ -425,77 +394,29 @@ def _verify_rowwise_paged(config: LlamaConfig, page_size: int,
     pid_safe = jnp.where((pid >= 0) & (page_idx < pps), pid, scratch)
     pool = dict(pool)
 
-    for layer in range(config.n_layers):
-        lp = layer_slice(params["layers"], layer)
-        with jax.named_scope("layer/attn"):
-            h = rms_norm(x, lp["attn_norm_scale"], config.norm_eps)
+    def attend(layer, q, k, v):
+        written = _pool_write(pool, layer, pid_safe, offset, k, v)
+        if use_kernel and "k_scale" in written:
+            # the kernel's local chunk part must see the SAME bits a
+            # later decode tick reads back from the int8 pool
+            chunk_k = _dequantize_kv(written["k"], written["k_scale"],
+                                     config.dtype)
+            chunk_v = _dequantize_kv(written["v"], written["v_scale"],
+                                     config.dtype)
+        else:
+            # reference decode splices the RAW token KV into its
+            # dequantized view — the verify fallback matches it
+            chunk_k, chunk_v = k, v
+        return paged_verify_attention(
+            q, chunk_k, chunk_v, pool["k"], pool["v"], layer,
+            page_table, pos, page_size=page_size,
+            impl="kernel" if use_kernel else "reference",
+            block_length=config.block_length,
+            **_pool_scales(pool)).astype(q.dtype)
 
-            def proj(h_in, w, t=None, _layer=layer):
-                out = jnp.einsum("bse,eh->bsh", h_in, w,
-                                 preferred_element_type=jnp.float32)
-                if lora is not None and t is not None and t in lora:
-                    out = out + _lora_delta(h_in, lora[t], _layer, adapter_ids)
-                return out.astype(x.dtype)
-
-            q = proj(h, lp["wq"], "wq").reshape(b, s, config.n_heads,
-                                                config.head_dim)
-            k = proj(h, lp["wk"], "wk").reshape(b, s, config.n_kv_heads,
-                                                config.head_dim)
-            v = proj(h, lp["wv"], "wv").reshape(b, s, config.n_kv_heads,
-                                                config.head_dim)
-            q, k = qk_normed(config, q, k, lp)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-
-            scales_kw = {}
-            if quantized:
-                kq_, ks_ = _quantize_kv(k)
-                vq_, vs_ = _quantize_kv(v)
-                pool["k"] = pool["k"].at[layer, pid_safe, offset].set(kq_)
-                pool["v"] = pool["v"].at[layer, pid_safe, offset].set(vq_)
-                pool["k_scale"] = pool["k_scale"].at[
-                    layer, pid_safe, offset].set(ks_)
-                pool["v_scale"] = pool["v_scale"].at[
-                    layer, pid_safe, offset].set(vs_)
-                scales_kw = {"k_scale": pool["k_scale"],
-                             "v_scale": pool["v_scale"]}
-                if use_kernel:
-                    # the kernel's local chunk part must see the SAME bits a
-                    # later decode tick reads back from the int8 pool
-                    chunk_k = _dequantize_kv(kq_, ks_, config.dtype)
-                    chunk_v = _dequantize_kv(vq_, vs_, config.dtype)
-                else:
-                    # reference decode splices the RAW token KV into its
-                    # dequantized view — the verify fallback matches it
-                    chunk_k, chunk_v = k, v
-            else:
-                pool["k"] = pool["k"].at[layer, pid_safe, offset].set(
-                    k.astype(pool["k"].dtype))
-                pool["v"] = pool["v"].at[layer, pid_safe, offset].set(
-                    v.astype(pool["v"].dtype))
-                chunk_k, chunk_v = k, v
-            attn = paged_verify_attention(
-                q, chunk_k, chunk_v, pool["k"], pool["v"], layer,
-                page_table, pos, page_size=page_size,
-                impl="kernel" if use_kernel else "reference",
-                block_length=config.block_length, **scales_kw)
-            attn = attn.astype(x.dtype).reshape(b, s, config.qkv_dim)
-            x_mid = x + proj(attn, lp["wo"], "wo")
-        with jax.named_scope("layer/mlp"):
-            h2 = rms_norm(x_mid, lp["mlp_norm_scale"], config.norm_eps)
-            out, load = layer_mlp(config, h2, lp, proj, live=live,
-                                  layer=layer)
-            x = x_mid + out
-            if load is not None:
-                loads.append(load)
-
-    with jax.named_scope("head"):
-        x = rms_norm(x, params["final_norm_scale"], config.norm_eps)
-        head = params.get("lm_head")
-        if head is None:
-            head = params["embedding"].T
-        logits = jnp.einsum("bse,ev->bsv", x, head,
-                            preferred_element_type=jnp.float32)
+    x, loads = _serving_layers(config, params, x, cos, sin, attend, lora,
+                               adapter_ids, live=live)
+    logits = head_logits(config, params, x)
     verified = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     if masked is None:
         return verified, pool

@@ -1,9 +1,10 @@
 """CPU-mesh attention-kernel comparison at growing sequence lengths
-(interpret mode, no chip involved): our pallas flash kernels vs the
-plain XLA reference at seq 2k/8k/32k, plus the VMEM-footprint model that
-documents the v1 full-KV-in-VMEM scaling wall and why the production
-path (flash_attention_mlt / the `attention` dispatcher) rides the
-grid-pipelined v2 kernel instead. A `paged_decode` row compares the
+(interpret mode, no chip involved): our grid-pipelined pallas flash
+kernel (the production path: flash_attention_mlt / the `attention`
+dispatcher) vs the plain XLA reference at seq 2k/8k/32k, plus its
+VMEM-footprint model (the v1 kernel that kept the full KV in VMEM, and
+its rows in older BENCH_ATTN_CPU.json files, are gone since PR 32). A
+`paged_decode` row compares the
 serving engines' page-table-indexed decode kernel
 (ops/paged_attention.py) against the gather+dense view it replaces,
 including the per-tick HBM-bytes model of the eliminated gather.
@@ -12,8 +13,7 @@ On CPU, pallas runs in INTERPRET mode — wall-clock there measures the
 interpreter, not the TPU kernel, so the numbers reported are:
 - correctness (max |err| vs reference) per kernel per seq;
 - XLA-reference wall-clock (a real CPU number, the baseline curve);
-- the analytic per-program VMEM bytes for v1 vs v2 against the ~16MB/core
-  budget — the actual scaling-wall evidence;
+- the analytic per-program VMEM bytes against the ~16MB/core budget;
 - the analytic per-decode-tick HBM bytes for gather-view vs paged kernel.
 
 Writes one JSON line per row and a summary file (BENCH_ATTN_CPU.json) —
@@ -44,7 +44,6 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from mlrun_tpu.ops.attention import (  # noqa: E402
-    _flash_fwd,
     _flash_fwd_v2,
     _repeat_kv,
     attention_reference,
@@ -53,14 +52,10 @@ from mlrun_tpu.ops.attention import (  # noqa: E402
 VMEM_BUDGET = 16 * 1024 * 1024  # bytes/core (v4/v5 class)
 
 
-def vmem_model(seq_k: int, d: int, block_q: int, block_k: int,
-               kernel: str, dtype_bytes: int = 4) -> int:
-    """Per-program VMEM bytes (inputs+outputs+scratch the kernel holds)."""
-    if kernel == "v1":
-        # q block + FULL kv + o block + lse block
-        return dtype_bytes * (block_q * d + 2 * seq_k * d
-                              + block_q * d + block_q * 8)
-    # v2: q block + one kv block tile + o/lse + scratch (m/l/acc)
+def vmem_model(d: int, block_q: int, block_k: int,
+               dtype_bytes: int = 4) -> int:
+    """Per-program VMEM bytes (inputs+outputs+scratch the kernel holds):
+    q block + one kv block tile + o/lse + scratch (m/l/acc)."""
     return dtype_bytes * (block_q * d + 2 * block_k * d + block_q * d
                           + block_q * 8 + block_q * (2 + d))
 
@@ -80,12 +75,12 @@ def timeit(fn, *args, reps: int = 3) -> float:
 def run():
     rows = []
     cases = [
-        # (seq, batch, q_heads, kv_heads, d, run_v1, run_v2)
-        (2048, 1, 4, 2, 64, True, True),
-        (8192, 1, 2, 1, 64, True, True),
-        (32768, 1, 1, 1, 64, False, True),  # v1 interpret too slow here
+        # (seq, batch, q_heads, kv_heads, d)
+        (2048, 1, 4, 2, 64),
+        (8192, 1, 2, 1, 64),
+        (32768, 1, 1, 1, 64),
     ]
-    for seq, b, h, hkv, d, run_v1, run_v2 in cases:
+    for seq, b, h, hkv, d in cases:
         key = jax.random.PRNGKey(seq)
         kq, kk, kv_ = jax.random.split(key, 3)
         q = jax.random.normal(kq, (b, seq, h, d), jnp.float32) * 0.3
@@ -96,29 +91,20 @@ def run():
         n_rep = h // hkv
         kr, vr = _repeat_kv(k, n_rep), _repeat_kv(v, n_rep)
 
-        for name, fn, enabled, bq, bk in (
-                ("flash_v1", _flash_fwd, run_v1, 256, 256),
-                ("flash_v2", _flash_fwd_v2, run_v2, 512, 512)):
-            bytes_needed = vmem_model(seq, d, bq, bk,
-                                      "v1" if name == "flash_v1" else "v2")
-            row = {
-                "kernel": name, "seq": seq, "heads": h, "d": d,
-                "vmem_bytes_per_program": bytes_needed,
-                "fits_vmem_budget": bytes_needed < VMEM_BUDGET,
-                "ref_xla_cpu_ms": round(ref_ms, 2),
-            }
-            if enabled:
-                start = time.perf_counter()
-                out, _ = fn(q, kr, vr, causal=True, interpret=True)
-                out.block_until_ready()
-                row["interpret_s"] = round(time.perf_counter() - start, 2)
-                row["max_err_vs_reference"] = float(
-                    jnp.max(jnp.abs(out - ref)))
-            else:
-                row["skipped"] = "interpret-mode cost; correctness " \
-                    "covered at shorter seqs, VMEM model still applies"
-            rows.append(row)
-            print(json.dumps(row))
+        bytes_needed = vmem_model(d, 512, 512)
+        row = {
+            "kernel": "flash_v2", "seq": seq, "heads": h, "d": d,
+            "vmem_bytes_per_program": bytes_needed,
+            "fits_vmem_budget": bytes_needed < VMEM_BUDGET,
+            "ref_xla_cpu_ms": round(ref_ms, 2),
+        }
+        start = time.perf_counter()
+        out, _ = _flash_fwd_v2(q, kr, vr, causal=True, interpret=True)
+        out.block_until_ready()
+        row["interpret_s"] = round(time.perf_counter() - start, 2)
+        row["max_err_vs_reference"] = float(jnp.max(jnp.abs(out - ref)))
+        rows.append(row)
+        print(json.dumps(row))
 
     # -- paged decode: the serving hot path ---------------------------------
     # one decode token per slot against a KV page pool, kernel (page-table
@@ -289,16 +275,11 @@ def run():
     rows.append(row)
     print(json.dumps(row))
 
-    # the scaling wall, stated plainly: the longest seq the v1 kernel can
-    # serve from VMEM at production head dim (128) vs v2's flat footprint
-    d_prod = 128
-    wall = next(s for s in (2048, 4096, 8192, 16384, 32768, 65536)
-                if vmem_model(s, d_prod, 256, 256, "v1") >= VMEM_BUDGET)
     summary = {
         "metric": "attention_kernel_comparison_cpu",
         "rows": rows,
-        "v1_vmem_wall_seq_at_d128": wall,
-        "v2_vmem_bytes_flat_d128": vmem_model(0, d_prod, 512, 512, "v2"),
+        # flat in the sequence length, at production head dim
+        "v2_vmem_bytes_flat_d128": vmem_model(128, 512, 512),
         "production_path": "flash_attention_mlt -> _flash_fwd_v2 "
                            "(grid-pipelined; KV streamed per block, "
                            "seq bounded by HBM not VMEM)",

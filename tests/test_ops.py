@@ -9,7 +9,7 @@ import pytest
 
 from mlrun_tpu.ops import attention_reference, rms_norm
 from mlrun_tpu.ops.attention import (
-    _flash_fwd,
+    _flash_fwd_v2,
     _flash_mlt_bwd,
     _flash_mlt_fwd,
     _repeat_kv,
@@ -28,8 +28,8 @@ def test_flash_kernel_matches_reference(qkv):
     q, k, v = qkv
     ref = attention_reference(q, k, v, causal=True)
     kk, vv = _repeat_kv(k, 2), _repeat_kv(v, 2)
-    o, _ = _flash_fwd(q, kk, vv, causal=True, interpret=True,
-                      block_q=128, block_k=128)
+    o, _ = _flash_fwd_v2(q, kk, vv, causal=True, interpret=True,
+                         block_q=128, block_k=128)
     assert float(jnp.max(jnp.abs(o - ref))) < 2e-5
 
 
@@ -37,8 +37,8 @@ def test_flash_kernel_noncausal(qkv):
     q, k, v = qkv
     ref = attention_reference(q, k, v, causal=False)
     kk, vv = _repeat_kv(k, 2), _repeat_kv(v, 2)
-    o, _ = _flash_fwd(q, kk, vv, causal=False, interpret=True,
-                      block_q=128, block_k=128)
+    o, _ = _flash_fwd_v2(q, kk, vv, causal=False, interpret=True,
+                         block_q=128, block_k=128)
     assert float(jnp.max(jnp.abs(o - ref))) < 2e-5
 
 
