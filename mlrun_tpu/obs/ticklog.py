@@ -29,24 +29,33 @@ KEPT_LOGS = 16
 FIELDS = ("n", "t0", "t_admit", "t_built", "t_dispatched", "t_fetched",
           "t1", "admit_wait_s", "rows", "ctx_tokens", "prefill_tokens",
           "kind", "positions", "tokens_out", "commit_rows", "expert_pairs",
-          "experts_touched", "expert_load_max")
+          "experts_touched", "expert_load_max", "lookahead")
 
 
 class TickRecord:
     """One scheduler iteration.
 
     ``t0`` iteration start; ``t_admit`` expiry, control and admission done;
-    ``t_built`` the decode tick's host inputs built and uploaded;
-    ``t_dispatched`` the decode program enqueued; ``t_fetched`` its tokens
-    on the host; ``t1`` the commit loop done. ``admit_wait_s`` is the part
-    of ``[t0, t_admit]`` spent blocked on a device result (a prefill's
-    first-token fetch). ``rows`` live rows of the decode tick (0 where the
-    iteration only admitted), ``ctx_tokens`` the tokens those rows attend
-    (prompt + generated so far, summed), ``prefill_tokens`` prompt tokens
-    prefilled in this iteration, ``kind`` ``plain``, ``spec`` or
-    ``denoise``. ``tokens_out`` is what the tick yielded over all rows: one
-    a row (plain), the tokens a speculative round emitted, the positions a
-    denoising pass unmasked (none in a row whose pass committed its block).
+    ``t_built`` the host inputs of the decode tick dispatched in this
+    iteration built and uploaded; ``t_dispatched`` that program enqueued;
+    ``t_fetched`` the tokens the iteration waited for on the host; ``t1``
+    the commit loop done. ``admit_wait_s`` is the part of ``[t0, t_admit]``
+    spent blocked on a device result (a prefill's first-token fetch, a
+    drain). ``rows`` rows of the decode tick dispatched (0 where the
+    iteration only admitted, or only read a tick), ``ctx_tokens`` the tokens
+    those rows attend (prompt + generated so far, summed),
+    ``prefill_tokens`` prompt tokens prefilled in this iteration, ``kind``
+    ``plain``, ``spec`` or ``denoise``. ``tokens_out`` is what the iteration
+    committed over all rows: one a row (plain), the tokens a speculative
+    round emitted, the positions a denoising pass unmasked (none in a row
+    whose pass committed its block).
+
+    The paged engine's plain tick looks one tick ahead (docs/serving.md
+    "The scheduler's iteration"): the fetch and the commit of an iteration
+    are those of the tick dispatched in the iteration before, and
+    ``lookahead`` is 1 where this iteration's dispatch overlapped that tick
+    in flight. An iteration that only reads the tick in flight has no
+    ``rows`` and its ``tokens_out``.
 
     A ``denoise`` tick (a pass of a block-diffusion model,
     docs/serving.md "Block-diffusion decoding") also fills ``positions``
@@ -71,6 +80,7 @@ class TickRecord:
         self.rows = self.ctx_tokens = self.prefill_tokens = 0
         self.positions = self.tokens_out = self.commit_rows = 0
         self.expert_pairs = self.experts_touched = self.expert_load_max = 0
+        self.lookahead = 0
         self.kind = "plain"
         self.t0 = t0
         self.admitted(t0)

@@ -926,7 +926,8 @@ class ContinuousBatchingEngine:
             self._stats["spec_accepted"] += accepted_total
             self._stats["spec_rejected"] += proposed_total - accepted_total
             self._stats["spec_tokens"] += tokens_total
-        self._tick.tokens_out = emitted_total
+        # beside what a drained plain tick committed in this iteration
+        self._tick.tokens_out += emitted_total
         for i in finished:
             self._finish(i)
         return len(active)
@@ -1619,6 +1620,7 @@ class ContinuousBatchingEngine:
         a decode slot. The paged engine's `_complete_storage` already
         registered the prompt blocks, so the prefix stays cache-resident
         here for the next request sharing it."""
+        self._drain_tick(admitting=True)    # the export reads the pool
         if adm.ledger is not None:
             # the slot-cache trim/serialize below is the prefill-side
             # handoff cost; the ledger closes here and rides the payload
@@ -1820,6 +1822,7 @@ class ContinuousBatchingEngine:
             return True
         # from here the scheduler waits for the prefill on the device
         waited = time.perf_counter()
+        self._await_tick()
         if sampling_enabled():
             # monitoring tap: first-token top1-top2 logit gap (a cheap
             # model-confidence proxy for the drift analyzer's "logit
@@ -2181,6 +2184,12 @@ class ContinuousBatchingEngine:
         if self.block_length > 1:
             return self._denoise_tick(active)
         if self._spec_tick_viable(active):
+            # a round drafts from the committed tokens: a plain tick in
+            # flight is read first, and may end rows
+            self._drain_tick()
+            active = [i for i in active if self._slot_state[i].active]
+            if not active:
+                return 0
             done = self._spec_decode_tick(active)
             if done is not None:
                 return done
@@ -2305,6 +2314,17 @@ class ContinuousBatchingEngine:
         unsafe by construction; docs/serving.md "Hierarchical KV").
         Base engine: nothing."""
 
+    def _drain_tick(self, admitting: bool = False):
+        """Read and commit a plain tick that was dispatched ahead of its
+        predecessor's commit (hook: the paged engine's plain tick looks
+        one tick ahead; this engine's is synchronous and has none in
+        flight)."""
+
+    def _await_tick(self):
+        """The scheduler is about to block on a prefill (hook: the paged
+        engine first reads the tick in flight, which the device runs
+        before it)."""
+
     def _count_attention_tick(self):
         """Per-tick counters of the engine's attention path, taken under
         the lock the tick's bookkeeping holds (hook: the paged engine
@@ -2341,7 +2361,7 @@ class ContinuousBatchingEngine:
         # (captured BEFORE the tick — finished rows are reset inside it)
         tick_adapters = {s.adapter for s in self._slot_state if s.active}
         tick.rows = self._decode_tick()
-        if not tick.rows and not tick.prefill_tokens:
+        if not (tick.rows or tick.prefill_tokens or tick.tokens_out):
             return 0                        # an idle poll writes nothing
         tick.t1 = time.perf_counter()
         elapsed = tick.t1 - tick.t0

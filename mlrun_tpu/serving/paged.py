@@ -48,6 +48,8 @@ import queue
 import time
 from collections import deque
 from concurrent.futures import Future
+from dataclasses import dataclass
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -69,6 +71,7 @@ from .llm import (
 from .llm_batch import (
     BlockDecodingError,
     ContinuousBatchingEngine,
+    EngineStoppedError,
     KVHandoff,
     _Admission,
 )
@@ -198,7 +201,9 @@ def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
                           rng: jax.Array = None,
                           temperature: jax.Array = None,
                           top_k: jax.Array = None, top_p: jax.Array = None,
-                          lora=None, adapter_ids: jax.Array = None):
+                          lora=None, adapter_ids: jax.Array = None,
+                          prev_token: jax.Array = None,
+                          from_prev: jax.Array = None):
     """One decode token per slot against the page pool.
 
     ``attn_impl="reference"``: per layer, gather the slot's pages into a
@@ -221,7 +226,10 @@ def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
     the dense ``_decode_rowwise`` (docs/serving.md "Multi-tenant LoRA"):
     each slot gathers its own (A, B) bank factors by adapter slot index.
 
-    tokens [slots, 1]; pos [slots] absolute positions.
+    tokens [slots, 1]; pos [slots] absolute positions. ``prev_token``
+    [slots] with ``from_prev`` [slots] bool: a row marked there takes its
+    input token from ``prev_token`` (the last tick's ``next_token``, which
+    may still be on its way to the host) and not from ``tokens``.
     Returns (next_token, new_pool, new_pos).
     """
     from ..ops.paged_attention import paged_attention
@@ -229,6 +237,8 @@ def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
     from .llm import _cached_attention, _quantize_kv
     from .sampling import sample_logits
 
+    if prev_token is not None:
+        tokens = jnp.where(from_prev[:, None], prev_token[:, None], tokens)
     positions = pos[:, None]
     rows = jnp.arange(tokens.shape[0])
     safe_table = jnp.maximum(page_table, 0)            # [slots, pages]
@@ -437,6 +447,21 @@ def _verify_rowwise_paged(config: LlamaConfig, page_size: int,
     return packed, pool
 
 
+@dataclass
+class _TickInFlight:
+    """A plain decode tick that was dispatched and is not yet read:
+    ``rows`` the slots whose next token it yields (a row whose request
+    turns out to have ended before it leaves the list: its token is thrown
+    away), ``next_token`` [slots] on the device, ``host`` the same once
+    fetched, ``rng`` the engine's key as it was before this tick drew from
+    it (None: every row was greedy)."""
+
+    rows: list
+    next_token: jax.Array
+    rng: Optional[jax.Array] = None
+    host: Optional[np.ndarray] = None
+
+
 class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
     """Continuous batching over a paged KV pool.
 
@@ -563,6 +588,10 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         self._pos = np.zeros((slots,), np.int32)
         self._free_pages: deque = deque(range(self.n_pages))
         self._slot_pages: dict[int, list] = {}
+        # the plain tick looks one tick ahead (docs/serving.md "The
+        # scheduler's iteration"): the tick dispatched and not yet read
+        self._in_flight: Optional[_TickInFlight] = None
+        self._no_tokens = jnp.zeros((slots,), jnp.int32)
         # HBM bytes the gather path would copy per decode tick (the dense
         # k+v view of every slot, per layer) — what the kernel path avoids
         self._gather_bytes_per_tick = sum(
@@ -571,6 +600,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             for name, arr in self._pool.items() if name in ("k", "v"))
         self._stats.update({"attn_kernel_ticks": 0, "attn_gather_ticks": 0,
                             "attn_hbm_bytes_avoided": 0,
+                            "lookahead_ticks": 0, "lookahead_drains": 0,
                             "prefill_kernel_chunks": 0,
                             "prefill_gather_admissions": 0,
                             "kv_demotes": 0, "kv_demoted_pages": 0,
@@ -672,6 +702,10 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                         block_length=self.block_length,
                         warmup_s=round(time.perf_counter() - started, 2))
             return
+        # as the tick dispatches them: each row's input token is the
+        # host's or the last tick's, still on the device
+        decode_kw.update(prev_token=self._no_tokens,
+                         from_prev=jnp.zeros((self.slots,), bool))
         tok, self._pool, _ = self._decode_paged(
             self.params, step, self._pool, table, pos, **decode_kw)
         jax.block_until_ready(tok)
@@ -733,6 +767,9 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         blocks the reclaim."""
         if self._prefix is None or len(self._free_pages) >= needed:
             return
+        # a victim's page is read from the host (a demote), and the tick in
+        # flight may hold the rows whose end frees the pages wanted
+        self._drain_tick(admitting=True)
         tier = self._kv_tier
         # _Node doesn't know its adapter — recover it from which
         # per-adapter root the victim's chain hangs off (one map per
@@ -873,6 +910,8 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         return future
 
     def _control_tick(self):
+        if self._control:
+            self._drain_tick(admitting=True)    # the ops read the pool
         while self._control:
             kind, args, future = self._control.popleft()
             if future.done():
@@ -1275,6 +1314,15 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             future = self._control.popleft()[2]
             if not future.done():
                 future.set_exception(exc)
+        if isinstance(exc, EngineStoppedError):
+            try:
+                # a clean stop answers a request whose last tick has run
+                self._drain_tick()
+            except Exception:  # noqa: BLE001 - teardown goes on
+                pass
+        # after a crash the commit may have stopped half way: the tick in
+        # flight is not read, its rows fail with the rest
+        self._in_flight = None
         super()._fail_pending(exc)
 
     def _release_slot_storage(self, index: int):
@@ -1292,6 +1340,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
     # paged-only cumulative stats mirrored to mlt_llm_events_total
     _COUNTER_STATS = ContinuousBatchingEngine._COUNTER_STATS + (
         "attn_kernel_ticks", "attn_gather_ticks", "attn_hbm_bytes_avoided",
+        "lookahead_ticks", "lookahead_drains",
         "prefill_kernel_chunks", "prefill_gather_admissions",
         "kv_demotes", "kv_demoted_pages", "kv_promotes",
         "kv_promoted_pages", "kv_fetches", "kv_fetched_pages",
@@ -1359,39 +1408,131 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             self._stats["attn_gather_ticks"] += 1
 
     def _plain_decode_tick(self, active) -> int:
+        """Dispatch the next tick, then read the one before it: build |
+        dispatch | fetch | commit, the first two for tick k+1 and the last
+        two for tick k, so the device runs k+1 while the host reads k. A
+        row of k takes its input token from k's ``next_token`` on the
+        device; its position is a count the host keeps (``_pos`` advances
+        at dispatch). A row that k completes by count (its last token, the
+        cache's end) is no row of k+1; one that ends on an end-of-sequence
+        id is learnt at commit(k), rode in k+1 too, and that token is
+        thrown away (its K/V write lands in the row's own page: whatever
+        takes the page next is ordered after k+1 through the pool).
+        Returns the rows dispatched."""
         tick = self._tick
+        ahead = self._in_flight
+        riding = set(ahead.rows) if ahead is not None else ()
+        rows = [i for i in active if i not in riding
+                or self._outlives_tick(self._slot_state[i])]
+        if not rows:
+            self._drain_tick()
+            return 0
         with annotate("mlt.sched.build"):
-            last, tick.ctx_tokens = self._tick_inputs(active)
-            table = jnp.asarray(self._page_table)
-            pos = jnp.asarray(self._pos)
+            last = np.zeros((self.slots, 1), np.int32)
+            from_prev = np.zeros((self.slots,), bool)
+            for i in rows:
+                if i in riding:
+                    from_prev[i] = True
+                else:
+                    last[i, 0] = self._slot_state[i].tokens[-1]
+            # the tick's own copies (the host's arrays move on while it
+            # runs), and only its rows: any other writes to the scratch page
+            table = np.full_like(self._page_table, -1)
+            table[rows] = self._page_table[rows]
+            pos = np.zeros_like(self._pos)
+            pos[rows] = self._pos[rows]
+            tick.ctx_tokens = int(pos.sum()) + len(rows)
+            self._pos[rows] += 1
             lora_kw = self._lora_kwargs(self._slot_adapter_ids()) \
                 if self._adapters is not None else {}
-            self._ledger_mark(active, "decode_active")
-            args = (jnp.asarray(last), self._pool, table, pos) \
-                + self._sampling_args(active)
+            self._ledger_mark(rows, "decode_active")
+            rng = self._rng
+            sampling = self._sampling_args(rows)
+            args = (jnp.asarray(last), self._pool, jnp.asarray(table),
+                    jnp.asarray(pos)) + sampling
+            prev_token = ahead.next_token if ahead is not None \
+                else self._no_tokens
         tick.t_built = time.perf_counter()
         with annotate("mlt.sched.dispatch"):
             next_token, self._pool, _ = self._decode_paged(
-                self.params, *args, **lora_kw)
-        tick.t_dispatched = time.perf_counter()
+                self.params, *args, prev_token=prev_token,
+                from_prev=jnp.asarray(from_prev), **lora_kw)
+            next_token.copy_to_host_async()
+        self._in_flight = _TickInFlight(rows, next_token,
+                                        rng if sampling else None)
+        tick.t_dispatched = tick.t_fetched = time.perf_counter()
+        if ahead is not None:
+            tick.lookahead = 1
+            with self._lock:
+                self._stats["lookahead_ticks"] += 1
+            self._land(ahead)
+        return len(rows)
+
+    def _outlives_tick(self, slot) -> bool:
+        """Whether a row of the tick in flight is a row of the next one
+        too, by count: the tick in flight yields neither the last token
+        asked for nor the cache's last position."""
+        return slot.remaining > 1 and \
+            slot.prompt_len + len(slot.tokens) + 1 < self.max_len
+
+    def _await_tick(self):
+        """The scheduler is about to wait on a prefill queued behind the
+        tick in flight: that tick's tokens come first, and its rows wait
+        from here (``decode_stall``) until their next dispatch."""
+        ahead = self._in_flight
+        if ahead is not None and ahead.host is None:
+            ahead.host = np.asarray(ahead.next_token)
+            self._ledger_mark(ahead.rows, "decode_stall")
+
+    def _drain_tick(self, admitting: bool = False):
+        """Read and commit the tick in flight with nothing dispatched
+        behind it: before whatever needs the committed state or the pool
+        on the host, and when no row is left to dispatch. ``admitting``:
+        the wait falls into the iteration's admission part."""
+        ahead, self._in_flight = self._in_flight, None
+        if ahead is None:
+            return
+        with self._lock:
+            self._stats["lookahead_drains"] += 1
+        self._land(ahead, admitting)
+
+    def _land(self, ahead: _TickInFlight, admitting: bool = False):
+        """fetch | commit of a dispatched tick; ``self._in_flight`` is the
+        tick behind it, or None."""
+        tick = self._tick
+        behind = self._in_flight
+        rides_on = set(behind.rows) if behind is not None else ()
+        started = time.perf_counter()
         with annotate("mlt.sched.fetch"):
-            tokens_host = np.asarray(next_token)
-        tick.t_fetched = time.perf_counter()
+            tokens_host = ahead.host if ahead.host is not None \
+                else np.asarray(ahead.next_token)
+        if admitting:
+            tick.admit_wait_s += time.perf_counter() - started
+        else:
+            tick.t_fetched = time.perf_counter()
         with annotate("mlt.sched.commit"):
-            self._ledger_mark(active, "decode_stall")
-            for i in active:
+            # a row that rides in the tick behind stays decode_active
+            self._ledger_mark([i for i in ahead.rows if i not in rides_on],
+                              "decode_stall")
+            for i in ahead.rows:
                 slot = self._slot_state[i]
                 token = int(tokens_host[i])
                 slot.tokens.append(token)
                 slot.remaining -= 1
-                self._pos[i] += 1
                 capacity = slot.prompt_len + len(slot.tokens) \
                     >= self.max_len
                 if (slot.eos_id is not None and token == slot.eos_id) or \
                         slot.remaining <= 0 or capacity:
                     self._finish(i)
-        tick.tokens_out = len(active)
-        return len(active)
+                    if i in rides_on:
+                        behind.rows.remove(i)   # ended unseen: rode along
+            if behind is not None and behind.rng is not None and not any(
+                    self._slot_state[i].temperature > 0
+                    for i in behind.rows):
+                # the sampled rows it drew for had all ended: the key is
+                # as if it had not drawn, as the next draw will find it
+                self._rng, behind.rng = behind.rng, None
+        tick.tokens_out += len(ahead.rows)
 
     # -- block-diffusion decoding (docs/serving.md) -------------------------
 

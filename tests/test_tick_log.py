@@ -40,6 +40,11 @@ def _engine(setup, **over):
 
 # -- the log against what the engine did --------------------------------------
 def test_tick_log_identities_and_survives_stop(setup):
+    """Over a drained run: every dispatch is a record with rows, every
+    token a tick yielded is in some record's ``tokens_out`` (the iteration
+    that committed it, one after the one that dispatched it), and with no
+    end-of-sequence id met no row rode along unseen, so the rows dispatched
+    are the tokens committed."""
     eng = _engine(setup)
     eng.start()
     try:
@@ -53,15 +58,22 @@ def test_tick_log_identities_and_survives_stop(setup):
     assert all(len(tokens) == 6 for tokens in outs)
     decoding = [r for r in records if r["rows"]]
     assert len(decoding) == stats["attn_kernel_ticks"]
-    assert sum(r["rows"] for r in records) \
+    # the first token of a request comes from its prefill
+    assert sum(r["tokens_out"] for r in records) \
         == stats["tokens_out"] - stats["completed"]
+    assert sum(r["rows"] for r in records) \
+        == sum(r["tokens_out"] for r in records)
     assert sum(r["prefill_tokens"] for r in records) \
         == sum(len(p) for p in PROMPTS)
+    assert sum(r["lookahead"] for r in records) == stats["lookahead_ticks"]
+    assert stats["lookahead_ticks"] + stats["lookahead_drains"] \
+        == len(decoding)
     for r in records:
         times = [r[key] for key in ORDERED]
         assert times == sorted(times), r
         assert 0.0 <= r["admit_wait_s"] <= r["t_admit"] - r["t0"]
         assert r["kind"] == "plain"
+        assert r["lookahead"] in (0, 1) and r["lookahead"] <= r["rows"]
     assert [r["n"] for r in records] == sorted({r["n"] for r in records})
     assert all(a["t1"] <= b["t0"] for a, b in zip(records, records[1:]))
     assert 1.0 <= stats["tick_rows_mean"] <= 2.0
@@ -70,25 +82,37 @@ def test_tick_log_identities_and_survives_stop(setup):
 
 
 def test_tick_ctx_tokens_are_the_slots_lengths(setup):
-    """Ticks driven by hand: before each one the live slots' prompt +
-    generated lengths are what the record says the rows attend."""
+    """Ticks driven by hand: a tick's rows are the live slots that the
+    tick in flight does not complete by count, and the record says they
+    attend their prompt and what was generated so far, the token still in
+    flight with it. The record of the iteration that only reads the last
+    tick has no rows, and the tokens it committed."""
     eng = _engine(setup)
     eng.start = lambda: None
     futures = [eng.submit(p, max_new_tokens=5) for p in PROMPTS[:2]]
     eng._admission_tick()
     seen = 0
     while not all(f.done() for f in futures):
-        live = [s for s in eng._slot_state if s.active]
-        expected = sum(s.prompt_len + len(s.tokens) for s in live)
-        assert expected == int(eng._pos[[i for i, s in enumerate(
-            eng._slot_state) if s.active]].sum()) + len(live)
+        ahead = eng._in_flight
+        riding = set(ahead.rows) if ahead is not None else set()
+        rows = [i for i, s in enumerate(eng._slot_state) if s.active
+                and (i not in riding or eng._outlives_tick(s))]
+        expected = sum(
+            eng._slot_state[i].prompt_len + len(eng._slot_state[i].tokens)
+            + (i in riding) for i in rows)
+        assert expected == int(eng._pos[rows].sum()) + len(rows)
         eng._tick = TickRecord(seen, time.perf_counter())
-        assert eng._decode_tick() == len(live)
+        assert eng._decode_tick() == len(rows)
         assert eng._tick.ctx_tokens == expected
+        assert eng._tick.lookahead == int(bool(rows) and ahead is not None)
+        assert eng._tick.tokens_out == len(riding)
         assert eng._tick.t_built <= eng._tick.t_dispatched \
             <= eng._tick.t_fetched
         seen += 1
-    assert seen == 4        # the first token comes from the prefill
+    # the first token comes from the prefill, four ticks follow, and one
+    # more iteration reads the last of them
+    assert seen == 5 and eng._in_flight is None
+    assert [len(f.result(timeout=0)[0]) for f in futures] == [5, 5]
 
 
 def test_tick_log_ring_is_bounded_and_sums_follow_it():
@@ -211,9 +235,12 @@ def test_kernel_names_and_scopes_in_the_programs(setup):
 
 # -- the same boundaries in the profiler's trace ------------------------------
 def test_profile_holds_the_scheduler_spans(setup, tmp_path):
-    """Three ticks under the profiler: on the host plane every decoding
-    iteration opens with an mlt.sched.tick that carries its index, and its
-    five parts follow as siblings, none inside another, before the next."""
+    """Three ticks under the profiler: on the host plane every iteration
+    opens with an mlt.sched.tick that carries its index, and its parts
+    follow as siblings, none inside another, before the next: admit, then
+    build and dispatch of the tick it sends, then fetch and commit of the
+    tick sent an iteration earlier (none in the first; the last iteration
+    reads the third tick and sends nothing)."""
     limit = time.monotonic() + 120.0
     eng = _engine(setup)
     eng.warmup()
@@ -238,19 +265,25 @@ def test_profile_holds_the_scheduler_spans(setup, tmp_path):
         for line in host[0].lines for e in line.events
         if e.name.startswith("mlt.sched."))
     records = get_tick_log(eng._obs_name).records()
-    decoded = {r["n"] for r in records if r["rows"]}
-    assert len(decoded) == 3
+    worked = {r["n"]: r for r in records if r["rows"] or r["tokens_out"]}
+    assert [(r["rows"], r["tokens_out"], r["lookahead"])
+            for r in worked.values()] \
+        == [(1, 0, 0), (1, 1, 1), (1, 1, 1), (0, 1, 0)]
     opened = [i for i, e in enumerate(events) if e[2] == "mlt.sched.tick"]
     seen = 0
     for i, following in zip(opened, opened[1:] + [len(events)]):
-        if events[i][3].get("n") not in decoded:
+        record = worked.get(events[i][3].get("n"))
+        if record is None:
             continue
         seen += 1
         parts = [e for e in events[i + 1:following]
                  if e[2] in SCHED_CHILDREN]
-        assert [e[2] for e in parts] == list(SCHED_CHILDREN)
+        want = [name for name, there in zip(SCHED_CHILDREN, (
+            True, record["rows"], record["rows"], record["tokens_out"],
+            record["tokens_out"])) if there]
+        assert [e[2] for e in parts] == want
         assert all(a[1] <= b[0] for a, b in zip([events[i]] + parts, parts))
-    assert seen == 3
+    assert seen == 4
     inside_admit = [e[2] for e in events
                     if e[2] in ("mlt.sched.prefill", "mlt.sched.insert")
                     and any(a[2] == "mlt.sched.admit" and a[0] <= e[0]

@@ -247,6 +247,10 @@ def test_pool_programs_copy_no_layer(program_shapes, on_chip, program,
         fn = functools.partial(paged._decode_rowwise_paged, config,
                                PAGE_SIZE, "kernel")
         args = (params, on_chip((SLOTS, 1), jnp.int32), pool, table, pos)
+        # as the engine dispatches it: a row's input token is the host's
+        # or the last tick's, still on the device
+        kwargs = {"prev_token": on_chip((SLOTS,), jnp.int32),
+                  "from_prev": on_chip((SLOTS,), jnp.bool_)}
     elif program == "verify":
         fn = functools.partial(paged._verify_rowwise_paged, config,
                                PAGE_SIZE, "kernel")
