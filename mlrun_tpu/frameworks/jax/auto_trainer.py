@@ -18,6 +18,7 @@ from typing import Any, Callable, Iterator, Optional
 from ...config import mlconf
 from ...execution import MLClientCtx
 from ...models import llama as llama_mod
+from ...models import xing4 as xing4_mod
 from ...models.llama import LlamaConfig
 from ...utils import logger
 
@@ -26,6 +27,8 @@ MODEL_PRESETS = {
     "llama3-70b": llama_mod.llama3_70b,
     "llama3-1b": llama_mod.llama3_1b,
     "tiny": llama_mod.tiny_llama,
+    "xing4-29b-a4b": xing4_mod.xing4_29b_a4b,
+    "tiny-xing4": xing4_mod.tiny_xing4,
 }
 
 

@@ -34,14 +34,19 @@ from .moe import (  # noqa: F401
     tiny_moe,
     tiny_sdar,
 )
-from . import llama as _llama, moe as _moe
+from .xing4 import Xing4Config, tiny_xing4, xing4_29b_a4b  # noqa: F401
+from . import llama as _llama, moe as _moe, xing4 as _xing4
 
 
 def init_params(config, key):
     """The weights that the config's own module defines, by its recipe:
-    ``models/moe.py`` for a config with experts, ``models/llama.py`` for
-    the dense decoder."""
-    module = _moe if isinstance(config, MoEConfig) else _llama
+    ``models/xing4.py`` for the latent-attention family, ``models/moe.py``
+    for any other config with experts, ``models/llama.py`` for the dense
+    decoder."""
+    if isinstance(config, Xing4Config):
+        module = _xing4
+    else:
+        module = _moe if isinstance(config, MoEConfig) else _llama
     return module.init_params(config, key)
 
 from . import vit  # noqa: F401  (vit.classify/encode stay namespaced —

@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -30,8 +30,32 @@ from ..ops.rotary import apply_rope, rope_table
 Params = dict
 
 
+class BlockSeams(NamedTuple):
+    """Where a family enters :func:`decoder_block`, as functions of the
+    config (``LlamaConfig.seams``; models/xing4.py has the other set).
+
+    ``qkv(config, lp, h, cos, sin, proj) -> (q, k, v)``: what ``attend``
+    is handed, out of the normed input. ``read(config, x, lp, sub) -> (u,
+    mix)`` and ``write(config, x, mix, y) -> x``: what sub-layer ``sub``
+    (``"attn"``, ``"mlp"``) reads of the residual state and how its output
+    goes back (``x`` and ``x + y`` here). ``enter(config, x)`` and
+    ``leave(config, x)``: the embeddings into the residual state before the
+    first layer, and the state out of it after the last."""
+
+    qkv: Callable
+    read: Callable
+    write: Callable
+    enter: Callable
+    leave: Callable
+
+
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
+    # a token leaves per-head keys and values in the cache (False), or one
+    # latent row all heads share (True: models/xing4.py); a class's, never
+    # an instance's
+    latent_cache = False
+
     vocab_size: int = 128256
     n_layers: int = 32
     embed_dim: int = 4096
@@ -67,6 +91,20 @@ class LlamaConfig:
     @property
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.head_dim
+
+    @property
+    def seams(self) -> BlockSeams:
+        return LLAMA_SEAMS
+
+    def cache_rows(self) -> dict:
+        """What a token leaves behind in a layer's cache: each buffer's
+        name and the shape of one token's row."""
+        row = (self.n_kv_heads, self.head_dim)
+        return {"k": row, "v": row}
+
+    def rope(self, positions: jax.Array) -> tuple[jax.Array, jax.Array]:
+        """The cos/sin tables of ``positions`` as this family rotates."""
+        return rope_table(positions, self.head_dim, self.rope_theta)
 
     def param_count(self) -> int:
         embed = self.vocab_size * self.embed_dim
@@ -257,43 +295,65 @@ def trainer_proj(lora: Optional[dict], dtype):
     return proj
 
 
+def llama_qkv(config: LlamaConfig, lp, h, cos, sin, proj):
+    """q, k and v of the Llama family out of the normed input ``h``: three
+    projections, the q/k norm where the config has one, the rotation."""
+    b, s, _ = h.shape
+    q = proj(h, lp["wq"], "wq").reshape(b, s, config.n_heads,
+                                        config.head_dim)
+    k = proj(h, lp["wk"], "wk").reshape(b, s, config.n_kv_heads,
+                                        config.head_dim)
+    v = proj(h, lp["wv"], "wv").reshape(b, s, config.n_kv_heads,
+                                        config.head_dim)
+    q, k = qk_normed(config, q, k, lp)
+    return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+
+
+LLAMA_SEAMS = BlockSeams(
+    qkv=llama_qkv,
+    read=lambda config, x, lp, sub: (x, None),
+    write=lambda config, x, mix, y: x + y,
+    enter=lambda config, x: x,
+    leave=lambda config, x: x)
+
+
 def decoder_block(config: LlamaConfig, lp, x, cos, sin, *, proj, attend,
                   mlp=None, live=None, layer=None):
-    """The Llama-family decoder layer, written once. x: [B, S, E]; ``lp``
-    the layer's parameters. Norm, q/k/v through ``proj(h_in, w, key)``
-    (:func:`trainer_proj` or serving/llm.py ``_serving_proj``), q/k norm,
-    rope, ``attend(q, k, v) -> [B, S, Hq, D]`` (or with the heads merged
-    already, [B, S, Hq * D]), ``wo``, residual, norm,
-    MLP (:func:`layer_mlp` with ``live`` and ``layer``, unless ``mlp`` is
-    given: ``mlp(h2) -> (out, extra)``), residual.
+    """The decoder layer, written once. x: the residual state ([B, S, E];
+    a family with several residual streams carries [B, S, n, E]); ``lp``
+    the layer's parameters. Each of the two sub-layers reads the state
+    (``seams.read``: the state itself here), norms what it read, computes,
+    and writes back (``seams.write``: an addition here). Attention: q/k/v
+    by ``seams.qkv`` (:func:`llama_qkv`: projections through ``proj(h_in,
+    w, key)``, :func:`trainer_proj` or serving/llm.py ``_serving_proj``,
+    q/k norm, rope), ``attend(q, k, v) -> [B, S, Hq, D]`` (or with the
+    heads merged already, [B, S, Hq * D]), ``wo``. MLP: :func:`layer_mlp`
+    with ``live`` and ``layer``, unless ``mlp`` is given: ``mlp(h2) ->
+    (out, extra)``. ``config.seams`` are the family's (:class:`BlockSeams`).
 
-    ``attend`` is all a caller says about its cache: the closure writes K
-    and V where that caller keeps them and reads the attention back
-    (none, dense rows, pages), keeping what it wrote for its own return.
+    ``attend`` is all a caller says about its cache: the closure writes
+    what the token leaves behind where that caller keeps it and reads the
+    attention back (none, dense rows, pages), keeping what it wrote for
+    its own return.
 
     Returns ``(x, extra)``: ``extra`` is what the MLP returned beside its
     output (expert load, aux loss, ``None``)."""
-    b, s, _ = x.shape
+    b, s = x.shape[:2]
+    seams = config.seams
     # the named scopes are metadata a profile groups operations by
     # (embed, layer/attn, layer/mlp, head, loss): no instruction is renamed
     with jax.named_scope("layer/attn"):
-        h = rms_norm(x, lp["attn_norm_scale"], config.norm_eps)
-        q = proj(h, lp["wq"], "wq").reshape(b, s, config.n_heads,
-                                            config.head_dim)
-        k = proj(h, lp["wk"], "wk").reshape(b, s, config.n_kv_heads,
-                                            config.head_dim)
-        v = proj(h, lp["wv"], "wv").reshape(b, s, config.n_kv_heads,
-                                            config.head_dim)
-        q, k = qk_normed(config, q, k, lp)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        u, mix = seams.read(config, x, lp, "attn")
+        h = rms_norm(u, lp["attn_norm_scale"], config.norm_eps)
+        q, k, v = seams.qkv(config, lp, h, cos, sin, proj)
         attn = attend(q, k, v).reshape(b, s, config.qkv_dim)
-        x = x + proj(attn, lp["wo"], "wo")
+        x = seams.write(config, x, mix, proj(attn, lp["wo"], "wo"))
     with jax.named_scope("layer/mlp"):
-        h2 = rms_norm(x, lp["mlp_norm_scale"], config.norm_eps)
+        u, mix = seams.read(config, x, lp, "mlp")
+        h2 = rms_norm(u, lp["mlp_norm_scale"], config.norm_eps)
         out, extra = mlp(h2) if mlp is not None else layer_mlp(
             config, h2, lp, proj, live=live, layer=layer)
-        x = x + out
+        x = seams.write(config, x, mix, out)
     return x, extra
 
 
@@ -330,14 +390,42 @@ def qk_normed(config: LlamaConfig, q, k, lp):
             rms_norm(k, lp["k_norm_scale"], config.norm_eps))
 
 
-def layer_slice(layers: Params, layer: int) -> Params:
+# a layer's MLP leaves: the dense SwiGLU's, and the expert layer's that are
+# stacked over the expert layers alone where dense layers lead
+DENSE_MLP_LEAVES = ("w_gate", "w_up", "w_down")
+EXPERT_LAYER_LEAVES = ("router", "shared_")
+
+
+def layer_slice(layers: Params, layer: int,
+                first_k_dense: int = 0) -> Params:
     """Layer ``layer``'s parameters out of the stacked tree, for the
     serving programs' Python loop over layers. Stacks of experts
     (``experts_*``) stay whole: their grouped products reach a layer's
     experts through the group sizes (models/moe.py ``_grouped``), where a
-    sliced stack would be copied before every product."""
-    return {name: (leaf if name.startswith("experts_") else leaf[layer])
-            for name, leaf in layers.items()}
+    sliced stack would be copied before every product.
+
+    ``first_k_dense`` > 0: the tree stacks the dense MLP's leaves over the
+    leading dense layers and the expert layer's (router, shared expert,
+    experts) over the layers after them; a layer gets its own kind alone,
+    which is how :func:`layer_mlp` tells them apart."""
+    if not first_k_dense:
+        return {name: (leaf if name.startswith("experts_") else leaf[layer])
+                for name, leaf in layers.items()}
+    dense = layer < first_k_dense
+    out = {}
+    for name, leaf in layers.items():
+        if name in DENSE_MLP_LEAVES:
+            if dense:
+                out[name] = leaf[layer]
+        elif name.startswith("experts_"):
+            if not dense:
+                out[name] = leaf
+        elif name.startswith(EXPERT_LAYER_LEAVES):
+            if not dense:
+                out[name] = leaf[layer - first_k_dense]
+        else:
+            out[name] = leaf[layer]
+    return out
 
 
 def layer_mlp(config: LlamaConfig, h2, lp, proj, live=None, layer=None):
@@ -354,6 +442,9 @@ def layer_mlp(config: LlamaConfig, h2, lp, proj, live=None, layer=None):
     if "experts_gate" in lp:
         from .moe import moe_mlp
 
+        if layer is not None:
+            # the experts' stacks hold the expert layers alone
+            layer -= getattr(config, "first_k_dense", 0)
         return moe_mlp(config, h2, lp,
                        held=getattr(config, "experts_held", None), live=live,
                        layer=layer)

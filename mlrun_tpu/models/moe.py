@@ -238,7 +238,10 @@ def moe_mlp(config: MoEConfig, x, lp, held=None, live=None, layer=None):
     load int32 [experts held]).
 
     Routes every token over the router's full width (softmax in float32,
-    top-k, gates renormalised under ``norm_topk``), keeps the
+    top-k, gates renormalised under ``norm_topk``; under ``config.scoring
+    == "sigmoid"`` sigmoid scores, top-k of score + ``lp["router_bias"]``,
+    gates from the scores, renormalised and times ``routed_scale``), adds
+    the shared expert where ``lp`` has one (:func:`shared_expert`), keeps the
     token-expert pairs whose expert lies in ``held`` (a contiguous range
     ``(lo, hi)``; ``None``: all) and whose token is ``live`` ([B, S] bool;
     ``None``: all), sorts them by expert, runs one grouped product per
@@ -264,10 +267,21 @@ def moe_mlp(config: MoEConfig, x, lp, held=None, live=None, layer=None):
     with jax.named_scope("layer/moe/route"):
         logits = jnp.einsum("tm,me->te", xt.astype(jnp.float32),
                             lp["router"].astype(jnp.float32))
-        probs = jax.nn.softmax(logits, axis=-1)
-        gates, chosen = jax.lax.top_k(probs, k)              # [T, k]
-        if getattr(config, "norm_topk", True):
-            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        if getattr(config, "scoring", "softmax") == "sigmoid":
+            # choice by score plus the per-expert selection bias, gates
+            # from the scores without it, renormalised and scaled
+            scores = jax.nn.sigmoid(logits)
+            _, chosen = jax.lax.top_k(scores + lp["router_bias"], k)
+            gates = jnp.take_along_axis(scores, chosen, axis=-1)
+            if getattr(config, "norm_topk", True):
+                gates = gates / (jnp.sum(gates, axis=-1, keepdims=True)
+                                 + 1e-20)
+            gates = gates * config.routed_scale
+        else:
+            probs = jax.nn.softmax(logits, axis=-1)
+            gates, chosen = jax.lax.top_k(probs, k)          # [T, k]
+            if getattr(config, "norm_topk", True):
+                gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
         expert = chosen.reshape(t * k)
         kept = (expert >= lo) & (expert < hi)
         if live is not None:
@@ -287,7 +301,25 @@ def moe_mlp(config: MoEConfig, x, lp, held=None, live=None, layer=None):
         weight = jnp.where(kept, gates.reshape(t * k), 0.0)
         pairs = jnp.where(kept[:, None], out[jnp.argsort(order)], 0.0)
         y = jnp.sum((pairs * weight[:, None]).reshape(t, k, m), axis=1)
-    return y.astype(x.dtype).reshape(b, s, m), load
+    y = y.astype(x.dtype).reshape(b, s, m)
+    if "shared_gate" in lp:
+        y = y + shared_expert(x, lp)
+    return y, load
+
+
+def shared_expert(x, lp):
+    """The expert every token goes through beside its routed ones (``lp``
+    carries ``shared_gate`` / ``shared_up`` / ``shared_down``): a SwiGLU
+    over x [B, S, M], whatever experts are held here. Runs under the named
+    scope ``layer/moe/shared``."""
+    with jax.named_scope("layer/moe/shared"):
+        gate = jnp.einsum("bse,eh->bsh", x, lp["shared_gate"],
+                          preferred_element_type=jnp.float32)
+        up = jnp.einsum("bse,eh->bsh", x, lp["shared_up"],
+                        preferred_element_type=jnp.float32)
+        hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
+        return jnp.einsum("bsh,he->bse", hidden, lp["shared_down"],
+                          preferred_element_type=jnp.float32).astype(x.dtype)
 
 
 def _moe_mlp(config: MoEConfig, x, lp):

@@ -159,6 +159,11 @@ LLM_FREE_PAGE_FRAC = REGISTRY.gauge(
     "mlt_llm_free_page_frac",
     "Free (incl. reclaimable prefix) KV-page fraction, paged engines",
     labels=("engine", "replica"), overflow="drop")
+LLM_KV_BYTES_PER_TOKEN = REGISTRY.gauge(
+    "mlt_llm_kv_bytes_per_token",
+    "Bytes a token leaves in the page pool over all layers (per-head keys "
+    "and values, or one latent row), paged engines",
+    labels=("engine", "replica"), overflow="drop")
 LLM_EVENTS = REGISTRY.counter(
     "mlt_llm_events_total",
     "Cumulative engine events mirrored from stats() (requests, completed, "

@@ -29,7 +29,8 @@ KEPT_LOGS = 16
 FIELDS = ("n", "t0", "t_admit", "t_built", "t_dispatched", "t_fetched",
           "t1", "admit_wait_s", "rows", "ctx_tokens", "prefill_tokens",
           "kind", "positions", "tokens_out", "commit_rows", "expert_pairs",
-          "experts_touched", "expert_load_max", "lookahead")
+          "experts_touched", "expert_load_max", "lookahead",
+          "prefill_ctx_tokens")
 
 
 class TickRecord:
@@ -44,7 +45,9 @@ class TickRecord:
     drain). ``rows`` rows of the decode tick dispatched (0 where the
     iteration only admitted, or only read a tick), ``ctx_tokens`` the tokens
     those rows attend (prompt + generated so far, summed),
-    ``prefill_tokens`` prompt tokens prefilled in this iteration, ``kind``
+    ``prefill_tokens`` prompt tokens prefilled in this iteration and
+    ``prefill_ctx_tokens`` the positions they attended (each token its own
+    and what precedes it in its prompt, summed), ``kind``
     ``plain``, ``spec`` or ``denoise``. ``tokens_out`` is what the iteration
     committed over all rows: one a row (plain), the tokens a speculative
     round emitted, the positions a denoising pass unmasked (none in a row
@@ -68,6 +71,11 @@ class TickRecord:
     layer). There ``ctx_tokens`` is the positions the live rows attend:
     each row's committed prefix and its block.
 
+    An expert model served token by token fills the three expert counters
+    on ``plain`` records too: those of the tick dispatched in the iteration
+    (read with that tick's own fetch, an iteration later) and of its
+    prefill dispatches, summed.
+
     A boundary that an iteration never reaches stays at the one before it,
     so every interval is defined and non-negative.
     """
@@ -80,7 +88,7 @@ class TickRecord:
         self.rows = self.ctx_tokens = self.prefill_tokens = 0
         self.positions = self.tokens_out = self.commit_rows = 0
         self.expert_pairs = self.experts_touched = self.expert_load_max = 0
-        self.lookahead = 0
+        self.lookahead = self.prefill_ctx_tokens = 0
         self.kind = "plain"
         self.t0 = t0
         self.admitted(t0)

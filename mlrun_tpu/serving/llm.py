@@ -28,36 +28,53 @@ from ..models.llama import (
     layer_slice,
 )
 from ..ops.attention import causal_bound
-from ..ops.rotary import rope_table
 from ..utils import logger
 
 
 def init_kv_cache(config: LlamaConfig, batch: int, max_len: int,
                   dtype=None, kv_dtype: str = "native") -> dict:
-    """KV cache pytree. ``kv_dtype="int8"`` stores k/v per-vector symmetric
+    """KV cache pytree: one buffer [layers, batch, max_len, *row] for each
+    of ``config.cache_rows()`` (per-head keys and values, or for a latent
+    family one latent and one rotated key a token), and ``pos``.
+    ``kv_dtype="int8"`` stores k/v per-vector symmetric
     int8 (scale over head_dim, kept f32 per [layer, batch, pos, kv_head]) —
     half the HBM residency of bf16, so twice the slots x context per chip.
     Dequantization happens at attention time; see _quantize_kv."""
     if kv_dtype not in ("native", "int8"):
         raise ValueError(
             f"unknown kv_dtype '{kv_dtype}' (native | int8)")
+    refuse_latent(config, "an int8 cache", kv_dtype == "int8")
     dtype = dtype or config.dtype
-    shape = (config.n_layers, batch, max_len, config.n_kv_heads,
-             config.head_dim)
+    lead = (config.n_layers, batch, max_len)
+    pos = {"pos": jnp.zeros((batch,), jnp.int32)}
     if kv_dtype == "int8":
+        shape = lead + config.cache_rows()["k"]
         scale_shape = shape[:-1]
         return {
             "k": jnp.zeros(shape, jnp.int8),
             "v": jnp.zeros(shape, jnp.int8),
             "k_scale": jnp.zeros(scale_shape, jnp.float32),
             "v_scale": jnp.zeros(scale_shape, jnp.float32),
-            "pos": jnp.zeros((batch,), jnp.int32),
+            **pos,
         }
-    return {
-        "k": jnp.zeros(shape, dtype),
-        "v": jnp.zeros(shape, dtype),
-        "pos": jnp.zeros((batch,), jnp.int32),
-    }
+    return {**{name: jnp.zeros(lead + row, dtype)
+               for name, row in config.cache_rows().items()}, **pos}
+
+
+class LatentCacheError(ValueError):
+    """A family whose cache holds one latent row a token
+    (``config.latent_cache``, docs/serving.md "Latent attention and the
+    latent page pool") was asked for something that layout does not carry
+    yet: an int8 cache, the host KV tier, a KV handoff, speculation, an
+    engine other than the paged one."""
+
+
+def refuse_latent(config, what: str, asked: bool = True):
+    if asked and config.latent_cache:
+        raise LatentCacheError(
+            f"{type(config).__name__} keeps a latent cache, which does "
+            f"not carry {what} yet (docs/serving.md \"Latent attention "
+            f"and the latent page pool\")")
 
 
 def _quantize_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -168,14 +185,52 @@ def _serving_layers(config: LlamaConfig, params: Params, x, cos, sin,
     reads the attention. Returns ``(x, loads)``: ``loads`` the expert
     layers' loads, empty for dense MLPs."""
     loads = []
+    first_k_dense = getattr(config, "first_k_dense", 0)
+    x = config.seams.enter(config, x)
     for layer in range(config.n_layers):
         x, load = decoder_block(
-            config, layer_slice(params["layers"], layer), x, cos, sin,
-            proj=_serving_proj(lora, adapter_ids, layer, x.dtype),
+            config, layer_slice(params["layers"], layer, first_k_dense), x,
+            cos, sin, proj=_serving_proj(lora, adapter_ids, layer, x.dtype),
             attend=functools.partial(attend, layer), live=live, layer=layer)
         if load is not None:
             loads.append(load)
-    return x, loads
+    return config.seams.leave(config, x), loads
+
+
+def expert_counters(loads: list):
+    """int32 [3] of the expert layers' ``loads`` of one dispatch: pairs
+    routed and experts that got a pair, both summed over layers, and the
+    most pairs one expert got in one layer."""
+    stacked = jnp.stack(loads)                   # [L, experts held]
+    return jnp.stack([jnp.sum(stacked), jnp.sum(stacked > 0),
+                      jnp.max(stacked)]).astype(jnp.int32)
+
+
+def latent_prefill_attend(config, params, cache: dict, new: dict, start,
+                          attn_impl: str):
+    """``attend`` of a prompt chunk for a latent family
+    (:func:`_forward_with_cache`): the chunk's cache rows (latent, then
+    rotated key) into the admission's dense rows at ``start``, then the
+    expanded form over the rows up to the chunk's end
+    (ops/mla_attention.py ``expanded_cached_attention``: the ``mla_flash``
+    kernel under ``attn_impl="flash"``, plain products otherwise)."""
+    from ..models.xing4 import expand_latents
+    from ..ops.mla_attention import expanded_cached_attention
+
+    def attend(layer, q, rows, _):
+        new["ckr"].append(jax.lax.dynamic_update_slice(
+            cache["ckr"][layer], rows.astype(cache["ckr"].dtype),
+            (0, start, 0)))
+        w_ukv = params["layers"]["w_ukv"][layer]
+        out = expanded_cached_attention(
+            q[0], new["ckr"][-1][0], start,
+            functools.partial(expand_latents, config, w_ukv),
+            v_dim=config.v_dim, scale=config.softmax_scale,
+            impl="flash" if attn_impl == "flash" and q.shape[1] > 1
+            else "dense")
+        return out[None].astype(q.dtype)
+
+    return attend
 
 
 def _stacked_cache(new: dict, pos) -> dict:
@@ -220,7 +275,8 @@ def _forward_with_cache(config: LlamaConfig, params: Params,
                         all_logits: bool = False,
                         attn_impl: str = "dense",
                         page_size: int = 0,
-                        logits_at: Optional[jax.Array] = None):
+                        logits_at: Optional[jax.Array] = None,
+                        with_loads: bool = False):
     """Run tokens starting at cache['pos']; returns (logits, new_cache):
     the logits of ONE dispatched position, [B, vocab] — the last one, or
     the one ``logits_at`` names (a traced int32 index into the ``S``
@@ -229,6 +285,9 @@ def _forward_with_cache(config: LlamaConfig, params: Params,
     length in it and the first token comes from the dispatch that
     prefilled it; causal masking keeps the padding to its right from
     touching it).
+    With ``with_loads`` (an engine's prefill program of an expert model
+    served token by token) a third output follows: the dispatch's
+    :func:`expert_counters`.
     ``all_logits=True`` returns [B, S, vocab] logits for every input
     position instead (speculative verification needs the target's
     distribution after each proposed token — serving/speculative.py).
@@ -253,14 +312,14 @@ def _forward_with_cache(config: LlamaConfig, params: Params,
     suffix rows, so a prefix hit never gathers the cached KV densely
     (``page_size`` must then be the pool's static page size)."""
     b, s = tokens.shape
-    max_len = cache["k"].shape[2]
+    new = {name: [] for name in cache if name != "pos"}
+    max_len = cache[next(iter(new))].shape[2]
     start = cache["pos"]  # [B]
     positions = start[:, None] + jnp.arange(s)[None, :]  # [B, S]
     x = embed(config, params, tokens)
     # rope per batch row (positions differ per row only after mixed prefill;
     # keep a single table using row 0 — engine keeps pos uniform per batch)
-    cos, sin = rope_table(positions[0], config.head_dim, config.rope_theta)
-    new = {name: [] for name in cache if name != "pos"}
+    cos, sin = config.rope(positions[0])
 
     def write(buffer, rows):
         # k,v into the cache at start..start+s (uniform start)
@@ -318,8 +377,13 @@ def _forward_with_cache(config: LlamaConfig, params: Params,
         return _cached_attention(config, q, k_attn, v_attn, positions,
                                  max_len)
 
-    x, _ = _serving_layers(config, params, x, cos, sin, attend, lora,
-                           adapter_ids)
+    if config.latent_cache:
+        # batch 1: the paged engine's admission (the dense engines refuse
+        # the family)
+        attend = latent_prefill_attend(config, params, cache, new,
+                                       start[0], attn_impl)
+    x, loads = _serving_layers(config, params, x, cos, sin, attend, lora,
+                               adapter_ids)
     if not all_logits:
         # one row through the final norm and the head: the position the
         # caller names, else the last dispatched one
@@ -327,8 +391,14 @@ def _forward_with_cache(config: LlamaConfig, params: Params,
             x = x[:, -1:] if logits_at is None else \
                 jax.lax.dynamic_slice_in_dim(x, logits_at, 1, axis=1)
     logits = head_logits(config, params, x)
-    return (logits if all_logits else logits[:, 0]), \
-        _stacked_cache(new, cache["pos"] + s)
+    out = ((logits if all_logits else logits[:, 0]),
+           _stacked_cache(new, cache["pos"] + s))
+    if with_loads:
+        # an expert model served token by token reports its experts' load
+        # a dispatch (a block model's pass does: _verify_rowwise_paged)
+        with jax.named_scope("head"):
+            out += (expert_counters(loads),)
+    return out
 
 
 class LLMEngine:
@@ -351,6 +421,8 @@ class LLMEngine:
                 f"LLMEngine decodes one token a step; a model with "
                 f"block_length {config.block_length} needs the paged "
                 f"engine (continuous_batching=True, paged=True)")
+        refuse_latent(config, "LLMEngine's dense rows (the paged engine "
+                      "serves it: continuous_batching=True, paged=True)")
         self.config = config
         self.params = params
         self.max_len = max_len

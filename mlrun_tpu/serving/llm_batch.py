@@ -41,6 +41,7 @@ from ..obs import (
     LLM_EVENTS,
     LLM_FREE_PAGE_FRAC,
     LLM_ITL,
+    LLM_KV_BYTES_PER_TOKEN,
     LLM_QUEUE_DEPTH,
     LLM_SPEC_ROUNDS,
     LLM_SPEC_TOKENS,
@@ -65,10 +66,12 @@ from .canary import get_canary_router, split_key_for
 from .llm import (
     _cached_attention,
     _dense_kv_write,
+    LatentCacheError,  # noqa: F401 - re-exported beside BlockDecodingError
     _forward_with_cache,
     _serving_layers,
     _stacked_cache,
     init_kv_cache,
+    refuse_latent,
 )
 from .samples import emit_sample, sampling_enabled
 from .resilience import (  # noqa: F401 - EngineStoppedError re-exported
@@ -361,6 +364,8 @@ class ContinuousBatchingEngine:
 
     # whether the engine has a tick for ``config.block_length`` > 1
     _serves_blocks = False
+    # whether its cache takes a latent family's rows (the paged pool does)
+    _serves_latent = False
 
     def __init__(self, config: LlamaConfig, params: Params,
                  max_len: int = 2048, slots: int = 4,
@@ -392,6 +397,9 @@ class ContinuousBatchingEngine:
                 f"{type(self).__name__} decodes one token a step; a model "
                 f"with block_length {self.block_length} needs the paged "
                 f"engine (paged=True)")
+        refuse_latent(config, f"{type(self).__name__}'s dense rows (the "
+                      f"paged engine serves it: paged=True)",
+                      not self._serves_latent)
         # -- overload protection (docs/serving_resilience.md) --------------
         # max_queue_size: bounded admission queue, reject-newest shedding
         # (0 = unbounded, the pre-resilience behavior)
@@ -498,7 +506,8 @@ class ContinuousBatchingEngine:
         # every jitted step under a stable name (utils/profiler.named):
         # a profile's modules then read jit_mlt_prefill, jit_mlt_decode, ...
         self._prefill = jax.jit(named("mlt_prefill", functools.partial(
-            _forward_with_cache, config, attn_impl=self.prefill_impl)))
+            _forward_with_cache, config, attn_impl=self.prefill_impl,
+            **self._loads_kw())))
         self._decode = jax.jit(
             named("mlt_decode", functools.partial(_decode_rowwise, config)),
             donate_argnums=(2,))
@@ -572,11 +581,29 @@ class ContinuousBatchingEngine:
             self._stats.update({"denoise_passes": 0, "commit_passes": 0,
                                 "expert_load_max": 0,
                                 "unmasked_positions": 0})
+        elif getattr(config, "n_experts", 0):
+            # an expert model served token by token: token-expert pairs
+            # routed and experts that got a pair (both summed over layers
+            # and dispatches, prefill chunks included), and the most pairs
+            # one expert got in one layer of one dispatch
+            self._stats.update({"expert_pairs": 0, "experts_touched": 0,
+                                "expert_load_max": 0})
+        # (tick record, counters on their way to the host) of dispatches
+        # whose experts' counters no fetch has brought yet
+        self._pending_loads: list = []
         # -- in-engine speculative decoding (docs/serving.md
         # "Speculative decoding"): draft model resident alongside the
         # target, per-row adaptive k, one multi-token verify dispatch per
         # tick. Off unless a draft model is supplied.
         self._init_speculative(speculative)
+
+    def _loads_kw(self) -> dict:
+        """The prefill program's keyword that makes an expert model served
+        token by token leave its experts' counters a dispatch; absent for
+        every other model, whose program is then as it was."""
+        reports = getattr(self.config, "n_experts", 0) \
+            and self.block_length == 1
+        return {"with_loads": True} if reports else {}
 
     # -- speculative decoding (shared by the dense and paged engines) ----
 
@@ -604,6 +631,7 @@ class ContinuousBatchingEngine:
                 "speculative decoding proposes one token after another; a "
                 f"model with block_length {self.block_length} fills a "
                 "block in any order: turn one of the two off")
+        refuse_latent(self.config, "speculation", self.spec_enabled)
         if not self.spec_enabled:
             return
         if draft_config.vocab_size != self.config.vocab_size:
@@ -1065,6 +1093,7 @@ class ContinuousBatchingEngine:
                 LLM_QUEUE_DEPTH.remove(engine=name, replica=replica,
                                        adapter=adapter)
             LLM_FREE_PAGE_FRAC.remove(engine=name, replica=replica)
+            LLM_KV_BYTES_PER_TOKEN.remove(engine=name, replica=replica)
             if has_spec:
                 LLM_SPEC_ROUNDS.remove(engine=name, replica=replica)
                 for outcome in ("accepted", "rejected"):
@@ -1129,6 +1158,9 @@ class ContinuousBatchingEngine:
             frac = engine._free_page_frac()
             if frac is not None:
                 LLM_FREE_PAGE_FRAC.set(frac, engine=name, replica=replica)
+            if "kv_bytes_per_token" in stats:
+                LLM_KV_BYTES_PER_TOKEN.set(stats["kv_bytes_per_token"],
+                                           engine=name, replica=replica)
             for key in engine._COUNTER_STATS:
                 if key in stats:
                     LLM_EVENTS.set_total(stats[key], engine=name,
@@ -1228,9 +1260,9 @@ class ContinuousBatchingEngine:
             tokens = jnp.zeros((1, bucket), jnp.int32)
             # the index of the position whose logits come back is an input:
             # this one program serves every prompt length in the bucket
-            _, small = self._prefill(self.params, tokens, small,
-                                     logits_at=np.int32(bucket - 1),
-                                     **prefill_kw)
+            small = self._prefill(self.params, tokens, small,
+                                  logits_at=np.int32(bucket - 1),
+                                  **prefill_kw)[1]
             self._cache = self._insert(self._cache, small, 0, bucket)
         if self.prefill_chunk and self.prefill_chunk not in \
                 self.prefill_buckets:
@@ -1540,6 +1572,7 @@ class ContinuousBatchingEngine:
         prefixes stay cache-resident — per tenant — on the prefill pool.
         ``max_new_tokens=1`` bounds the paged page reservation to the
         prompt itself."""
+        refuse_latent(self.config, "a KV handoff (submit_prefill)")
         return self.submit(prompt_tokens, max_new_tokens=1, eos_id=eos_id,
                            temperature=temperature, top_k=top_k,
                            top_p=top_p, max_wait=max_wait, adapter=adapter,
@@ -1561,6 +1594,7 @@ class ContinuousBatchingEngine:
         (serving/podfleet.py): the imported prompt pages ALSO register in
         this engine's prefix index, so a reassigned hot key's first real
         request after a ring join is a cache hit."""
+        refuse_latent(self.config, "a KV handoff (submit_prefilled)")
         expects_scales = self.kv_dtype == "int8"
         wire_dtype = getattr(handoff, "kv_dtype", None) or (
             "int8" if "k_scale" in handoff.kv else "native")
@@ -1805,11 +1839,20 @@ class ContinuousBatchingEngine:
         lora_kw = self._lora_kwargs(adm.adapter_slot)
         # the logits that come back are those of the chunk's last REAL
         # position: a padded prompt's first token needs no second dispatch
-        logits, adm.small = self._prefill_dispatch(
+        out = self._prefill_dispatch(
             adm, jnp.asarray(padded), np.int32(take - 1), lora_kw)
+        logits, adm.small = out[:2]
+        if len(out) > 2:
+            # an expert model's dispatch leaves its experts' counters; a
+            # later fetch brings them (_settle_loads)
+            out[2].copy_to_host_async()
+            self._pending_loads.append((self._tick, out[2]))
         adm.offset += take
         adm.chunks += 1
         self._tick.prefill_tokens += take
+        # the positions the chunk's tokens attended: each its own and
+        # what precedes it
+        self._tick.prefill_ctx_tokens += take * start + take * (take + 1) // 2
         with self._lock:
             self._stats["prefill_chunks"] += 1
             # tick instrumentation: the most prefill compute any single
@@ -1833,6 +1876,7 @@ class ContinuousBatchingEngine:
                 top2 = np.partition(row, -2)[-2:]
                 adm.logit_margin = float(top2[1] - top2[0])
         adm.first_token = self._first_token(logits, adm.sampling)
+        self._settle_loads()                # this prompt's chunks have run
         self._tick.admit_wait_s += time.perf_counter() - waited
         return True
 
@@ -2107,6 +2151,35 @@ class ContinuousBatchingEngine:
         if done:
             self._finish_admission(adm)
             self._admission = None
+
+    def _settle_loads(self, before=None):
+        """Read the experts' counters that dispatches left behind
+        (``_pending_loads``) into the records of the iterations that
+        dispatched them: all of them, or those dispatched no later than
+        the iteration of ``before`` (the record of a tick whose fetch just
+        returned: a prefill goes out before its iteration's tick, so it
+        has run)."""
+        keep = []
+        for record, counters in self._pending_loads:
+            if before is not None and record.n > before.n:
+                keep.append((record, counters))
+            else:
+                self._count_experts(record, np.asarray(counters))
+        self._pending_loads = keep
+
+    def _count_experts(self, record, counters):
+        """One dispatch's experts' counters (pairs, experts touched, the
+        most pairs on one expert) into its iteration's record and the
+        engine's running sums."""
+        pairs, touched, most = (int(v) for v in counters)
+        record.expert_pairs += pairs
+        record.experts_touched += touched
+        record.expert_load_max = max(record.expert_load_max, most)
+        with self._lock:
+            self._stats["expert_pairs"] += pairs
+            self._stats["experts_touched"] += touched
+            self._stats["expert_load_max"] = max(
+                self._stats["expert_load_max"], most)
 
     def _ledger_mark(self, active: list, phase: str):
         """Flip every active slot's ledger into ``phase`` (the

@@ -66,7 +66,9 @@ from .llm import (
     _forward_with_cache,
     _kv_rows,
     _serving_layers,
+    expert_counters,
     init_kv_cache,
+    refuse_latent,
 )
 from .llm_batch import (
     BlockDecodingError,
@@ -82,22 +84,34 @@ def init_paged_pool(config: LlamaConfig, n_pages: int, page_size: int,
                     kv_dtype: str = "native") -> dict:
     """Page pool pytree with ``n_pages`` physical pages (callers that need
     a scratch page pass n_pages + 1 and keep the last id out of the free
-    list). The int8 variant carries per-vector scales."""
+    list): one buffer [layers, n_pages, page_size, *row] for each of
+    ``config.cache_rows()``, so the config's type decides the layout
+    (per-head keys and values; one latent and one rotated key a token for
+    a latent family). The int8 variant carries per-vector scales."""
     if kv_dtype not in ("native", "int8"):
         raise ValueError(f"unknown kv_dtype '{kv_dtype}' (native | int8)")
-    shape = (config.n_layers, n_pages, page_size, config.n_kv_heads,
-             config.head_dim)
+    refuse_latent(config, "an int8 page pool", kv_dtype == "int8")
+    lead = (config.n_layers, n_pages, page_size)
     if kv_dtype == "int8":
+        shape = lead + config.cache_rows()["k"]
         return {
             "k": jnp.zeros(shape, jnp.int8),
             "v": jnp.zeros(shape, jnp.int8),
             "k_scale": jnp.zeros(shape[:-1], jnp.float32),
             "v_scale": jnp.zeros(shape[:-1], jnp.float32),
         }
-    return {
-        "k": jnp.zeros(shape, config.dtype),
-        "v": jnp.zeros(shape, config.dtype),
-    }
+    return {name: jnp.zeros(lead + row, config.dtype)
+            for name, row in config.cache_rows().items()}
+
+
+def _buffers(pool: dict) -> list:
+    """The pool's buffer names, the rows' before their scales'."""
+    return sorted(pool, key=lambda name: (name.endswith("_scale"), name))
+
+
+def _scratch_page(pool: dict) -> int:
+    """The id of the pool's last physical page, which is never read."""
+    return next(iter(pool.values())).shape[1] - 1
 
 
 def insert_prompt_pages(pool: dict, small: dict, page_ids: jax.Array,
@@ -106,16 +120,14 @@ def insert_prompt_pages(pool: dict, small: dict, page_ids: jax.Array,
     batch=1, max_len a multiple of page_size) into the pool at
     ``page_ids`` ([pages_per_slot] int32). Ids < 0 write to the scratch
     page (last physical page) — never to a live one."""
-    scratch = pool["k"].shape[1] - 1
+    scratch = _scratch_page(pool)
     pages = page_ids.shape[0]
 
     def body(p, pool_):
         pid = page_ids[p]
         pid_safe = jnp.where(pid >= 0, pid, scratch)
         out = dict(pool_)
-        for name in ("k", "v", "k_scale", "v_scale"):
-            if name not in pool_:
-                continue
+        for name in _buffers(pool_):
             row = jax.lax.dynamic_slice_in_dim(
                 small[name][:, 0], p * page_size, page_size, axis=1)
             out[name] = jax.lax.dynamic_update_index_in_dim(
@@ -138,8 +150,8 @@ def gather_prefix_pages(pool: dict, small: dict, page_ids: jax.Array,
     def body(p, small_):
         pid = page_ids[p]
         out = dict(small_)
-        for name in ("k", "v", "k_scale", "v_scale"):
-            if name not in pool or name not in small_:
+        for name in _buffers(pool):
+            if name not in small_:
                 continue
             row = pool[name][:, jnp.maximum(pid, 0)]
             cur = jax.lax.dynamic_slice_in_dim(
@@ -158,7 +170,7 @@ def _write_token_all_layers(pool: dict, k_tok, v_tok, page_table, pos,
     current page at pos % page_size. Slots with an unmapped page (id < 0,
     e.g. inactive) write to the scratch page instead — duplicate scratch
     writes are harmless because the scratch page is never read."""
-    scratch = pool["k"].shape[1] - 1
+    scratch = _scratch_page(pool)
     page_idx = pos // page_size
     offset = pos % page_size
     pid = jnp.take_along_axis(page_table, page_idx[:, None], axis=1)[:, 0]
@@ -203,7 +215,8 @@ def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
                           top_k: jax.Array = None, top_p: jax.Array = None,
                           lora=None, adapter_ids: jax.Array = None,
                           prev_token: jax.Array = None,
-                          from_prev: jax.Array = None):
+                          from_prev: jax.Array = None,
+                          with_loads: bool = False):
     """One decode token per slot against the page pool.
 
     ``attn_impl="reference"``: per layer, gather the slot's pages into a
@@ -230,10 +243,12 @@ def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
     [slots] with ``from_prev`` [slots] bool: a row marked there takes its
     input token from ``prev_token`` (the last tick's ``next_token``, which
     may still be on its way to the host) and not from ``tokens``.
-    Returns (next_token, new_pool, new_pos).
+    Returns (next_token, new_pool, new_pos); with ``with_loads`` (the
+    engine's program of an expert model) a fourth output follows: one
+    int32 vector of the tokens and behind them the tick's
+    ``expert_counters``, which one fetch brings.
     """
     from ..ops.paged_attention import paged_attention
-    from ..ops.rotary import rope_table
     from .llm import _cached_attention, _quantize_kv
     from .sampling import sample_logits
 
@@ -244,11 +259,11 @@ def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
     safe_table = jnp.maximum(page_table, 0)            # [slots, pages]
     live = page_table[:, :1] >= 0       # [slots, 1]: the row holds a request
     x = embed(config, params, tokens)
-    cos, sin = rope_table(positions, config.head_dim, config.rope_theta)
+    cos, sin = config.rope(positions)
     quantized = "k_scale" in pool
     use_kernel = attn_impl == "kernel"
-    if use_kernel:
-        scratch = pool["k"].shape[1] - 1
+    if use_kernel or config.latent_cache:
+        scratch = _scratch_page(pool)
         page_idx = pos // page_size
         offset = pos % page_size
         pid = jnp.take_along_axis(page_table, page_idx[:, None],
@@ -299,7 +314,11 @@ def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
         v_new.append(v[:, 0])
         return _cached_attention(config, q, kd, vd, positions, kd.shape[1])
 
-    x, _ = _serving_layers(
+    if config.latent_cache:
+        attend_kernel, attend_reference = _latent_decode_attends(
+            config, page_size, params, pool, page_table, pos, pid_safe,
+            offset)
+    x, loads = _serving_layers(
         config, params, x, cos, sin,
         attend_kernel if use_kernel else attend_reference, lora,
         adapter_ids, live=live)
@@ -308,10 +327,16 @@ def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
         next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     else:
         next_token = sample_logits(logits, rng, temperature, top_k, top_p)
+    # an expert model's tick also yields its experts' load, in one vector
+    # with the tokens: one fetch brings both
+    packed = ()
+    if with_loads:
+        with jax.named_scope("head"):
+            packed = (jnp.concatenate([next_token, expert_counters(loads)]),)
 
-    if use_kernel:
+    if use_kernel or config.latent_cache:
         # KV was written layer-by-layer before each attention call
-        return next_token, pool, pos + 1
+        return (next_token, pool, pos + 1) + packed
 
     # one pooled write for all layers: [L, slots, H, D]
     k_tok = jnp.stack(k_new)
@@ -324,7 +349,50 @@ def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
     else:
         new_pool = _write_token_all_layers(
             pool, k_tok, v_tok, page_table, pos, page_size)
-    return next_token, new_pool, pos + 1
+    return (next_token, new_pool, pos + 1) + packed
+
+
+def _latent_decode_attends(config, page_size: int, params, pool: dict,
+                           page_table, pos, pid_safe, offset):
+    """The two ``attend`` closures of a decode tick over a latent pool
+    (``pool``: the tick's own dict, updated in place): the token's cache
+    row (latent, then rotated key) into its page first, then the absorbed
+    form over the slot's rows. ``kernel``: ``mla_paged_decode`` reads the
+    pool through the page table; ``reference``: the pages gathered into a
+    dense view
+    and attended by plain products. Both store and read the same rows."""
+    from ..models.xing4 import absorb_query, unfold_values
+    from ..ops.mla_attention import (
+        absorbed_attention,
+        gather_latents,
+        mla_paged_decode,
+    )
+
+    scale = config.softmax_scale
+    rank = config.kv_lora_rank
+
+    def written(layer, rows):
+        pool["ckr"] = pool["ckr"].at[layer, pid_safe, offset].set(
+            rows[:, 0].astype(pool["ckr"].dtype))
+        return params["layers"]["w_ukv"][layer]
+
+    def attend_kernel(layer, q, rows, _):
+        w_ukv = written(layer, rows)
+        o_lat = mla_paged_decode(
+            absorb_query(config, w_ukv, q[:, 0]), pool["ckr"], layer,
+            page_table, pos, page_size=page_size, rank=rank, scale=scale)
+        return unfold_values(config, w_ukv, o_lat, q.dtype)[:, None]
+
+    def attend_reference(layer, q, rows, _):
+        w_ukv = written(layer, rows)
+        dense = gather_latents(pool["ckr"], layer, page_table)
+        visible = jnp.arange(dense.shape[1])[None, None, :] \
+            <= pos[:, None, None]
+        o_lat = absorbed_attention(absorb_query(config, w_ukv, q), dense,
+                                   visible, rank=rank, scale=scale)
+        return unfold_values(config, w_ukv, o_lat, q.dtype)
+
+    return attend_kernel, attend_reference
 
 
 def _verify_rowwise_paged(config: LlamaConfig, page_size: int,
@@ -380,7 +448,6 @@ def _verify_rowwise_paged(config: LlamaConfig, page_size: int,
     Returns (verified [slots, S] int32, new_pool).
     """
     from ..ops.paged_attention import paged_verify_attention
-    from ..ops.rotary import rope_table
     from .llm import _dequantize_kv
 
     b, s = chunk.shape
@@ -390,9 +457,9 @@ def _verify_rowwise_paged(config: LlamaConfig, page_size: int,
     if masked is not None:
         chunk = jnp.where(masked, config.mask_token_id, chunk)
     x = embed(config, params, chunk)
-    cos, sin = rope_table(positions, config.head_dim, config.rope_theta)
+    cos, sin = config.rope(positions)
     use_kernel = attn_impl == "kernel"
-    scratch = pool["k"].shape[1] - 1
+    scratch = _scratch_page(pool)
     page_idx = positions // page_size
     offset = positions % page_size
     pid = jnp.take_along_axis(page_table,
@@ -434,11 +501,8 @@ def _verify_rowwise_paged(config: LlamaConfig, page_size: int,
         # softmax(logits)[x0] = exp(max - logsumexp)
         confidence = jnp.exp(jnp.max(logits, axis=-1)
                              - jax.nn.logsumexp(logits, axis=-1))
-        counters = jnp.zeros((3,), jnp.int32)
-        if loads:
-            stacked = jnp.stack(loads)                   # [L, experts held]
-            counters = jnp.stack([jnp.sum(stacked), jnp.sum(stacked > 0),
-                                  jnp.max(stacked)]).astype(jnp.int32)
+        counters = expert_counters(loads) if loads \
+            else jnp.zeros((3,), jnp.int32)
         packed = jnp.concatenate([
             verified.reshape(-1),
             jax.lax.bitcast_convert_type(confidence.astype(jnp.float32),
@@ -460,6 +524,11 @@ class _TickInFlight:
     next_token: jax.Array
     rng: Optional[jax.Array] = None
     host: Optional[np.ndarray] = None
+    # what the host fetches: ``next_token``, or for an expert model the
+    # vector that holds the experts' counters behind the tokens, and the
+    # record of the iteration that dispatched the tick, which gets them
+    fetched: Optional[jax.Array] = None
+    record: Optional[object] = None
 
 
 class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
@@ -479,6 +548,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
     """
 
     _serves_blocks = True
+    _serves_latent = True
     REMASKING = ("low_confidence_static",)
 
     def __init__(self, config: LlamaConfig, params, max_len: int = 2048,
@@ -548,6 +618,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         self._kv_tier = (
             HostKVTier(int(tier_conf.get("host_bytes", 64 << 20)))
             if kv_tier and self._prefix is not None else None)
+        refuse_latent(config, "the host KV tier", self._kv_tier is not None)
         # fetch_prefix/import_prefix control ops queue here and run on
         # the scheduler thread between ticks (_control_tick): the page
         # pool is donated through every decode dispatch, so off-thread
@@ -578,8 +649,11 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         # the local flash over the suffix — docs/serving.md "Attention
         # kernels"); "gather" is the dense gather_prefix_pages seed
         # (reference/CPU fallback)
+        # a latent pool's prefix pages are small: a hit gathers them into
+        # the admission's rows, and its chunks expand them like its own
         self.paged_prefill_impl = (
-            "kernel" if self.prefill_impl == "flash" else "gather")
+            "kernel" if self.prefill_impl == "flash"
+            and not config.latent_cache else "gather")
         # +1 physical page: the scratch page for masked writes
         self._pool = init_paged_pool(config, self.n_pages + 1, page_size,
                                      kv_dtype)
@@ -597,7 +671,12 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         self._gather_bytes_per_tick = sum(
             arr.dtype.itemsize * config.n_layers * slots * max_len
             * int(np.prod(arr.shape[3:]))
-            for name, arr in self._pool.items() if name in ("k", "v"))
+            for name, arr in self._pool.items()
+            if not name.endswith("_scale"))
+        # bytes a token leaves in the pool over all layers, scales included
+        self.kv_bytes_per_token = sum(
+            arr.dtype.itemsize * config.n_layers
+            * int(np.prod(arr.shape[3:])) for arr in self._pool.values())
         self._stats.update({"attn_kernel_ticks": 0, "attn_gather_ticks": 0,
                             "attn_hbm_bytes_avoided": 0,
                             "lookahead_ticks": 0, "lookahead_drains": 0,
@@ -612,10 +691,11 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         # (prefix_kv= — see _prefill_dispatch)
         self._prefill = jax.jit(named("mlt_prefill", functools.partial(
             _forward_with_cache, config, attn_impl=self.prefill_impl,
-            page_size=page_size)))
+            page_size=page_size, **self._loads_kw())))
         self._decode_paged = jax.jit(
             named("mlt_decode", functools.partial(
-                _decode_rowwise_paged, config, page_size, self.attn_impl)),
+                _decode_rowwise_paged, config, page_size, self.attn_impl,
+                **self._loads_kw())),
             donate_argnums=(2,))
         # a block model's pass: the verify program with a mask bitmap
         self._denoise_paged = jax.jit(
@@ -642,9 +722,9 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         for bucket in self.prefill_buckets:
             small = init_kv_cache(self.config, 1, self.max_len,
                                   kv_dtype=self.kv_dtype)
-            _, small = self._prefill(
+            small = self._prefill(
                 self.params, jnp.zeros((1, bucket), jnp.int32), small,
-                logits_at=np.int32(bucket - 1), **prefill_kw)
+                logits_at=np.int32(bucket - 1), **prefill_kw)[1]
             self._pool = self._insert_paged(self._pool, small, ids)
         if self.prefill_chunk and self.prefill_chunk not in \
                 self.prefill_buckets:
@@ -706,15 +786,15 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         # host's or the last tick's, still on the device
         decode_kw.update(prev_token=self._no_tokens,
                          from_prev=jnp.zeros((self.slots,), bool))
-        tok, self._pool, _ = self._decode_paged(
-            self.params, step, self._pool, table, pos, **decode_kw)
+        tok, self._pool = self._decode_paged(
+            self.params, step, self._pool, table, pos, **decode_kw)[:2]
         jax.block_until_ready(tok)
-        tok, self._pool, _ = self._decode_paged(
+        tok, self._pool = self._decode_paged(
             self.params, step, self._pool, table, pos,
             jax.random.PRNGKey(0),
             jnp.zeros((self.slots,), jnp.float32),
             jnp.zeros((self.slots,), jnp.int32),
-            jnp.ones((self.slots,), jnp.float32), **decode_kw)
+            jnp.ones((self.slots,), jnp.float32), **decode_kw)[:2]
         jax.block_until_ready(tok)
         self._spec_warmup()
         logger.info("paged engine warm", slots=self.slots,
@@ -881,6 +961,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         The op runs on the scheduler thread between ticks
         (``_control_tick``): the page pool is donated through every
         decode dispatch, so off-thread pool reads are unsafe."""
+        refuse_latent(self.config, "a KV handoff (fetch_prefix)")
         future: Future = Future()
         self._control.append(("fetch", (list(prompt_tokens), adapter),
                               future))
@@ -893,6 +974,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         page pool + prefix index without admitting a request — the
         receiving side of the fetch hop. Resolves to the number of newly
         cached pages (0 = already cached, or no pages free)."""
+        refuse_latent(self.config, "a KV handoff (import_prefix)")
         expects_scales = self.kv_dtype == "int8"
         wire_dtype = getattr(handoff, "kv_dtype", None) or (
             "int8" if "k_scale" in handoff.kv else "native")
@@ -1316,8 +1398,10 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 future.set_exception(exc)
         if isinstance(exc, EngineStoppedError):
             try:
-                # a clean stop answers a request whose last tick has run
+                # a clean stop answers a request whose last tick has run,
+                # and reads the counters its last dispatches left
                 self._drain_tick()
+                self._settle_loads()
             except Exception:  # noqa: BLE001 - teardown goes on
                 pass
         # after a crash the commit may have stopped half way: the tick in
@@ -1344,13 +1428,15 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         "prefill_kernel_chunks", "prefill_gather_admissions",
         "kv_demotes", "kv_demoted_pages", "kv_promotes",
         "kv_promoted_pages", "kv_fetches", "kv_fetched_pages",
-        "kv_imports", "kv_imported_pages")
+        "kv_imports", "kv_imported_pages", "expert_pairs",
+        "experts_touched")
 
     @property
     def stats(self) -> dict:
         out = ContinuousBatchingEngine.stats.fget(self)
         out["decode_attn_impl"] = self.attn_impl
         out["paged_prefill_impl"] = self.paged_prefill_impl
+        out["kv_bytes_per_token"] = self.kv_bytes_per_token
         out["free_pages"] = len(self._free_pages)
         if self._prefix is not None:
             queries = self._prefix.queries
@@ -1454,12 +1540,16 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 else self._no_tokens
         tick.t_built = time.perf_counter()
         with annotate("mlt.sched.dispatch"):
-            next_token, self._pool, _ = self._decode_paged(
+            out = self._decode_paged(
                 self.params, *args, prev_token=prev_token,
                 from_prev=jnp.asarray(from_prev), **lora_kw)
-            next_token.copy_to_host_async()
+            next_token, self._pool = out[:2]
+            # an expert model's tokens come with its experts' counters
+            fetched = out[3] if len(out) > 3 else next_token
+            fetched.copy_to_host_async()
         self._in_flight = _TickInFlight(rows, next_token,
-                                        rng if sampling else None)
+                                        rng if sampling else None,
+                                        fetched=fetched, record=tick)
         tick.t_dispatched = tick.t_fetched = time.perf_counter()
         if ahead is not None:
             tick.lookahead = 1
@@ -1481,7 +1571,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         from here (``decode_stall``) until their next dispatch."""
         ahead = self._in_flight
         if ahead is not None and ahead.host is None:
-            ahead.host = np.asarray(ahead.next_token)
+            ahead.host = np.asarray(ahead.fetched)
             self._ledger_mark(ahead.rows, "decode_stall")
 
     def _drain_tick(self, admitting: bool = False):
@@ -1505,7 +1595,11 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         started = time.perf_counter()
         with annotate("mlt.sched.fetch"):
             tokens_host = ahead.host if ahead.host is not None \
-                else np.asarray(ahead.next_token)
+                else np.asarray(ahead.fetched)
+        # dispatches that ran before this tick have left their counters
+        self._settle_loads(before=ahead.record)
+        if len(tokens_host) > self.slots:
+            self._count_experts(ahead.record, tokens_host[self.slots:])
         if admitting:
             tick.admit_wait_s += time.perf_counter() - started
         else:

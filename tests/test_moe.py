@@ -163,32 +163,63 @@ def test_dropless_layer_matches_capacity_layer_when_nothing_drops(routing):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
+def _xing4_layer():
+    """(config, an expert layer's parameters, the family's reference, the
+    keyword that leaves the shared expert out of the reference's part)."""
+    from mlrun_tpu.models import init_params as any_init
+    from mlrun_tpu.models import tiny_xing4
+    from mlrun_tpu.models.llama import layer_slice
+
+    from . import xing4_reference
+
+    cfg = tiny_xing4(dtype=jnp.float32)
+    params = any_init(cfg, jax.random.PRNGKey(0))
+    lp = layer_slice(params["layers"], cfg.first_k_dense, cfg.first_k_dense)
+    # the stack of one expert layer's experts, as a layer's own
+    lp = {name: (value[0] if name.startswith("experts_") else value)
+          for name, value in lp.items()}
+    return cfg, lp, xing4_reference
+
+
 @pytest.mark.parametrize("routing", ["even", "skewed"])
 @pytest.mark.parametrize("shares", [2, 4, 8])
-def test_expert_shares_add_up_to_the_whole_layer(shares, routing):
+@pytest.mark.parametrize("family", ["sdar", "xing4"])
+def test_expert_shares_add_up_to_the_whole_layer(family, shares, routing):
     """The share test: with ``held`` set to each share of the experts in
     turn, the partial results add up to the whole layer's, in the program
-    and in the reference alike, and the loads to the whole load."""
-    cfg, lp = _sdar_layer()
+    and in the reference alike, and the loads to the whole load. What every
+    share computes alike (``xing4``: the shared expert) is counted once."""
+    from mlrun_tpu.models.moe import shared_expert
+
+    if family == "sdar":
+        (cfg, lp), reference = _sdar_layer(), ref
+    else:
+        cfg, lp, reference = _xing4_layer()
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 12, cfg.embed_dim),
                           jnp.float32)
     if routing == "skewed":
         x, lp = _skewed(cfg, lp)
     whole, load = moe_mlp(cfg, x, lp)
-    fields = ref.fields_of(cfg)
+    fields = reference.fields_of(cfg)
     flat = x.reshape(-1, cfg.embed_dim)
+    alike = shared_expert(x, lp) if "shared_gate" in lp else 0.0
+    routed_only = {"shared": False} if "shared_gate" in lp else {}
     width = cfg.n_experts // shares
-    total, total_ref, loads = 0.0, 0.0, []
+    total, total_ref, loads = alike, jnp.reshape(alike, (-1,) + (
+        (cfg.embed_dim,) if "shared_gate" in lp else ())), []
     for lo in range(0, cfg.n_experts, width):
         held = (lo, lo + width)
         part = {name: (value[lo:lo + width]
                        if name.startswith("experts_") else value)
                 for name, value in lp.items()}
         y, part_load = moe_mlp(cfg, x, part, held=held)
-        total = total + y
+        total = total + (y - alike)
         loads.append(part_load)
-        total_ref = total_ref + ref.experts_mlp(fields, flat, part,
-                                                held=held)
+        if family == "xing4":
+            part = {name: (value[None] if name.startswith("experts_")
+                           else value) for name, value in part.items()}
+        total_ref = total_ref + reference.experts_mlp(
+            fields, flat, part, held=held, **routed_only)
     np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
                                atol=2e-5)
     np.testing.assert_allclose(np.asarray(total_ref).reshape(whole.shape),

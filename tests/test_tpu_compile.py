@@ -351,3 +351,113 @@ def test_block_masked_prefill_compiles(sdar_shapes, on_chip, bucket):
     hlo = compiled.as_text()
     assert "flash_v2" in hlo and "tpu_custom_call" in hlo
     assert len(re.findall(r"%gmm[\w.-]* = ", hlo)) == 6
+
+
+# -- the latent-attention family: xing4-serve-longdoc's programs ------------
+# the benchmark cell's geometry (benchmarks/workloads/xing4-serve-longdoc.json)
+# at the published widths, seven layers: 32 slots x 64 pages/slot of 128,
+# 2,048 pool pages + the scratch page, chunks of 1,024
+X4_SLOTS, X4_PAGES_PER_SLOT, X4_PAGES, X4_CHUNK = 32, 64, 2048, 1024
+
+
+@pytest.fixture
+def xing4_shapes(on_chip, monkeypatch):
+    from mlrun_tpu.models import xing4
+    from mlrun_tpu.ops import mla_attention as mla
+
+    for module in (pattn, attn, mla):
+        monkeypatch.setattr(module, "interpret_default", lambda: False)
+    config = xing4.xing4_29b_a4b(n_layers=7, first_k_dense=1)
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda a: on_chip(a.shape, a.dtype), tree)
+
+    params = place(xing4.param_shapes(config))
+    pool = place(jax.eval_shape(lambda: paged.init_paged_pool(
+        config, X4_PAGES + 1, PAGE_SIZE)))
+    return config, params, pool, place
+
+
+def test_mla_flash_compiles(on_chip):
+    """``mla_flash`` at the published head widths (keys of 192, values of
+    128, 32 heads), a chunk of 1,024 against a block of 1,024."""
+    from mlrun_tpu.ops import mla_attention as mla
+
+    q = on_chip((X4_CHUNK, 32, 192), jnp.bfloat16)
+    v = on_chip((X4_CHUNK, 32, 128), jnp.bfloat16)
+    at = on_chip((), jnp.int32)
+    _compile(functools.partial(mla.mla_flash, scale=0.14468,
+                               interpret=False), q, q, v, at, at)
+
+
+def test_mla_paged_decode_compiles(xing4_shapes, on_chip):
+    """``mla_paged_decode`` over the cell's latent pool: one row of 512 +
+    64 a token for 32 heads."""
+    from mlrun_tpu.ops import mla_attention as mla
+
+    config, _params, pool, _place = xing4_shapes
+    _compile(functools.partial(mla.mla_paged_decode, page_size=PAGE_SIZE,
+                               rank=512, scale=config.softmax_scale,
+                               interpret=False),
+             on_chip((X4_SLOTS, 32, 640), jnp.bfloat16), pool["ckr"],
+             on_chip((), jnp.int32),
+             on_chip((X4_SLOTS, X4_PAGES_PER_SLOT), jnp.int32),
+             on_chip((X4_SLOTS,), jnp.int32))
+
+
+def test_xing4_decode_program_compiles(xing4_shapes, on_chip):
+    """``jit_mlt_decode`` of the cell: the absorbed kernel a layer, the
+    grouped expert products of six expert layers, the pool updated in
+    place, tokens and the experts' counters in one vector."""
+    config, params, pool, _place = xing4_shapes
+    fn = functools.partial(paged._decode_rowwise_paged, config, PAGE_SIZE,
+                           "kernel", with_loads=True)
+    compiled = jax.jit(fn, donate_argnums=(2,)).lower(
+        params, on_chip((X4_SLOTS, 1), jnp.int32), pool,
+        on_chip((X4_SLOTS, X4_PAGES_PER_SLOT), jnp.int32),
+        on_chip((X4_SLOTS,), jnp.int32),
+        prev_token=on_chip((X4_SLOTS,), jnp.int32),
+        from_prev=on_chip((X4_SLOTS,), jnp.bool_)).compile()
+    hlo = compiled.as_text()
+    assert "mla_paged_decode" in hlo
+    assert len(re.findall(r"%gmm[\w.-]* = ", hlo)) == 18
+    assert f"s32[{X4_SLOTS + 3}]" in hlo
+    memory = compiled.memory_analysis()
+    # weights and pool as stored, and no copy of an expert stack or of a
+    # pool layer among the temporaries
+    assert memory.temp_size_in_bytes < 512 << 20
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 15 << 30
+
+
+def test_xing4_prefill_chunk_compiles(xing4_shapes, on_chip):
+    """``jit_mlt_prefill`` of the cell: a chunk of 1,024 against the
+    admission's 8,192 latent rows, the expanded kernel in the loop over
+    blocks, the experts' counters as a third output."""
+    config, params, _pool, place = xing4_shapes
+    fn = functools.partial(llm._forward_with_cache, config,
+                           attn_impl="flash", page_size=PAGE_SIZE,
+                           with_loads=True)
+    cache = place(jax.eval_shape(lambda: llm.init_kv_cache(
+        config, 1, X4_PAGES_PER_SLOT * PAGE_SIZE)))
+    compiled = jax.jit(fn).lower(
+        params, on_chip((1, X4_CHUNK), jnp.int32), cache,
+        logits_at=on_chip((), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert "mla_flash" in hlo and "tpu_custom_call" in hlo
+    assert len(re.findall(r"%gmm[\w.-]* = ", hlo)) == 18
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 14 << 30
+
+
+def test_xing4_insert_program_compiles(xing4_shapes, on_chip):
+    """``jit_mlt_insert``: an admission's latent rows into the pool."""
+    config, _params, pool, place = xing4_shapes
+    small = place(jax.eval_shape(lambda: llm.init_kv_cache(
+        config, 1, X4_PAGES_PER_SLOT * PAGE_SIZE)))
+    fn = functools.partial(paged.insert_prompt_pages, page_size=PAGE_SIZE)
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(
+        pool, small, on_chip((X4_PAGES_PER_SLOT,), jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
