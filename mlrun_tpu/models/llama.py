@@ -281,7 +281,12 @@ def trainer_proj(lora: Optional[dict], dtype):
     added in ``dtype``. The serving form (serving/llm.py ``_serving_proj``)
     adds its delta in f32 before the cast: the two round in a different
     order, so they stay two."""
-    def proj(h_in, w, key=None):
+    def proj(h_in, w, key=None, out_major=False):
+        if out_major:
+            raise ValueError(
+                f"'{key}' is in a serving engine's layout "
+                f"({SERVING_LEAVES.get(key, key)}, [heads, head_dim, E]); "
+                f"the trainer takes the logical tree")
         out = jnp.einsum("bse,eh->bsh", h_in, w,
                          preferred_element_type=jnp.float32).astype(dtype)
         if lora is not None and key in lora:
@@ -295,16 +300,33 @@ def trainer_proj(lora: Optional[dict], dtype):
     return proj
 
 
+# the attention's input projections, and the names under which a serving
+# engine holds them out-major and split into heads, [L, heads, head_dim, E]:
+# the layout their products contract over (serving/llm.py ``serving_tree``).
+# Which layout a tree holds is read from these names and from nothing else.
+SERVING_LEAVES = {"wq": "wq_t", "wk": "wk_t", "wv": "wv_t"}
+
+
+def _in_proj(proj, lp, h, key: str):
+    """``h`` through the layer's projection ``key`` as ``lp`` holds it: the
+    logical leaf ``lp[key]`` [E, H], or the same matrix stored [heads,
+    head_dim, E] under ``SERVING_LEAVES[key]``, which ``proj`` is told
+    (``out_major``) and answers split into heads."""
+    if key in lp:
+        return proj(h, lp[key], key)
+    return proj(h, lp[SERVING_LEAVES[key]], key, out_major=True)
+
+
 def llama_qkv(config: LlamaConfig, lp, h, cos, sin, proj):
     """q, k and v of the Llama family out of the normed input ``h``: three
     projections, the q/k norm where the config has one, the rotation."""
     b, s, _ = h.shape
-    q = proj(h, lp["wq"], "wq").reshape(b, s, config.n_heads,
-                                        config.head_dim)
-    k = proj(h, lp["wk"], "wk").reshape(b, s, config.n_kv_heads,
-                                        config.head_dim)
-    v = proj(h, lp["wv"], "wv").reshape(b, s, config.n_kv_heads,
-                                        config.head_dim)
+    q = _in_proj(proj, lp, h, "wq").reshape(b, s, config.n_heads,
+                                            config.head_dim)
+    k = _in_proj(proj, lp, h, "wk").reshape(b, s, config.n_kv_heads,
+                                            config.head_dim)
+    v = _in_proj(proj, lp, h, "wv").reshape(b, s, config.n_kv_heads,
+                                            config.head_dim)
     q, k = qk_normed(config, q, k, lp)
     return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 

@@ -164,6 +164,12 @@ LLM_KV_BYTES_PER_TOKEN = REGISTRY.gauge(
     "Bytes a token leaves in the page pool over all layers (per-head keys "
     "and values, or one latent row), paged engines",
     labels=("engine", "replica"), overflow="drop")
+LLM_WEIGHTS_RELAID_BYTES = REGISTRY.gauge(
+    "mlt_llm_weights_relaid_bytes",
+    "Bytes of weight leaves the engine holds in the serving layout (wq, wk, "
+    "wv stored [L, heads, head_dim, E]; the draft model's too); 0 for a "
+    "family whose q/k/v read other leaves",
+    labels=("engine", "replica"), overflow="drop")
 LLM_EVENTS = REGISTRY.counter(
     "mlt_llm_events_total",
     "Cumulative engine events mirrored from stats() (requests, completed, "

@@ -20,12 +20,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.llama import (
+    SERVING_LEAVES,
     LlamaConfig,
     Params,
     decoder_block,
     embed,
     head_logits,
     layer_slice,
+    llama_qkv,
 )
 from ..ops.attention import causal_bound
 from ..utils import logger
@@ -164,15 +166,69 @@ def _serving_proj(lora, adapter_ids, layer: int, dtype):
     """The serving programs' projection ``proj(h_in, w, key)`` for layer
     ``layer``: f32 product, plus each row's LoRA delta out of the adapter
     bank (``lora[key]``, where present) in f32, then the cast to ``dtype``
-    (models/llama.py ``trainer_proj`` casts first: they stay two)."""
-    def proj(h_in, w, key=None):
-        out = jnp.einsum("bse,eh->bsh", h_in, w,
-                         preferred_element_type=jnp.float32)
+    (models/llama.py ``trainer_proj`` casts first: they stay two).
+    ``out_major``: ``w`` is stored [heads, head_dim, E]
+    (:func:`serving_tree`) and the output comes split into heads, [B, S,
+    heads, head_dim]: the same product over another order of storage."""
+    def proj(h_in, w, key=None, out_major=False):
+        out = jnp.einsum("bse,nde->bsnd" if out_major else "bse,eh->bsh",
+                         h_in, w, preferred_element_type=jnp.float32)
         if lora is not None and key is not None and key in lora:
-            out = out + _lora_delta(h_in, lora[key], layer, adapter_ids)
+            out = out + _lora_delta(h_in, lora[key], layer,
+                                    adapter_ids).reshape(out.shape)
         return out.astype(dtype)
 
     return proj
+
+
+def relay_layers(config: LlamaConfig, layers: dict) -> dict:
+    """``layers`` (a tree's stacked layers) with the attention's input
+    projections in the serving layout, **in place**: for a family whose
+    q/k/v are models/llama.py ``llama_qkv``'s, each of ``wq``, ``wk``,
+    ``wv`` [L, E, H] is stored anew out-major and split into heads, [L,
+    heads, head_dim, E], under its name in ``SERVING_LEAVES``, and the
+    logical leaf is let go before the next is touched, so at no moment
+    both copies of more than one leaf are held (by this dict: a caller
+    that keeps the leaves elsewhere keeps them).
+
+    Why (AOT compile, tests/test_tpu_compile.py): a serving program's
+    product ``"bse,eh->bsh"`` with the reshape into heads behind it is
+    laid out with the contraction minor where the logical leaf has it
+    major, so every run of the program slices the layer's leaf out of the
+    stack and transposes it before the product, 50 MB a layer at
+    Mistral-7B's widths. ``"bse,nde->bsnd"`` over the relaid leaf slices
+    the layer inside the product, as ``wo`` and the MLP's are; held [L,
+    H, E] without the heads the transposition goes and the slice stays a
+    copy of its own. A leaf already relaid, and a family whose seam reads
+    other leaves, are left as they are."""
+    if config.seams.qkv is not llama_qkv:
+        return layers
+    for name, relaid in SERVING_LEAVES.items():
+        if name in layers:
+            w = layers.pop(name)
+            layers[relaid] = jax.block_until_ready(
+                jnp.swapaxes(w, 1, 2).reshape(
+                    w.shape[0], -1, config.head_dim, w.shape[1]))
+    return layers
+
+
+def serving_tree(config: LlamaConfig, params: Params) -> Params:
+    """The tree a serving engine holds, out of the one it is given: the
+    logical tree's leaves with ``wq``, ``wk``, ``wv`` relaid
+    (:func:`relay_layers`, on a copy of the two dicts: the caller's tree
+    is not changed, and its logical leaves live as long as the caller
+    keeps them). A tree already in the serving layout, or of a family
+    with nothing to relay, is returned as it is."""
+    layers = relay_layers(config, dict(params["layers"]))
+    if layers.keys() == params["layers"].keys():
+        return params
+    return {**params, "layers": layers}
+
+
+def relaid_bytes(params: Params) -> int:
+    """Bytes of the leaves ``params`` holds in the serving layout."""
+    return sum(int(leaf.nbytes) for name, leaf in params["layers"].items()
+               if name in SERVING_LEAVES.values())
 
 
 def _serving_layers(config: LlamaConfig, params: Params, x, cos, sin,
@@ -424,7 +480,9 @@ class LLMEngine:
         refuse_latent(config, "LLMEngine's dense rows (the paged engine "
                       "serves it: continuous_batching=True, paged=True)")
         self.config = config
-        self.params = params
+        # wq, wk, wv in the layout their products contract over
+        self.params = serving_tree(config, params)
+        self.weights_relaid_bytes = relaid_bytes(self.params)
         self.max_len = max_len
         self.batch = batch
         self.temperature = temperature
@@ -920,6 +978,11 @@ class LLMModelServer:
                 else:
                     config = MODEL_PRESETS[self.model_preset]()
                     params = init_params(config, jax.random.PRNGKey(0))
+                # this tree is the server's alone, so it goes into the
+                # serving layout in place, a leaf at a time: the engines
+                # (a fleet's replicas share it) then take it as it is,
+                # and the device never holds both copies of all three
+                relay_layers(config, params["layers"])
                 if self.tokenizer_id:
                     from transformers import AutoTokenizer
 
@@ -950,6 +1013,8 @@ class LLMModelServer:
                         spec_conf["draft_config"] = draft_config
                         spec_conf["draft_params"] = init_params(
                             draft_config, jax.random.PRNGKey(1))
+                        relay_layers(draft_config,
+                                     spec_conf["draft_params"]["layers"])
                     if not (spec_conf.get("enabled")
                             and spec_conf.get("draft_config") is not None):
                         spec_conf = None

@@ -46,6 +46,7 @@ from ..obs import (
     LLM_SPEC_ROUNDS,
     LLM_SPEC_TOKENS,
     LLM_TTFT,
+    LLM_WEIGHTS_RELAID_BYTES,
     REGISTRY,
     RequestLedger,
     TickRecord,
@@ -72,6 +73,8 @@ from .llm import (
     _stacked_cache,
     init_kv_cache,
     refuse_latent,
+    relaid_bytes,
+    serving_tree,
 )
 from .samples import emit_sample, sampling_enabled
 from .resilience import (  # noqa: F401 - EngineStoppedError re-exported
@@ -385,7 +388,10 @@ class ContinuousBatchingEngine:
         from .adapters import AdapterRegistry, TenantRateLimiter
 
         self.config = config
-        self.params = params
+        # the engine's own tree: wq, wk, wv in the layout their products
+        # contract over (serving/llm.py ``serving_tree``); a tree that
+        # already is, is taken as it is
+        self.params = serving_tree(config, params)
         self.max_len = max_len
         self.slots = slots
         self.kv_dtype = kv_dtype
@@ -574,7 +580,8 @@ class ContinuousBatchingEngine:
                        "prefill_tokens_tick_max": 0,
                        "handoffs_out": 0, "handoff_bytes_out": 0,
                        "handoffs_in": 0, "handoff_bytes_in": 0,
-                       "adapter_rate_limited": 0}
+                       "adapter_rate_limited": 0,
+                       "weights_relaid_bytes": relaid_bytes(self.params)}
         if self.block_length > 1:
             # row-passes that denoised and that committed a block, and the
             # most pairs one expert got in one layer of one pass
@@ -637,7 +644,9 @@ class ContinuousBatchingEngine:
         if draft_config.vocab_size != self.config.vocab_size:
             raise ValueError("draft and target must share a vocabulary")
         self._spec_draft_config = draft_config
-        self._spec_draft_params = draft_params
+        self._spec_draft_params = serving_tree(draft_config, draft_params)
+        self._stats["weights_relaid_bytes"] += relaid_bytes(
+            self._spec_draft_params)
         # draft KV is always the dense slot layout (tiny model — the page
         # pool exists for the TARGET's HBM footprint, not the draft's)
         self._spec_dcache = init_kv_cache(draft_config, self.slots,
@@ -1094,6 +1103,7 @@ class ContinuousBatchingEngine:
                                        adapter=adapter)
             LLM_FREE_PAGE_FRAC.remove(engine=name, replica=replica)
             LLM_KV_BYTES_PER_TOKEN.remove(engine=name, replica=replica)
+            LLM_WEIGHTS_RELAID_BYTES.remove(engine=name, replica=replica)
             if has_spec:
                 LLM_SPEC_ROUNDS.remove(engine=name, replica=replica)
                 for outcome in ("accepted", "rejected"):
@@ -1161,6 +1171,8 @@ class ContinuousBatchingEngine:
             if "kv_bytes_per_token" in stats:
                 LLM_KV_BYTES_PER_TOKEN.set(stats["kv_bytes_per_token"],
                                            engine=name, replica=replica)
+            LLM_WEIGHTS_RELAID_BYTES.set(stats["weights_relaid_bytes"],
+                                         engine=name, replica=replica)
             for key in engine._COUNTER_STATS:
                 if key in stats:
                     LLM_EVENTS.set_total(stats[key], engine=name,

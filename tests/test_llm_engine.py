@@ -12,7 +12,11 @@ from mlrun_tpu.serving.llm import LLMEngine, init_kv_cache
 def engine():
     cfg = tiny_llama(attention_impl="reference")
     params = init_params(cfg, jax.random.PRNGKey(0))
-    return LLMEngine(cfg, params, max_len=128, prefill_buckets=(32, 64))
+    eng = LLMEngine(cfg, params, max_len=128, prefill_buckets=(32, 64))
+    # ``eng.params`` is the engine's own tree (wq, wk, wv in the serving
+    # layout); the plain forward takes the logical one
+    eng.logical_params = params
+    return eng
 
 
 def test_generate_greedy(engine):
@@ -35,7 +39,7 @@ def test_generate_matches_full_forward(engine):
     seq = list(prompt)
     expected = []
     for _ in range(4):
-        logits = forward(cfg, engine.params,
+        logits = forward(cfg, engine.logical_params,
                          jnp.asarray([seq], jnp.int32))
         nxt = int(jnp.argmax(logits[0, -1]))
         expected.append(nxt)
@@ -75,8 +79,8 @@ def test_first_token_from_the_padded_prefill(engine, length, batched):
     else:
         got, _ = eng.generate(prompt, max_new_tokens=5)
     assert_greedy_equal_up_to_tie(
-        cfg, engine.params, prompt, got,
-        greedy_reference(cfg, engine.params, prompt, 5))
+        cfg, engine.logical_params, prompt, got,
+        greedy_reference(cfg, engine.logical_params, prompt, 5))
     rows = 2 if batched else 1
     assert prefills == [((rows, 32 if length <= 32 else 64), False)]
     assert not decodes

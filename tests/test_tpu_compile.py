@@ -87,6 +87,13 @@ def _pool(on_chip, head_dim, kv_dtype):
     return on_chip(dims, jnp.bfloat16), layer, {}
 
 
+def _placer(on_chip):
+    """``place(tree)``: the shapes of a tree's leaves on the described
+    chip."""
+    return lambda tree: jax.tree_util.tree_map(
+        lambda a: on_chip(a.shape, a.dtype), tree)
+
+
 def _compile(fn, *args, **kwargs):
     compiled = jax.jit(fn).lower(*args, **kwargs).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -205,17 +212,16 @@ def program_shapes(on_chip, monkeypatch):
     monkeypatch.setattr(pattn, "interpret_default", lambda: False)
     monkeypatch.setattr(attn, "interpret_default", lambda: False)
 
-    def place(tree):
-        return jax.tree_util.tree_map(
-            lambda a: on_chip(a.shape, a.dtype), tree)
+    place = _placer(on_chip)
 
     def shapes(head_dim, kv_dtype):
         config = LlamaConfig(
             vocab_size=1024, n_layers=POOL_LAYERS, embed_dim=512,
             n_heads=N_HEADS, n_kv_heads=N_KV_HEADS, head_dim=head_dim,
             mlp_dim=1024)
-        params = place(jax.eval_shape(
-            lambda: init_params(config, jax.random.PRNGKey(0))))
+        # the tree as an engine holds it (serving/llm.py serving_tree)
+        params = place(jax.eval_shape(lambda: llm.serving_tree(
+            config, init_params(config, jax.random.PRNGKey(0)))))
         pool = place(jax.eval_shape(lambda: paged.init_paged_pool(
             config, N_PAGES + 1, PAGE_SIZE, KV_DTYPES[kv_dtype])))
         return config, params, pool, place
@@ -282,6 +288,101 @@ def test_pool_programs_copy_no_layer(program_shapes, on_chip, program,
         assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
 
 
+# -- wq, wk, wv as the engine holds them --------------------------------------
+# the benchmark cell m7b-serve-chat's model as it runs there
+# (benchmarks/configs/: 16 layers; at two the compiler fetches both layers'
+# leaves ahead into its fast memory, which 16 do not fit): 32 slots x 16
+# pages of 128
+M7B_SLOTS, M7B_LAYERS, M7B_HIDDEN, M7B_MLP, M7B_VOCAB, M7B_HEAD_DIM = \
+    32, 16, 4096, 14336, 32768, 128
+# the dimensions, 1s dropped, under which a layer's wq [4096, 4096] or
+# wk / wv [4096, 1024] shows in the HLO, in either order, heads split or not
+_QKV_DIMS = {dims for h in (N_HEADS, N_KV_HEADS) for dims in (
+    (M7B_HIDDEN, h * M7B_HEAD_DIM), (h * M7B_HEAD_DIM, M7B_HIDDEN),
+    (M7B_HIDDEN, h, M7B_HEAD_DIM), (h, M7B_HEAD_DIM, M7B_HIDDEN))}
+
+
+def _qkv_sized_results(hlo: str):
+    """Opcodes of the instructions of the entry computation whose result,
+    or an element of whose tuple, has the elements of one layer's ``wq``,
+    ``wk`` or ``wv``. What is fused into a product (a ``slice``, a
+    ``bitcast``) sits in that fusion's own computation and yields no
+    buffer."""
+    found, entry = set(), False
+    line_re = re.compile(r"^\s*(?:ROOT )?\S+ = (.*?) ([a-z][a-z0-9-]*)\(")
+    array_re = re.compile(r"bf16\[([\d,]+)\]")
+    for line in hlo.splitlines():
+        if line.startswith("ENTRY "):
+            entry = True
+        elif line.startswith("}"):
+            entry = False
+        elif entry and (m := line_re.match(line)):
+            if any(tuple(int(d) for d in dims.split(",") if d != "1")
+                   in _QKV_DIMS for dims in array_re.findall(m.group(1))):
+                found.add(m.group(2))
+    return found
+
+
+@pytest.fixture
+def m7b_shapes(on_chip, monkeypatch):
+    monkeypatch.setattr(pattn, "interpret_default", lambda: False)
+    monkeypatch.setattr(attn, "interpret_default", lambda: False)
+    config = LlamaConfig(
+        vocab_size=M7B_VOCAB, n_layers=M7B_LAYERS, embed_dim=M7B_HIDDEN,
+        n_heads=N_HEADS, n_kv_heads=N_KV_HEADS, head_dim=M7B_HEAD_DIM,
+        mlp_dim=M7B_MLP, rope_theta=1e6)
+
+    place = _placer(on_chip)
+
+    def shapes(tree):
+        def made():
+            params = init_params(config, jax.random.PRNGKey(0))
+            return params if tree == "logical" \
+                else llm.serving_tree(config, params)
+
+        return config, place(jax.eval_shape(made)), place
+
+    return shapes
+
+
+@pytest.mark.parametrize("tree", ["engine", "logical"])
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_qkv_weights_read_as_stored(m7b_shapes, on_chip, program, tree):
+    """``jit_mlt_decode`` and the cold prefill at bucket 512 of
+    ``m7b-serve-chat``: on the engine's tree (``wq``, ``wk``, ``wv`` held
+    [L, heads, head_dim, E], serving/llm.py ``serving_tree``) no
+    instruction of the program yields an array with the elements of a
+    layer's leaf but a ``bitcast``: the layer is sliced inside its
+    product. The control compiles the same program on the logical tree,
+    whose product wants the contraction minor where the leaf has it major,
+    and finds the slice and the ``copy`` a layer a leaf that every run
+    would pay (0.8 GB): the test sees what it guards."""
+    config, params, place = m7b_shapes(tree)
+    if program == "decode":
+        pool = place(jax.eval_shape(lambda: paged.init_paged_pool(
+            config, N_PAGES + 1, PAGE_SIZE)))
+        compiled = jax.jit(functools.partial(
+            paged._decode_rowwise_paged, config, PAGE_SIZE, "kernel"),
+            donate_argnums=(2,)).lower(
+                params, on_chip((M7B_SLOTS, 1), jnp.int32), pool,
+                on_chip((M7B_SLOTS, PAGES_PER_SLOT), jnp.int32),
+                on_chip((M7B_SLOTS,), jnp.int32),
+                prev_token=on_chip((M7B_SLOTS,), jnp.int32),
+                from_prev=on_chip((M7B_SLOTS,), jnp.bool_)).compile()
+    else:
+        cache = place(jax.eval_shape(lambda: llm.init_kv_cache(
+            config, 1, PAGES_PER_SLOT * PAGE_SIZE)))
+        compiled = jax.jit(functools.partial(
+            llm._forward_with_cache, config, attn_impl="flash")).lower(
+                params, on_chip((1, PREFILL_CHUNK), jnp.int32), cache,
+                logits_at=on_chip((), jnp.int32)).compile()
+    opcodes = _qkv_sized_results(compiled.as_text())
+    if tree == "logical":
+        assert {"fusion", "copy"} <= opcodes, opcodes
+    else:
+        assert opcodes <= {"bitcast"}, opcodes
+
+
 # -- the block-diffusion family: experts, q/k norms, the block mask ----------
 # the benchmark cell sdar-serve-chat's shapes (benchmarks/workloads/), the
 # model cut to two layers (a copy of a layer's experts shows from two on): 32 slots x 16 pages/slot of 128, a block of 4
@@ -301,12 +402,10 @@ def sdar_shapes(on_chip, monkeypatch):
         expert_dim=768, block_length=SDAR_BLOCK, rope_theta=1e6,
         norm_eps=1e-6)
 
-    def place(tree):
-        return jax.tree_util.tree_map(
-            lambda a: on_chip(a.shape, a.dtype), tree)
+    place = _placer(on_chip)
 
-    params = place(jax.eval_shape(
-        lambda: init_moe(config, jax.random.PRNGKey(0))))
+    params = place(jax.eval_shape(lambda: llm.serving_tree(
+        config, init_moe(config, jax.random.PRNGKey(0)))))
     return config, params, place
 
 
@@ -369,9 +468,7 @@ def xing4_shapes(on_chip, monkeypatch):
         monkeypatch.setattr(module, "interpret_default", lambda: False)
     config = xing4.xing4_29b_a4b(n_layers=7, first_k_dense=1)
 
-    def place(tree):
-        return jax.tree_util.tree_map(
-            lambda a: on_chip(a.shape, a.dtype), tree)
+    place = _placer(on_chip)
 
     params = place(xing4.param_shapes(config))
     pool = place(jax.eval_shape(lambda: paged.init_paged_pool(
