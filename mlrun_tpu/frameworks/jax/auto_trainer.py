@@ -18,6 +18,7 @@ from typing import Any, Callable, Iterator, Optional
 from ...config import mlconf
 from ...execution import MLClientCtx
 from ...models import llama as llama_mod
+from ...models import nemotron_h as nemotron_h_mod
 from ...models import xing4 as xing4_mod
 from ...models.llama import LlamaConfig
 from ...utils import logger
@@ -29,6 +30,8 @@ MODEL_PRESETS = {
     "tiny": llama_mod.tiny_llama,
     "xing4-29b-a4b": xing4_mod.xing4_29b_a4b,
     "tiny-xing4": xing4_mod.tiny_xing4,
+    "nemotron-3-nano-30b-a3b": nemotron_h_mod.nemotron_3_nano_30b_a3b,
+    "tiny-nemotron-h": nemotron_h_mod.tiny_nemotron_h,
 }
 
 

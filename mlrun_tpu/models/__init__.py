@@ -35,16 +35,25 @@ from .moe import (  # noqa: F401
     tiny_sdar,
 )
 from .xing4 import Xing4Config, tiny_xing4, xing4_29b_a4b  # noqa: F401
-from . import llama as _llama, moe as _moe, xing4 as _xing4
+from .nemotron_h import (  # noqa: F401
+    NemotronHConfig,
+    nemotron_3_nano_30b_a3b,
+    tiny_nemotron_h,
+)
+from . import llama as _llama, moe as _moe, nemotron_h as _nemotron_h, \
+    xing4 as _xing4
 
 
 def init_params(config, key):
     """The weights that the config's own module defines, by its recipe:
-    ``models/xing4.py`` for the latent-attention family, ``models/moe.py``
-    for any other config with experts, ``models/llama.py`` for the dense
-    decoder."""
+    ``models/xing4.py`` for the latent-attention family,
+    ``models/nemotron_h.py`` for the one with state-space layers,
+    ``models/moe.py`` for any other config with experts,
+    ``models/llama.py`` for the dense decoder."""
     if isinstance(config, Xing4Config):
         module = _xing4
+    elif isinstance(config, NemotronHConfig):
+        module = _nemotron_h
     else:
         module = _moe if isinstance(config, MoEConfig) else _llama
     return module.init_params(config, key)

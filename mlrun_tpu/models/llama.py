@@ -55,6 +55,10 @@ class LlamaConfig:
     # latent row all heads share (True: models/xing4.py); a class's, never
     # an instance's
     latent_cache = False
+    # a sequence keeps a recurrent state of constant size a layer beside its
+    # rows in the cache (True: models/nemotron_h.py, ``state_rows()``); a
+    # class's, never an instance's
+    recurrent_state = False
 
     vocab_size: int = 128256
     n_layers: int = 32
@@ -101,6 +105,32 @@ class LlamaConfig:
         name and the shape of one token's row."""
         row = (self.n_kv_heads, self.head_dim)
         return {"k": row, "v": row}
+
+    @property
+    def cache_layers(self) -> int:
+        """The layers whose tokens leave rows in the cache."""
+        return self.n_layers
+
+    def state_rows(self) -> dict:
+        """What a sequence keeps a layer beside its rows, whatever its
+        length: each buffer's name with its shape and dtype (none here)."""
+        return {}
+
+    @property
+    def state_layers(self) -> int:
+        """The layers that keep ``state_rows()``."""
+        return 0
+
+    def sublayers(self, layer) -> tuple:
+        """The sub-layers of layer ``layer`` (None: any) as
+        :func:`decoder_block` runs them, in order."""
+        return ("attn", "mlp")
+
+    def leaf_index(self, name: str, layer: int):
+        """Where layer ``layer`` sits in the stack of leaf ``name``, or
+        None where the layer has no such leaf (:func:`layer_slice`): here
+        every leaf is stacked over all layers."""
+        return layer
 
     def rope(self, positions: jax.Array) -> tuple[jax.Array, jax.Array]:
         """The cos/sin tables of ``positions`` as this family rotates."""
@@ -305,6 +335,14 @@ def trainer_proj(lora: Optional[dict], dtype):
 # the layout their products contract over (serving/llm.py ``serving_tree``).
 # Which layout a tree holds is read from these names and from nothing else.
 SERVING_LEAVES = {"wq": "wq_t", "wk": "wk_t", "wv": "wv_t"}
+# an expert's input matrices, and the names under which a serving engine
+# holds them out-major, [L, experts, width, E], where the expert's width is
+# no whole number of 128 lanes (serving/llm.py ``relay_layers``): the device
+# keeps a buffer whose minor dimension is not one with the other dimension
+# minor, and a kernel that wants it row-major gets a copy of all of it
+# before every call
+EXPERT_SERVING_LEAVES = {"experts_gate": "experts_gate_t",
+                         "experts_up": "experts_up_t"}
 
 
 def _in_proj(proj, lp, h, key: str):
@@ -328,6 +366,8 @@ def llama_qkv(config: LlamaConfig, lp, h, cos, sin, proj):
     v = _in_proj(proj, lp, h, "wv").reshape(b, s, config.n_kv_heads,
                                             config.head_dim)
     q, k = qk_normed(config, q, k, lp)
+    if cos is None:             # a family that rotates nothing
+        return q, k, v
     return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
 
@@ -340,42 +380,55 @@ LLAMA_SEAMS = BlockSeams(
 
 
 def decoder_block(config: LlamaConfig, lp, x, cos, sin, *, proj, attend,
-                  mlp=None, live=None, layer=None):
-    """The decoder layer, written once. x: the residual state ([B, S, E];
-    a family with several residual streams carries [B, S, n, E]); ``lp``
-    the layer's parameters. Each of the two sub-layers reads the state
-    (``seams.read``: the state itself here), norms what it read, computes,
-    and writes back (``seams.write``: an addition here). Attention: q/k/v
-    by ``seams.qkv`` (:func:`llama_qkv`: projections through ``proj(h_in,
-    w, key)``, :func:`trainer_proj` or serving/llm.py ``_serving_proj``,
-    q/k norm, rope), ``attend(q, k, v) -> [B, S, Hq, D]`` (or with the
-    heads merged already, [B, S, Hq * D]), ``wo``. MLP: :func:`layer_mlp`
-    with ``live`` and ``layer``, unless ``mlp`` is given: ``mlp(h2) ->
-    (out, extra)``. ``config.seams`` are the family's (:class:`BlockSeams`).
+                  mlp=None, ssm=None, live=None, layer=None):
+    """The decoder layer, written once: a sequence of sub-layers
+    (``config.sublayers(layer)``: attention then MLP for the dense, the
+    block-diffusion and the latent families; one a layer, of the kind the
+    config's pattern gives, for models/nemotron_h.py). x: the residual
+    state ([B, S, E]; a family with several residual streams carries [B,
+    S, n, E]); ``lp`` the layer's parameters. Each sub-layer reads the
+    state (``seams.read``: the state itself here), norms what it read
+    (``<sub>_norm_scale``), computes, and writes back (``seams.write``: an
+    addition here). ``attn``: q/k/v by ``seams.qkv`` (:func:`llama_qkv`:
+    projections through ``proj(h_in, w, key)``, :func:`trainer_proj` or
+    serving/llm.py ``_serving_proj``, q/k norm, rope), ``attend(q, k, v) ->
+    [B, S, Hq, D]`` (or with the heads merged already, [B, S, Hq * D]),
+    ``wo``. ``mlp``: :func:`layer_mlp` with ``live`` and ``layer``, unless
+    ``mlp`` is given: ``mlp(h2) -> (out, extra)``. ``ssm``: the caller's
+    ``ssm(lp, h, proj) -> out``. ``config.seams`` are the family's
+    (:class:`BlockSeams`).
 
     ``attend`` is all a caller says about its cache: the closure writes
     what the token leaves behind where that caller keeps it and reads the
     attention back (none, dense rows, pages), keeping what it wrote for
-    its own return.
+    its own return. ``ssm`` likewise is all it says about a recurrent
+    state: the closure runs the mixer from the state the sequence kept and
+    keeps the new one.
 
     Returns ``(x, extra)``: ``extra`` is what the MLP returned beside its
     output (expert load, aux loss, ``None``)."""
     b, s = x.shape[:2]
     seams = config.seams
-    # the named scopes are metadata a profile groups operations by
-    # (embed, layer/attn, layer/mlp, head, loss): no instruction is renamed
-    with jax.named_scope("layer/attn"):
-        u, mix = seams.read(config, x, lp, "attn")
-        h = rms_norm(u, lp["attn_norm_scale"], config.norm_eps)
-        q, k, v = seams.qkv(config, lp, h, cos, sin, proj)
-        attn = attend(q, k, v).reshape(b, s, config.qkv_dim)
-        x = seams.write(config, x, mix, proj(attn, lp["wo"], "wo"))
-    with jax.named_scope("layer/mlp"):
-        u, mix = seams.read(config, x, lp, "mlp")
-        h2 = rms_norm(u, lp["mlp_norm_scale"], config.norm_eps)
-        out, extra = mlp(h2) if mlp is not None else layer_mlp(
-            config, h2, lp, proj, live=live, layer=layer)
-        x = seams.write(config, x, mix, out)
+    extra = None
+    for sub in config.sublayers(layer):
+        # the named scopes are metadata a profile groups operations by
+        # (embed, layer/attn, layer/mlp, head, loss): no instruction is
+        # renamed
+        with jax.named_scope(f"layer/{sub}"):
+            u, mix = seams.read(config, x, lp, sub)
+            h = rms_norm(u, lp[f"{sub}_norm_scale"], config.norm_eps)
+            if sub == "attn":
+                q, k, v = seams.qkv(config, lp, h, cos, sin, proj)
+                attn = attend(q, k, v).reshape(b, s, config.qkv_dim)
+                out = proj(attn, lp["wo"], "wo")
+            elif sub == "ssm":
+                out = ssm(lp, h, proj)
+            elif mlp is not None:
+                out, extra = mlp(h)
+            else:
+                out, extra = layer_mlp(config, h, lp, proj, live=live,
+                                       layer=layer)
+            x = seams.write(config, x, mix, out)
     return x, extra
 
 
@@ -415,38 +468,42 @@ def qk_normed(config: LlamaConfig, q, k, lp):
 # a layer's MLP leaves: the dense SwiGLU's, and the expert layer's that are
 # stacked over the expert layers alone where dense layers lead
 DENSE_MLP_LEAVES = ("w_gate", "w_up", "w_down")
-EXPERT_LAYER_LEAVES = ("router", "shared_")
+EXPERT_LAYER_LEAVES = ("router", "shared_", "experts_")
 
 
-def layer_slice(layers: Params, layer: int,
-                first_k_dense: int = 0) -> Params:
+def dense_then_experts(first_k_dense: int, name: str, layer: int):
+    """``leaf_index`` of a tree whose first ``first_k_dense`` layers run
+    the dense MLP and the others experts: the dense MLP's leaves are
+    stacked over the leading layers, the expert layer's (router, shared
+    expert, experts) over the layers after them, every other leaf over
+    all."""
+    if name in DENSE_MLP_LEAVES and first_k_dense:
+        return layer if layer < first_k_dense else None
+    if name.startswith(EXPERT_LAYER_LEAVES) and first_k_dense:
+        return layer - first_k_dense if layer >= first_k_dense else None
+    return layer
+
+
+def layer_slice(layers: Params, layer: int, first_k_dense: int = 0,
+                index_of=None) -> Params:
     """Layer ``layer``'s parameters out of the stacked tree, for the
     serving programs' Python loop over layers. Stacks of experts
     (``experts_*``) stay whole: their grouped products reach a layer's
     experts through the group sizes (models/moe.py ``_grouped``), where a
     sliced stack would be copied before every product.
 
-    ``first_k_dense`` > 0: the tree stacks the dense MLP's leaves over the
-    leading dense layers and the expert layer's (router, shared expert,
-    experts) over the layers after them; a layer gets its own kind alone,
-    which is how :func:`layer_mlp` tells them apart."""
-    if not first_k_dense:
-        return {name: (leaf if name.startswith("experts_") else leaf[layer])
-                for name, leaf in layers.items()}
-    dense = layer < first_k_dense
+    A leaf may be stacked over some of the layers only: ``index_of(name,
+    layer)`` (a config's ``leaf_index``) says where the layer sits in the
+    leaf's stack, or None: the layer has no such leaf and gets none, which
+    is how :func:`decoder_block` and :func:`layer_mlp` tell a layer's kind.
+    Absent, it is :func:`dense_then_experts` of ``first_k_dense``."""
+    if index_of is None:
+        index_of = functools.partial(dense_then_experts, first_k_dense)
     out = {}
     for name, leaf in layers.items():
-        if name in DENSE_MLP_LEAVES:
-            if dense:
-                out[name] = leaf[layer]
-        elif name.startswith("experts_"):
-            if not dense:
-                out[name] = leaf
-        elif name.startswith(EXPERT_LAYER_LEAVES):
-            if not dense:
-                out[name] = leaf[layer - first_k_dense]
-        else:
-            out[name] = leaf[layer]
+        at = index_of(name, layer)
+        if at is not None:
+            out[name] = leaf if name.startswith("experts_") else leaf[at]
     return out
 
 
@@ -461,12 +518,12 @@ def layer_mlp(config: LlamaConfig, h2, lp, proj, live=None, layer=None):
     Returns ``(out, load)``: ``load`` is
     the pairs each held expert got (int32 [experts held]), ``None`` for
     the dense MLP."""
-    if "experts_gate" in lp:
+    if "experts_down" in lp:
         from .moe import moe_mlp
 
         if layer is not None:
             # the experts' stacks hold the expert layers alone
-            layer -= getattr(config, "first_k_dense", 0)
+            layer = config.leaf_index("experts_down", layer)
         return moe_mlp(config, h2, lp,
                        held=getattr(config, "experts_held", None), live=live,
                        layer=layer)

@@ -32,7 +32,14 @@ import jax.numpy as jnp
 from ..ops.attention import attention
 from ..ops.norms import rms_norm
 from ..ops.rotary import rope_table
-from .llama import LlamaConfig, decoder_block, embed, lm_head, trainer_proj
+from .llama import (
+    EXPERT_SERVING_LEAVES,
+    LlamaConfig,
+    decoder_block,
+    embed,
+    lm_head,
+    trainer_proj,
+)
 
 Params = dict
 
@@ -194,11 +201,12 @@ def init_params(config: MoEConfig, key: jax.Array) -> Params:
     return params
 
 
-def _grouped(lhs, rhs, group_sizes, layer=None):
+def _grouped(lhs, rhs, group_sizes, layer=None, out_major=False):
     """``lhs[rows of group g] @ rhs[g]`` for every group, rows sorted by
     group: [P, K] x [G, K, N] -> [P, N] float32, by the library's megablox
     ``gmm`` kernel (its operations read ``gmm`` in a device trace). Rows
     past the groups' sum come out undefined; the caller weights them with 0.
+    ``out_major``: ``rhs`` is stored [G, N, K] (:func:`_expert_product`).
 
     With ``layer`` given, ``rhs`` is the stack of all layers' groups [L, G,
     K, N] and the product runs over layer ``layer``'s: the kernel is handed
@@ -222,15 +230,34 @@ def _grouped(lhs, rhs, group_sizes, layer=None):
             (layer * groups,))
         rhs = rhs.reshape(n_layers * groups, *rhs.shape[2:])
     rows, k = lhs.shape
-    n = rhs.shape[-1]
+    n = rhs.shape[-2 if out_major else -1]
     tile_rows = min(128, -(-rows // 8) * 8)
     pad = (-rows) % tile_rows
     if pad:
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+    tile_k = min(k, 2048)
+    # a tile of an expert's matrix stays within 4 MiB (two of them are in
+    # flight): widths such as 2688 x 1856 would else overrun the kernel's
+    # fast memory (AOT compile, PR 37)
+    tile_n = min(n, 2048, (2048 * 1024 // tile_k) // 128 * 128)
     out = gmm(lhs, rhs, group_sizes, jnp.float32,
-              (tile_rows, min(k, 2048), min(n, 2048)),
+              (tile_rows, tile_k, tile_n), transpose_rhs=out_major,
               interpret=interpret_default())
     return out[:rows]
+
+
+def _expert_product(rows, lp, name: str, load, layer):
+    """The sorted ``rows`` through the held experts' matrices ``name`` as
+    ``lp`` holds them: the logical stack ``lp[name]`` [.., E, K, N], or the
+    same matrices out-major under ``EXPERT_SERVING_LEAVES[name]`` [.., E,
+    N, K] (what a serving engine keeps where N is no whole number of
+    lanes). None where the experts have no such matrix."""
+    if name in lp:
+        return _grouped(rows, lp[name], load, layer)
+    relaid = EXPERT_SERVING_LEAVES[name]
+    if relaid in lp:
+        return _grouped(rows, lp[relaid], load, layer, out_major=True)
+    return None
 
 
 def moe_mlp(config: MoEConfig, x, lp, held=None, live=None, layer=None):
@@ -247,9 +274,11 @@ def moe_mlp(config: MoEConfig, x, lp, held=None, live=None, layer=None):
     ``None``: all), sorts them by expert, runs one grouped product per
     projection over the held experts' weights (``lp["experts_*"]``
     [hi - lo, ...]) and adds each pair's output, times its gate, to its
-    token. No token is dropped; what an absent expert would add is left
-    out and nothing stands in for it. ``load`` is the pairs each held
-    expert got. With ``layer`` given, ``lp["experts_*"]`` are the stacks
+    token. An expert is a SwiGLU of three products where ``lp`` holds
+    ``experts_gate`` (under that name or its serving one), else two
+    products with ``relu(.)^2`` between them (:func:`_activated`). No token
+    is dropped; what an absent expert would add is left out and nothing
+    stands in for it. ``load`` is the pairs each held expert got. With ``layer`` given, ``lp["experts_*"]`` are the stacks
     of every layer's held experts ([L, hi - lo, ...], as the parameters
     store them) and the products run over that layer's (:func:`_grouped`). The routing runs under the named scope
     ``layer/moe/route``, the products under ``layer/moe/experts``."""
@@ -257,7 +286,7 @@ def moe_mlp(config: MoEConfig, x, lp, held=None, live=None, layer=None):
     E, k = config.n_experts, config.top_k
     lo, hi = (0, E) if held is None else held
     n_held = hi - lo
-    given = lp["experts_gate"].shape[0 if layer is None else 1]
+    given = lp["experts_down"].shape[0 if layer is None else 1]
     if given != n_held:
         raise ValueError(
             f"moe_mlp holds experts [{lo}, {hi}) but was given {given} "
@@ -293,31 +322,43 @@ def moe_mlp(config: MoEConfig, x, lp, held=None, live=None, layer=None):
             1)[:n_held]
         rows = xt[order // k]                                # [T * k, M]
     with jax.named_scope("layer/moe/experts"):
-        gate_h = _grouped(rows, lp["experts_gate"], load, layer)
-        up_h = _grouped(rows, lp["experts_up"], load, layer)
-        hidden = (jax.nn.silu(gate_h) * up_h).astype(x.dtype)
+        gate_h = _expert_product(rows, lp, "experts_gate", load, layer)
+        up_h = _expert_product(rows, lp, "experts_up", load, layer)
+        hidden = _activated(gate_h, up_h).astype(x.dtype)
         out = _grouped(hidden, lp["experts_down"], load, layer)  # [T*k, M]
     with jax.named_scope("layer/moe/route"):
         weight = jnp.where(kept, gates.reshape(t * k), 0.0)
         pairs = jnp.where(kept[:, None], out[jnp.argsort(order)], 0.0)
         y = jnp.sum((pairs * weight[:, None]).reshape(t, k, m), axis=1)
     y = y.astype(x.dtype).reshape(b, s, m)
-    if "shared_gate" in lp:
+    if "shared_up" in lp:
         y = y + shared_expert(x, lp)
     return y, load
 
 
+def _activated(gate, up):
+    """An expert's hidden activation out of its first products (float32):
+    SwiGLU, ``silu(gate) * up``, where the expert has a gate matrix;
+    ``relu(up)^2`` where it is two products with nothing beside them
+    (``gate`` None). What the layer's leaves hold decides, never a
+    switch."""
+    if gate is None:
+        return jnp.square(jax.nn.relu(up))
+    return jax.nn.silu(gate) * up
+
+
 def shared_expert(x, lp):
     """The expert every token goes through beside its routed ones (``lp``
-    carries ``shared_gate`` / ``shared_up`` / ``shared_down``): a SwiGLU
-    over x [B, S, M], whatever experts are held here. Runs under the named
-    scope ``layer/moe/shared``."""
+    carries ``shared_up`` / ``shared_down`` and, for a SwiGLU,
+    ``shared_gate``), over x [B, S, M], whatever experts are held here.
+    Runs under the named scope ``layer/moe/shared``."""
     with jax.named_scope("layer/moe/shared"):
         gate = jnp.einsum("bse,eh->bsh", x, lp["shared_gate"],
-                          preferred_element_type=jnp.float32)
+                          preferred_element_type=jnp.float32) \
+            if "shared_gate" in lp else None
         up = jnp.einsum("bse,eh->bsh", x, lp["shared_up"],
                         preferred_element_type=jnp.float32)
-        hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
+        hidden = _activated(gate, up).astype(x.dtype)
         return jnp.einsum("bsh,he->bse", hidden, lp["shared_down"],
                           preferred_element_type=jnp.float32).astype(x.dtype)
 

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 
 from ..ops.norms import rms_norm
 from ..ops.rotary import apply_rope
-from .llama import BlockSeams
+from .llama import BlockSeams, dense_then_experts
 from .moe import MoEConfig, _normal_leaf
 
 Params = dict
@@ -119,6 +119,9 @@ class Xing4Config(MoEConfig):
     def cache_rows(self) -> dict:
         """What a token leaves behind a layer: one row all heads share."""
         return {"ckr": (self.latent_dim,)}
+
+    def leaf_index(self, name: str, layer: int):
+        return dense_then_experts(self.first_k_dense, name, layer)
 
     def rope(self, positions):
         return yarn_table(

@@ -164,6 +164,12 @@ LLM_KV_BYTES_PER_TOKEN = REGISTRY.gauge(
     "Bytes a token leaves in the page pool over all layers (per-head keys "
     "and values, or one latent row), paged engines",
     labels=("engine", "replica"), overflow="drop")
+LLM_STATE_BYTES_PER_SLOT = REGISTRY.gauge(
+    "mlt_llm_state_bytes_per_slot",
+    "Bytes a slot keeps beside its pages whatever its length: a recurrent "
+    "family's state over its state-space layers (0 for any other), paged "
+    "engines",
+    labels=("engine", "replica"), overflow="drop")
 LLM_WEIGHTS_RELAID_BYTES = REGISTRY.gauge(
     "mlt_llm_weights_relaid_bytes",
     "Bytes of weight leaves the engine holds in the serving layout (wq, wk, "

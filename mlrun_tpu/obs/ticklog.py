@@ -30,7 +30,8 @@ FIELDS = ("n", "t0", "t_admit", "t_built", "t_dispatched", "t_fetched",
           "t1", "admit_wait_s", "rows", "ctx_tokens", "prefill_tokens",
           "kind", "positions", "tokens_out", "commit_rows", "expert_pairs",
           "experts_touched", "expert_load_max", "lookahead",
-          "prefill_ctx_tokens")
+          "prefill_ctx_tokens", "state_rows", "state_tokens",
+          "prefill_dispatches")
 
 
 class TickRecord:
@@ -45,7 +46,9 @@ class TickRecord:
     drain). ``rows`` rows of the decode tick dispatched (0 where the
     iteration only admitted, or only read a tick), ``ctx_tokens`` the tokens
     those rows attend (prompt + generated so far, summed),
-    ``prefill_tokens`` prompt tokens prefilled in this iteration and
+    ``prefill_tokens`` prompt tokens prefilled in this iteration in
+    ``prefill_dispatches`` dispatches (a chunk each, or a whole prompt in
+    its bucket: several where several requests were admitted) and
     ``prefill_ctx_tokens`` the positions they attended (each token its own
     and what precedes it in its prompt, summed), ``kind``
     ``plain``, ``spec`` or ``denoise``. ``tokens_out`` is what the iteration
@@ -76,6 +79,12 @@ class TickRecord:
     (read with that tick's own fetch, an iteration later) and of its
     prefill dispatches, summed.
 
+    A family with a recurrent state (docs/serving.md "State-space layers
+    and the per-slot state") also fills ``state_rows``, the rows whose
+    state the tick dispatched in the iteration advanced, and
+    ``state_tokens``, the real prompt tokens its prefill dispatches
+    integrated into a state (a bucket's padding left out).
+
     A boundary that an iteration never reaches stays at the one before it,
     so every interval is defined and non-negative.
     """
@@ -89,6 +98,7 @@ class TickRecord:
         self.positions = self.tokens_out = self.commit_rows = 0
         self.expert_pairs = self.experts_touched = self.expert_load_max = 0
         self.lookahead = self.prefill_ctx_tokens = 0
+        self.state_rows = self.state_tokens = self.prefill_dispatches = 0
         self.kind = "plain"
         self.t0 = t0
         self.admitted(t0)

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.llama import (
+    EXPERT_SERVING_LEAVES,
     SERVING_LEAVES,
     LlamaConfig,
     Params,
@@ -37,7 +38,10 @@ def init_kv_cache(config: LlamaConfig, batch: int, max_len: int,
                   dtype=None, kv_dtype: str = "native") -> dict:
     """KV cache pytree: one buffer [layers, batch, max_len, *row] for each
     of ``config.cache_rows()`` (per-head keys and values, or for a latent
-    family one latent and one rotated key a token), and ``pos``.
+    family one latent and one rotated key a token) over the layers that
+    leave rows (``config.cache_layers``), one buffer [layers, batch,
+    *shape] for each of ``config.state_rows()`` (a recurrent family's
+    state, over its ``state_layers``), and ``pos``.
     ``kv_dtype="int8"`` stores k/v per-vector symmetric
     int8 (scale over head_dim, kept f32 per [layer, batch, pos, kv_head]) —
     half the HBM residency of bf16, so twice the slots x context per chip.
@@ -45,10 +49,11 @@ def init_kv_cache(config: LlamaConfig, batch: int, max_len: int,
     if kv_dtype not in ("native", "int8"):
         raise ValueError(
             f"unknown kv_dtype '{kv_dtype}' (native | int8)")
-    refuse_latent(config, "an int8 cache", kv_dtype == "int8")
+    refuse_layout(config, "an int8 cache", kv_dtype == "int8")
     dtype = dtype or config.dtype
-    lead = (config.n_layers, batch, max_len)
-    pos = {"pos": jnp.zeros((batch,), jnp.int32)}
+    lead = (config.cache_layers, batch, max_len)
+    rest = {**init_slot_state(config, batch),
+            "pos": jnp.zeros((batch,), jnp.int32)}
     if kv_dtype == "int8":
         shape = lead + config.cache_rows()["k"]
         scale_shape = shape[:-1]
@@ -57,10 +62,18 @@ def init_kv_cache(config: LlamaConfig, batch: int, max_len: int,
             "v": jnp.zeros(shape, jnp.int8),
             "k_scale": jnp.zeros(scale_shape, jnp.float32),
             "v_scale": jnp.zeros(scale_shape, jnp.float32),
-            **pos,
+            **rest,
         }
     return {**{name: jnp.zeros(lead + row, dtype)
-               for name, row in config.cache_rows().items()}, **pos}
+               for name, row in config.cache_rows().items()}, **rest}
+
+
+def init_slot_state(config: LlamaConfig, slots: int) -> dict:
+    """What ``slots`` sequences keep beside their rows: one buffer
+    [state_layers, slots, *shape] for each of ``config.state_rows()``;
+    empty for a family without a recurrent state."""
+    return {name: jnp.zeros((config.state_layers, slots) + shape, dtype)
+            for name, (shape, dtype) in config.state_rows().items()}
 
 
 class LatentCacheError(ValueError):
@@ -71,12 +84,29 @@ class LatentCacheError(ValueError):
     engine other than the paged one."""
 
 
-def refuse_latent(config, what: str, asked: bool = True):
+class RecurrentStateError(ValueError):
+    """A family that keeps a recurrent state a slot
+    (``config.recurrent_state``, docs/serving.md "State-space layers and
+    the per-slot state") was asked for something a state cannot give: a
+    prefix hit skips tokens the state needs and a rejected draft token
+    cannot be taken back out of it, so prefix reuse, speculation, a KV
+    handoff and the host tier are refused, and with them an int8 cache,
+    per-tenant adapters and every engine but the paged one."""
+
+
+def refuse_layout(config, what: str, asked: bool = True):
+    """Raise the family's typed error where ``asked`` is for something
+    that what it keeps a sequence by does not carry."""
     if asked and config.latent_cache:
         raise LatentCacheError(
             f"{type(config).__name__} keeps a latent cache, which does "
             f"not carry {what} yet (docs/serving.md \"Latent attention "
             f"and the latent page pool\")")
+    if asked and config.recurrent_state:
+        raise RecurrentStateError(
+            f"{type(config).__name__} keeps a recurrent state a slot, "
+            f"which does not carry {what} (docs/serving.md \"State-space "
+            f"layers and the per-slot state\")")
 
 
 def _quantize_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -200,15 +230,30 @@ def relay_layers(config: LlamaConfig, layers: dict) -> dict:
     the layer inside the product, as ``wo`` and the MLP's are; held [L,
     H, E] without the heads the transposition goes and the slice stays a
     copy of its own. A leaf already relaid, and a family whose seam reads
-    other leaves, are left as they are."""
-    if config.seams.qkv is not llama_qkv:
-        return layers
-    for name, relaid in SERVING_LEAVES.items():
-        if name in layers:
-            w = layers.pop(name)
+    other leaves, are left as they are.
+
+    An expert's input matrices (``experts_gate``, ``experts_up`` [L,
+    experts, E, width]) whose width is no whole number of 128 lanes are
+    stored anew out-major, [L, experts, width, E], under their names in
+    ``EXPERT_SERVING_LEAVES``: the device keeps such a buffer with E minor
+    whatever the program says, and the grouped kernel, which takes its
+    operands row-major, got a copy of the whole stack before every call
+    (1.76 GB eleven times a tick at 2688 x 1856; AOT compile, PR 37).
+    Out-major the same bytes are row-major, and the kernel reads them
+    transposed."""
+    if config.seams.qkv is llama_qkv:
+        for name, relaid in SERVING_LEAVES.items():
+            if name in layers:
+                w = layers.pop(name)
+                layers[relaid] = jax.block_until_ready(
+                    jnp.swapaxes(w, 1, 2).reshape(
+                        w.shape[0], -1, config.head_dim, w.shape[1]))
+    for name, relaid in EXPERT_SERVING_LEAVES.items():
+        # (a width under one lane is padded to a lane whichever way)
+        if name in layers and layers[name].shape[-1] > 128 \
+                and layers[name].shape[-1] % 128:
             layers[relaid] = jax.block_until_ready(
-                jnp.swapaxes(w, 1, 2).reshape(
-                    w.shape[0], -1, config.head_dim, w.shape[1]))
+                jnp.swapaxes(layers.pop(name), -1, -2))
     return layers
 
 
@@ -227,27 +272,34 @@ def serving_tree(config: LlamaConfig, params: Params) -> Params:
 
 def relaid_bytes(params: Params) -> int:
     """Bytes of the leaves ``params`` holds in the serving layout."""
+    relaid = {*SERVING_LEAVES.values(), *EXPERT_SERVING_LEAVES.values()}
     return sum(int(leaf.nbytes) for name, leaf in params["layers"].items()
-               if name in SERVING_LEAVES.values())
+               if name in relaid)
 
 
 def _serving_layers(config: LlamaConfig, params: Params, x, cos, sin,
-                    attend, lora=None, adapter_ids=None, live=None):
+                    attend, lora=None, adapter_ids=None, live=None,
+                    ssm=None):
     """The decoder's layers as every serving program runs them: a Python
     loop (compiled once per program; exposes per-layer cache updates
     without scan-carry gymnastics) of models/llama.py ``decoder_block``
     over ``layer_slice`` and :func:`_serving_proj`. ``attend(layer, q, k,
     v) -> [B, S, Hq, D]`` is the caller's cache: it writes K and V and
-    reads the attention. Returns ``(x, loads)``: ``loads`` the expert
-    layers' loads, empty for dense MLPs."""
+    reads the attention; ``layer`` counts the layers that leave rows.
+    ``ssm(layer, lp, h, proj) -> out`` is the caller's recurrent state
+    (models/nemotron_h.py): ``layer`` counts the layers that keep one.
+    Returns ``(x, loads)``: ``loads`` the expert layers' loads, empty for
+    dense MLPs."""
     loads = []
-    first_k_dense = getattr(config, "first_k_dense", 0)
+    at = config.leaf_index
     x = config.seams.enter(config, x)
     for layer in range(config.n_layers):
         x, load = decoder_block(
-            config, layer_slice(params["layers"], layer, first_k_dense), x,
+            config, layer_slice(params["layers"], layer, index_of=at), x,
             cos, sin, proj=_serving_proj(lora, adapter_ids, layer, x.dtype),
-            attend=functools.partial(attend, layer), live=live, layer=layer)
+            attend=functools.partial(attend, at("wo", layer)),
+            ssm=ssm and functools.partial(ssm, at("ssm_in", layer)),
+            live=live, layer=layer)
         if load is not None:
             loads.append(load)
     return config.seams.leave(config, x), loads
@@ -287,6 +339,26 @@ def latent_prefill_attend(config, params, cache: dict, new: dict, start,
         return out[None].astype(q.dtype)
 
     return attend
+
+
+def state_prefill_mixer(config, cache: dict, new: dict, n_real):
+    """``ssm`` of a prompt chunk for a family with a recurrent state
+    (:func:`_forward_with_cache`, batch 1): the mixer runs from what the
+    admission's cache kept before the chunk (zeros, or the chunk before
+    it) and the cache gets what the sequence keeps after the chunk's
+    ``n_real``-th token, the last of the prompt's own: attention masks a
+    bucket's padding out, a recurrence would integrate it."""
+    from ..models.nemotron_h import mamba_chunk
+
+    def ssm(layer, lp, h, proj):
+        out, state, window = mamba_chunk(
+            config, lp, h, cache["ssm"][layer, 0], cache["conv"][layer, 0],
+            n_real, proj)
+        new["ssm"].append(state[None])
+        new["conv"].append(window[None])
+        return out
+
+    return ssm
 
 
 def _stacked_cache(new: dict, pos) -> dict:
@@ -341,6 +413,10 @@ def _forward_with_cache(config: LlamaConfig, params: Params,
     length in it and the first token comes from the dispatch that
     prefilled it; causal masking keeps the padding to its right from
     touching it).
+    A family with a recurrent state keeps it in the cache too
+    (:func:`state_prefill_mixer`): what comes back is the state after that
+    last real position, so a padded bucket does not move it, and the next
+    chunk of a chunked prefill starts from it.
     With ``with_loads`` (an engine's prefill program of an expert model
     served token by token) a third output follows: the dispatch's
     :func:`expert_counters`.
@@ -433,13 +509,17 @@ def _forward_with_cache(config: LlamaConfig, params: Params,
         return _cached_attention(config, q, k_attn, v_attn, positions,
                                  max_len)
 
+    ssm = None
     if config.latent_cache:
         # batch 1: the paged engine's admission (the dense engines refuse
         # the family)
         attend = latent_prefill_attend(config, params, cache, new,
                                        start[0], attn_impl)
+    elif config.recurrent_state:
+        ssm = state_prefill_mixer(config, cache, new,
+                                  s if logits_at is None else logits_at + 1)
     x, loads = _serving_layers(config, params, x, cos, sin, attend, lora,
-                               adapter_ids)
+                               adapter_ids, ssm=ssm)
     if not all_logits:
         # one row through the final norm and the head: the position the
         # caller names, else the last dispatched one
@@ -477,7 +557,7 @@ class LLMEngine:
                 f"LLMEngine decodes one token a step; a model with "
                 f"block_length {config.block_length} needs the paged "
                 f"engine (continuous_batching=True, paged=True)")
-        refuse_latent(config, "LLMEngine's dense rows (the paged engine "
+        refuse_layout(config, "LLMEngine's dense rows (the paged engine "
                       "serves it: continuous_batching=True, paged=True)")
         self.config = config
         # wq, wk, wv in the layout their products contract over

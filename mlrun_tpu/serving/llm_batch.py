@@ -18,6 +18,7 @@ behind the <200ms p50 TTFT target under concurrency (BASELINE.md).
 from __future__ import annotations
 
 import functools
+import gc
 import math
 import queue
 import threading
@@ -42,6 +43,7 @@ from ..obs import (
     LLM_FREE_PAGE_FRAC,
     LLM_ITL,
     LLM_KV_BYTES_PER_TOKEN,
+    LLM_STATE_BYTES_PER_SLOT,
     LLM_QUEUE_DEPTH,
     LLM_SPEC_ROUNDS,
     LLM_SPEC_TOKENS,
@@ -68,11 +70,12 @@ from .llm import (
     _cached_attention,
     _dense_kv_write,
     LatentCacheError,  # noqa: F401 - re-exported beside BlockDecodingError
+    RecurrentStateError,  # noqa: F401 - likewise
     _forward_with_cache,
     _serving_layers,
     _stacked_cache,
     init_kv_cache,
-    refuse_latent,
+    refuse_layout,
     relaid_bytes,
     serving_tree,
 )
@@ -367,7 +370,8 @@ class ContinuousBatchingEngine:
 
     # whether the engine has a tick for ``config.block_length`` > 1
     _serves_blocks = False
-    # whether its cache takes a latent family's rows (the paged pool does)
+    # whether its cache takes a latent family's rows and a recurrent
+    # family's state (the paged pool does)
     _serves_latent = False
 
     def __init__(self, config: LlamaConfig, params: Params,
@@ -403,7 +407,7 @@ class ContinuousBatchingEngine:
                 f"{type(self).__name__} decodes one token a step; a model "
                 f"with block_length {self.block_length} needs the paged "
                 f"engine (paged=True)")
-        refuse_latent(config, f"{type(self).__name__}'s dense rows (the "
+        refuse_layout(config, f"{type(self).__name__}'s dense rows (the "
                       f"paged engine serves it: paged=True)",
                       not self._serves_latent)
         # -- overload protection (docs/serving_resilience.md) --------------
@@ -460,6 +464,9 @@ class ContinuousBatchingEngine:
         # device-resident bank; every prefill/decode dispatch gathers
         # per-row (A, B) deltas by bank slot index. None = single-tenant
         # engine, compile-identical to the pre-adapter programs.
+        if adapters is not None and config.recurrent_state:
+            # the bank's targets are the attention-then-MLP block's
+            refuse_layout(config, "per-tenant adapters")
         if adapters is None:
             self._adapters = None
             self._owns_adapters = True
@@ -638,7 +645,7 @@ class ContinuousBatchingEngine:
                 "speculative decoding proposes one token after another; a "
                 f"model with block_length {self.block_length} fills a "
                 "block in any order: turn one of the two off")
-        refuse_latent(self.config, "speculation", self.spec_enabled)
+        refuse_layout(self.config, "speculation", self.spec_enabled)
         if not self.spec_enabled:
             return
         if draft_config.vocab_size != self.config.vocab_size:
@@ -1004,6 +1011,12 @@ class ContinuousBatchingEngine:
             return
         self._running = True
         self._epoch += 1
+        # what the process holds by now (imports, compiled programs, the
+        # weights' trees) leaves the collector's sight while the engine
+        # runs: a full collection over it stops this thread, which owns the
+        # device, for 0.1-0.3 s at moments of its own choosing (PERF.md,
+        # PR 37); ``stop`` gives it back
+        gc.freeze()
         self._register_metrics()
         # device HBM / host RSS exposition while this engine lives
         # (mlt_device_mem_bytes — weakref, shared across owners)
@@ -1030,6 +1043,7 @@ class ContinuousBatchingEngine:
         """
         self._running = False
         self._stopped = True
+        gc.unfreeze()
         thread, self._thread = self._thread, None
         if thread is not None:
             thread.join(timeout=timeout)
@@ -1103,6 +1117,7 @@ class ContinuousBatchingEngine:
                                        adapter=adapter)
             LLM_FREE_PAGE_FRAC.remove(engine=name, replica=replica)
             LLM_KV_BYTES_PER_TOKEN.remove(engine=name, replica=replica)
+            LLM_STATE_BYTES_PER_SLOT.remove(engine=name, replica=replica)
             LLM_WEIGHTS_RELAID_BYTES.remove(engine=name, replica=replica)
             if has_spec:
                 LLM_SPEC_ROUNDS.remove(engine=name, replica=replica)
@@ -1171,6 +1186,9 @@ class ContinuousBatchingEngine:
             if "kv_bytes_per_token" in stats:
                 LLM_KV_BYTES_PER_TOKEN.set(stats["kv_bytes_per_token"],
                                            engine=name, replica=replica)
+                LLM_STATE_BYTES_PER_SLOT.set(
+                    stats["state_bytes_per_slot"], engine=name,
+                    replica=replica)
             LLM_WEIGHTS_RELAID_BYTES.set(stats["weights_relaid_bytes"],
                                          engine=name, replica=replica)
             for key in engine._COUNTER_STATS:
@@ -1584,7 +1602,7 @@ class ContinuousBatchingEngine:
         prefixes stay cache-resident — per tenant — on the prefill pool.
         ``max_new_tokens=1`` bounds the paged page reservation to the
         prompt itself."""
-        refuse_latent(self.config, "a KV handoff (submit_prefill)")
+        refuse_layout(self.config, "a KV handoff (submit_prefill)")
         return self.submit(prompt_tokens, max_new_tokens=1, eos_id=eos_id,
                            temperature=temperature, top_k=top_k,
                            top_p=top_p, max_wait=max_wait, adapter=adapter,
@@ -1606,7 +1624,7 @@ class ContinuousBatchingEngine:
         (serving/podfleet.py): the imported prompt pages ALSO register in
         this engine's prefix index, so a reassigned hot key's first real
         request after a ring join is a cache hit."""
-        refuse_latent(self.config, "a KV handoff (submit_prefilled)")
+        refuse_layout(self.config, "a KV handoff (submit_prefilled)")
         expects_scales = self.kv_dtype == "int8"
         wire_dtype = getattr(handoff, "kv_dtype", None) or (
             "int8" if "k_scale" in handoff.kv else "native")
@@ -1862,6 +1880,10 @@ class ContinuousBatchingEngine:
         adm.offset += take
         adm.chunks += 1
         self._tick.prefill_tokens += take
+        self._tick.prefill_dispatches += 1
+        if self.config.recurrent_state:
+            # the real tokens the scan integrated: the padding is left out
+            self._tick.state_tokens += take
         # the positions the chunk's tokens attended: each its own and
         # what precedes it
         self._tick.prefill_ctx_tokens += take * start + take * (take + 1) // 2

@@ -68,7 +68,8 @@ from .llm import (
     _serving_layers,
     expert_counters,
     init_kv_cache,
-    refuse_latent,
+    init_slot_state,
+    refuse_layout,
 )
 from .llm_batch import (
     BlockDecodingError,
@@ -80,18 +81,30 @@ from .llm_batch import (
 from .prefix import PrefixCache, block_chain_key
 
 
+# where a pool that keeps a per-slot state holds it, beside its page buffers
+STATE = "state"
+
+
 def init_paged_pool(config: LlamaConfig, n_pages: int, page_size: int,
-                    kv_dtype: str = "native") -> dict:
+                    kv_dtype: str = "native", slots: int = 0) -> dict:
     """Page pool pytree with ``n_pages`` physical pages (callers that need
     a scratch page pass n_pages + 1 and keep the last id out of the free
     list): one buffer [layers, n_pages, page_size, *row] for each of
-    ``config.cache_rows()``, so the config's type decides the layout
+    ``config.cache_rows()`` over the layers that leave rows
+    (``config.cache_layers``), so the config's type decides the layout
     (per-head keys and values; one latent and one rotated key a token for
-    a latent family). The int8 variant carries per-vector scales."""
+    a latent family). The int8 variant carries per-vector scales.
+
+    Beside the pages, for a family with a recurrent state and ``slots``
+    given: under ``STATE`` what each of ``slots`` sequences keeps whatever
+    its length, one buffer [state_layers, slots, *shape] for each of
+    ``config.state_rows()`` (serving/llm.py ``init_slot_state``). It rides
+    in the pool's tree, so every program that is handed the pool (and
+    donates it) is handed the state."""
     if kv_dtype not in ("native", "int8"):
         raise ValueError(f"unknown kv_dtype '{kv_dtype}' (native | int8)")
-    refuse_latent(config, "an int8 page pool", kv_dtype == "int8")
-    lead = (config.n_layers, n_pages, page_size)
+    refuse_layout(config, "an int8 page pool", kv_dtype == "int8")
+    lead = (config.cache_layers, n_pages, page_size)
     if kv_dtype == "int8":
         shape = lead + config.cache_rows()["k"]
         return {
@@ -100,28 +113,41 @@ def init_paged_pool(config: LlamaConfig, n_pages: int, page_size: int,
             "k_scale": jnp.zeros(shape[:-1], jnp.float32),
             "v_scale": jnp.zeros(shape[:-1], jnp.float32),
         }
-    return {name: jnp.zeros(lead + row, config.dtype)
+    pool = {name: jnp.zeros(lead + row, config.dtype)
             for name, row in config.cache_rows().items()}
+    if slots and config.recurrent_state:
+        pool[STATE] = init_slot_state(config, slots)
+    return pool
 
 
 def _buffers(pool: dict) -> list:
-    """The pool's buffer names, the rows' before their scales'."""
-    return sorted(pool, key=lambda name: (name.endswith("_scale"), name))
+    """The names of the pool's page buffers, the rows' before their
+    scales'."""
+    return sorted((name for name in pool if name != STATE),
+                  key=lambda name: (name.endswith("_scale"), name))
 
 
 def _scratch_page(pool: dict) -> int:
     """The id of the pool's last physical page, which is never read."""
-    return next(iter(pool.values())).shape[1] - 1
+    return pool[_buffers(pool)[0]].shape[1] - 1
 
 
 def insert_prompt_pages(pool: dict, small: dict, page_ids: jax.Array,
-                        page_size: int) -> dict:
+                        page_size: int, slot=None) -> dict:
     """Scatter a prefilled slot-cache (``small`` from init_kv_cache with
     batch=1, max_len a multiple of page_size) into the pool at
     ``page_ids`` ([pages_per_slot] int32). Ids < 0 write to the scratch
-    page (last physical page) — never to a live one."""
+    page (last physical page) — never to a live one. ``slot`` (a traced
+    int32, for a pool that keeps a per-slot state): the state ``small``
+    holds after the prompt's last token overwrites the slot's, whatever
+    the slot's last request or a tick still in flight for it left there."""
     scratch = _scratch_page(pool)
     pages = page_ids.shape[0]
+    if slot is not None:
+        pool = {**pool, STATE: {
+            name: jax.lax.dynamic_update_slice_in_dim(
+                buffer, small[name].astype(buffer.dtype), slot, axis=1)
+            for name, buffer in pool[STATE].items()}}
 
     def body(p, pool_):
         pid = page_ids[p]
@@ -243,6 +269,9 @@ def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
     [slots] with ``from_prev`` [slots] bool: a row marked there takes its
     input token from ``prev_token`` (the last tick's ``next_token``, which
     may still be on its way to the host) and not from ``tokens``.
+    A pool that keeps a per-slot state (``pool[STATE]``, a family with a
+    recurrent state) has the live rows' states advanced by the token, in
+    place (models/nemotron_h.py ``mamba_step``); a dead row's stays.
     Returns (next_token, new_pool, new_pos); with ``with_loads`` (the
     engine's program of an expert model) a fourth output follows: one
     int32 vector of the tokens and behind them the tick's
@@ -271,6 +300,18 @@ def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
         pid_safe = jnp.where(pid >= 0, pid, scratch)
         pool = dict(pool)
     k_new, v_new = [], []
+    ssm = None
+    if config.recurrent_state:
+        from ..models.nemotron_h import mamba_step
+
+        pool = dict(pool)
+        state = pool[STATE] = dict(pool[STATE])
+
+        def ssm(layer, lp, h, proj):
+            out, state["ssm"], state["conv"] = mamba_step(
+                config, lp, h, state["ssm"], state["conv"], layer,
+                live[:, 0], proj)
+            return out
 
     def attend_kernel(layer, q, k, v):
         # token KV lands in the pool first (unmapped slots route to
@@ -321,7 +362,7 @@ def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
     x, loads = _serving_layers(
         config, params, x, cos, sin,
         attend_kernel if use_kernel else attend_reference, lora,
-        adapter_ids, live=live)
+        adapter_ids, live=live, ssm=ssm)
     logits = head_logits(config, params, x)[:, 0]
     if rng is None:
         next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -598,8 +639,13 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         # _pending exists before super().__init__ so _queue_depth /
         # pressure_level are safe during construction
         self._pending: deque = deque()
+        # a prefix hit skips tokens a recurrent state needs: such a family
+        # gets no index to look a prompt up in, and one asked for is refused
+        refuse_layout(config, "prefix reuse", bool(prefix_cache)
+                      and config.recurrent_state)
         if prefix_cache is None:
-            prefix_cache = bool(mlconf.serving.llm.prefix_cache)
+            prefix_cache = bool(mlconf.serving.llm.prefix_cache) \
+                and not config.recurrent_state
         self._prefix = PrefixCache(page_size) if prefix_cache else None
         # trie nodes each slot holds a refcount on (matched + registered)
         self._slot_prefix_nodes: dict[int, list] = {}
@@ -618,7 +664,9 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         self._kv_tier = (
             HostKVTier(int(tier_conf.get("host_bytes", 64 << 20)))
             if kv_tier and self._prefix is not None else None)
-        refuse_latent(config, "the host KV tier", self._kv_tier is not None)
+        # (a recurrent family has no prefix index for a tier to hang on)
+        refuse_layout(config, "the host KV tier", self._kv_tier is not None
+                      or bool(kv_tier) and config.recurrent_state)
         # fetch_prefix/import_prefix control ops queue here and run on
         # the scheduler thread between ticks (_control_tick): the page
         # pool is donated through every decode dispatch, so off-thread
@@ -656,7 +704,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             and not config.latent_cache else "gather")
         # +1 physical page: the scratch page for masked writes
         self._pool = init_paged_pool(config, self.n_pages + 1, page_size,
-                                     kv_dtype)
+                                     kv_dtype, slots=slots)
         self._page_table = np.full((slots, self.pages_per_slot), -1,
                                    np.int32)
         self._pos = np.zeros((slots,), np.int32)
@@ -668,15 +716,21 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         self._no_tokens = jnp.zeros((slots,), jnp.int32)
         # HBM bytes the gather path would copy per decode tick (the dense
         # k+v view of every slot, per layer) — what the kernel path avoids
+        pages = {name: self._pool[name] for name in _buffers(self._pool)}
         self._gather_bytes_per_tick = sum(
-            arr.dtype.itemsize * config.n_layers * slots * max_len
+            arr.dtype.itemsize * arr.shape[0] * slots * max_len
             * int(np.prod(arr.shape[3:]))
-            for name, arr in self._pool.items()
-            if not name.endswith("_scale"))
-        # bytes a token leaves in the pool over all layers, scales included
+            for name, arr in pages.items() if not name.endswith("_scale"))
+        # bytes a token leaves in the pool over the layers that leave rows,
+        # scales included
         self.kv_bytes_per_token = sum(
-            arr.dtype.itemsize * config.n_layers
-            * int(np.prod(arr.shape[3:])) for arr in self._pool.values())
+            arr.dtype.itemsize * arr.shape[0]
+            * int(np.prod(arr.shape[3:])) for arr in pages.values())
+        # bytes a slot keeps beside its pages whatever its length: a
+        # recurrent family's state over its layers (0 for any other)
+        self.state_bytes_per_slot = sum(
+            arr.dtype.itemsize * arr.shape[0] * int(np.prod(arr.shape[2:]))
+            for arr in self._pool.get(STATE, {}).values())
         self._stats.update({"attn_kernel_ticks": 0, "attn_gather_ticks": 0,
                             "attn_hbm_bytes_avoided": 0,
                             "lookahead_ticks": 0, "lookahead_drains": 0,
@@ -725,7 +779,8 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             small = self._prefill(
                 self.params, jnp.zeros((1, bucket), jnp.int32), small,
                 logits_at=np.int32(bucket - 1), **prefill_kw)[1]
-            self._pool = self._insert_paged(self._pool, small, ids)
+            self._pool = self._insert_paged(self._pool, small, ids,
+                                            **self._state_slot(0))
         if self.prefill_chunk and self.prefill_chunk not in \
                 self.prefill_buckets:
             small = init_kv_cache(self.config, 1, self.max_len,
@@ -961,7 +1016,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         The op runs on the scheduler thread between ticks
         (``_control_tick``): the page pool is donated through every
         decode dispatch, so off-thread pool reads are unsafe."""
-        refuse_latent(self.config, "a KV handoff (fetch_prefix)")
+        refuse_layout(self.config, "a KV handoff (fetch_prefix)")
         future: Future = Future()
         self._control.append(("fetch", (list(prompt_tokens), adapter),
                               future))
@@ -974,7 +1029,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         page pool + prefix index without admitting a request — the
         receiving side of the fetch hop. Resolves to the number of newly
         cached pages (0 = already cached, or no pages free)."""
-        refuse_latent(self.config, "a KV handoff (import_prefix)")
+        refuse_layout(self.config, "a KV handoff (import_prefix)")
         expects_scales = self.kv_dtype == "int8"
         wire_dtype = getattr(handoff, "kv_dtype", None) or (
             "int8" if "k_scale" in handoff.kv else "native")
@@ -1350,7 +1405,8 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         # shared prefix pages are read-only — route their rows to scratch
         insert_ids[:k] = -1
         self._pool = self._insert_paged(self._pool, adm.small,
-                                        jnp.asarray(insert_ids))
+                                        jnp.asarray(insert_ids),
+                                        **self._state_slot(adm.slot))
         held = list(adm.prefix_nodes)
         pages = list(adm.pages)
         # imported handoffs skip registration: a decode-pool replica never
@@ -1377,6 +1433,12 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         # where the next step writes: the prompt's end, or for a block
         # model the start of the block that the prompt's tail opens
         self._pos[adm.slot] = self._prompt_lead(len(adm.prompt))
+
+    def _state_slot(self, slot: int) -> dict:
+        """The insert program's keyword that names the slot whose state an
+        admission overwrites; absent for a pool without one, whose program
+        is then as it was."""
+        return {"slot": np.int32(slot)} if STATE in self._pool else {}
 
     def _abort_admission(self, adm: _Admission):
         self._free_pages.extend(adm.pages)
@@ -1437,6 +1499,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         out["decode_attn_impl"] = self.attn_impl
         out["paged_prefill_impl"] = self.paged_prefill_impl
         out["kv_bytes_per_token"] = self.kv_bytes_per_token
+        out["state_bytes_per_slot"] = self.state_bytes_per_slot
         out["free_pages"] = len(self._free_pages)
         if self._prefix is not None:
             queries = self._prefix.queries
@@ -1528,6 +1591,8 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             pos = np.zeros_like(self._pos)
             pos[rows] = self._pos[rows]
             tick.ctx_tokens = int(pos.sum()) + len(rows)
+            if STATE in self._pool:
+                tick.state_rows = len(rows)
             self._pos[rows] += 1
             lora_kw = self._lora_kwargs(self._slot_adapter_ids()) \
                 if self._adapters is not None else {}

@@ -22,6 +22,7 @@ nowhere else — only the xdist worker that is handed this file loads the
 TPU library, and it compiles in its own process.
 """
 
+import dataclasses
 import functools
 import importlib
 import os
@@ -558,3 +559,173 @@ def test_xing4_insert_program_compiles(xing4_shapes, on_chip):
     compiled = jax.jit(fn, donate_argnums=(0,)).lower(
         pool, small, on_chip((X4_PAGES_PER_SLOT,), jnp.int32)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+# -- the state-space family: nemotron-serve-chat128's programs --------------
+# the benchmark cell's geometry (benchmarks/workloads/
+# nemotron-serve-chat128.json) at the published widths and the cell's own
+# depth (26 layers: 12 state-space, 11 expert, 3 attention; 16 of 128 experts
+# held; a vocabulary of 16,384): 128 slots x 10 pages/slot of 128, 1,280 pool
+# pages + the scratch page
+NH_SLOTS, NH_PAGES_PER_SLOT, NH_PAGES = 128, 10, 1280
+NH_HEADS, NH_HEAD_DIM, NH_GROUPS, NH_STATE = 64, 64, 8, 128
+
+
+@pytest.fixture
+def nemotron_shapes(on_chip, monkeypatch):
+    from mlrun_tpu.models import nemotron_h
+    from mlrun_tpu.ops import ssm
+
+    for module in (pattn, attn, ssm):
+        monkeypatch.setattr(module, "interpret_default", lambda: False)
+    whole = nemotron_h.nemotron_3_nano_30b_a3b()
+    config = dataclasses.replace(
+        whole, n_layers=26, pattern=whole.pattern[:26],
+        experts_held=(0, 16), vocab_size=16384)
+
+    place = _placer(on_chip)
+
+    params = place(jax.eval_shape(lambda: llm.serving_tree(
+        config, nemotron_h.init_params(config, jax.random.PRNGKey(0)))))
+    pool = place(jax.eval_shape(lambda: paged.init_paged_pool(
+        config, NH_PAGES + 1, PAGE_SIZE, slots=NH_SLOTS)))
+    return config, params, pool, place
+
+
+@pytest.mark.parametrize("bucket", [128, 512, 1024])
+def test_ssd_prefill_compiles(on_chip, bucket):
+    """``ssd_prefill`` at the published widths (64 heads of 64 in 8 groups,
+    a state of 128, chunks of 128) over each prefill bucket."""
+    from mlrun_tpu.ops import ssm
+
+    bc = on_chip((bucket, NH_GROUPS, NH_STATE), jnp.bfloat16)
+    _compile(functools.partial(ssm.ssd_prefill, chunk=128, interpret=False),
+             on_chip((bucket, NH_HEADS, NH_HEAD_DIM), jnp.bfloat16),
+             on_chip((bucket, NH_HEADS), jnp.float32),
+             on_chip((NH_HEADS,), jnp.float32), bc, bc,
+             on_chip((NH_HEADS, NH_HEAD_DIM, NH_STATE), jnp.float32))
+
+
+def test_ssm_decode_compiles(nemotron_shapes, on_chip):
+    """``ssm_decode`` over the cell's stack of states (12 layers x 128
+    rows x 2 MB), read and written in place: the program's temporaries
+    stay far under one layer's states."""
+    from mlrun_tpu.ops import ssm
+
+    _config, _params, pool, _place = nemotron_shapes
+    bc = on_chip((NH_SLOTS, NH_GROUPS, NH_STATE), jnp.bfloat16)
+    compiled = jax.jit(
+        functools.partial(ssm.ssm_decode, interpret=False),
+        donate_argnums=(0,)).lower(
+            pool[paged.STATE]["ssm"], on_chip((), jnp.int32),
+            on_chip((NH_SLOTS, NH_HEADS, NH_HEAD_DIM), jnp.bfloat16),
+            on_chip((NH_SLOTS, NH_HEADS), jnp.float32),
+            on_chip((NH_HEADS,), jnp.float32), bc, bc).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("kernel", ["paged_decode", "flash_v2"])
+def test_attention_kernels_at_a_group_of_sixteen(on_chip, kernel):
+    """32 query heads over 2 key/value heads of 128: ``paged_decode`` over
+    the cell's pool and the cached flash prefill at bucket 1,024."""
+    if kernel == "paged_decode":
+        pool = on_chip((3, NH_PAGES + 1, PAGE_SIZE, 2, 128), jnp.bfloat16)
+        _compile(
+            lambda q, k, v, layer, table, pos: pattn._paged_decode_call(
+                q, k, v, layer, table, pos, PAGE_SIZE, interpret=False),
+            on_chip((NH_SLOTS, 32, 128), jnp.bfloat16), pool, pool,
+            on_chip((), jnp.int32),
+            on_chip((NH_SLOTS, NH_PAGES_PER_SLOT), jnp.int32),
+            on_chip((NH_SLOTS,), jnp.int32))
+    else:
+        q = on_chip((1, 1024, 32, 128), jnp.bfloat16)
+        kv = on_chip((1, NH_PAGES_PER_SLOT * PAGE_SIZE, 2, 128),
+                     jnp.bfloat16)
+        _compile(
+            lambda q, k, v, start: attn._flash_fwd_v2_cached(
+                q, attn._repeat_kv(k, 16), attn._repeat_kv(v, 16), start,
+                interpret=False), q, kv, kv, on_chip((), jnp.int32))
+
+
+def _sized_results(hlo: str, elements: int) -> set:
+    """Opcodes of the instructions, anywhere in the program, whose result
+    (or an element of whose tuple) has ``elements`` entries."""
+    found = set()
+    line_re = re.compile(r"^\s*(?:ROOT )?\S+ = (.*?) ([a-z][a-z0-9-]*)\(")
+    array_re = re.compile(r"(?:bf16|f32)\[([\d,]+)\]")
+    for line in hlo.splitlines():
+        if m := line_re.match(line):
+            for dims in array_re.findall(m.group(1)):
+                size = 1
+                for d in dims.split(","):
+                    size *= int(d)
+                if size == elements:
+                    found.add(m.group(2))
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode", "insert"])
+def test_state_programs_copy_no_layer(nemotron_shapes, on_chip, program):
+    """``jit_mlt_decode`` and ``jit_mlt_insert`` of the cell at its own
+    depth hand the state on as stored: in the optimised HLO nothing but a
+    ``bitcast`` yields an array the size of a layer's states (128 rows x 2
+    MB) or of a pool layer (the stack and the pool are written in place:
+    what updates them yields the whole of them), and the temporaries stay
+    under one layer's states, so nothing copies the whole either. A kernel
+    handed ``state[layer]`` would make XLA copy a layer's states before
+    every call: 128 x 2 MB x 12 a tick, the cell's largest stream twice."""
+    config, params, pool, place = nemotron_shapes
+    state_layer = NH_SLOTS * NH_HEADS * NH_HEAD_DIM * NH_STATE
+    pool_layer = (NH_PAGES + 1) * PAGE_SIZE * 2 * 128
+    if program == "decode":
+        compiled = jax.jit(functools.partial(
+            paged._decode_rowwise_paged, config, PAGE_SIZE, "kernel",
+            with_loads=True), donate_argnums=(2,)).lower(
+                params, on_chip((NH_SLOTS, 1), jnp.int32), pool,
+                on_chip((NH_SLOTS, NH_PAGES_PER_SLOT), jnp.int32),
+                on_chip((NH_SLOTS,), jnp.int32),
+                prev_token=on_chip((NH_SLOTS,), jnp.int32),
+                from_prev=on_chip((NH_SLOTS,), jnp.bool_)).compile()
+        hlo = compiled.as_text()
+        assert len(re.findall(r"%ssm_decode[\w.-]* = ", hlo)) == 12
+        assert len(re.findall(r"%gmm[\w.-]* = ", hlo)) == 22
+        assert len(re.findall(r"%paged_decode[\w.-]* = ", hlo)) == 3
+    else:
+        small = place(jax.eval_shape(lambda: llm.init_kv_cache(
+            config, 1, NH_PAGES_PER_SLOT * PAGE_SIZE)))
+        compiled = jax.jit(functools.partial(
+            paged.insert_prompt_pages, page_size=PAGE_SIZE),
+            donate_argnums=(0,)).lower(
+                pool, small, on_chip((NH_PAGES_PER_SLOT,), jnp.int32),
+                slot=on_chip((), jnp.int32)).compile()
+        hlo = compiled.as_text()
+    for size in (state_layer, pool_layer):
+        assert _sized_results(hlo, size) <= {"bitcast"}, \
+            (size, _sized_results(hlo, size))
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < state_layer * 4
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 15 << 30
+
+
+@pytest.mark.parametrize("bucket", [128, 512, 1024])
+def test_nemotron_prefill_compiles(nemotron_shapes, on_chip, bucket):
+    """``jit_mlt_prefill`` of the cell at each bucket: the chunked scan a
+    state-space layer, the cached flash kernel an attention layer, the
+    grouped expert products, the state after the prompt's last real token
+    in the admission's cache."""
+    config, params, _pool, place = nemotron_shapes
+    cache = place(jax.eval_shape(lambda: llm.init_kv_cache(
+        config, 1, NH_PAGES_PER_SLOT * PAGE_SIZE)))
+    compiled = jax.jit(functools.partial(
+        llm._forward_with_cache, config, attn_impl="flash",
+        page_size=PAGE_SIZE, with_loads=True)).lower(
+            params, on_chip((1, bucket), jnp.int32), cache,
+            logits_at=on_chip((), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert len(re.findall(r"%ssd_prefill[\w.-]* = ", hlo)) == 12
+    assert len(re.findall(r"%gmm[\w.-]* = ", hlo)) == 22
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 10 << 30
