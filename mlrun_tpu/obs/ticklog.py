@@ -56,23 +56,24 @@ class TickRecord:
     round emitted, the positions a denoising pass unmasked (none in a row
     whose pass committed its block).
 
-    The paged engine's plain tick looks one tick ahead (docs/serving.md
-    "The scheduler's iteration"): the fetch and the commit of an iteration
-    are those of the tick dispatched in the iteration before, and
-    ``lookahead`` is 1 where this iteration's dispatch overlapped that tick
-    in flight. An iteration that only reads the tick in flight has no
-    ``rows`` and its ``tokens_out``.
+    The paged engine's plain tick looks one tick ahead, and a block model's
+    pass one pass ahead (docs/serving.md "The scheduler's iteration"): the
+    fetch and the commit of an iteration are those of the tick or pass
+    dispatched in the iteration before, and ``lookahead`` is 1 where this
+    iteration's dispatch overlapped that one in flight. An iteration that
+    only reads the tick in flight has no ``rows`` and its ``tokens_out``.
 
     A ``denoise`` tick (a pass of a block-diffusion model,
-    docs/serving.md "Block-diffusion decoding") also fills ``positions``
-    (row-positions in the pass: live rows x block length), ``commit_rows``
-    (rows whose pass was the commit of a finished block) and, from the
-    pass's own fetch, the expert layers' counters over the live rows:
+    docs/serving.md "Block-diffusion decoding") also fills, for the pass
+    dispatched in the iteration, ``positions`` (row-positions in the pass:
+    its rows x block length), ``commit_rows`` (rows for which the pass is
+    the commit of a finished block) and, from that pass's own fetch an
+    iteration later, the expert layers' counters over its rows:
     ``expert_pairs`` (token-expert pairs routed, summed over layers),
     ``experts_touched`` (experts that got at least one pair, summed over
     layers) and ``expert_load_max`` (the most pairs one expert got in one
-    layer). There ``ctx_tokens`` is the positions the live rows attend:
-    each row's committed prefix and its block.
+    layer). There ``ctx_tokens`` is the positions the rows attend: each
+    row's committed prefix and its block.
 
     An expert model served token by token fills the three expert counters
     on ``plain`` records too: those of the tick dispatched in the iteration

@@ -336,20 +336,24 @@ class _Slot:
     # what the request asked for: ``_finish`` cuts the answer to it (a
     # block model denoises its last block whole and may overrun)
     max_new: int = 0
-    # block-diffusion decoding (serving/paged.py ``_denoise_tick``): the
-    # block being filled in starts at absolute position ``block_base``;
-    # ``block_ids`` its ids so far, ``block_masked`` which positions are
-    # still masked (host state, never inferred from an id), ``block_m0``
-    # the masked count when it was opened, ``passes_in_block`` the
-    # denoising passes it has had, ``block_pass`` the pass that unmasked
-    # each position (-1: given by the prompt) and ``block_confidence`` the
-    # confidence it had then. ``unmask_pass`` and ``unmask_confidence`` are
-    # those two for every committed generated position, in position order
+    # block-diffusion decoding (serving/paged.py ``_denoise_tick``). The
+    # counts, which the host advances as it dispatches a pass: the block
+    # being filled in starts at absolute position ``block_base``,
+    # ``block_m0`` its masked count when it was opened, ``passes_in_block``
+    # the denoising passes dispatched for it, ``block_left`` the positions
+    # those leave masked. The values, which follow a pass behind as each
+    # one lands: ``block_ids`` the block's ids so far, ``block_masked``
+    # which positions are still masked (host state, never inferred from an
+    # id), ``block_pass`` the pass that unmasked each position (-1: given
+    # by the prompt) and ``block_confidence`` the confidence it had then.
+    # ``unmask_pass`` and ``unmask_confidence`` are those two for every
+    # committed generated position, in position order
     block_base: int = 0
     block_ids: list = field(default_factory=list)
     block_masked: list = field(default_factory=list)
     block_m0: int = 0
     passes_in_block: int = 0
+    block_left: int = 0
     block_pass: list = field(default_factory=list)
     block_confidence: list = field(default_factory=list)
     unmask_pass: list = field(default_factory=list)
@@ -591,8 +595,11 @@ class ContinuousBatchingEngine:
                        "weights_relaid_bytes": relaid_bytes(self.params)}
         if self.block_length > 1:
             # row-passes that denoised and that committed a block, and the
-            # most pairs one expert got in one layer of one pass
+            # passes' expert counters: pairs routed and experts that got a
+            # pair (both summed over layers and passes), and the most pairs
+            # one expert got in one layer of one pass
             self._stats.update({"denoise_passes": 0, "commit_passes": 0,
+                                "expert_pairs": 0, "experts_touched": 0,
                                 "expert_load_max": 0,
                                 "unmasked_positions": 0})
         elif getattr(config, "n_experts", 0):
@@ -2422,10 +2429,10 @@ class ContinuousBatchingEngine:
         Base engine: nothing."""
 
     def _drain_tick(self, admitting: bool = False):
-        """Read and commit a plain tick that was dispatched ahead of its
-        predecessor's commit (hook: the paged engine's plain tick looks
-        one tick ahead; this engine's is synchronous and has none in
-        flight)."""
+        """Read and commit a decode dispatch that was sent ahead of its
+        predecessor's commit (hook: the paged engine's plain tick and its
+        block model's pass look one dispatch ahead; this engine's tick is
+        synchronous and has none in flight)."""
 
     def _await_tick(self):
         """The scheduler is about to block on a prefill (hook: the paged

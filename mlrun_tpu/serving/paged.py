@@ -49,7 +49,7 @@ import time
 from collections import deque
 from concurrent.futures import Future
 from dataclasses import dataclass
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -441,7 +441,11 @@ def _verify_rowwise_paged(config: LlamaConfig, page_size: int,
                           pool: dict, page_table: jax.Array,
                           pos: jax.Array, lora=None,
                           adapter_ids: jax.Array = None,
-                          masked: jax.Array = None):
+                          masked: jax.Array = None,
+                          count: jax.Array = None,
+                          prev_ids: jax.Array = None,
+                          prev_masked: jax.Array = None,
+                          from_prev: jax.Array = None):
     """Batched multi-token forward of one chunk a slot against the page
     pool: the speculative verify (docs/serving.md "Speculative decoding")
     and, with ``masked`` given, the pass of a block-diffusion model
@@ -454,18 +458,28 @@ def _verify_rowwise_paged(config: LlamaConfig, page_size: int,
     ``config.block_length``, ``pos[r]`` the block's start): masked lanes
     embed ``config.mask_token_id`` whatever id the chunk holds there, the
     chunk attends its prefix pages and, under the block mask, all of
-    itself. It returns ``(packed, new_pool)``: ``packed`` one int32 vector
-    of, in order, the argmax ``x0`` of every lane [slots * S], the bits of
-    its float32 confidence ``softmax(logits)[x0]`` [slots * S], and three
-    counters of the expert layers over the live rows (pairs routed and
-    experts that got a pair, both summed over layers, and the most pairs
-    one expert got in one layer; zeros for a dense MLP): one fetch brings
-    all of it. A pass writes the chunk's keys and values like the verify
-    does: a denoising pass's are overwritten by the block's commit pass
-    before anything reads them (the prefix part reads positions below
-    ``pos[r]`` only), the rule the speculative path relies on too. Rows in
-    a commit pass (no lane masked) and rows in a denoising pass share the
-    dispatch; which is which is the host's.
+    itself. A row that rides from the pass in flight (``from_prev[r]``)
+    takes its ids and its mask from that pass's block state, still on the
+    device (``prev_ids``, ``prev_masked`` [slots, S]), as the plain tick
+    takes ``prev_token``; every other row from ``chunk`` and ``masked``.
+    After the head the pass unmasks its share of each row itself, under
+    ``low_confidence_static``: ``count[r]`` (int32, the host's schedule; 0
+    for a row in its commit pass) of the row's still-masked lanes, the
+    most confident first and equal confidences by lower position, are set
+    to their argmax (``_most_confident``). It returns ``(packed, new_pool,
+    ids_after, masked_after)``: the last two the block state for the next
+    pass, on the device; ``packed`` one int32 vector of, in order, the
+    argmax ``x0`` of every lane [slots * S], the bits of its float32
+    confidence ``softmax(logits)[x0]`` [slots * S], which lanes the pass
+    unmasked [slots * S], and three counters of the expert layers over the
+    live rows (pairs routed and experts that got a pair, both summed over
+    layers, and the most pairs one expert got in one layer; zeros for a
+    dense MLP): one fetch brings all of it. A pass writes the chunk's keys
+    and values like the verify does: a denoising pass's are overwritten by
+    the block's commit pass before anything reads them (the prefix part
+    reads positions below ``pos[r]`` only), the rule the speculative path
+    relies on too. Rows in a commit pass (no lane masked) and rows in a
+    denoising pass share the dispatch; which is which is the host's.
 
     ``attn_impl="kernel"``: per layer, the chunk's KV scatters into the
     pool through the page table FIRST (int8 pools quantize per vector on
@@ -486,7 +500,7 @@ def _verify_rowwise_paged(config: LlamaConfig, page_size: int,
     query can attend them — no page ever has to move back to the free
     list mid-round. ``pos`` is NOT advanced here; the host commits it.
 
-    Returns (verified [slots, S] int32, new_pool).
+    The verify returns (verified [slots, S] int32, new_pool).
     """
     from ..ops.paged_attention import paged_verify_attention
     from .llm import _dequantize_kv
@@ -496,7 +510,9 @@ def _verify_rowwise_paged(config: LlamaConfig, page_size: int,
     positions = pos[:, None] + jnp.arange(s)[None, :]     # [slots, S]
     live = jnp.broadcast_to(page_table[:, :1] >= 0, (b, s))
     if masked is not None:
-        chunk = jnp.where(masked, config.mask_token_id, chunk)
+        ids = jnp.where(from_prev[:, None], prev_ids, chunk)
+        masked = jnp.where(from_prev[:, None], prev_masked, masked)
+        chunk = jnp.where(masked, config.mask_token_id, ids)
     x = embed(config, params, chunk)
     cos, sin = config.rope(positions)
     use_kernel = attn_impl == "kernel"
@@ -542,27 +558,57 @@ def _verify_rowwise_paged(config: LlamaConfig, page_size: int,
         # softmax(logits)[x0] = exp(max - logsumexp)
         confidence = jnp.exp(jnp.max(logits, axis=-1)
                              - jax.nn.logsumexp(logits, axis=-1))
+        confidence = confidence.astype(jnp.float32)
+        chosen = _most_confident(confidence, masked, count)
         counters = expert_counters(loads) if loads \
             else jnp.zeros((3,), jnp.int32)
         packed = jnp.concatenate([
             verified.reshape(-1),
-            jax.lax.bitcast_convert_type(confidence.astype(jnp.float32),
-                                         jnp.int32).reshape(-1),
+            jax.lax.bitcast_convert_type(confidence, jnp.int32).reshape(-1),
+            chosen.astype(jnp.int32).reshape(-1),
             counters])
-    return packed, pool
+    return packed, pool, jnp.where(chosen, verified, ids), masked & ~chosen
+
+
+def _most_confident(confidence: jax.Array, masked: jax.Array,
+                    count: jax.Array) -> jax.Array:
+    """The lanes a pass unmasks under ``low_confidence_static`` ([slots, S]
+    bool): of each row's ``masked`` lanes the ``count[r]`` of highest
+    ``confidence`` (float32), equal confidences by lower position. A lane's
+    rank is the masked lanes that go before it, a pairwise comparison: no
+    sort, and no ``top_k`` whose order among equals is unspecified."""
+    lane = jnp.arange(confidence.shape[1])
+    ours, theirs = confidence[:, None, :], confidence[:, :, None]
+    before = (theirs > ours) | ((theirs == ours)
+                                & (lane[:, None] < lane[None, :]))
+    rank = jnp.sum(before & masked[:, :, None], axis=1)
+    return masked & (rank < count[:, None])
+
+
+class _RowPass(NamedTuple):
+    """What a dispatched pass is to one of its rows, all of it known at
+    dispatch by count: ``base`` the block's start, ``index`` the pass's
+    number within the block (None: the block's commit pass), ``outlived``
+    whether the row is a row of the next pass too (False only where the
+    commit completes the answer or reaches the cache's end)."""
+
+    base: int
+    index: Optional[int]
+    outlived: bool = True
 
 
 @dataclass
 class _TickInFlight:
-    """A plain decode tick that was dispatched and is not yet read:
-    ``rows`` the slots whose next token it yields (a row whose request
-    turns out to have ended before it leaves the list: its token is thrown
-    away), ``next_token`` [slots] on the device, ``host`` the same once
-    fetched, ``rng`` the engine's key as it was before this tick drew from
-    it (None: every row was greedy)."""
+    """A decode dispatch (a plain tick, or a block model's pass) that was
+    sent and is not yet read: ``rows`` the slots whose next token, or
+    whose block's values, it yields (a row whose request turns out to have
+    ended before it leaves the list: what the dispatch holds for it is
+    thrown away), ``next_token`` [slots] on the device, ``host`` what was
+    fetched once it is there, ``rng`` the engine's key as it was before
+    this tick drew from it (None: every row was greedy)."""
 
     rows: list
-    next_token: jax.Array
+    next_token: Optional[jax.Array]
     rng: Optional[jax.Array] = None
     host: Optional[np.ndarray] = None
     # what the host fetches: ``next_token``, or for an expert model the
@@ -570,6 +616,11 @@ class _TickInFlight:
     # record of the iteration that dispatched the tick, which gets them
     fetched: Optional[jax.Array] = None
     record: Optional[object] = None
+    # a pass (``record.kind`` "denoise") has no ``next_token``: what it is
+    # to each of its rows, and the block state it leaves on the device for
+    # the pass behind it (ids and mask after its own unmasking, [slots, S])
+    passes: Optional[dict] = None
+    block: Optional[tuple] = None
 
 
 class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
@@ -710,10 +761,14 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         self._pos = np.zeros((slots,), np.int32)
         self._free_pages: deque = deque(range(self.n_pages))
         self._slot_pages: dict[int, list] = {}
-        # the plain tick looks one tick ahead (docs/serving.md "The
-        # scheduler's iteration"): the tick dispatched and not yet read
+        # the plain tick and a block model's pass look one dispatch ahead
+        # (docs/serving.md "The scheduler's iteration"): the one sent and
+        # not yet read, and what a dispatch with none before it is handed
+        # in place of that one's tokens or block state
         self._in_flight: Optional[_TickInFlight] = None
         self._no_tokens = jnp.zeros((slots,), jnp.int32)
+        self._no_block = (jnp.zeros((slots, block), jnp.int32),
+                          jnp.zeros((slots, block), bool))
         # HBM bytes the gather path would copy per decode tick (the dense
         # k+v view of every slot, per layer) — what the kernel path avoids
         pages = {name: self._pool[name] for name in _buffers(self._pool)}
@@ -826,11 +881,18 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         pos = jnp.asarray(self._pos)
         if self.block_length > 1:
             # the one program a block model decodes with (all-(-1) table:
-            # every write lands on the scratch page)
+            # every write lands on the scratch page), as the tick dispatches
+            # it: each row's block state is the host's or the last pass's,
+            # still on the device
             shape = (self.slots, self.block_length)
-            packed, self._pool = self._denoise_paged(
-                self.params, jnp.zeros(shape, jnp.int32), self._pool, table,
-                pos, masked=jnp.ones(shape, bool), **decode_kw)
+            state = self._no_block
+            for _ in range(2):
+                packed, self._pool, *state = self._denoise_paged(
+                    self.params, jnp.zeros(shape, jnp.int32), self._pool,
+                    table, pos, masked=jnp.ones(shape, bool),
+                    count=jnp.zeros((self.slots,), jnp.int32),
+                    prev_ids=state[0], prev_masked=state[1],
+                    from_prev=jnp.zeros((self.slots,), bool), **decode_kw)
             jax.block_until_ready(packed)
             logger.info("paged engine warm", slots=self.slots,
                         pages=self.n_pages, page_size=self.page_size,
@@ -1612,16 +1674,22 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             # an expert model's tokens come with its experts' counters
             fetched = out[3] if len(out) > 3 else next_token
             fetched.copy_to_host_async()
-        self._in_flight = _TickInFlight(rows, next_token,
-                                        rng if sampling else None,
-                                        fetched=fetched, record=tick)
+        self._sent(_TickInFlight(rows, next_token,
+                                 rng if sampling else None,
+                                 fetched=fetched, record=tick), ahead)
+        return len(rows)
+
+    def _sent(self, sent: _TickInFlight, ahead: Optional[_TickInFlight]):
+        """The tick or pass just dispatched is in flight, and the one
+        before it, ``ahead``, is read while it runs."""
+        tick = self._tick
+        self._in_flight = sent
         tick.t_dispatched = tick.t_fetched = time.perf_counter()
         if ahead is not None:
             tick.lookahead = 1
             with self._lock:
                 self._stats["lookahead_ticks"] += 1
             self._land(ahead)
-        return len(rows)
 
     def _outlives_tick(self, slot) -> bool:
         """Whether a row of the tick in flight is a row of the next one
@@ -1652,19 +1720,26 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         self._land(ahead, admitting)
 
     def _land(self, ahead: _TickInFlight, admitting: bool = False):
-        """fetch | commit of a dispatched tick; ``self._in_flight`` is the
-        tick behind it, or None."""
+        """fetch | commit of a dispatched tick or pass; ``self._in_flight``
+        is the dispatch behind it, or None. What the commit is follows from
+        the kind of the iteration that dispatched: a token a row, or a
+        block's values."""
         tick = self._tick
         behind = self._in_flight
         rides_on = set(behind.rows) if behind is not None else ()
+        denoise = ahead.record.kind == "denoise"
+        if denoise:
+            tick.kind = "denoise"       # also where it only reads a pass
+        values = 3 * self.slots * self.block_length if denoise \
+            else self.slots
         started = time.perf_counter()
         with annotate("mlt.sched.fetch"):
-            tokens_host = ahead.host if ahead.host is not None \
+            host = ahead.host if ahead.host is not None \
                 else np.asarray(ahead.fetched)
         # dispatches that ran before this tick have left their counters
         self._settle_loads(before=ahead.record)
-        if len(tokens_host) > self.slots:
-            self._count_experts(ahead.record, tokens_host[self.slots:])
+        if len(host) > values:
+            self._count_experts(ahead.record, host[values:])
         if admitting:
             tick.admit_wait_s += time.perf_counter() - started
         else:
@@ -1673,139 +1748,202 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             # a row that rides in the tick behind stays decode_active
             self._ledger_mark([i for i in ahead.rows if i not in rides_on],
                               "decode_stall")
+            commit = self._commit_pass if denoise else self._commit_tokens
+            tick.tokens_out += commit(ahead, host[:values])
             for i in ahead.rows:
-                slot = self._slot_state[i]
-                token = int(tokens_host[i])
-                slot.tokens.append(token)
-                slot.remaining -= 1
-                capacity = slot.prompt_len + len(slot.tokens) \
-                    >= self.max_len
-                if (slot.eos_id is not None and token == slot.eos_id) or \
-                        slot.remaining <= 0 or capacity:
-                    self._finish(i)
-                    if i in rides_on:
-                        behind.rows.remove(i)   # ended unseen: rode along
+                if i in rides_on and not self._slot_state[i].active:
+                    behind.rows.remove(i)       # ended unseen: rode along
             if behind is not None and behind.rng is not None and not any(
                     self._slot_state[i].temperature > 0
                     for i in behind.rows):
                 # the sampled rows it drew for had all ended: the key is
                 # as if it had not drawn, as the next draw will find it
                 self._rng, behind.rng = behind.rng, None
-        tick.tokens_out += len(ahead.rows)
+
+    def _commit_tokens(self, ahead: _TickInFlight, host) -> int:
+        """A landed plain tick's token into each of its rows; a row ends on
+        its end-of-sequence id, its last token or the cache's end."""
+        for i in ahead.rows:
+            slot = self._slot_state[i]
+            token = int(host[i])
+            slot.tokens.append(token)
+            slot.remaining -= 1
+            capacity = slot.prompt_len + len(slot.tokens) >= self.max_len
+            if (slot.eos_id is not None and token == slot.eos_id) or \
+                    slot.remaining <= 0 or capacity:
+                self._finish(i)
+        return len(ahead.rows)
 
     # -- block-diffusion decoding (docs/serving.md) -------------------------
 
     def _denoise_tick(self, active) -> int:
-        """One pass over every live row's current block: rows whose block
-        still has masked positions are denoised (the pass unmasks this
-        pass's share of them, the most confident first), rows whose block
-        is full are committed (the pass stores the block's keys and values
-        for good, yields no token, and the next block opens). One dispatch
-        for both; which row is which is decided here."""
+        """Dispatch the next pass over every live row's current block, then
+        read the one before it: build | dispatch for pass p+1, fetch |
+        commit for pass p, as the plain tick does (``_plain_decode_tick``).
+        Rows whose block still has masked positions are denoised (the pass
+        unmasks this pass's share of them, the most confident first), rows
+        whose block is full are committed (the pass stores the block's keys
+        and values for good, yields no token, and the next block opens).
+        One dispatch for both; which row is which is decided here, by
+        count: how many lanes a pass unmasks, whether it is a commit, where
+        the next block starts and whether the row outlives its commit need
+        none of pass p's values. *Which* lanes and which ids do, and stay
+        on the device: a row of p takes its block state from p there. A row
+        that a commit ends on an end-of-sequence id is learnt at commit(p),
+        rode in p+1 too, and p+1's values for it are thrown away (its
+        writes fell into the row's own pages). Returns the rows
+        dispatched."""
         tick = self._tick
         tick.kind = "denoise"
         size = self.block_length
+        ahead = self._in_flight
+        riding = set(ahead.rows) if ahead is not None else ()
+        rows = [i for i in active
+                if i not in riding or ahead.passes[i].outlived]
+        if not rows:
+            self._drain_tick()
+            return 0
         with annotate("mlt.sched.build"):
             chunk = np.zeros((self.slots, size), np.int32)
             masked = np.zeros((self.slots, size), bool)
-            for i in active:
+            count = np.zeros((self.slots,), np.int32)
+            from_prev = np.zeros((self.slots,), bool)
+            # the pass's own copies, and only its rows: any other writes to
+            # the scratch page
+            table = np.full_like(self._page_table, -1)
+            table[rows] = self._page_table[rows]
+            pos = np.zeros_like(self._pos)
+            passes = {}
+            for i in rows:
                 slot = self._slot_state[i]
-                chunk[i] = slot.block_ids
-                masked[i] = slot.block_masked
-                tick.ctx_tokens += slot.block_base + size
-            tick.positions = len(active) * size
-            table = jnp.asarray(self._page_table)
-            pos = jnp.asarray(self._pos)
+                base = pos[i] = slot.block_base
+                tick.ctx_tokens += base + size
+                if i not in riding:
+                    chunk[i] = slot.block_ids
+                    masked[i] = slot.block_masked
+                elif ahead.passes[i].index is None:
+                    masked[i] = True        # behind a commit: a new block
+                else:
+                    from_prev[i] = True
+                count[i], passes[i] = self._schedule_pass(i)
+            tick.commit_rows = sum(row.index is None
+                                   for row in passes.values())
+            tick.positions = len(rows) * size
             lora_kw = self._lora_kwargs(self._slot_adapter_ids()) \
                 if self._adapters is not None else {}
-            self._ledger_mark(active, "decode_active")
-            args = (jnp.asarray(chunk), self._pool, table, pos)
-            masked_dev = jnp.asarray(masked)
+            self._ledger_mark(rows, "decode_active")
+            args = (jnp.asarray(chunk), self._pool, jnp.asarray(table),
+                    jnp.asarray(pos))
+            prev_ids, prev_masked = ahead.block if ahead is not None \
+                else self._no_block
+            state_kw = dict(masked=jnp.asarray(masked),
+                            count=jnp.asarray(count), prev_ids=prev_ids,
+                            prev_masked=prev_masked,
+                            from_prev=jnp.asarray(from_prev))
         tick.t_built = time.perf_counter()
         with annotate("mlt.sched.dispatch"):
-            packed, self._pool = self._denoise_paged(
-                self.params, *args, masked=masked_dev, **lora_kw)
-        tick.t_dispatched = time.perf_counter()
-        with annotate("mlt.sched.fetch"):
-            host = np.asarray(packed)
-        tick.t_fetched = time.perf_counter()
-        with annotate("mlt.sched.commit"):
-            lanes = self.slots * size
-            x0 = host[:lanes].reshape(self.slots, size)
-            confidence = host[lanes:2 * lanes].view(np.float32).reshape(
-                self.slots, size)
-            tick.expert_pairs, tick.experts_touched, \
-                tick.expert_load_max = (int(v) for v in host[2 * lanes:])
-            self._ledger_mark(active, "decode_stall")
-            for i in active:
-                if masked[i].any():
-                    tick.tokens_out += self._unmask(
-                        self._slot_state[i], x0[i], confidence[i])
-                else:
-                    tick.commit_rows += 1
-                    self._commit_block(i)
-            with self._lock:
-                self._stats["commit_passes"] += tick.commit_rows
-                self._stats["denoise_passes"] += \
-                    len(active) - tick.commit_rows
-                self._stats["unmasked_positions"] += tick.tokens_out
-                self._stats["expert_load_max"] = max(
-                    self._stats["expert_load_max"], tick.expert_load_max)
-        return len(active)
+            packed, self._pool, *block = self._denoise_paged(
+                self.params, *args, **state_kw, **lora_kw)
+            packed.copy_to_host_async()
+        with self._lock:
+            self._stats["commit_passes"] += tick.commit_rows
+            self._stats["denoise_passes"] += len(rows) - tick.commit_rows
+        self._sent(_TickInFlight(rows, None, fetched=packed, record=tick,
+                                 passes=passes, block=tuple(block)), ahead)
+        return len(rows)
 
-    def _open_block(self, slot, base: int, known=()):
-        """Open the block at ``base``: ``known`` ids (the prompt's tail, in
-        the first block) stand unmasked, every other position masked."""
-        size = self.block_length
+    def _schedule_pass(self, index: int) -> tuple:
+        """Advance the slot's counts by the pass being dispatched for it:
+        (the lanes the pass unmasks, what the pass is to the row). Pass
+        ``s`` of a block opened with ``m0`` masked positions takes ``m0 //
+        steps``, one more while ``s < m0 mod steps``; a block with none
+        left is committed, and where the row outlives that (more to
+        generate than the block yields, room for another block) the next
+        block's counts open and ``_pos`` moves on."""
+        slot = self._slot_state[index]
+        size, steps = self.block_length, self.denoising_steps
+        base = slot.block_base
+        if slot.block_left:
+            s, m0 = slot.passes_in_block, slot.block_m0
+            share = m0 // steps + (s < m0 % steps)
+            slot.block_left -= share
+            slot.passes_in_block = s + 1
+            return share, _RowPass(base, s)
+        yields = size - max(0, slot.prompt_len - base)
+        outlived = slot.remaining > yields \
+            and base + 2 * size <= self.max_len
+        if outlived:
+            self._schedule_block(slot, base + size, size)
+            self._pos[index] = base + size
+        return 0, _RowPass(base, None, outlived)
+
+    def _commit_pass(self, ahead: _TickInFlight, host) -> int:
+        """A landed pass's values (``host``: ``x0``, the confidences' bits
+        and the lanes chosen, [3 * slots * S]) into each of its rows: the
+        lanes the pass unmasked take its ids, its number and its
+        confidences, and a row in its commit pass commits its block.
+        Returns the positions unmasked."""
+        x0, confidence, chosen = host.reshape(3, self.slots, -1)
+        confidence = confidence.view(np.float32)
+        unmasked = 0
+        for i in ahead.rows:
+            slot = self._slot_state[i]
+            row = ahead.passes[i]
+            if row.index is None:
+                self._commit_block(i, row)
+                continue
+            for j in np.flatnonzero(chosen[i]):
+                slot.block_ids[j] = int(x0[i, j])
+                slot.block_masked[j] = False
+                slot.block_pass[j] = row.index
+                slot.block_confidence[j] = float(confidence[i, j])
+                unmasked += 1
+        with self._lock:
+            self._stats["unmasked_positions"] += unmasked
+        return unmasked
+
+    def _schedule_block(self, slot, base: int, masked: int):
+        """The counts of the block at ``base``, which the host advances
+        as it dispatches: ``masked`` positions to unmask, no pass yet."""
         slot.block_base = base
+        slot.block_m0 = slot.block_left = masked
+        slot.passes_in_block = 0
+
+    def _blank_block(self, slot, known=()):
+        """The values of a block no pass has landed in: ``known`` ids (the
+        prompt's tail, in the first block) stand unmasked, every other
+        position masked."""
+        size = self.block_length
         slot.block_ids = list(known) + [0] * (size - len(known))
         slot.block_masked = [False] * len(known) \
             + [True] * (size - len(known))
         slot.block_pass = [-1] * len(known) + [0] * (size - len(known))
         slot.block_confidence = [1.0] * size
-        slot.block_m0 = size - len(known)
-        slot.passes_in_block = 0
 
-    def _unmask(self, slot, x0, confidence) -> int:
-        """Pass ``passes_in_block`` of the slot's block under
-        ``low_confidence_static``: the block's ``m0`` masked positions are
-        split evenly over the steps (the first ``m0 mod S`` passes take one
-        more), and each pass sets the still-masked positions of highest
-        confidence to their argmax. Returns how many it unmasked."""
-        steps, m0, s = self.denoising_steps, slot.block_m0, \
-            slot.passes_in_block
-        count = m0 // steps + (1 if s < m0 % steps else 0)
-        still = [j for j, m in enumerate(slot.block_masked) if m]
-        # the most confident first; equal confidences by position
-        still.sort(key=lambda j: (-float(confidence[j]), j))
-        for j in still[:count]:
-            slot.block_ids[j] = int(x0[j])
-            slot.block_masked[j] = False
-            slot.block_pass[j] = s
-            slot.block_confidence[j] = float(confidence[j])
-        slot.passes_in_block = s + 1
-        return min(count, len(still))
+    def _open_block(self, slot, base: int, known=()):
+        """Open an admission's first block at ``base``: its counts and its
+        values, the prompt's tail ``known`` unmasked."""
+        self._schedule_block(slot, base, self.block_length - len(known))
+        self._blank_block(slot, known)
 
-    def _commit_block(self, index: int):
-        """The slot's block is full and its commit pass has stored it: its
-        generated positions extend the answer in position order, and the
-        next block opens, unless the answer is complete (``max_new``
-        tokens, an end-of-sequence id, or the cache's end)."""
+    def _commit_block(self, index: int, row: _RowPass):
+        """The slot's block is full and its commit pass ``row`` has stored
+        it: its generated positions extend the answer in position order,
+        and the values of the next block open (its counts did when the
+        commit was dispatched), unless the answer is complete: by count
+        (``max_new`` tokens or the cache's end, ``row.outlived``), or on an
+        end-of-sequence id found here."""
         slot = self._slot_state[index]
-        size = self.block_length
-        first = max(0, slot.prompt_len - slot.block_base)
+        first = max(0, slot.prompt_len - row.base)
         new = slot.block_ids[first:]
         slot.unmask_pass.extend(slot.block_pass[first:])
         slot.unmask_confidence.extend(slot.block_confidence[first:])
-        if slot.eos_id is not None and slot.eos_id in new:
+        ended = slot.eos_id is not None and slot.eos_id in new
+        if ended:
             new = new[:new.index(slot.eos_id) + 1]
         slot.tokens.extend(new)
         slot.remaining -= len(new)
-        base = slot.block_base + size
-        self._pos[index] = base
-        ended = slot.eos_id is not None and bool(new) \
-            and new[-1] == slot.eos_id
-        if ended or slot.remaining <= 0 or base + size > self.max_len:
+        if ended or not row.outlived:
             self._finish(index)
         else:
-            self._open_block(slot, base)
+            self._blank_block(slot)

@@ -151,25 +151,45 @@ def test_commit_and_denoising_rows_share_a_dispatch(model):
         assert len(tokens) == 9
         _check_against_reference(model, prompt, tokens,
                                  request["unmask_pass"], 2)
-    ticks = [t for t in get_tick_log(engine._obs_name).records()
-             if t["kind"] == "denoise"]
-    assert ticks and all(t["rows"] > 0 for t in ticks)
-    assert any(0 < t["commit_rows"] < t["rows"] for t in ticks)
+    records = get_tick_log(engine._obs_name).records()
+    assert all(t["kind"] == "denoise" for t in records)
+    # an iteration dispatches a pass and commits the one before it: its
+    # rows, positions, context and expert counters are those of the pass it
+    # dispatched, its ``tokens_out`` what the pass dispatched before it
+    # unmasked (an iteration that only reads the last pass has no rows)
+    ticks = [t for t in records if t["rows"] > 0]
+    assert ticks and any(0 < t["commit_rows"] < t["rows"] for t in ticks)
     cfg = model[0]
     for t in ticks:
         assert t["positions"] == t["rows"] * B
         assert t["expert_pairs"] == t["positions"] * cfg.top_k * cfg.n_layers
         assert 0 < t["experts_touched"] <= cfg.n_experts * cfg.n_layers
         assert 0 < t["expert_load_max"] <= t["positions"]
-        assert t["tokens_out"] <= (t["rows"] - t["commit_rows"]) * B
         assert t["ctx_tokens"] >= t["positions"]
+    landed = None
+    for t in records:
+        room = 0 if landed is None \
+            else (landed["rows"] - landed["commit_rows"]) * B
+        assert t["tokens_out"] <= room
+        if t["rows"]:
+            landed = t
     row_passes = sum(t["rows"] for t in ticks)
     assert stats["denoise_passes"] + stats["commit_passes"] == row_passes
     assert stats["commit_passes"] == sum(t["commit_rows"] for t in ticks)
     assert stats["tokens_per_row_pass"] == pytest.approx(
-        sum(t["tokens_out"] for t in ticks) / row_passes)
+        sum(t["tokens_out"] for t in records) / row_passes)
     assert stats["expert_load_max"] == max(t["expert_load_max"]
                                            for t in ticks)
+    assert stats["expert_pairs"] == sum(t["expert_pairs"] for t in ticks)
+    assert stats["experts_touched"] == sum(t["experts_touched"]
+                                           for t in ticks)
+    # every pass was dispatched over a pass in flight or read by a drain
+    assert stats["lookahead_ticks"] == sum(t["lookahead"] for t in records)
+    assert stats["lookahead_ticks"] + stats["lookahead_drains"] == len(ticks)
+    # 9 tokens of a prompt of P: the blocks cover P mod 4 + 9 positions, and
+    # every position of theirs that the prompt does not give is unmasked once
+    assert sum(t["tokens_out"] for t in records) == sum(
+        -(-(len(p) % B + 9) // B) * B - len(p) % B for p in prompts)
     for _tokens, request in results:
         phases = request["timing"]["phases"]
         assert phases["decode_active"] > 0 and "prefill" in phases
@@ -212,25 +232,41 @@ def test_pass_program_against_reference(model, impl):
     pool = init_paged_pool(cfg, 5, PAGE)
     table = np.full((2, 4), -1, np.int32)
     table[0, :2] = [2, 0]
+    nothing = dict(prev_ids=jnp.zeros((2, B), jnp.int32),
+                   prev_masked=jnp.zeros((2, B), bool),
+                   from_prev=jnp.zeros((2,), bool))
     step = jax.jit(lambda *a, **k: _verify_rowwise_paged(
-        cfg, PAGE, impl, params, *a, **k))
+        cfg, PAGE, impl, params, *a, **nothing, **k))
     pos = jnp.asarray([0, 0], jnp.int32)
     for base in range(0, 20, B):              # commit the leading blocks
         chunk = np.zeros((2, B), np.int32)
         chunk[0] = committed[base:base + B]
-        _, pool = step(jnp.asarray(chunk), pool, jnp.asarray(table),
-                       pos.at[0].set(base),
-                       masked=jnp.zeros((2, B), bool))
+        _, pool, after, left = step(
+            jnp.asarray(chunk), pool, jnp.asarray(table),
+            pos.at[0].set(base), masked=jnp.zeros((2, B), bool),
+            count=jnp.zeros((2,), jnp.int32))
+        assert (np.asarray(after) == chunk).all() and not left.any()
     ids, masked = [41, 0, 42, 0], [False, True, False, True]
     chunk = np.zeros((2, B), np.int32)
     chunk[0] = ids
-    packed, pool = step(jnp.asarray(chunk), pool, jnp.asarray(table),
-                        pos.at[0].set(20),
-                        masked=jnp.asarray([masked, [True] * B]))
+    packed, pool, after, left = step(
+        jnp.asarray(chunk), pool, jnp.asarray(table), pos.at[0].set(20),
+        masked=jnp.asarray([masked, [True] * B]),
+        count=jnp.asarray([1, 0], jnp.int32))
     host = np.asarray(packed)
     x0 = host[:2 * B].reshape(2, B)
     confidence = host[2 * B:4 * B].view(np.float32).reshape(2, B)
-    pairs, touched, load_max = host[4 * B:]
+    chosen = host[4 * B:6 * B].reshape(2, B)
+    pairs, touched, load_max = host[6 * B:]
+    # the pass unmasked the more confident of the row's two masked lanes,
+    # and left the block state for the pass behind it on the device
+    lane = 1 if confidence[0, 1] >= confidence[0, 3] else 3
+    assert chosen.tolist() == [[int(j == lane) for j in range(B)], [0] * B]
+    want = list(ids)
+    want[lane] = x0[0, lane]
+    assert np.asarray(after)[0].tolist() == want
+    assert np.asarray(left).tolist() == [
+        [m and j != lane for j, m in enumerate(masked)], [True] * B]
     logits, want_x0, want_conf = ref.denoise_pass(
         fields, params, committed, ids, masked, B, pad_to=PAD)
     for lane in range(B):

@@ -81,6 +81,55 @@ def test_tick_log_identities_and_survives_stop(setup):
     assert 0.0 <= stats["tick_host_share"] <= 1.0
 
 
+def test_denoise_records_under_the_lookahead():
+    """A block-diffusion model's passes look one pass ahead too: a
+    ``denoise`` record holds the rows, positions, context and expert
+    counters of the pass its iteration dispatched and, as ``tokens_out``,
+    what the pass dispatched before it unmasked. Over a drained run whose
+    answers end on a block's edge: every dispatch is a record with rows,
+    ``sum(lookahead)`` is ``lookahead_ticks``, the two counters add up to
+    the dispatches, and the positions unmasked are the tokens returned."""
+    from mlrun_tpu.models import tiny_sdar
+
+    cfg = tiny_sdar(dtype=jnp.float32)
+    eng = PagedContinuousBatchingEngine(
+        cfg, init_params(cfg, jax.random.PRNGKey(0)), max_len=64, slots=2,
+        prefill_buckets=(16,), page_size=8, attention_impl="kernel",
+        prefix_cache=False, denoising_steps=2)
+    block = cfg.block_length
+    prompts = [p[:len(p) - len(p) % block] or p + p for p in PROMPTS]
+    eng.start()
+    try:
+        futures = [eng.submit(p, max_new_tokens=2 * block) for p in prompts]
+        outs = [f.result(timeout=300)[0] for f in futures]
+    finally:
+        eng.stop()
+    stats = eng.stats
+    records = get_tick_log(eng._obs_name).records()
+    assert all(len(tokens) == 2 * block for tokens in outs)
+    passes = [r for r in records if r["rows"]]
+    assert all(r["kind"] == "denoise" for r in records)
+    assert sum(r["lookahead"] for r in records) == stats["lookahead_ticks"]
+    assert stats["lookahead_ticks"] + stats["lookahead_drains"] \
+        == len(passes)
+    assert stats["lookahead_ticks"] > stats["lookahead_drains"] > 0
+    assert sum(r["tokens_out"] for r in records) == stats["tokens_out"] \
+        == sum(len(tokens) for tokens in outs)
+    # two denoising passes and a commit a block, two blocks a request
+    assert sum(r["rows"] for r in records) == 6 * len(prompts)
+    assert sum(r["commit_rows"] for r in records) == 2 * len(prompts)
+    for r in records:
+        times = [r[key] for key in ORDERED]
+        assert times == sorted(times), r
+        assert r["lookahead"] in (0, 1) and r["lookahead"] <= r["rows"]
+        assert r["positions"] == r["rows"] * block
+        assert r["expert_pairs"] \
+            == r["positions"] * cfg.top_k * cfg.n_layers
+        assert (r["experts_touched"] > 0) == (r["rows"] > 0)
+    assert stats["expert_pairs"] == sum(r["expert_pairs"] for r in records)
+    assert all(a["t1"] <= b["t0"] for a, b in zip(records, records[1:]))
+
+
 def test_tick_ctx_tokens_are_the_slots_lengths(setup):
     """Ticks driven by hand: a tick's rows are the live slots that the
     tick in flight does not complete by count, and the record says they

@@ -411,9 +411,11 @@ def sdar_shapes(on_chip, monkeypatch):
 
 
 def test_denoise_program_compiles(sdar_shapes, on_chip):
-    """``jit_mlt_denoise``: the verify program with a mask bitmap, a block
-    of 4 a slot, q/k norms, the prefix kernel and the grouped expert
-    products (three a layer, on the device under a name a trace shows)."""
+    """``jit_mlt_denoise`` as the tick dispatches it: the verify program
+    with a mask bitmap, the schedule's counts and the block state of the
+    pass in flight, a block of 4 a slot, q/k norms, the prefix kernel and
+    the grouped expert products (three a layer, on the device under a name
+    a trace shows)."""
     config, params, place = sdar_shapes
     pool = place(jax.eval_shape(lambda: paged.init_paged_pool(
         config, N_PAGES + 1, PAGE_SIZE)))
@@ -424,16 +426,24 @@ def test_denoise_program_compiles(sdar_shapes, on_chip):
         params, on_chip(lanes, jnp.int32), pool,
         on_chip((SDAR_SLOTS, PAGES_PER_SLOT), jnp.int32),
         on_chip((SDAR_SLOTS,), jnp.int32),
-        masked=on_chip(lanes, jnp.bool_)).compile()
+        masked=on_chip(lanes, jnp.bool_),
+        count=on_chip((SDAR_SLOTS,), jnp.int32),
+        prev_ids=on_chip(lanes, jnp.int32),
+        prev_masked=on_chip(lanes, jnp.bool_),
+        from_prev=on_chip((SDAR_SLOTS,), jnp.bool_)).compile()
     hlo = compiled.as_text()
     assert hlo.count("tpu_custom_call") >= 4      # prefix kernel + products
     assert len(re.findall(r"%gmm[\w.-]* = ", hlo)) == 6
     assert re.search(r"paged_verify", hlo)
     # the experts' stacks reach the products as stored: no copy of them
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
-    # packed: x0 and confidence of every lane, three counters
-    packed = 2 * SDAR_SLOTS * SDAR_BLOCK + 3
+    # packed: x0, confidence and the choice of every lane, three counters;
+    # beside it the block state the next pass rides on, left on the device
+    packed = 3 * SDAR_SLOTS * SDAR_BLOCK + 3
     assert f"s32[{packed}]" in hlo
+    out = jax.tree_util.tree_leaves(compiled.out_info)
+    assert [(o.shape, o.dtype) for o in out[-2:]] == [
+        (lanes, jnp.int32), (lanes, jnp.bool_)]
 
 
 @pytest.mark.parametrize("bucket", [128, 1024])
