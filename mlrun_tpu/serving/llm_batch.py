@@ -60,6 +60,7 @@ from ..obs import (
     register_memory_collector,
     wall_now,
 )
+from ..obs import ticklog
 from ..obs.stats import nearest_rank
 from ..ops.rotary import rope_table
 from ..utils import logger
@@ -446,13 +447,12 @@ class ContinuousBatchingEngine:
             latency_window = int(llm_defaults.latency_window)
         if latency_window <= 0:
             raise ValueError("latency_window must be > 0")
-        # bounded rings behind the p50/p95 TTFT / inter-token-latency
-        # percentiles in stats (per-slot ttft alone was discarded)
+        # bounded ring behind the p50/p95 TTFT percentiles in stats
+        # (per-slot ttft alone was discarded); the inter-token and
+        # decode-tick percentiles read the tick log's newest
+        # latency_window records with rows
         self._ttft_ring: deque = deque(maxlen=latency_window)
-        self._itl_ring: deque = deque(maxlen=latency_window)
-        # decode-dispatch wall time (the attention-dominated device step,
-        # admission prefill excluded) behind decode_tick_p50/p95_s
-        self._tick_ring: deque = deque(maxlen=latency_window)
+        self._latency_window = latency_window
         # -- attention kernel dispatch (docs/serving.md "Attention kernels")
         # auto | flash | kernel | reference; prefill resolves to the
         # offset-aware flash kernel or the dense masked softmax. The
@@ -575,6 +575,30 @@ class ContinuousBatchingEngine:
         self._tick_log = get_tick_log(self._obs_name)
         self._tick = TickRecord()
         self._iterations = 0
+        # what closes the records over wall time (docs/observability.md
+        # "Tick log"): the last record's t1 and, since it, the end of the
+        # first idle poll; since when the device is known to have nothing
+        # queued (None: not known; ``_sent_seq`` counts the programs
+        # enqueued, so that a fetch can tell whether it read the last of
+        # them); when the last first token came back, until the next prefill
+        # or decode goes out; the collector's sums as the last record left
+        # them
+        self._last_t1: Optional[float] = None
+        self._idle_since: Optional[float] = None
+        self._quiet_since: Optional[float] = None
+        self._sent_seq = 0
+        self._first_token_at: Optional[float] = None
+        self._gc_seen = ticklog.gc_sums()
+        self._gc_watched = False
+        # the thread's clock and usage at their last reading (None: the next
+        # record starts one), the records until the next (a system call
+        # each, of 6-20 us on some hosts: PERF.md PR 39), and the wall and
+        # the CPU seconds that the next reading covers beside its own
+        # difference (what a reading cut short by an idle poll found)
+        self._cpu_seen: Optional[float] = None
+        self._usage_seen = (0, 0)
+        self._cpu_due = ticklog.CPU_EVERY
+        self._cpu_wall = self._cpu_carry = 0.0
         self.replica = ""
         self._metrics_collector = None
         self._next_id = 0
@@ -592,6 +616,7 @@ class ContinuousBatchingEngine:
                        "handoffs_out": 0, "handoff_bytes_out": 0,
                        "handoffs_in": 0, "handoff_bytes_in": 0,
                        "adapter_rate_limited": 0,
+                       "sched_stalls": 0, "sched_stall_s_max": 0.0,
                        "weights_relaid_bytes": relaid_bytes(self.params)}
         if self.block_length > 1:
             # row-passes that denoised and that committed a block, and the
@@ -916,6 +941,9 @@ class ContinuousBatchingEngine:
         # (t_built = t_dispatched = t_admit)
         tick = self._tick
         tick.kind = "spec"
+        # the round's wait starts where admission ended: what the device
+        # stood dry before it ends there too
+        self._enqueued(tick.t_dispatched)
         with annotate("mlt.sched.fetch"):
             last, tick.ctx_tokens = self._tick_inputs(active)
             self._ledger_mark(active, "decode_active")
@@ -930,7 +958,7 @@ class ContinuousBatchingEngine:
             chunk[:, 0] = last[:, 0]
             chunk[:, 1:] = proposals_h
             verified_h = self._spec_verify_dispatch(chunk, active)
-        tick.t_fetched = time.perf_counter()
+        tick.t_fetched = self._quiet_since = time.perf_counter()
         with annotate("mlt.sched.commit"):
             return self._spec_commit(active, k_effs, proposals_h,
                                      verified_h)
@@ -1024,6 +1052,13 @@ class ContinuousBatchingEngine:
         # device, for 0.1-0.3 s at moments of its own choosing (PERF.md,
         # PR 37); ``stop`` gives it back
         gc.freeze()
+        if not self._gc_watched:
+            self._gc_watched = True
+            ticklog.watch_gc()
+        self._last_t1 = self._idle_since = self._quiet_since = None
+        self._first_token_at = self._cpu_seen = None
+        self._cpu_wall = self._cpu_carry = 0.0
+        self._gc_seen = ticklog.gc_sums()
         self._register_metrics()
         # device HBM / host RSS exposition while this engine lives
         # (mlt_device_mem_bytes — weakref, shared across owners)
@@ -1051,6 +1086,9 @@ class ContinuousBatchingEngine:
         self._running = False
         self._stopped = True
         gc.unfreeze()
+        if self._gc_watched:
+            self._gc_watched = False
+            ticklog.unwatch_gc()
         thread, self._thread = self._thread, None
         if thread is not None:
             thread.join(timeout=timeout)
@@ -1087,7 +1125,7 @@ class ContinuousBatchingEngine:
     # adapter activity in federated sums)
     _COUNTER_STATS = ("requests", "completed", "tokens_out", "shed",
                       "expired", "degraded", "rejected_too_long",
-                      "prefill_chunks",
+                      "prefill_chunks", "sched_stalls",
                       "prefix_queries", "prefix_hits",
                       "prefix_evictions", "prefix_cached_tokens",
                       "handoffs_out", "handoff_bytes_out", "handoffs_in",
@@ -1771,8 +1809,8 @@ class ContinuousBatchingEngine:
         with self._lock:
             out = dict(self._stats)
             ttfts = sorted(self._ttft_ring)
-            itls = sorted(self._itl_ring)
-            ticks = sorted(self._tick_ring)
+        itls, ticks = (sorted(values) for values in
+                       self._tick_log.latest(self._latency_window))
         if out["completed"]:
             out["ttft_avg_s"] = out["ttft_sum"] / out["completed"]
         if ttfts:
@@ -1785,7 +1823,8 @@ class ContinuousBatchingEngine:
             out["decode_tick_p50_s"] = _percentile(ticks, 0.50)
             out["decode_tick_p95_s"] = _percentile(ticks, 0.95)
         # over the tick log's ring: live rows a decode tick, the loop's
-        # share not blocked on the device, admission's share of the loop
+        # share not blocked on the device, admission's share of the loop,
+        # the share the thread was executing, the device known dry
         out.update(self._tick_log.summary())
         out["attention_impl"] = self.attention_impl
         out["prefill_impl"] = self.prefill_impl
@@ -1843,83 +1882,121 @@ class ContinuousBatchingEngine:
         limit is None). The cursor starts at ``adm.base`` — on a paged
         prefix-cache hit the cached prefix KV is already in ``adm.small``
         and only the suffix runs. Returns True once the prompt is fully
-        prefilled and the first token is sampled."""
-        if adm.ledger is not None and \
-                adm.ledger.current_phase != "prefill":
-            # first chunk dispatch: the request is in prefill from here
-            # to the first token — decode ticks interleaved between
-            # chunks included, that IS this request's prefill latency
-            adm.ledger.enter("prefill")
-        fire(FaultPoints.llm_prefill, request_id=adm.request_id,
-             slot=adm.slot, offset=adm.offset, chunks=adm.chunks)
-        prompt = adm.prompt
-        # a block model takes no token from the prefill either
-        total = self._prompt_lead(len(prompt))
-        start = adm.offset
-        remaining = total - start
-        if remaining <= 0:
-            return True                     # nothing left of it to prefill
-        cap = self.max_len - start
-        if limit is None:
-            # prefer a warmed bucket shape that still fits the cache tail
-            # (start > 0 after a prefix hit can rule the usual bucket
-            # out); the cap fallback compiles once per distinct tail
-            pad_len = next(
-                (b for b in self.prefill_buckets if remaining <= b <= cap),
-                min(self._bucket_for(remaining), cap))
-        else:
-            pad_len = min(limit, cap)
-        take = min(remaining, pad_len)
-        padded = np.zeros((1, pad_len), np.int32)
-        padded[0, :take] = prompt[start:start + take]
-        adm.small["pos"] = jnp.full((1,), start, jnp.int32)
-        lora_kw = self._lora_kwargs(adm.adapter_slot)
-        # the logits that come back are those of the chunk's last REAL
-        # position: a padded prompt's first token needs no second dispatch
-        out = self._prefill_dispatch(
-            adm, jnp.asarray(padded), np.int32(take - 1), lora_kw)
-        logits, adm.small = out[:2]
-        if len(out) > 2:
-            # an expert model's dispatch leaves its experts' counters; a
-            # later fetch brings them (_settle_loads)
-            out[2].copy_to_host_async()
-            self._pending_loads.append((self._tick, out[2]))
-        adm.offset += take
-        adm.chunks += 1
-        self._tick.prefill_tokens += take
-        self._tick.prefill_dispatches += 1
-        if self.config.recurrent_state:
-            # the real tokens the scan integrated: the padding is left out
-            self._tick.state_tokens += take
-        # the positions the chunk's tokens attended: each its own and
-        # what precedes it
-        self._tick.prefill_ctx_tokens += take * start + take * (take + 1) // 2
-        with self._lock:
-            self._stats["prefill_chunks"] += 1
-            # tick instrumentation: the most prefill compute any single
-            # scheduler iteration absorbed (tests assert <= prefill_chunk)
-            if take > self._stats["prefill_tokens_tick_max"]:
-                self._stats["prefill_tokens_tick_max"] = take
-        if adm.offset < total:
-            return False
-        if self.block_length > 1:
-            return True
-        # from here the scheduler waits for the prefill on the device
-        waited = time.perf_counter()
-        self._await_tick()
-        if sampling_enabled():
-            # monitoring tap: first-token top1-top2 logit gap (a cheap
-            # model-confidence proxy for the drift analyzer's "logit
-            # statistics"). Only while an observer is armed — the host
-            # transfer of one logits row is not paid when dark.
-            row = np.asarray(logits).reshape(-1)
-            if row.size >= 2:
-                top2 = np.partition(row, -2)[-2:]
-                adm.logit_margin = float(top2[1] - top2[0])
-        adm.first_token = self._first_token(logits, adm.sampling)
-        self._settle_loads()                # this prompt's chunks have run
-        self._tick.admit_wait_s += time.perf_counter() - waited
+        prefilled and the first token is sampled. In the profiler's trace
+        the dispatch (build and enqueue) is ``mlt.sched.prefill`` and the
+        wait for the first token ``mlt.sched.first_token``, siblings."""
+        tick = self._tick
+        with annotate("mlt.sched.prefill"):
+            if adm.ledger is not None and \
+                    adm.ledger.current_phase != "prefill":
+                # first chunk dispatch: the request is in prefill from here
+                # to the first token — decode ticks interleaved between
+                # chunks included, that IS this request's prefill latency
+                adm.ledger.enter("prefill")
+                adm.ledger.note("tick_first", tick.n)
+            fire(FaultPoints.llm_prefill, request_id=adm.request_id,
+                 slot=adm.slot, offset=adm.offset, chunks=adm.chunks)
+            prompt = adm.prompt
+            # a block model takes no token from the prefill either
+            total = self._prompt_lead(len(prompt))
+            start = adm.offset
+            remaining = total - start
+            if remaining <= 0:
+                return True                 # nothing left of it to prefill
+            cap = self.max_len - start
+            if limit is None:
+                # prefer a warmed bucket shape that still fits the cache
+                # tail (start > 0 after a prefix hit can rule the usual
+                # bucket out); the cap fallback compiles once per distinct
+                # tail
+                pad_len = next(
+                    (b for b in self.prefill_buckets
+                     if remaining <= b <= cap),
+                    min(self._bucket_for(remaining), cap))
+            else:
+                pad_len = min(limit, cap)
+            take = min(remaining, pad_len)
+            padded = np.zeros((1, pad_len), np.int32)
+            padded[0, :take] = prompt[start:start + take]
+            adm.small["pos"] = jnp.full((1,), start, jnp.int32)
+            lora_kw = self._lora_kwargs(adm.adapter_slot)
+            # the logits that come back are those of the chunk's last REAL
+            # position: a padded prompt's first token needs no second
+            # dispatch
+            out = self._prefill_dispatch(
+                adm, jnp.asarray(padded), np.int32(take - 1), lora_kw)
+            self._enqueued(time.perf_counter())
+            logits, adm.small = out[:2]
+            if len(out) > 2:
+                # an expert model's dispatch leaves its experts' counters;
+                # a later fetch brings them (_settle_loads)
+                out[2].copy_to_host_async()
+                self._pending_loads.append((tick, out[2]))
+            adm.offset += take
+            adm.chunks += 1
+            tick.prefill_tokens += take
+            tick.prefill_dispatches += 1
+            if self.config.recurrent_state:
+                # the real tokens the scan integrated: the padding is left
+                # out
+                tick.state_tokens += take
+            # the positions the chunk's tokens attended: each its own and
+            # what precedes it
+            tick.prefill_ctx_tokens += \
+                take * start + take * (take + 1) // 2
+            with self._lock:
+                self._stats["prefill_chunks"] += 1
+                # tick instrumentation: the most prefill compute any single
+                # scheduler iteration absorbed (tests assert <=
+                # prefill_chunk)
+                if take > self._stats["prefill_tokens_tick_max"]:
+                    self._stats["prefill_tokens_tick_max"] = take
+            if adm.offset < total:
+                return False
+            if self.block_length > 1:
+                return True
+        with annotate("mlt.sched.first_token"):
+            # from here the scheduler waits on the device: for the tick in
+            # flight, which runs before the prefill, then for the prefill
+            waited = time.perf_counter()
+            self._await_tick()
+            landed = time.perf_counter()
+            if sampling_enabled():
+                # monitoring tap: first-token top1-top2 logit gap (a cheap
+                # model-confidence proxy for the drift analyzer's "logit
+                # statistics"). Only while an observer is armed — the host
+                # transfer of one logits row is not paid when dark.
+                row = np.asarray(logits).reshape(-1)
+                if row.size >= 2:
+                    top2 = np.partition(row, -2)[-2:]
+                    adm.logit_margin = float(top2[1] - top2[0])
+            adm.first_token = self._first_token(logits, adm.sampling)
+            # the token's program was the last one enqueued: the device has
+            # nothing left, and the host's path to the next dispatch begins
+            sampled = self._quiet_since = self._first_token_at = \
+                time.perf_counter()
+            tick.inflight_wait_s += landed - waited
+            tick.prefill_wait_s += sampled - landed
+            self._settle_loads()            # this prompt's chunks have run
+            tick.admit_wait_s += time.perf_counter() - waited
         return True
+
+    def _enqueued(self, now: float, work: bool = True):
+        """The enqueueing call of a device program returned at ``now``:
+        what the device stood dry until then goes to the iteration's
+        record, and nothing is known of it until a fetch reads the last
+        program enqueued. ``work``: the program is a prefill or a decode,
+        where the host's path behind a first token ends (an insert is on
+        that path)."""
+        self._sent_seq += 1
+        tick = self._tick
+        quiet = self._quiet_since
+        if quiet is not None:
+            tick.dry_s += max(0.0, now - quiet)
+            self._quiet_since = None
+        if work and self._first_token_at is not None:
+            tick.after_prefill_s += max(0.0, now - self._first_token_at)
+            self._first_token_at = None
 
     def _prefill_dispatch(self, adm: _Admission, tokens, logits_at,
                           lora_kw, prefix_kv=None):
@@ -2041,6 +2118,8 @@ class ContinuousBatchingEngine:
                 if adm.small is None:
                     adm.small = init_kv_cache(self.config, 1, self.max_len,
                                               kv_dtype=self.kv_dtype)
+                # the staging cache is filled on the device
+                self._enqueued(time.perf_counter(), work=False)
                 return adm
             except Exception as exc:
                 # dequeued but not yet tracked in self._admission — fail
@@ -2096,9 +2175,20 @@ class ContinuousBatchingEngine:
                                    len(adm.prompt))
 
     def _finish_admission(self, adm: _Admission):
+        """The prefilled rows into the slot's storage, then the slot's
+        bookkeeping: ``mlt.sched.insert | mlt.sched.activate``, siblings of
+        the prefill and of the wait for its first token."""
         with annotate("mlt.sched.insert"):
             self._complete_storage(adm)
+            self._enqueued(time.perf_counter(), work=False)
+        self._tick.admissions += 1
+        with annotate("mlt.sched.activate"):
+            self._activate_admission(adm)
+
+    def _activate_admission(self, adm: _Admission):
         if adm.ledger is not None:
+            # an imported prefill dispatched none here
+            adm.ledger.notes.setdefault("tick_first", self._tick.n)
             adm.ledger.note("prefill_chunks", adm.chunks)
             if adm.base:
                 adm.ledger.note("cached_prefix", adm.base)
@@ -2153,13 +2243,13 @@ class ContinuousBatchingEngine:
         ``self._admission`` while prefill runs so a scheduler crash
         mid-prefill still fails its future (and frees its storage) via
         ``_fail_pending``."""
-        adm = self._prepare_admission()
+        with annotate("mlt.sched.claim"):
+            adm = self._prepare_admission()
         if adm is None:
             return False
         self._admission = adm
         if not adm.prefilled:
-            with annotate("mlt.sched.prefill"):
-                self._run_prefill(adm, limit=None)
+            self._run_prefill(adm, limit=None)
         self._finish_admission(adm)
         self._admission = None
         return True
@@ -2177,7 +2267,8 @@ class ContinuousBatchingEngine:
             return
         adm = self._admission
         if adm is None:
-            adm = self._prepare_admission()
+            with annotate("mlt.sched.claim"):
+                adm = self._prepare_admission()
             if adm is None:
                 return
             self._admission = adm
@@ -2187,8 +2278,7 @@ class ContinuousBatchingEngine:
         # unchunked path behaves the same)
         done = adm.prefilled
         if not done:
-            with annotate("mlt.sched.prefill"):
-                done = self._run_prefill(adm, limit=self.prefill_chunk)
+            done = self._run_prefill(adm, limit=self.prefill_chunk)
         if done:
             self._finish_admission(adm)
             self._admission = None
@@ -2251,6 +2341,9 @@ class ContinuousBatchingEngine:
                 slot.unmask_confidence[:len(slot.tokens)]
         timing = None
         if slot.ledger is not None:
+            # the iteration that committed its last token: with
+            # ``tick_first`` the tick records that served the request
+            slot.ledger.note("tick_last", self._tick.n)
             timing = slot.ledger.close()
             stats["timing"] = timing
             export_phases(timing, adapter=slot.adapter)
@@ -2289,6 +2382,7 @@ class ContinuousBatchingEngine:
         # zero the freed row's position so decode writes land in its own
         # (now unused) region
         self._cache["pos"] = self._cache["pos"].at[index].set(0)
+        self._enqueued(time.perf_counter(), work=False)
         self._spec_release_slot(index)
 
     def _decode_tick(self) -> int:
@@ -2358,9 +2452,11 @@ class ContinuousBatchingEngine:
             next_token, self._cache = self._decode(self.params, *args,
                                                    **lora_kw)
         tick.t_dispatched = time.perf_counter()
+        self._enqueued(tick.t_dispatched)
         with annotate("mlt.sched.fetch"):
             tokens_host = np.asarray(next_token)
-        tick.t_fetched = time.perf_counter()
+        # this tick is synchronous: what it fetched was the last program
+        tick.t_fetched = self._quiet_since = time.perf_counter()
         with annotate("mlt.sched.commit"):
             self._ledger_mark(active, "decode_stall")
             for i in active:
@@ -2449,10 +2545,13 @@ class ContinuousBatchingEngine:
         the live rows; returns how many rows it decoded. What it did goes
         to the tick log, and as spans into the profiler's trace: tick |
         admit | build | dispatch | fetch | commit, siblings that partition
-        the iteration, with prefill and insert inside admit.
+        the iteration, and an admission's own parts siblings among them:
+        admit (expiry, control) | claim (a request, its slot, its pages and
+        its staging cache) | prefill | first_token | insert | activate,
+        claim again for the next request.
 
         ``mlt.sched.tick`` opens the iteration and carries its index; it
-        does not enclose the other five. A tool that puts a device gap down
+        does not enclose the others. A tool that puts a device gap down
         to the host span overlapping it most (the benchmark's
         ``trace_reduce.attribute_gap``) would name an enclosing span for
         every gap, since a gap runs from one part into the next (measured,
@@ -2469,33 +2568,107 @@ class ContinuousBatchingEngine:
                  engine=self._obs_name)
             self._expire_queued()
             self._control_tick()
-            self._admission_tick()
+        self._admission_tick()
         tick.admitted(time.perf_counter())
         # per-tenant ITL: one observation per adapter active in the tick
         # (captured BEFORE the tick — finished rows are reset inside it)
         tick_adapters = {s.adapter for s in self._slot_state if s.active}
         tick.rows = self._decode_tick()
         if not (tick.rows or tick.prefill_tokens or tick.tokens_out):
-            return 0                        # an idle poll writes nothing
-        tick.t1 = time.perf_counter()
+            # an idle poll writes nothing, and a device idle for want of
+            # work is not the host's doing; from the first poll's end the
+            # seconds are idle, no record's span and no reading's
+            self._quiet_since = self._first_token_at = None
+            if self._idle_since is None:
+                self._idle_since = now = time.perf_counter()
+                if self._cpu_seen is not None:
+                    self._cpu_carry += time.thread_time() - self._cpu_seen
+                    self._cpu_wall += now - self._last_t1
+                    self._cpu_seen = None
+            return 0
+        stalled = self._close_record(tick)
         elapsed = tick.t1 - tick.t0
         tick_s = tick.t1 - tick.t_admit
-        with self._lock:
-            self._tick_log.append(tick)
-            if tick.rows:
-                self._itl_ring.append(elapsed)
-                # decode dispatch alone (admission prefill excluded): the
-                # per-tick attention cost the kernel work targets
-                self._tick_ring.append(tick_s)
+        if tick.rows:
+            with self._lock:
                 self._adapter_labels_seen.update(
                     a for a in tick_adapters if a)
                 self._count_attention_tick()
-        if tick.rows:
             for tick_adapter in tick_adapters:
                 LLM_ITL.observe(elapsed, replica=self.replica,
                                 adapter=tick_adapter)
+            # decode dispatch alone (admission prefill excluded): the
+            # per-tick attention cost the kernel work targets
             LLM_DECODE_TICK.observe(tick_s, replica=self.replica)
+        if stalled:
+            self._record_stall(tick)
         return tick.rows
+
+    def _close_record(self, tick: TickRecord) -> bool:
+        """The iteration's end: what closes its record over wall time (the
+        gap before it and the idle part of that, what the device stood dry
+        and the host's path behind a first token up to here, the
+        collector's seconds since the last record, the thread's clock where
+        a reading is due), then the record into the log. True where the log
+        calls it a stall."""
+        now = tick.t1 = time.perf_counter()
+        if self._last_t1 is not None:
+            tick.gap_s = tick.t0 - self._last_t1
+            if self._idle_since is not None:
+                tick.idle_s = tick.t0 - self._idle_since
+        self._last_t1, self._idle_since = now, None
+        # the rest of either interval belongs to the next record
+        if self._quiet_since is not None:
+            tick.dry_s += now - self._quiet_since
+            self._quiet_since = now
+        if self._first_token_at is not None:
+            tick.after_prefill_s += now - self._first_token_at
+            self._first_token_at = now
+        collected = ticklog.gc_sums()
+        if collected != self._gc_seen:
+            seen, self._gc_seen = self._gc_seen, collected
+            tick.gc_s = collected[0] - seen[0]
+            tick.gc_gen = max((generation for generation in range(3)
+                              if collected[1 + generation]
+                              != seen[1 + generation]), default=0)
+        self._read_thread(tick)
+        return self._tick_log.append(tick)
+
+    def _read_thread(self, tick: TickRecord):
+        """The thread's clock and usage, every ``CPU_EVERY`` records and in
+        a record long enough to be a stall: the difference since the
+        reading before goes to this record with the wall seconds it covers
+        (``cpu_s``, ``cpu_span_s``). After ``start()`` or an idle poll the
+        record only starts a reading, at its end."""
+        if self._cpu_seen is None:
+            self._cpu_seen = time.thread_time()
+            self._usage_seen = ticklog.thread_usage()
+            return
+        span_s = tick.span_s
+        self._cpu_wall += span_s
+        self._cpu_due -= 1
+        if self._cpu_due > 0 and span_s <= ticklog.STALL_FLOOR_S:
+            return
+        cpu, usage = time.thread_time(), ticklog.thread_usage()
+        tick.cpu_s = self._cpu_carry + cpu - self._cpu_seen
+        tick.cpu_span_s = self._cpu_wall
+        tick.nivcsw = usage[0] - self._usage_seen[0]
+        tick.majflt = usage[1] - self._usage_seen[1]
+        self._cpu_seen, self._usage_seen = cpu, usage
+        self._cpu_wall = self._cpu_carry = 0.0
+        self._cpu_due = ticklog.CPU_EVERY
+
+    def _record_stall(self, tick: TickRecord):
+        """A stalled iteration leaves its record, and its parts by cause,
+        on the flight ring (``sched.stall``)."""
+        fields = tick.as_dict()
+        parts = ticklog.stall_parts(fields)
+        with self._lock:
+            self._stats["sched_stalls"] += 1
+            self._stats["sched_stall_s_max"] = max(
+                self._stats["sched_stall_s_max"], parts["span_s"])
+        flight_record("sched.stall", engine=self._obs_name,
+                      replica=self.replica, record=fields, **parts)
 
     def _loop(self, epoch: int = 0):
         try:

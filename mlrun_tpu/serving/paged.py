@@ -621,6 +621,9 @@ class _TickInFlight:
     # the pass behind it (ids and mask after its own unmasking, [slots, S])
     passes: Optional[dict] = None
     block: Optional[tuple] = None
+    # the engine's count of programs enqueued as this one went out: the
+    # fetch that reads it read the device's last program where none followed
+    seq: int = 0
 
 
 class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
@@ -1111,6 +1114,8 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
     def _control_tick(self):
         if self._control:
             self._drain_tick(admitting=True)    # the ops read the pool
+            # and may write it: nothing is known of the device behind them
+            self._enqueued(time.perf_counter(), work=False)
         while self._control:
             kind, args, future = self._control.popleft()
             if future.done():
@@ -1408,6 +1413,9 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                         adm.small = self._gather_paged(
                             self._pool, adm.small,
                             jnp.asarray(prefix_ids))
+                # the staging cache is filled on the device (and a promote
+                # or a gather wrote into it)
+                self._enqueued(time.perf_counter(), work=False)
                 return adm
             except Exception as exc:
                 # popped but not yet tracked in self._admission: fail the
@@ -1685,6 +1693,8 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         tick = self._tick
         self._in_flight = sent
         tick.t_dispatched = tick.t_fetched = time.perf_counter()
+        self._enqueued(tick.t_dispatched)
+        sent.seq = self._sent_seq
         if ahead is not None:
             tick.lookahead = 1
             with self._lock:
@@ -1701,7 +1711,9 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
     def _await_tick(self):
         """The scheduler is about to wait on a prefill queued behind the
         tick in flight: that tick's tokens come first, and its rows wait
-        from here (``decode_stall``) until their next dispatch."""
+        from here (``decode_stall``) until their next dispatch. The caller
+        times the wait (``inflight_wait_s``); the prefill went out behind
+        the tick, so the device is not dry when this returns."""
         ahead = self._in_flight
         if ahead is not None and ahead.host is None:
             ahead.host = np.asarray(ahead.fetched)
@@ -1740,10 +1752,14 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         self._settle_loads(before=ahead.record)
         if len(host) > values:
             self._count_experts(ahead.record, host[values:])
+        now = time.perf_counter()
         if admitting:
-            tick.admit_wait_s += time.perf_counter() - started
+            tick.admit_wait_s += now - started
+            tick.inflight_wait_s += now - started
         else:
-            tick.t_fetched = time.perf_counter()
+            tick.t_fetched = now
+        if ahead.seq == self._sent_seq:
+            self._quiet_since = now         # nothing went out behind it
         with annotate("mlt.sched.commit"):
             # a row that rides in the tick behind stays decode_active
             self._ledger_mark([i for i in ahead.rows if i not in rides_on],

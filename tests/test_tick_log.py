@@ -22,6 +22,11 @@ PROMPTS = [[1, 7, 3, 9, 2], [4, 5, 6, 7, 8, 9, 1, 2, 3], [11, 12],
 ORDERED = ("t0", "t_admit", "t_built", "t_dispatched", "t_fetched", "t1")
 SCHED_CHILDREN = ("mlt.sched.admit", "mlt.sched.build", "mlt.sched.dispatch",
                   "mlt.sched.fetch", "mlt.sched.commit")
+# an admission's own parts, siblings of those; admit (expiry, control)
+# closes before the first claim, and a claim follows every admission: the
+# next request's, or the one that finds none
+ADMISSION = ("mlt.sched.prefill", "mlt.sched.first_token",
+             "mlt.sched.insert", "mlt.sched.activate", "mlt.sched.claim")
 
 
 @pytest.fixture(scope="module")
@@ -286,7 +291,9 @@ def test_kernel_names_and_scopes_in_the_programs(setup):
 def test_profile_holds_the_scheduler_spans(setup, tmp_path):
     """Three ticks under the profiler: on the host plane every iteration
     opens with an mlt.sched.tick that carries its index, and its parts
-    follow as siblings, none inside another, before the next: admit, then
+    follow as siblings, none inside another, before the next: admit
+    (expiry and control, then the claim), for each admission prefill,
+    first_token, insert, activate and admit again for the next claim, then
     build and dispatch of the tick it sends, then fetch and commit of the
     tick sent an iteration earlier (none in the first; the last iteration
     reads the third tick and sends nothing)."""
@@ -326,18 +333,16 @@ def test_profile_holds_the_scheduler_spans(setup, tmp_path):
             continue
         seen += 1
         parts = [e for e in events[i + 1:following]
-                 if e[2] in SCHED_CHILDREN]
-        want = [name for name, there in zip(SCHED_CHILDREN, (
-            True, record["rows"], record["rows"], record["tokens_out"],
-            record["tokens_out"])) if there]
+                 if e[2] in SCHED_CHILDREN + ADMISSION]
+        want = ["mlt.sched.admit", "mlt.sched.claim"] \
+            + list(ADMISSION) * record["admissions"] \
+            + [name for name, there in zip(SCHED_CHILDREN[1:], (
+                record["rows"], record["rows"], record["tokens_out"],
+                record["tokens_out"])) if there]
         assert [e[2] for e in parts] == want
         assert all(a[1] <= b[0] for a, b in zip([events[i]] + parts, parts))
     assert seen == 4
-    inside_admit = [e[2] for e in events
-                    if e[2] in ("mlt.sched.prefill", "mlt.sched.insert")
-                    and any(a[2] == "mlt.sched.admit" and a[0] <= e[0]
-                            and e[1] <= a[1] for a in events)]
-    assert inside_admit == ["mlt.sched.prefill", "mlt.sched.insert"]
+    assert sum(r["admissions"] for r in worked.values()) == 1
     assert time.monotonic() < limit
 
 
