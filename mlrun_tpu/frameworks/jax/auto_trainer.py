@@ -17,6 +17,7 @@ from typing import Any, Callable, Iterator, Optional
 
 from ...config import mlconf
 from ...execution import MLClientCtx
+from ...models import laguna as laguna_mod
 from ...models import llama as llama_mod
 from ...models import nemotron_h as nemotron_h_mod
 from ...models import xing4 as xing4_mod
@@ -32,6 +33,8 @@ MODEL_PRESETS = {
     "tiny-xing4": xing4_mod.tiny_xing4,
     "nemotron-3-nano-30b-a3b": nemotron_h_mod.nemotron_3_nano_30b_a3b,
     "tiny-nemotron-h": nemotron_h_mod.tiny_nemotron_h,
+    "laguna-xs.2": laguna_mod.laguna_xs2,
+    "tiny-laguna": laguna_mod.tiny_laguna,
 }
 
 

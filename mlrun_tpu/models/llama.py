@@ -59,6 +59,10 @@ class LlamaConfig:
     # rows in the cache (True: models/nemotron_h.py, ``state_rows()``); a
     # class's, never an instance's
     recurrent_state = False
+    # some layers attend to their last positions alone and keep a window of
+    # them a sequence beside the rows of the others (True:
+    # models/laguna.py, ``state_rows()``); a class's, never an instance's
+    window_cache = False
 
     vocab_size: int = 128256
     n_layers: int = 32
@@ -132,9 +136,19 @@ class LlamaConfig:
         every leaf is stacked over all layers."""
         return layer
 
+    def cache_index(self, layer: int):
+        """What the serving programs' ``attend`` is told of layer
+        ``layer``'s cache: its index among the layers that leave rows."""
+        return self.leaf_index("wo", layer)
+
     def rope(self, positions: jax.Array) -> tuple[jax.Array, jax.Array]:
         """The cos/sin tables of ``positions`` as this family rotates."""
         return rope_table(positions, self.head_dim, self.rope_theta)
+
+    def layer_rope(self, cos, sin, layer: int):
+        """The tables layer ``layer`` rotates by, out of what :meth:`rope`
+        returned: all of it here."""
+        return cos, sin
 
     def param_count(self) -> int:
         embed = self.vocab_size * self.embed_dim
@@ -359,8 +373,9 @@ def llama_qkv(config: LlamaConfig, lp, h, cos, sin, proj):
     """q, k and v of the Llama family out of the normed input ``h``: three
     projections, the q/k norm where the config has one, the rotation."""
     b, s, _ = h.shape
-    q = _in_proj(proj, lp, h, "wq").reshape(b, s, config.n_heads,
-                                            config.head_dim)
+    # the layer's own number of heads: a family may give kinds of layer
+    # several (models/laguna.py)
+    q = _in_proj(proj, lp, h, "wq").reshape(b, s, -1, config.head_dim)
     k = _in_proj(proj, lp, h, "wk").reshape(b, s, config.n_kv_heads,
                                             config.head_dim)
     v = _in_proj(proj, lp, h, "wv").reshape(b, s, config.n_kv_heads,
@@ -419,7 +434,7 @@ def decoder_block(config: LlamaConfig, lp, x, cos, sin, *, proj, attend,
             h = rms_norm(u, lp[f"{sub}_norm_scale"], config.norm_eps)
             if sub == "attn":
                 q, k, v = seams.qkv(config, lp, h, cos, sin, proj)
-                attn = attend(q, k, v).reshape(b, s, config.qkv_dim)
+                attn = attend(q, k, v).reshape(b, s, -1)
                 out = proj(attn, lp["wo"], "wo")
             elif sub == "ssm":
                 out = ssm(lp, h, proj)
@@ -465,6 +480,15 @@ def qk_normed(config: LlamaConfig, q, k, lp):
             rms_norm(k, lp["k_norm_scale"], config.norm_eps))
 
 
+# a leaf stacked over the layers of one kind, where another kind has a leaf
+# of the same name and another shape, carries its kind after this mark
+# (``wq@window``, models/laguna.py); a layer's slice holds it under its name
+# (:func:`layer_slice`)
+KIND_MARK = "@"
+# the kinds of attention layer a config's ``cache_index`` routes a layer by:
+# the whole context on pages, or a window of positions on a ring a slot
+# (serving/window.py)
+FULL, WINDOW = "full", "window"
 # a layer's MLP leaves: the dense SwiGLU's, and the expert layer's that are
 # stacked over the expert layers alone where dense layers lead
 DENSE_MLP_LEAVES = ("w_gate", "w_up", "w_down")
@@ -496,14 +520,17 @@ def layer_slice(layers: Params, layer: int, first_k_dense: int = 0,
     layer)`` (a config's ``leaf_index``) says where the layer sits in the
     leaf's stack, or None: the layer has no such leaf and gets none, which
     is how :func:`decoder_block` and :func:`layer_mlp` tell a layer's kind.
-    Absent, it is :func:`dense_then_experts` of ``first_k_dense``."""
+    Absent, it is :func:`dense_then_experts` of ``first_k_dense``. A leaf
+    stacked over one kind of layer under ``<name>@<kind>``
+    (``KIND_MARK``) reaches the layer under ``<name>``."""
     if index_of is None:
         index_of = functools.partial(dense_then_experts, first_k_dense)
     out = {}
     for name, leaf in layers.items():
         at = index_of(name, layer)
         if at is not None:
-            out[name] = leaf if name.startswith("experts_") else leaf[at]
+            out[name.partition(KIND_MARK)[0]] = \
+                leaf if name.startswith("experts_") else leaf[at]
     return out
 
 

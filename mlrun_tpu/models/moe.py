@@ -266,7 +266,8 @@ def moe_mlp(config: MoEConfig, x, lp, held=None, live=None, layer=None):
 
     Routes every token over the router's full width (softmax in float32,
     top-k, gates renormalised under ``norm_topk``; under ``config.scoring
-    == "sigmoid"`` sigmoid scores, top-k of score + ``lp["router_bias"]``,
+    == "sigmoid"`` sigmoid scores, top-k of score + ``lp["router_bias"]``
+    (of the score alone where the layer has no such leaf),
     gates from the scores, renormalised and times ``routed_scale``), adds
     the shared expert where ``lp`` has one (:func:`shared_expert`), keeps the
     token-expert pairs whose expert lies in ``held`` (a contiguous range
@@ -297,10 +298,13 @@ def moe_mlp(config: MoEConfig, x, lp, held=None, live=None, layer=None):
         logits = jnp.einsum("tm,me->te", xt.astype(jnp.float32),
                             lp["router"].astype(jnp.float32))
         if getattr(config, "scoring", "softmax") == "sigmoid":
-            # choice by score plus the per-expert selection bias, gates
-            # from the scores without it, renormalised and scaled
+            # choice by score plus the per-expert selection bias where the
+            # layer has one, gates from the scores without it, renormalised
+            # and scaled
             scores = jax.nn.sigmoid(logits)
-            _, chosen = jax.lax.top_k(scores + lp["router_bias"], k)
+            biased = scores + lp["router_bias"] if "router_bias" in lp \
+                else scores
+            _, chosen = jax.lax.top_k(biased, k)
             gates = jnp.take_along_axis(scores, chosen, axis=-1)
             if getattr(config, "norm_topk", True):
                 gates = gates / (jnp.sum(gates, axis=-1, keepdims=True)

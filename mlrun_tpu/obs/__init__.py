@@ -167,8 +167,14 @@ LLM_KV_BYTES_PER_TOKEN = REGISTRY.gauge(
 LLM_STATE_BYTES_PER_SLOT = REGISTRY.gauge(
     "mlt_llm_state_bytes_per_slot",
     "Bytes a slot keeps beside its pages whatever its length: a recurrent "
-    "family's state over its state-space layers (0 for any other), paged "
-    "engines",
+    "family's state over its state-space layers, or a window family's "
+    "rings over its window layers (0 for any other), paged engines",
+    labels=("engine", "replica"), overflow="drop")
+LLM_WINDOW_BYTES_PER_SLOT = REGISTRY.gauge(
+    "mlt_llm_window_bytes_per_slot",
+    "Bytes of a slot's window cache: the keys and values of its last "
+    "sliding_window positions a window layer, whatever its length (0 for a "
+    "family without window layers), paged engines",
     labels=("engine", "replica"), overflow="drop")
 LLM_WEIGHTS_RELAID_BYTES = REGISTRY.gauge(
     "mlt_llm_weights_relaid_bytes",

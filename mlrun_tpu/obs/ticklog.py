@@ -61,6 +61,7 @@ FIELDS = ("n", "t0", "t_admit", "t_built", "t_dispatched", "t_fetched",
           "kind", "positions", "tokens_out", "commit_rows", "expert_pairs",
           "experts_touched", "expert_load_max", "lookahead",
           "prefill_ctx_tokens", "state_rows", "state_tokens",
+          "window_keys", "window_prefill_pairs",
           "prefill_dispatches", "gap_s", "inflight_wait_s",
           "prefill_wait_s", "dry_s", "after_prefill_s", "admissions",
           "cpu_s", "gc_s", "gc_gen", "nivcsw", "majflt", "idle_s",
@@ -118,7 +119,13 @@ class TickRecord:
     and the per-slot state") also fills ``state_rows``, the rows whose
     state the tick dispatched in the iteration advanced, and
     ``state_tokens``, the real prompt tokens its prefill dispatches
-    integrated into a state (a bucket's padding left out).
+    integrated into a state (a bucket's padding left out). A family with
+    window layers (docs/serving.md "Window layers and the window cache")
+    fills ``window_keys``, the keys a window layer's decode read for the
+    tick dispatched in the iteration (over its rows, the least of the
+    row's context and the window), and ``window_prefill_pairs``, the
+    query-key pairs inside the window that its prefill dispatches
+    computed a window layer (the real tokens').
 
     A boundary that an iteration never reaches stays at the one before it,
     so every interval is defined and non-negative.
@@ -174,6 +181,7 @@ class TickRecord:
         self.expert_pairs = self.experts_touched = self.expert_load_max = 0
         self.lookahead = self.prefill_ctx_tokens = 0
         self.state_rows = self.state_tokens = self.prefill_dispatches = 0
+        self.window_keys = self.window_prefill_pairs = 0
         self.gap_s = self.inflight_wait_s = self.prefill_wait_s = 0.0
         self.dry_s = self.after_prefill_s = self.cpu_s = self.gc_s = 0.0
         self.idle_s = self.cpu_span_s = 0.0

@@ -20,7 +20,13 @@ def rope_table(positions: jax.Array, head_dim: int,
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     """Apply rotary embedding. x: [..., seq, heads, head_dim] (interleaved
-    pair convention: (x1, x2) halves)."""
+    pair convention: (x1, x2) halves). Tables of fewer pairs than half a
+    head rotate its leading ``2 * pairs`` dims alone (a partial rotary
+    factor) and leave the rest as they are."""
+    rotated = 2 * cos.shape[-1]
+    if rotated < x.shape[-1]:
+        return jnp.concatenate([apply_rope(x[..., :rotated], cos, sin),
+                                x[..., rotated:]], axis=-1)
     dtype = x.dtype
     x = x.astype(jnp.float32)
     half = x.shape[-1] // 2

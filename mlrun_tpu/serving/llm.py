@@ -21,6 +21,7 @@ import numpy as np
 
 from ..models.llama import (
     EXPERT_SERVING_LEAVES,
+    KIND_MARK,
     SERVING_LEAVES,
     LlamaConfig,
     Params,
@@ -41,7 +42,8 @@ def init_kv_cache(config: LlamaConfig, batch: int, max_len: int,
     family one latent and one rotated key a token) over the layers that
     leave rows (``config.cache_layers``), one buffer [layers, batch,
     *shape] for each of ``config.state_rows()`` (a recurrent family's
-    state, over its ``state_layers``), and ``pos``.
+    state, or a window family's rings, over its ``state_layers``), and
+    ``pos``.
     ``kv_dtype="int8"`` stores k/v per-vector symmetric
     int8 (scale over head_dim, kept f32 per [layer, batch, pos, kv_head]) —
     half the HBM residency of bf16, so twice the slots x context per chip.
@@ -94,6 +96,17 @@ class RecurrentStateError(ValueError):
     per-tenant adapters and every engine but the paged one."""
 
 
+class WindowCacheError(ValueError):
+    """A family with sliding-window layers (``config.window_cache``,
+    docs/serving.md "Window layers and the window cache") was asked for
+    something its window cache does not carry: the cache holds a slot's
+    last window of positions in a ring beside the pages of its full layers,
+    so a prefix hit (whose window is in no page), speculation (a rejected
+    token's row would have overwritten one still in the window), a KV
+    handoff and the host tier are refused, and with them an int8 cache,
+    per-tenant adapters and every engine but the paged one."""
+
+
 def refuse_layout(config, what: str, asked: bool = True):
     """Raise the family's typed error where ``asked`` is for something
     that what it keeps a sequence by does not carry."""
@@ -107,6 +120,11 @@ def refuse_layout(config, what: str, asked: bool = True):
             f"{type(config).__name__} keeps a recurrent state a slot, "
             f"which does not carry {what} (docs/serving.md \"State-space "
             f"layers and the per-slot state\")")
+    if asked and config.window_cache:
+        raise WindowCacheError(
+            f"{type(config).__name__} keeps a window cache a slot, which "
+            f"does not carry {what} (docs/serving.md \"Window layers and "
+            f"the window cache\")")
 
 
 def _quantize_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -243,9 +261,11 @@ def relay_layers(config: LlamaConfig, layers: dict) -> dict:
     transposed."""
     if config.seams.qkv is llama_qkv:
         for name, relaid in SERVING_LEAVES.items():
-            if name in layers:
-                w = layers.pop(name)
-                layers[relaid] = jax.block_until_ready(
+            # a kind's own stack (``wq@window``) keeps its mark
+            for key in [key for key in layers
+                        if key.partition(KIND_MARK)[0] == name]:
+                w = layers.pop(key)
+                layers[relaid + key[len(name):]] = jax.block_until_ready(
                     jnp.swapaxes(w, 1, 2).reshape(
                         w.shape[0], -1, config.head_dim, w.shape[1]))
     for name, relaid in EXPERT_SERVING_LEAVES.items():
@@ -274,7 +294,7 @@ def relaid_bytes(params: Params) -> int:
     """Bytes of the leaves ``params`` holds in the serving layout."""
     relaid = {*SERVING_LEAVES.values(), *EXPERT_SERVING_LEAVES.values()}
     return sum(int(leaf.nbytes) for name, leaf in params["layers"].items()
-               if name in relaid)
+               if name.partition(KIND_MARK)[0] in relaid)
 
 
 def _serving_layers(config: LlamaConfig, params: Params, x, cos, sin,
@@ -285,7 +305,10 @@ def _serving_layers(config: LlamaConfig, params: Params, x, cos, sin,
     without scan-carry gymnastics) of models/llama.py ``decoder_block``
     over ``layer_slice`` and :func:`_serving_proj`. ``attend(layer, q, k,
     v) -> [B, S, Hq, D]`` is the caller's cache: it writes K and V and
-    reads the attention; ``layer`` counts the layers that leave rows.
+    reads the attention; ``layer`` is ``config.cache_index``, the layer's
+    place among those that leave rows (for a family with a window cache,
+    its kind and its place within the kind). Each layer rotates by its
+    own tables out of ``cos``, ``sin`` (``config.layer_rope``).
     ``ssm(layer, lp, h, proj) -> out`` is the caller's recurrent state
     (models/nemotron_h.py): ``layer`` counts the layers that keep one.
     Returns ``(x, loads)``: ``loads`` the expert layers' loads, empty for
@@ -296,8 +319,9 @@ def _serving_layers(config: LlamaConfig, params: Params, x, cos, sin,
     for layer in range(config.n_layers):
         x, load = decoder_block(
             config, layer_slice(params["layers"], layer, index_of=at), x,
-            cos, sin, proj=_serving_proj(lora, adapter_ids, layer, x.dtype),
-            attend=functools.partial(attend, at("wo", layer)),
+            *config.layer_rope(cos, sin, layer),
+            proj=_serving_proj(lora, adapter_ids, layer, x.dtype),
+            attend=functools.partial(attend, config.cache_index(layer)),
             ssm=ssm and functools.partial(ssm, at("ssm_in", layer)),
             live=live, layer=layer)
         if load is not None:
@@ -518,6 +542,13 @@ def _forward_with_cache(config: LlamaConfig, params: Params,
     elif config.recurrent_state:
         ssm = state_prefill_mixer(config, cache, new,
                                   s if logits_at is None else logits_at + 1)
+    elif config.window_cache:
+        # batch 1: the paged engine's admission, as above
+        from .window import window_prefill_attend
+
+        attend = window_prefill_attend(
+            cache, new, start[0], s if logits_at is None else logits_at + 1,
+            attend, attn_impl)
     x, loads = _serving_layers(config, params, x, cos, sin, attend, lora,
                                adapter_ids, ssm=ssm)
     if not all_logits:

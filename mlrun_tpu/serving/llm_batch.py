@@ -44,6 +44,7 @@ from ..obs import (
     LLM_ITL,
     LLM_KV_BYTES_PER_TOKEN,
     LLM_STATE_BYTES_PER_SLOT,
+    LLM_WINDOW_BYTES_PER_SLOT,
     LLM_QUEUE_DEPTH,
     LLM_SPEC_ROUNDS,
     LLM_SPEC_TOKENS,
@@ -80,6 +81,7 @@ from .llm import (
     relaid_bytes,
     serving_tree,
 )
+from .window import window_pairs
 from .samples import emit_sample, sampling_enabled
 from .resilience import (  # noqa: F401 - EngineStoppedError re-exported
     DeadlineExceeded,
@@ -468,8 +470,10 @@ class ContinuousBatchingEngine:
         # device-resident bank; every prefill/decode dispatch gathers
         # per-row (A, B) deltas by bank slot index. None = single-tenant
         # engine, compile-identical to the pre-adapter programs.
-        if adapters is not None and config.recurrent_state:
-            # the bank's targets are the attention-then-MLP block's
+        if adapters is not None and (config.recurrent_state
+                                     or config.window_cache):
+            # the bank's targets are the attention-then-MLP block's, of one
+            # width a projection
             refuse_layout(config, "per-tenant adapters")
         if adapters is None:
             self._adapters = None
@@ -1164,6 +1168,7 @@ class ContinuousBatchingEngine:
             LLM_FREE_PAGE_FRAC.remove(engine=name, replica=replica)
             LLM_KV_BYTES_PER_TOKEN.remove(engine=name, replica=replica)
             LLM_STATE_BYTES_PER_SLOT.remove(engine=name, replica=replica)
+            LLM_WINDOW_BYTES_PER_SLOT.remove(engine=name, replica=replica)
             LLM_WEIGHTS_RELAID_BYTES.remove(engine=name, replica=replica)
             if has_spec:
                 LLM_SPEC_ROUNDS.remove(engine=name, replica=replica)
@@ -1234,6 +1239,9 @@ class ContinuousBatchingEngine:
                                            engine=name, replica=replica)
                 LLM_STATE_BYTES_PER_SLOT.set(
                     stats["state_bytes_per_slot"], engine=name,
+                    replica=replica)
+                LLM_WINDOW_BYTES_PER_SLOT.set(
+                    stats["window_bytes_per_slot"], engine=name,
                     replica=replica)
             LLM_WEIGHTS_RELAID_BYTES.set(stats["weights_relaid_bytes"],
                                          engine=name, replica=replica)
@@ -1941,6 +1949,11 @@ class ContinuousBatchingEngine:
                 # the real tokens the scan integrated: the padding is left
                 # out
                 tick.state_tokens += take
+            if self.config.window_cache:
+                # the query-key pairs inside the window that the window
+                # layers' chunk computed, a layer
+                tick.window_prefill_pairs += window_pairs(
+                    start, take, self.config.sliding_window)
             # the positions the chunk's tokens attended: each its own and
             # what precedes it
             tick.prefill_ctx_tokens += \

@@ -95,10 +95,11 @@ def init_paged_pool(config: LlamaConfig, n_pages: int, page_size: int,
     (per-head keys and values; one latent and one rotated key a token for
     a latent family). The int8 variant carries per-vector scales.
 
-    Beside the pages, for a family with a recurrent state and ``slots``
-    given: under ``STATE`` what each of ``slots`` sequences keeps whatever
-    its length, one buffer [state_layers, slots, *shape] for each of
-    ``config.state_rows()`` (serving/llm.py ``init_slot_state``). It rides
+    Beside the pages, for a family with a recurrent state or a window cache
+    and ``slots`` given: under ``STATE`` what each of ``slots`` sequences
+    keeps whatever its length, one buffer [state_layers, slots, *shape] for
+    each of ``config.state_rows()`` (serving/llm.py ``init_slot_state``:
+    a state, or a window layer's ring, serving/window.py). It rides
     in the pool's tree, so every program that is handed the pool (and
     donates it) is handed the state."""
     if kv_dtype not in ("native", "int8"):
@@ -115,7 +116,7 @@ def init_paged_pool(config: LlamaConfig, n_pages: int, page_size: int,
         }
     pool = {name: jnp.zeros(lead + row, config.dtype)
             for name, row in config.cache_rows().items()}
-    if slots and config.recurrent_state:
+    if slots and config.state_layers:
         pool[STATE] = init_slot_state(config, slots)
     return pool
 
@@ -271,7 +272,10 @@ def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
     may still be on its way to the host) and not from ``tokens``.
     A pool that keeps a per-slot state (``pool[STATE]``, a family with a
     recurrent state) has the live rows' states advanced by the token, in
-    place (models/nemotron_h.py ``mamba_step``); a dead row's stays.
+    place (models/nemotron_h.py ``mamba_step``); a dead row's stays. For a
+    family with a window cache ``pool[STATE]`` holds the window layers'
+    rings, into which a live row's key and value go at ``pos mod W``
+    (serving/window.py ``window_decode_attend``).
     Returns (next_token, new_pool, new_pos); with ``with_loads`` (the
     engine's program of an expert model) a fourth output follows: one
     int32 vector of the tokens and behind them the tick's
@@ -359,9 +363,16 @@ def _decode_rowwise_paged(config: LlamaConfig, page_size: int,
         attend_kernel, attend_reference = _latent_decode_attends(
             config, page_size, params, pool, page_table, pos, pid_safe,
             offset)
+    attend = attend_kernel if use_kernel else attend_reference
+    if config.window_cache:
+        from .window import window_decode_attend
+
+        pool = dict(pool)
+        state = pool[STATE] = dict(pool[STATE])
+        attend = window_decode_attend(config, state, pos, live[:, 0],
+                                      attend, attn_impl)
     x, loads = _serving_layers(
-        config, params, x, cos, sin,
-        attend_kernel if use_kernel else attend_reference, lora,
+        config, params, x, cos, sin, attend, lora,
         adapter_ids, live=live, ssm=ssm)
     logits = head_logits(config, params, x)[:, 0]
     if rng is None:
@@ -722,13 +733,16 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         # _pending exists before super().__init__ so _queue_depth /
         # pressure_level are safe during construction
         self._pending: deque = deque()
-        # a prefix hit skips tokens a recurrent state needs: such a family
-        # gets no index to look a prompt up in, and one asked for is refused
+        # a prefix hit skips what a slot keeps beside its pages: the tokens
+        # a recurrent state needs, the rows of a window layer that no page
+        # holds. Such a family gets no index to look a prompt up in, and one
+        # asked for is refused
+        slot_bound = bool(config.state_layers)
         refuse_layout(config, "prefix reuse", bool(prefix_cache)
-                      and config.recurrent_state)
+                      and slot_bound)
         if prefix_cache is None:
             prefix_cache = bool(mlconf.serving.llm.prefix_cache) \
-                and not config.recurrent_state
+                and not slot_bound
         self._prefix = PrefixCache(page_size) if prefix_cache else None
         # trie nodes each slot holds a refcount on (matched + registered)
         self._slot_prefix_nodes: dict[int, list] = {}
@@ -747,9 +761,9 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         self._kv_tier = (
             HostKVTier(int(tier_conf.get("host_bytes", 64 << 20)))
             if kv_tier and self._prefix is not None else None)
-        # (a recurrent family has no prefix index for a tier to hang on)
+        # (such a family has no prefix index for a tier to hang on)
         refuse_layout(config, "the host KV tier", self._kv_tier is not None
-                      or bool(kv_tier) and config.recurrent_state)
+                      or bool(kv_tier) and slot_bound)
         # fetch_prefix/import_prefix control ops queue here and run on
         # the scheduler thread between ticks (_control_tick): the page
         # pool is donated through every decode dispatch, so off-thread
@@ -813,11 +827,14 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         self.kv_bytes_per_token = sum(
             arr.dtype.itemsize * arr.shape[0]
             * int(np.prod(arr.shape[3:])) for arr in pages.values())
-        # bytes a slot keeps beside its pages whatever its length: a
-        # recurrent family's state over its layers (0 for any other)
-        self.state_bytes_per_slot = sum(
+        # bytes a slot keeps beside its pages whatever its length, over its
+        # layers: a window family's rings, or else a recurrent family's
+        # state (0 for any other family)
+        held = sum(
             arr.dtype.itemsize * arr.shape[0] * int(np.prod(arr.shape[2:]))
             for arr in self._pool.get(STATE, {}).values())
+        self.window_bytes_per_slot = held if config.window_cache else 0
+        self.state_bytes_per_slot = held - self.window_bytes_per_slot
         self._stats.update({"attn_kernel_ticks": 0, "attn_gather_ticks": 0,
                             "attn_hbm_bytes_avoided": 0,
                             "lookahead_ticks": 0, "lookahead_drains": 0,
@@ -1599,6 +1616,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         out["paged_prefill_impl"] = self.paged_prefill_impl
         out["kv_bytes_per_token"] = self.kv_bytes_per_token
         out["state_bytes_per_slot"] = self.state_bytes_per_slot
+        out["window_bytes_per_slot"] = self.window_bytes_per_slot
         out["free_pages"] = len(self._free_pages)
         if self._prefix is not None:
             queries = self._prefix.queries
@@ -1692,6 +1710,11 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             tick.ctx_tokens = int(pos.sum()) + len(rows)
             if STATE in self._pool:
                 tick.state_rows = len(rows)
+            if self.config.window_cache:
+                # the keys the window layers read a layer: a row's own and
+                # what precedes it inside the window
+                tick.window_keys = int(np.minimum(
+                    pos[rows] + 1, self.config.sliding_window).sum())
             self._pos[rows] += 1
             lora_kw = self._lora_kwargs(self._slot_adapter_ids()) \
                 if self._adapters is not None else {}

@@ -756,3 +756,88 @@ def test_nemotron_prefill_compiles(nemotron_shapes, on_chip, bucket):
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
         < 10 << 30
+
+
+# -- the window family: laguna-serve-longdoc's kernels and decode program ----
+# (8, 128)-aligned tiny shapes of the cell's heads: 64 query heads over 8 of
+# 128 in a window layer (a group of 8), 48 over the same 8 in a full layer
+# (a group of 6); a ring of 256 positions and a chunk of 512 (the cell's are
+# 512 and 1,024)
+LG_WINDOW, LG_CHUNK, LG_RINGS = 256, 512, 2
+LG_HEADS = {"window": 64, "full": 48}
+
+
+@pytest.mark.parametrize("kernel", ["window_decode", "window_prefill",
+                                    "paged_decode_group6"])
+def test_window_family_kernels_compile(on_chip, kernel):
+    from mlrun_tpu.ops import window_attention as wattn
+
+    d, bf16, i32 = 128, jnp.bfloat16, jnp.int32
+    scalar = on_chip((), i32)
+    if kernel == "window_decode":
+        rings = on_chip((LG_RINGS, SLOTS, LG_WINDOW, N_KV_HEADS, d), bf16)
+        _compile(functools.partial(wattn._window_decode_call,
+                                   interpret=False),
+                 on_chip((SLOTS, LG_HEADS["window"], d), bf16), rings,
+                 rings, scalar, on_chip((SLOTS,), i32))
+    elif kernel == "window_prefill":
+        chunk_kv = on_chip((LG_CHUNK, N_KV_HEADS, d), bf16)
+        ring = on_chip((LG_WINDOW, N_KV_HEADS, d), bf16)
+        _compile(functools.partial(wattn.window_prefill, impl="kernel",
+                                   interpret=False),
+                 on_chip((LG_CHUNK, LG_HEADS["window"], d), bf16), chunk_kv,
+                 chunk_kv, ring, ring, scalar, scalar)
+    else:
+        pool, layer, _ = _pool(on_chip, d, "bf16")
+        _compile(lambda q, k, v, layer, table, pos: pattn._paged_decode_call(
+            q, k, v, layer, table, pos, PAGE_SIZE, interpret=False),
+            on_chip((SLOTS, LG_HEADS["full"], d), bf16), pool, pool, layer,
+            on_chip((SLOTS, PAGES_PER_SLOT), i32), on_chip((SLOTS,), i32))
+
+
+def test_window_decode_program_copies_no_ring_or_pool_layer(on_chip,
+                                                            monkeypatch):
+    """``jit_mlt_decode`` of two full layers and two window layers at the
+    cell's heads (a hidden size of 384, no leaf of which has as many
+    elements as a layer's rings, and eight experts keep the compile
+    short):
+    ``paged_decode`` at a group of 6 and ``window_decode`` at 8 are handed
+    the pool and the rings as stored, so nothing but a ``bitcast`` yields
+    an array the size of a pool layer or of a layer's rings (what writes
+    them in place yields the whole stack), and the temporaries stay under
+    one layer's rings."""
+    from mlrun_tpu.models.laguna import init_params as init_laguna
+    from mlrun_tpu.models.laguna import laguna_xs2
+    from mlrun_tpu.ops import window_attention as wattn
+
+    for module in (pattn, attn, wattn):
+        monkeypatch.setattr(module, "interpret_default", lambda: False)
+    config = laguna_xs2(
+        n_layers=4, layer_types=("full_attention", "sliding_attention",
+                                 "sliding_attention", "full_attention"),
+        mlp_layer_types=("dense", "sparse", "sparse", "sparse"),
+        heads_per_layer=(48, 64, 64, 48),
+        embed_dim=384, mlp_dim=1024, n_experts=8, top_k=2, expert_dim=256,
+        shared_dim=256, vocab_size=1024, sliding_window=LG_WINDOW)
+    place = _placer(on_chip)
+    params = place(jax.eval_shape(lambda: llm.serving_tree(
+        config, init_laguna(config, jax.random.PRNGKey(0)))))
+    pool = place(jax.eval_shape(lambda: paged.init_paged_pool(
+        config, N_PAGES + 1, PAGE_SIZE, slots=SLOTS)))
+    compiled = jax.jit(functools.partial(
+        paged._decode_rowwise_paged, config, PAGE_SIZE, "kernel",
+        with_loads=True), donate_argnums=(2,)).lower(
+            params, on_chip((SLOTS, 1), jnp.int32), pool,
+            on_chip((SLOTS, PAGES_PER_SLOT), jnp.int32),
+            on_chip((SLOTS,), jnp.int32),
+            prev_token=on_chip((SLOTS,), jnp.int32),
+            from_prev=on_chip((SLOTS,), jnp.bool_)).compile()
+    hlo = compiled.as_text()
+    assert len(re.findall(r"%window_decode[\w.-]* = ", hlo)) == 2
+    assert len(re.findall(r"%paged_decode[\w.-]* = ", hlo)) == 2
+    ring_layer = SLOTS * LG_WINDOW * N_KV_HEADS * 128
+    pool_layer = (N_PAGES + 1) * PAGE_SIZE * N_KV_HEADS * 128
+    for size in (ring_layer, pool_layer):
+        assert _sized_results(hlo, size) <= {"bitcast"}, \
+            (size, _sized_results(hlo, size))
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * ring_layer
