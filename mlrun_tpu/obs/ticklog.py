@@ -61,7 +61,7 @@ FIELDS = ("n", "t0", "t_admit", "t_built", "t_dispatched", "t_fetched",
           "kind", "positions", "tokens_out", "commit_rows", "expert_pairs",
           "experts_touched", "expert_load_max", "lookahead",
           "prefill_ctx_tokens", "state_rows", "state_tokens",
-          "window_keys", "window_prefill_pairs",
+          "window_keys", "window_prefill_pairs", "kv_pages",
           "prefill_dispatches", "gap_s", "inflight_wait_s",
           "prefill_wait_s", "dry_s", "after_prefill_s", "admissions",
           "cpu_s", "gc_s", "gc_gen", "nivcsw", "majflt", "idle_s",
@@ -127,6 +127,12 @@ class TickRecord:
     query-key pairs inside the window that its prefill dispatches
     computed a window layer (the real tokens').
 
+    The paged engine's plain tick fills ``kv_pages``, the pages its rows
+    hold: the sum over them of ``ceil((pos + 1) / page_size)``, what the
+    decode kernel walks a layer (``ops/paged_attention.decode_pages``;
+    against ``slots x pages_per_slot``, the pages a grid of a page a step
+    visits).
+
     A boundary that an iteration never reaches stays at the one before it,
     so every interval is defined and non-negative.
 
@@ -181,7 +187,7 @@ class TickRecord:
         self.expert_pairs = self.experts_touched = self.expert_load_max = 0
         self.lookahead = self.prefill_ctx_tokens = 0
         self.state_rows = self.state_tokens = self.prefill_dispatches = 0
-        self.window_keys = self.window_prefill_pairs = 0
+        self.window_keys = self.window_prefill_pairs = self.kv_pages = 0
         self.gap_s = self.inflight_wait_s = self.prefill_wait_s = 0.0
         self.dry_s = self.after_prefill_s = self.cpu_s = self.gc_s = 0.0
         self.idle_s = self.cpu_span_s = 0.0

@@ -5,9 +5,8 @@ The paged engine (serving/paged.py) historically materialized a dense
 the page table) and ran plain masked attention on it — HBM traffic on the
 order of the whole cache for every generated token. This module computes
 the same attention by indexing the page pool THROUGH the page table inside
-a Pallas kernel: each grid step DMAs exactly one physical page from
-HBM into VMEM, so the bytes read per tick are the slot's *live* pages once
-— never a gathered copy of the full view.
+a Pallas kernel, so the bytes read per tick are the slots' *live* pages
+once — never a gathered copy of the full view.
 
 Layout (the pool as serving/paged.py stores it, all layers stacked):
 
@@ -23,22 +22,30 @@ Layout (the pool as serving/paged.py stores it, all layers stacked):
 - pos:        [slots] int32 absolute position of the current token
   (valid cache length is ``pos + 1``).
 
-Grid ``(slots, pages_per_slot)``: for a fixed slot the kernel streams
-that slot's pages in order — all kv heads of a page per step, which is
-the blocking the TPU lowering accepts for this pool layout — carrying
-the online-softmax running max/denominator/accumulator of every GQA
-query group in VMEM scratch — the same accumulation scheme as the
-verified flash_v2 kernel (ops/attention.py), so numerics match the dense
-reference to float32 round-off. The layer index, the page table and the
-positions ride scalar prefetch (``PrefetchScalarGridSpec``) because the
-k/v BlockSpec index maps need them to translate (slot, page-slot) ->
-(layer, physical page id) before the DMA.
+One grid step walks every row's live pages — ``ceil((pos + 1) /
+page_size)`` of them (:func:`decode_pages`), none for a row whose first
+page is unmapped — a block of 512 positions at a time: the kernel copies
+a block's pages itself from the pool in HBM into one half of a VMEM double
+buffer (all kv heads of a page a copy) while the block before it is
+multiplied, the next row's first block included. At 8 kv heads a block's
+keys fill whole (8, 128) tiles and all GQA groups meet them in one score
+product and one value product on the pages as stored (another group's
+keys masked); at fewer, a group's keys are read out a kv head at a time.
+Each group's online-softmax running max/denominator/accumulator is
+float32 VMEM scratch — the same accumulation scheme as the verified
+flash_v2 kernel (ops/attention.py), so numerics match the dense reference
+to float32 round-off. The layer index, the page table and the positions
+ride scalar prefetch (``PrefetchScalarGridSpec``). Mosaic copies a page by
+hand only where the pool's minor dim is whole tiles of 128 lanes: a
+head_dim of 64 and the int8 pool's scales keep the grid ``(slots,
+pages_per_slot)`` of one page a step, whose BlockSpec index maps
+translate (slot, page-slot) -> (layer, physical page id) before the DMA.
 
 Why the whole pool and an index, not ``pool[layer]``: an operand of a
 ``pallas_call`` is a buffer of its own, so a sliced layer is a copy XLA
 must make before every call — a whole pool layer moved to read a few
 live pages of it. Handed the pool as stored, the kernel reaches the
-layer through its index maps and no program copies anything; because
+layer through its copies or index maps and no program copies anything; because
 ``layer`` is a value and not a Python constant, all layers of a program
 are one kernel (tests/test_tpu_compile.py holds both).
 
@@ -177,13 +184,187 @@ def _layer_prefetch(layer):
     return jnp.asarray(layer, jnp.int32).reshape(1)
 
 
-def _paged_decode_kernel(layer_ref, pt_ref, pos_ref, q_ref, k_ref, v_ref,
-                         *refs, page_size: int, pages_per_slot: int,
-                         scale: float, quantized: bool):
-    """Grid (slot, page-slot); refs: q [1, Hkv, n_rep, d] (the slot's
-    token, heads grouped per kv head), k/v [1, page_size, Hkv, d] (the
-    physical page the index map resolved via ``layer_ref`` and the page
-    table; only the index maps read ``layer_ref``). Scratch
+def decode_pages(page_table, pos, page_size: int):
+    """The pages the decode kernel walks for each row: a row that holds a
+    request (its first page mapped) reads ``ceil((pos + 1) / page_size)``
+    of them, capped at the table's width; a row the tick does not carry
+    (page table all -1) reads none. Works on numpy and jax arrays alike."""
+    live = (pos // page_size + 1).clip(max=page_table.shape[-1])
+    return (page_table[..., 0] >= 0) * live
+
+
+# positions one decode block holds (4 pages of 128): a row's first block is
+# most of a chat row's context, and a long row's copies run behind its
+# products (a chip microbenchmark at the serve cells' shapes, PERF.md)
+_DECODE_BLOCK_TOKENS = 512
+
+
+def _decode_pages_per_block(page_size: int, pages_per_slot: int) -> int:
+    return max(1, min(pages_per_slot, _DECODE_BLOCK_TOKENS // page_size))
+
+
+def _online_softmax(m_prev, l_prev, acc_prev, logits, v):
+    """One online-softmax step of rows ``logits`` [rows, tokens] (float32,
+    masked entries at NEG_INF) over values ``v`` [tokens, d]: the new
+    running max, denominator and accumulator. bf16 values take the
+    weights as two bf16 parts (what a bf16 rounding of the weight leaves
+    is the second), so the product keeps the float32 weights' precision
+    to about 2^-16."""
+    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+    weight = jnp.exp(logits - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_new = l_prev * alpha + jnp.sum(weight, axis=-1, keepdims=True)
+    if v.dtype == jnp.bfloat16:
+        high = weight.astype(jnp.bfloat16)
+        low = (weight - high.astype(jnp.float32)).astype(jnp.bfloat16)
+        pv = (jnp.dot(high, v, preferred_element_type=jnp.float32)
+              + jnp.dot(low, v, preferred_element_type=jnp.float32))
+    else:
+        pv = jnp.dot(weight, v.astype(jnp.float32),
+                     preferred_element_type=jnp.float32)
+    return m_new, l_new, acc_prev * alpha + pv
+
+
+def _paged_decode_kernel(layer_ref, pt_ref, pos_ref, q_ref, k_hbm, v_hbm,
+                         o_ref, k_buf, v_buf, sems, m_scr, l_scr, acc_scr,
+                         *, page_size: int, pages_per_block: int,
+                         scale: float):
+    """One grid step walks every row's live pages, ``pages_per_block`` at
+    a time. q [slots, Hkv, n_rep, d] (each row's token, heads grouped per
+    kv head) and out are in VMEM; the pools [L, P+1, page_size, Hkv, d]
+    stay in HBM as stored. Scratch: a double buffer [2, pages_per_block,
+    page_size, Hkv, d] a pool, a DMA semaphore a buffer, and ([Hkv,
+    n_rep, 1|d]) each group's online softmax over the row's blocks.
+
+    The blocks of all rows are one sequence (row by row, a row's blocks in
+    order, rows that hold no page left out): the copies of block i+1 start
+    before block i's products, so a row's first block is in flight while
+    the row before it finishes. A block's pages past the row's last are
+    not copied; their buffer entries are masked by position, and the
+    value buffer starts zeroed, so what they hold is finite and weighs
+    0."""
+    slots, pages_per_slot = pt_ref.shape
+    _, hkv, n_rep, d = q_ref.shape
+    ppb = pages_per_block
+    tokens = ppb * page_size
+    scratch_page = k_hbm.shape[1] - 1
+    layer = layer_ref[0]
+
+    def n_pages(s):
+        """:func:`decode_pages` of row ``s`` (the last row's past it)."""
+        s = jnp.minimum(s, slots - 1)
+        live = jnp.minimum(pos_ref[s] // page_size + 1, pages_per_slot)
+        return jnp.where(pt_ref[s, 0] >= 0, live, 0)
+
+    def n_blocks(s):
+        return (n_pages(s) + ppb - 1) // ppb
+
+    def next_block(s, j):
+        """The block after block ``j`` of row ``s``; (slots, 0) past the
+        last row that holds a page."""
+        return jax.lax.while_loop(
+            lambda c: (c[0] < slots) & (c[1] >= n_blocks(c[0])),
+            lambda c: (c[0] + 1, jnp.int32(0)), (s, j + 1))
+
+    def for_copies(s, j, buf, method):
+        """``method`` (start or wait) on the copies of block ``j`` of row
+        ``s`` into buffer ``buf``: one a live page and pool."""
+        live = n_pages(s)
+        for i in range(ppb):
+            page = j * ppb + i
+            pid = pt_ref[s, jnp.minimum(page, pages_per_slot - 1)]
+            pid = jnp.where(pid >= 0, pid, scratch_page)
+
+            @pl.when(page < live)
+            def _():
+                for pool, dst in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                    getattr(pltpu.make_async_copy(
+                        pool.at[layer, pid], dst.at[buf, i], sems.at[buf]),
+                        method)()
+
+    def attend(s, j, buf):
+        """Online-softmax update of every GQA group over block ``j``."""
+        pos = pos_ref[s]
+        if hkv % 8 == 0:
+            # a block's keys are rows (token, kv head) of whole (8, 128)
+            # tiles: all groups' rows against all of them, as stored, in
+            # one score product and one value product; a row's scores on
+            # another group's keys are masked out with the positions
+            rows = hkv * n_rep
+            logits = jax.lax.dot_general(
+                q_ref[s].reshape(rows, d),
+                k_buf[buf].reshape(tokens * hkv, d),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            col = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+            row = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0)
+            own = ((col % hkv == row // n_rep)
+                   & (j * tokens + col // hkv <= pos))
+            m, l, acc = _online_softmax(
+                m_scr[...].reshape(rows, 1), l_scr[...].reshape(rows, 1),
+                acc_scr[...].reshape(rows, d),
+                jnp.where(own, logits, NEG_INF),
+                v_buf[buf].reshape(tokens * hkv, d))
+            m_scr[...] = m.reshape(m_scr.shape)
+            l_scr[...] = l.reshape(l_scr.shape)
+            acc_scr[...] = acc.reshape(acc_scr.shape)
+            return
+        # fewer kv heads than a tile's rows: a group's keys are every
+        # token's row ``h``, read out a kv head at a time
+        k_pos = j * tokens + jax.lax.broadcasted_iota(
+            jnp.int32, (1, tokens), 1)
+        for h in range(hkv):
+            k = k_buf[buf, :, :, h, :].astype(jnp.float32).reshape(tokens, d)
+            v = v_buf[buf, :, :, h, :].astype(jnp.float32).reshape(tokens, d)
+            q = q_ref[s, h].astype(jnp.float32) * scale      # [n_rep, d]
+            logits = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+            m_scr[h], l_scr[h], acc_scr[h] = _online_softmax(
+                m_scr[h], l_scr[h], acc_scr[h],
+                jnp.where(k_pos <= pos, logits, NEG_INF), v)
+
+    o_ref[...] = jnp.zeros_like(o_ref)
+    v_buf[...] = jnp.zeros_like(v_buf)
+    first = next_block(jnp.int32(0), jnp.int32(-1))
+
+    @pl.when(first[0] < slots)
+    def _():
+        for_copies(*first, 0, "start")
+
+    def step(carry):
+        s, j, buf = carry
+        nxt = next_block(s, j)
+
+        @pl.when(nxt[0] < slots)
+        def _():
+            for_copies(*nxt, 1 - buf, "start")
+
+        for_copies(s, j, buf, "wait")
+
+        @pl.when(j == 0)
+        def _():
+            _reset_softmax_state(m_scr, l_scr, acc_scr)
+
+        attend(s, j, buf)
+
+        @pl.when(j == n_blocks(s) - 1)
+        def _():
+            l = jnp.maximum(l_scr[:], 1e-30)
+            o_ref[s] = (acc_scr[:] / l).astype(o_ref.dtype)
+
+        return nxt + (1 - buf,)
+
+    jax.lax.while_loop(lambda c: c[0] < slots, step, first + (0,))
+
+
+def _paged_decode_grid_kernel(layer_ref, pt_ref, pos_ref, q_ref, k_ref,
+                              v_ref, *refs, page_size: int,
+                              pages_per_slot: int, scale: float,
+                              quantized: bool):
+    """The pools no page of which is copied by hand (see
+    :func:`_walks_pages`). Grid (slot, page-slot); refs: q [1, Hkv, n_rep,
+    d] (the slot's token, heads grouped per kv head), k/v [1, page_size,
+    Hkv, d] (the physical page the index map resolved via ``layer_ref``
+    and the page table; only the index maps read ``layer_ref``). Scratch
     ([Hkv, n_rep, 1|d]) carries each group's online softmax across the
     page-slot grid dim.
 
@@ -216,6 +397,14 @@ def _paged_decode_kernel(layer_ref, pt_ref, pos_ref, q_ref, k_ref, v_ref,
         o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
 
 
+def _walks_pages(k_pool, quantized: bool) -> bool:
+    """Whether the decode kernel copies the pool's pages by hand. Mosaic
+    slices an operand in HBM only where its minor dim is whole tiles of
+    128 lanes: not at a head_dim of 64, nor an int8 pool's scales (Hkv
+    lanes). Such pools keep the grid of a page a step."""
+    return not quantized and k_pool.shape[-1] % 128 == 0
+
+
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
 def _paged_decode_call(q, k_pool, v_pool, layer, page_table, pos,
                        page_size: int, k_scale=None, v_scale=None,
@@ -224,7 +413,11 @@ def _paged_decode_call(q, k_pool, v_pool, layer, page_table, pos,
     pool [L, P+1, page_size, Hkv, D] -> [slots, H, D]. ``page_table``
     may contain -1 (routed to the scratch page). ``k_scale``/``v_scale``
     ([L, P+1, page_size, Hkv] f32) select the int8 kernel: pages are
-    dequantized per vector inside the kernel."""
+    dequantized per vector inside the kernel.
+
+    A row whose first entry is -1 (no request) reads no page where the
+    kernel walks pages (:func:`_walks_pages`) and yields zeros; the grid
+    kernel reads its position's pages of the scratch page."""
     if interpret is None:
         interpret = interpret_default()
     slots, h, d = q.shape
@@ -232,13 +425,41 @@ def _paged_decode_call(q, k_pool, v_pool, layer, page_table, pos,
     n_rep = h // hkv
     pages_per_slot = page_table.shape[1]
     scale = d ** -0.5
-    safe_table = _safe_table(page_table, k_pool)
     pos = pos.astype(jnp.int32)
     quantized = k_scale is not None
+    softmax_state = [
+        pltpu.VMEM((hkv, n_rep, 1), jnp.float32),   # running max
+        pltpu.VMEM((hkv, n_rep, 1), jnp.float32),   # running denom
+        pltpu.VMEM((hkv, n_rep, d), jnp.float32),   # accumulator
+    ]
+    # heads h*n_rep..(h+1)*n_rep are kv head h's GQA group (matches
+    # _repeat_kv order), so grouping q per kv head is a free reshape
+    qg = q.reshape(slots, hkv, n_rep, d)
+    out_shape = jax.ShapeDtypeStruct(qg.shape, q.dtype)
 
-    kernel = functools.partial(
-        _paged_decode_kernel, page_size=page_size,
-        pages_per_slot=pages_per_slot, scale=scale, quantized=quantized)
+    if _walks_pages(k_pool, quantized):
+        ppb = _decode_pages_per_block(page_size, pages_per_slot)
+        vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+        hbm = pl.BlockSpec(memory_space=pl.ANY)
+        out = pl.pallas_call(
+            functools.partial(_paged_decode_kernel, page_size=page_size,
+                              pages_per_block=ppb, scale=scale),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(1,),
+                in_specs=[vmem, hbm, hbm],
+                out_specs=vmem,
+                scratch_shapes=[
+                    pltpu.VMEM((2, ppb) + k_pool.shape[2:], k_pool.dtype),
+                    pltpu.VMEM((2, ppb) + v_pool.shape[2:], v_pool.dtype),
+                    pltpu.SemaphoreType.DMA((2,)),
+                ] + softmax_state),
+            out_shape=out_shape,
+            interpret=interpret,
+            name="paged_decode",
+        )(_layer_prefetch(layer), page_table.astype(jnp.int32), pos, qg,
+          k_pool, v_pool)
+        return out.reshape(slots, h, d)
 
     def q_map(s, p, ly, pt, ps):
         return (s, 0, 0, 0)
@@ -254,29 +475,23 @@ def _paged_decode_call(q, k_pool, v_pool, layer, page_table, pos,
         pl.BlockSpec((None, 1, page_size, hkv, d), kv_map),
         pl.BlockSpec((None, 1, page_size, hkv, d), kv_map),
     ]
-    # heads h*n_rep..(h+1)*n_rep are kv head h's GQA group (matches
-    # _repeat_kv order), so grouping q per kv head is a free reshape
-    operands = [_layer_prefetch(layer), safe_table, pos,
-                q.reshape(slots, hkv, n_rep, d), k_pool, v_pool]
+    operands = [_layer_prefetch(layer), _safe_table(page_table, k_pool),
+                pos, qg, k_pool, v_pool]
     if quantized:
         in_specs += [pl.BlockSpec((None, 1, page_size, hkv), sc_map),
                      pl.BlockSpec((None, 1, page_size, hkv), sc_map)]
         operands += [k_scale, v_scale]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(slots, pages_per_slot),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, hkv, n_rep, d), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((hkv, n_rep, 1), jnp.float32),   # running max
-            pltpu.VMEM((hkv, n_rep, 1), jnp.float32),   # running denom
-            pltpu.VMEM((hkv, n_rep, d), jnp.float32),   # accumulator
-        ],
-    )
     out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((slots, hkv, n_rep, d), q.dtype),
+        functools.partial(_paged_decode_grid_kernel, page_size=page_size,
+                          pages_per_slot=pages_per_slot, scale=scale,
+                          quantized=quantized),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(slots, pages_per_slot),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, hkv, n_rep, d), q_map),
+            scratch_shapes=softmax_state),
+        out_shape=out_shape,
         interpret=interpret,
         name="paged_decode",
     )(*operands)

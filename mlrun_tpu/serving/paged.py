@@ -59,6 +59,7 @@ from ..chaos import FaultPoints, fire
 from ..config import mlconf
 from ..models.llama import LlamaConfig, embed, head_logits
 from ..obs import KV_TIER_BYTES, KV_TIER_EVENTS, KV_TIER_HITS, wall_now
+from ..ops.paged_attention import decode_pages
 from ..utils import logger
 from ..utils.profiler import annotate, named
 from .kv_tier import HostKVTier
@@ -836,7 +837,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         self.window_bytes_per_slot = held if config.window_cache else 0
         self.state_bytes_per_slot = held - self.window_bytes_per_slot
         self._stats.update({"attn_kernel_ticks": 0, "attn_gather_ticks": 0,
-                            "attn_hbm_bytes_avoided": 0,
+                            "attn_hbm_bytes_avoided": 0, "kv_pages": 0,
                             "lookahead_ticks": 0, "lookahead_drains": 0,
                             "prefill_kernel_chunks": 0,
                             "prefill_gather_admissions": 0,
@@ -1602,7 +1603,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
     # paged-only cumulative stats mirrored to mlt_llm_events_total
     _COUNTER_STATS = ContinuousBatchingEngine._COUNTER_STATS + (
         "attn_kernel_ticks", "attn_gather_ticks", "attn_hbm_bytes_avoided",
-        "lookahead_ticks", "lookahead_drains",
+        "kv_pages", "lookahead_ticks", "lookahead_drains",
         "prefill_kernel_chunks", "prefill_gather_admissions",
         "kv_demotes", "kv_demoted_pages", "kv_promotes",
         "kv_promoted_pages", "kv_fetches", "kv_fetched_pages",
@@ -1672,6 +1673,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 self._gather_bytes_per_tick
         else:
             self._stats["attn_gather_ticks"] += 1
+        self._stats["kv_pages"] += self._tick.kv_pages
 
     def _plain_decode_tick(self, active) -> int:
         """Dispatch the next tick, then read the one before it: build |
@@ -1708,6 +1710,8 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             pos = np.zeros_like(self._pos)
             pos[rows] = self._pos[rows]
             tick.ctx_tokens = int(pos.sum()) + len(rows)
+            tick.kv_pages = int(decode_pages(table, pos,
+                                             self.page_size).sum())
             if STATE in self._pool:
                 tick.state_rows = len(rows)
             if self.config.window_cache:

@@ -399,7 +399,7 @@ def test_ticks_and_chunks_report_window_work_and_expert_load(model):
 # family (tiny configs; the kernels under the interpreter, as on the CPU)
 PROGRAMS_BEFORE = {
     "llama.kernel.decode":
-        "bf757030cc417ff54dad697f4398f988f458a40d34a131cebf50dba99769d195",
+        "6c0da188cff98c2d3604528b7da80217c14ec7b9635195cbceda1dbaa38ace08",
     "llama.kernel.prefill":
         "d5c25b5cd992465b07130d4143c8dd1d46a9d5fe7f90b6d773cd4d06d76dc005",
     "llama.reference.decode":
@@ -407,7 +407,7 @@ PROGRAMS_BEFORE = {
     "llama.reference.prefill":
         "9595cdcbbe884af6b08d7b69d1582f08072435476a9ab695b33a1078a238a957",
     "nemotronh.kernel.decode":
-        "4e09f0e8674db32b34cd1cca2731b89145adfea454527816610ce1e16c5710b6",
+        "8fb37bf91ee971f91d8a3331ec1a1aab86a386b0fc7f2feb567ca727038148a0",
     "nemotronh.kernel.prefill":
         "1c797a441a50232969d5b265c07686f6df121a96cec0a6f5a10a40237a88e71f",
     "nemotronh.reference.decode":

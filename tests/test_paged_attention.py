@@ -68,18 +68,87 @@ def _decode_case(key):
     return q, jnp.asarray(table), jnp.asarray([11, 31, 0], jnp.int32)
 
 
-def test_paged_kernel_matches_gather_dense():
+# rows of the walking kernel's cases (page 128, 6 pages a row, blocks of 4
+# pages): no request, one token, a page's first and last position, every
+# page (a second block of 2)
+WALK_POSITIONS = (None, 0, 128, 511, 767)
+KERNEL_CASES = {
+    # the grid of a page a step (head_dim 32 is no whole number of lanes)
+    "f32-grid": None,
+    "group4-of-8": dict(heads=32, hkv=8, dtype=jnp.bfloat16),
+    "group16-of-2": dict(heads=32, hkv=2, dtype=jnp.bfloat16),
+    "group6-of-8": dict(heads=48, hkv=8, dtype=jnp.bfloat16),
+    "group4-of-8-int8": dict(heads=32, hkv=8, dtype=jnp.int8),
+}
+
+
+def _walk_case(key, heads: int, hkv: int, dtype):
+    """q, pool, scales and the rows of ``WALK_POSITIONS`` over pages
+    scattered through a pool of 32, at head_dim 128 and page 128."""
+    ps, pps, d = 128, 6, 128
+    k_pool, v_pool = _random_pool(key, 32, ps, hkv, d)
+    scales = {}
+    if dtype == jnp.int8:
+        k_pool, scales["k_scale"] = _quantize_kv(k_pool)
+        v_pool, scales["v_scale"] = _quantize_kv(v_pool)
+    else:
+        k_pool, v_pool = k_pool.astype(dtype), v_pool.astype(dtype)
+    pages = iter(np.random.default_rng(0).permutation(32))
+    table = np.full((len(WALK_POSITIONS), pps), -1, np.int32)
+    pos = np.zeros(len(WALK_POSITIONS), np.int32)
+    for row, at in enumerate(WALK_POSITIONS):
+        if at is not None:
+            pos[row] = at
+            table[row, :at // ps + 1] = [next(pages)
+                                         for _ in range(at // ps + 1)]
+    q = (jax.random.normal(jax.random.fold_in(key, 1),
+                           (len(WALK_POSITIONS), heads, d), jnp.float32)
+         * 0.5).astype(jnp.bfloat16)
+    return q, k_pool, v_pool, scales, jnp.asarray(table), jnp.asarray(pos)
+
+
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_paged_kernel_matches_gather_dense(case):
     """Tolerance-bounded parity: page-table-indexed kernel (interpret) vs
     the dense gathered view, with unmapped (-1) entries and mid-page
-    positions in the mix."""
+    positions in the mix. At head_dim 128 the kernel walks a row's live
+    pages four a block (the cells' GQA groups, bf16 and int8 pools): a row
+    that holds no page yields finite numbers, the others the reference's
+    to a bf16 rounding of the output."""
     key = jax.random.PRNGKey(0)
-    k_pool, v_pool = _random_pool(key, 10, 8, 2, 32)
-    q, table, pos = _decode_case(key)
-    out_k = pattn._paged_decode_call(q, k_pool, v_pool, 0, table, pos, 8,
-                                     interpret=True)
-    out_r = pattn.paged_decode_reference(q, k_pool, v_pool, 0, table,
-                                         pos, 8)
-    assert float(jnp.max(jnp.abs(out_k - out_r))) < 2e-6
+    if KERNEL_CASES[case] is None:
+        k_pool, v_pool = _random_pool(key, 10, 8, 2, 32)
+        q, table, pos = _decode_case(key)
+        out_k = pattn._paged_decode_call(q, k_pool, v_pool, 0, table, pos,
+                                         8, interpret=True)
+        out_r = pattn.paged_decode_reference(q, k_pool, v_pool, 0, table,
+                                             pos, 8)
+        assert float(jnp.max(jnp.abs(out_k - out_r))) < 2e-6
+        return
+    q, k_pool, v_pool, scales, table, pos = _walk_case(
+        key, **KERNEL_CASES[case])
+    assert pattn._walks_pages(k_pool, bool(scales)) == (not scales)
+    out = np.asarray(pattn._paged_decode_call(
+        q, k_pool, v_pool, 0, table, pos, 128, interpret=True, **scales),
+        np.float32)
+    ref = np.asarray(pattn.paged_decode_reference(
+        q.astype(jnp.float32), k_pool, v_pool, 0, table, pos, 128,
+        **scales))
+    assert np.isfinite(out).all()
+    live = [row for row, at in enumerate(WALK_POSITIONS) if at is not None]
+    gap = np.abs(out[live] - ref[live]).max()
+    assert gap <= 2.0 ** -8 * np.abs(ref[live]).max(), gap
+
+
+def test_decode_pages_counts_what_the_kernel_walks():
+    """``decode_pages``: ``ceil((pos + 1) / page_size)`` a row that holds
+    a request, capped at the table's width; 0 for a row with none."""
+    table = np.full((5, 6), -1, np.int32)
+    table[1:, 0] = 0
+    pos = np.asarray([300, 0, 128, 511, 2000], np.int32)
+    assert list(pattn.decode_pages(table, pos, 128)) == [0, 1, 2, 4, 6]
+    assert list(np.asarray(pattn.decode_pages(
+        jnp.asarray(table), jnp.asarray(pos), 128))) == [0, 1, 2, 4, 6]
 
 
 @pytest.mark.parametrize("layer", [0, 1, 2])
@@ -228,6 +297,48 @@ def test_kernel_engine_tokens_match_reference_engine(setup):
     assert stats["kernel"]["decode_attn_impl"] == "kernel"
     assert stats["reference"]["attn_kernel_ticks"] == 0
     assert stats["reference"]["attn_gather_ticks"] > 0
+
+
+def test_engine_walks_live_pages_at_head_dim_128(monkeypatch):
+    """At head_dim 128 over 8 kv heads the decode kernel walks each row's
+    live pages (all groups in one score product a block): greedy tokens
+    are those of the grid of a page a step, and a plain tick's
+    ``kv_pages`` is the pages its rows hold, whose running sum the
+    engine's stats keep. (Against the gather path this random model's
+    greedy tokens part at a near tie, with either kernel.)"""
+    from mlrun_tpu.obs import get_tick_log
+
+    cfg = tiny_llama(attention_impl="reference", head_dim=128, n_heads=16,
+                     n_kv_heads=8)
+    params = init_params(cfg, jax.random.PRNGKey(1))
+    # 130 tokens run into a second page of 128
+    prompts = [list(range(1, 131)), [5, 9, 2], list(range(7, 127))]
+    outs, records, stats = {}, {}, {}
+    for kernel in ("grid", "walk"):
+        with monkeypatch.context() as patch:
+            if kernel == "grid":
+                patch.setattr(pattn, "_walks_pages", lambda *_: False)
+            jax.clear_caches()
+            eng = PagedContinuousBatchingEngine(
+                cfg, params, max_len=256, slots=2, prefill_buckets=(256,),
+                page_size=128, attention_impl="kernel")
+            eng.start()
+            try:
+                futs = [eng.submit(p, max_new_tokens=4) for p in prompts]
+                outs[kernel] = [f.result(timeout=300)[0] for f in futs]
+                stats[kernel] = eng.stats
+            finally:
+                eng.stop()
+        records[kernel] = [r for r in get_tick_log(eng._obs_name).records()
+                           if r["rows"]]
+    jax.clear_caches()
+    assert outs["walk"] == outs["grid"]
+    for kernel, ticks in records.items():
+        assert all(r["rows"] <= r["kv_pages"] <= 2 * r["rows"]
+                   for r in ticks)
+        assert any(r["kv_pages"] > r["rows"] for r in ticks)
+        assert stats[kernel]["kv_pages"] == sum(r["kv_pages"]
+                                                for r in ticks)
 
 
 def test_flash_engine_cold_vs_hit_parity(setup):

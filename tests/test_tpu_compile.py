@@ -76,10 +76,10 @@ def on_chip():
     compilation_cache.reset_cache()
 
 
-def _pool(on_chip, head_dim, kv_dtype):
+def _pool(on_chip, head_dim, kv_dtype, n_pages=N_PAGES):
     """(pool, layer, scales-kwargs) shapes: the pool as the engine stores
     it and the traced layer index."""
-    dims = (POOL_LAYERS, N_PAGES + 1, PAGE_SIZE, N_KV_HEADS, head_dim)
+    dims = (POOL_LAYERS, n_pages + 1, PAGE_SIZE, N_KV_HEADS, head_dim)
     layer = on_chip((), jnp.int32)
     if kv_dtype == "int8":
         scale = on_chip(dims[:-1], jnp.float32)
@@ -100,14 +100,27 @@ def _compile(fn, *args, **kwargs):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# (slots, pages a slot, pool pages): chip_smoke.py's serving shape and
+# m7b-serve-chat's (32 x 16 pages of 128 over 512)
+DECODE_GEOMETRY = {"smoke": (SLOTS, PAGES_PER_SLOT, N_PAGES),
+                   "m7b": (32, 16, 512)}
+
+
+@pytest.mark.parametrize("geometry", list(DECODE_GEOMETRY))
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
 @pytest.mark.parametrize("model", list(HEAD_DIMS))
-def test_paged_decode_compiles(on_chip, model, kv_dtype):
+def test_paged_decode_compiles(on_chip, model, kv_dtype, geometry):
+    """At head_dim 128 a bf16 pool's pages are copied by hand (the kernel
+    walks the live pages); at 64, and on an int8 pool, the grid of a page
+    a step keeps them."""
     d = HEAD_DIMS[model]
-    pool, layer, scales = _pool(on_chip, d, kv_dtype)
-    q = on_chip((SLOTS, N_HEADS, d), jnp.bfloat16)
-    table = on_chip((SLOTS, PAGES_PER_SLOT), jnp.int32)
-    pos = on_chip((SLOTS,), jnp.int32)
+    slots, pages_per_slot, n_pages = DECODE_GEOMETRY[geometry]
+    pool, layer, scales = _pool(on_chip, d, kv_dtype, n_pages)
+    assert pattn._walks_pages(pool, bool(scales)) == (
+        d == 128 and kv_dtype == "bf16")
+    q = on_chip((slots, N_HEADS, d), jnp.bfloat16)
+    table = on_chip((slots, pages_per_slot), jnp.int32)
+    pos = on_chip((slots,), jnp.int32)
 
     def decode(q, k, v, layer, table, pos, **scales):
         return pattn._paged_decode_call(q, k, v, layer, table, pos,
@@ -764,6 +777,7 @@ def test_nemotron_prefill_compiles(nemotron_shapes, on_chip, bucket):
 # (a group of 6); a ring of 256 positions and a chunk of 512 (the cell's are
 # 512 and 1,024)
 LG_WINDOW, LG_CHUNK, LG_RINGS = 256, 512, 2
+LG_SLOTS, LG_PAGES_PER_SLOT, LG_PAGES = 32, 64, 2048
 LG_HEADS = {"window": 64, "full": 48}
 
 
@@ -788,11 +802,13 @@ def test_window_family_kernels_compile(on_chip, kernel):
                  on_chip((LG_CHUNK, LG_HEADS["window"], d), bf16), chunk_kv,
                  chunk_kv, ring, ring, scalar, scalar)
     else:
-        pool, layer, _ = _pool(on_chip, d, "bf16")
+        # the cell's page geometry: 32 slots x 64 pages of 128 over 2,048
+        pool, layer, _ = _pool(on_chip, d, "bf16", LG_PAGES)
         _compile(lambda q, k, v, layer, table, pos: pattn._paged_decode_call(
             q, k, v, layer, table, pos, PAGE_SIZE, interpret=False),
-            on_chip((SLOTS, LG_HEADS["full"], d), bf16), pool, pool, layer,
-            on_chip((SLOTS, PAGES_PER_SLOT), i32), on_chip((SLOTS,), i32))
+            on_chip((LG_SLOTS, LG_HEADS["full"], d), bf16), pool, pool,
+            layer, on_chip((LG_SLOTS, LG_PAGES_PER_SLOT), i32),
+            on_chip((LG_SLOTS,), i32))
 
 
 def test_window_decode_program_copies_no_ring_or_pool_layer(on_chip,
